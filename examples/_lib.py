@@ -24,9 +24,9 @@ REPO = Path(__file__).resolve().parent.parent
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-from fl4health_tpu.utils.bootstrap import honor_cpu_platform_request  # noqa: E402
+from fl4health_tpu.utils.runtime import configure_compile_cache  # noqa: E402
 
-honor_cpu_platform_request()
+configure_compile_cache()
 
 from fl4health_tpu.clients import engine  # noqa: E402
 from fl4health_tpu.datasets.partitioners import DirichletLabelBasedAllocation  # noqa: E402

@@ -29,8 +29,10 @@ import functools
 import os
 
 if os.environ.get("FL4HEALTH_EXAMPLE_TINY"):
-    # smoke-suite budget: interpret-mode flash at seq 256 is too slow on
-    # one CPU core; keep the code path, shrink the shapes
+    # smoke-suite budget: interpret-mode flash at seq 256 is too slow for
+    # the CPU lane; keep the code path, shrink the shapes (interpret mode
+    # takes any block size — the compiled kernel needs multiples of 128,
+    # which is what config.yaml ships)
     cfg.update(seq_len=32, vocab_size=64, d_model=16, n_heads=2, n_layers=1,
                d_ff=32, block=16, local_steps=2)
 

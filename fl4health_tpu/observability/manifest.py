@@ -31,12 +31,12 @@ def config_hash(config: Mapping[str, Any]) -> str:
 
 def device_facts() -> dict[str, Any]:
     """Backend/device identity from the live (already-initialized) jax
-    runtime — ``utils/tpu_probe.live_device_summary`` (the one home of the
-    "which chip, what peak" policy; its subprocess probes cover the
-    pre-init case) plus the process-level facts only the manifest needs."""
+    runtime — ``utils/runtime.live_device_summary`` (the one home of the
+    "which chip, what peak" policy) plus the process-level facts only the
+    manifest needs."""
     import jax
 
-    from fl4health_tpu.utils.tpu_probe import live_device_summary
+    from fl4health_tpu.utils.runtime import live_device_summary
 
     return {
         "backend": jax.default_backend(),

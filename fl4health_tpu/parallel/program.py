@@ -33,6 +33,7 @@ donation-prone than the single-chip one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Sequence
 
 import jax
@@ -131,6 +132,14 @@ class RoundProgramBuilder:
         if self.mesh is None:
             return 1
         return int(self.mesh.shape[CLIENTS_AXIS])
+
+    @property
+    def spmd_axis_name(self) -> str | None:
+        """``spmd_axis_name`` for the ``vmap`` over clients: with a mesh, a
+        ``shard_map`` inside the vmapped client step (the Pallas kernels'
+        wrapper, see :meth:`jit`) learns that the batched axis is the
+        "clients" mesh axis."""
+        return CLIENTS_AXIS if self.mesh is not None else None
 
     def descriptor(self) -> dict | None:
         """JSON-able mesh + sharding-policy descriptor (manifest /
@@ -256,13 +265,25 @@ class RoundProgramBuilder:
         keys) are unchanged. With a mesh, ``in_shardings``/``out_shardings``
         (trees of ``NamedSharding`` / ``None`` = unconstrained) pin the
         client axis split and keep the state outputs sharded — a round
-        program can never silently gather the cohort onto one chip."""
+        program can never silently gather the cohort onto one chip. The
+        program is TRACED with the mesh in context
+        (``jax.sharding.get_abstract_mesh()``): XLA cannot auto-partition a
+        Mosaic custom call, so a Pallas kernel inside the round
+        (``kernels/flash_attention.py``) wraps itself in a ``shard_map``
+        over the mesh it finds there."""
         donate_argnums = self.donate(*donate)
         if self.mesh is None:
             return jax.jit(fn, donate_argnums=donate_argnums)
+        abstract_mesh = self.mesh.abstract_mesh
+
+        @functools.wraps(fn)
+        def traced_under_mesh(*args, **kwargs):
+            with jax.sharding.use_abstract_mesh(abstract_mesh):
+                return fn(*args, **kwargs)
+
         kwargs: dict[str, Any] = {"donate_argnums": donate_argnums}
         if in_shardings is not None:
             kwargs["in_shardings"] = in_shardings
         if out_shardings is not None:
             kwargs["out_shardings"] = out_shardings
-        return jax.jit(fn, **kwargs)
+        return jax.jit(traced_under_mesh, **kwargs)

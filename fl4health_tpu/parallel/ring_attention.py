@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from fl4health_tpu.parallel.compat import axis_size, shard_map
-
 NEG_INF = -1e30
 
 
@@ -77,7 +75,7 @@ def _ring_body(q_blk, k_blk, v_blk, mask_blk, local_fn, axis_name: str):
     online-softmax algebra one level up), so the driver is the ONE copy of
     the rotation/merge logic for both the dense and the flash local block.
     """
-    ring = axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     perm = [(j, (j + 1) % ring) for j in range(ring)]
 
     # local block first, then n-1 hops: rotate-THEN-compute so no transfer's
@@ -112,12 +110,12 @@ def _ring_body(q_blk, k_blk, v_blk, mask_blk, local_fn, axis_name: str):
 def _ring_shard_map(local_fn, mesh, axis_name, q, k, v, pad_mask):
     qkv_spec = P(None, axis_name, None, None)
     mask_spec = P(None, axis_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_body, local_fn=local_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec,
-        check=False,
+        check_vma=False,
     )
     return fn(q, k, v, pad_mask)
 
@@ -165,7 +163,9 @@ def ring_flash_attention(
     Same contract as ring_self_attention; additionally the local length T/N
     must be divisible by usable block sizes: each block shrinks to
     gcd(T/N, block) and a degenerate shrink (below 8 on a real-sized
-    shard) raises rather than compiling a pathological Mosaic tile.
+    shard) raises rather than compiling a pathological Mosaic tile. On the
+    TPU the shrunk blocks must also pass the kernel's own check (multiples
+    of 128 or the whole local length) — pick T/N divisible by the blocks.
     """
     import math as _math
 

@@ -45,7 +45,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fl4health_tpu.core import pytree as ptu
 from fl4health_tpu.core.types import Params
-from fl4health_tpu.parallel.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,12 +103,12 @@ class ZeroShardedOptimizer:
         def shard_update(g, state, p):
             return self.tx.update(g, state, p)
 
-        updates_flat, new_state = shard_map(
+        updates_flat, new_state = jax.shard_map(
             shard_update,
             mesh=self.mesh,
             in_specs=(vec_spec, state_specs, vec_spec if flat_p is not None else None),
             out_specs=(vec_spec, state_specs),
-            check=False,
+            check_vma=False,
         )(flat_g, opt_state, flat_p)
         return unravel(updates_flat[:size]), new_state
 
@@ -209,13 +208,13 @@ class Zero2ShardedOptimizer:
             )
             return upd_full, new_state
 
-        updates_flat, new_state = shard_map(
+        updates_flat, new_state = jax.shard_map(
             shard_update,
             mesh=self.mesh,
             in_specs=(stack_spec, state_specs,
                       vec_spec if flat_p is not None else None),
             out_specs=(P(), state_specs),
-            check=False,
+            check_vma=False,
         )(flat_stack, opt_state, flat_p)
         return unravel(updates_flat[:size]), new_state
 

@@ -240,9 +240,12 @@ def child_main(spec_path: str) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    if spec.get("jax_cache_dir"):
-        jax.config.update("jax_compilation_cache_dir", spec["jax_cache_dir"])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from fl4health_tpu.utils.runtime import configure_compile_cache
+
+    # the spec's directory is the default (the tests share .jax_test_cache
+    # with the parent); an operator-set JAX_COMPILATION_CACHE_DIR wins
+    configure_compile_cache(spec.get("jax_cache_dir"),
+                            min_compile_time_secs=0.0)
 
     factory = _load_factory(spec["factory_file"], spec["factory_name"])
     sim = factory(spec.get("ckpt_dir"))

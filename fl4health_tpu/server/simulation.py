@@ -79,16 +79,17 @@ EXEC_CHUNKED = "chunked_scan"
 def _donate_argnums(*argnums: int) -> tuple[int, ...]:
     """Buffer donation, gated OFF the CPU backend.
 
-    Verified in this environment (jax 0.4.37, XLA:CPU, persistent
-    compilation cache enabled by tests/conftest.py): an executable compiled
-    WITH input-output aliasing computes correct results on the compile run
-    but WRONG numerics after being reloaded from the persistent cache
-    (A/B: the same program without donate_argnums round-trips exactly).
-    Donation on CPU saves nothing we need — the in-place client-stack
-    update is a device-memory lever — so CPU runs plain and TPU/GPU get
-    the donation. Re-evaluate when the jaxlib cache serializes aliasing
-    correctly. (One implementation: RoundProgramBuilder.donate — the
-    sharded programs route through the same gate.)"""
+    The gate was written against an older jaxlib (XLA:CPU with the
+    persistent compilation cache of tests/conftest.py): an executable
+    compiled WITH input-output aliasing computed correct results on the
+    compile run but WRONG numerics after being reloaded from the
+    persistent cache. Whether jax 0.9.0 still does that is UNVERIFIED —
+    the gate stays until it is re-tested (ROADMAP D10). Donation on CPU
+    saves nothing we need — the in-place client-stack update is a
+    device-memory lever — so CPU runs plain and the TPU gets the donation
+    (exercised on a v5e by chip_smoke.py, with a cold and a warm cache).
+    (One implementation: RoundProgramBuilder.donate — the sharded programs
+    route through the same gate.)"""
     return RoundProgramBuilder.donate(*argnums)
 
 
@@ -1196,6 +1197,7 @@ class FederatedSimulation:
         client_fit, client_eval = self._build_client_fns(collect_telemetry)
         strategy = self.strategy
         baked_sample_counts = self.sample_counts
+        spmd_axis = self._program_builder.spmd_axis_name
 
         # Chaos layer (resilience/faults.py): compiled into the round
         # program so the same seeded plan injects identical faults on both
@@ -1221,7 +1223,8 @@ class FederatedSimulation:
                 mask = mask * fault_plan.participation_factor(
                     round_idx, n_clients
                 )
-            vmapped = jax.vmap(client_fit, in_axes=(0, None, 0, 0, 0))(
+            vmapped = jax.vmap(client_fit, in_axes=(0, None, 0, 0, 0),
+                               spmd_axis_name=spmd_axis)(
                 client_states, payload, batches, mask, val_batches
             )
             if collect_telemetry:
@@ -1293,9 +1296,9 @@ class FederatedSimulation:
 
         def eval_round(server_state, client_states, batches, eval_counts):
             gp = strategy.client_payload(server_state, jnp.zeros((), jnp.int32))
-            new_states, losses, metrics = jax.vmap(client_eval, in_axes=(0, None, 0))(
-                client_states, gp, batches
-            )
+            new_states, losses, metrics = jax.vmap(
+                client_eval, in_axes=(0, None, 0), spmd_axis_name=spmd_axis
+            )(client_states, gp, batches)
             agg_losses = {
                 k: jnp.sum(v * eval_counts) / jnp.maximum(jnp.sum(eval_counts), 1.0)
                 for k, v in losses.items()
@@ -1386,10 +1389,9 @@ class FederatedSimulation:
         donation, so misuse is only visible on device backends.)
 
         This is the SURVEY §7 "keep entire rounds (or multi-round chunks)
-        on-device" lever: over a tunneled/remote TPU each dispatch costs a
-        host round trip, and amortizing it across k rounds removes the
-        per-round dispatch latency from the hot loop. Used by ``fit_chunk``
-        and the bench.
+        on-device" lever: each dispatch costs host work between rounds, and
+        amortizing it across k rounds removes the per-round dispatch latency
+        from the hot loop. Used by ``fit_chunk`` and the bench.
         """
         if self._chunked_fit is not None:
             return self._chunked_fit
@@ -1604,6 +1606,7 @@ class FederatedSimulation:
         same client math (shared ``_build_client_fns`` closures), same
         aggregation arithmetic, same round indices."""
         client_fit, _ = self._build_client_fns(collect_telemetry)
+        spmd_axis = self._program_builder.spmd_axis_name
         _, eval_round = self._build_round_fns(collect_telemetry)
         strategy = self.strategy
         fault_plan = self._fault_plan
@@ -1663,7 +1666,8 @@ class FederatedSimulation:
             ``wave_counts`` (registry occupancy only) pins the per-slot
             sample counts the wave trained under into the pending buffer."""
             payload = strategy.client_payload(server_state, round_idx)
-            vmapped = jax.vmap(client_fit, in_axes=(0, None, 0, 0, 0))(
+            vmapped = jax.vmap(client_fit, in_axes=(0, None, 0, 0, 0),
+                               spmd_axis_name=spmd_axis)(
                 client_states, payload, batches, train_mask, val_batches
             )
             if collect_telemetry:

@@ -1,14 +1,19 @@
 """Loader for the native framing codec (_codec.cpp) with a pure-Python twin.
 
 The shared object is compiled on first use with the system C++ toolchain and
-cached next to the source; environments without a compiler (or with
-FL4HEALTH_NO_NATIVE=1) run the ``PyFraming`` fallback — identical wire
-format, zlib's C crc32, ~same speed for small frames, slower for giant ones.
+cached next to the source as ``_codec.<sha8 of the source>.so``: the name is
+keyed on what was compiled, so a binary left behind by another version of
+the source (a copied tree, a checkout switch) can never be picked up — it
+is simply not the file this source looks for. Environments without a
+compiler (or with FL4HEALTH_NO_NATIVE=1) run the ``PyFraming`` fallback —
+identical wire format, zlib's C crc32, ~same speed for small frames, slower
+for giant ones.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import struct
@@ -28,15 +33,25 @@ _native = None
 _native_tried = False
 
 
-def _compile_native() -> ctypes.CDLL | None:
-    src = Path(__file__).with_name("_codec.cpp")
-    so = Path(__file__).with_name("_codec.so")
-    if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
-        cmd = ["g++", "-O2", "-shared", "-fPIC", "-o", str(so), str(src)]
+def _so_path(src: Path) -> Path:
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:8]
+    return src.with_name(f"_codec.{digest}.so")
+
+
+def _compile_native(src: Path | None = None) -> ctypes.CDLL | None:
+    src = src or Path(__file__).with_name("_codec.cpp")
+    so = _so_path(src)
+    if not so.exists():
+        # build under a private name, then publish atomically: concurrent
+        # first users (test children) must never load a half-written object
+        tmp = so.with_name(f"{so.name}.{os.getpid()}.tmp")
+        cmd = ["g++", "-O2", "-shared", "-fPIC", "-o", str(tmp), str(src)]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            os.replace(tmp, so)
         except (OSError, subprocess.SubprocessError) as e:
             logger.info("native codec build failed (%s); using Python framing", e)
+            tmp.unlink(missing_ok=True)
             return None
     try:
         lib = ctypes.CDLL(str(so))
@@ -59,22 +74,14 @@ def _compile_native() -> ctypes.CDLL | None:
         ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_uint64),
         ctypes.POINTER(ctypes.c_uint16),
     ]
-    # nibble helpers are newer than the framing ABI: a stale cached .so
-    # (rebuilt lazily off mtime) may not export them — fall back per-symbol
-    try:
-        lib.fl4h_pack_nibbles.restype = ctypes.c_int64
-        lib.fl4h_pack_nibbles.argtypes = [
-            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
-            ctypes.c_uint64,
-        ]
-        lib.fl4h_unpack_nibbles.restype = ctypes.c_int64
-        lib.fl4h_unpack_nibbles.argtypes = [
-            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
-            ctypes.c_uint64,
-        ]
-    except AttributeError:
-        logger.info("native codec lacks nibble helpers; int4 packing uses "
-                    "the NumPy fallback")
+    lib.fl4h_pack_nibbles.restype = ctypes.c_int64
+    lib.fl4h_pack_nibbles.argtypes = [
+        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64,
+    ]
+    lib.fl4h_unpack_nibbles.restype = ctypes.c_int64
+    lib.fl4h_unpack_nibbles.argtypes = [
+        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64,
+    ]
     return lib
 
 
@@ -200,7 +207,7 @@ def pack_int4(vals) -> bytes:
 
     v = np.ascontiguousarray(vals, np.int8)
     lib = get_native()
-    if lib is None or not hasattr(lib, "fl4h_pack_nibbles"):
+    if lib is None:
         return _pack_int4_py(v)
     out = ctypes.create_string_buffer((v.size + 1) // 2)
     n = lib.fl4h_pack_nibbles(v.tobytes(), v.size, out, len(out))
@@ -218,7 +225,7 @@ def unpack_int4(packed: bytes, n: int):
             f"int4 payload too short: {len(packed)} bytes for {n} values"
         )
     lib = get_native()
-    if lib is None or not hasattr(lib, "fl4h_unpack_nibbles"):
+    if lib is None:
         return _unpack_int4_py(packed, n)
     out = ctypes.create_string_buffer(max(n, 1))
     rc = lib.fl4h_unpack_nibbles(packed, n, out, len(out))

@@ -24,10 +24,6 @@ REPO = Path(__file__).resolve().parent.parent.parent
 sys.path.insert(0, str(REPO))
 
 import jax
-
-from fl4health_tpu.utils.bootstrap import honor_cpu_platform_request
-
-honor_cpu_platform_request()
 import numpy as np
 import optax
 

@@ -25,9 +25,6 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent.parent
 sys.path.insert(0, str(REPO))
 
-from fl4health_tpu.utils.bootstrap import honor_cpu_platform_request
-
-honor_cpu_platform_request()
 import jax
 import jax.numpy as jnp
 import numpy as np

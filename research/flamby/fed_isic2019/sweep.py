@@ -22,10 +22,6 @@ REPO = Path(__file__).resolve().parent.parent.parent.parent
 sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "research" / "flamby"))
 
-from fl4health_tpu.utils.bootstrap import honor_cpu_platform_request
-
-honor_cpu_platform_request()
-
 import numpy as np
 
 import common

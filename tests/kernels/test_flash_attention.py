@@ -122,3 +122,79 @@ def test_transformer_with_flash_attention_matches_dense():
     fb = ravel_pytree(gf)[0]
     np.testing.assert_allclose(np.asarray(fa), np.asarray(fb), atol=2e-3,
                                rtol=1e-3)
+
+
+class TestCompiledModeBoundary:
+    """What Mosaic cannot compile is refused in Python, with the reason
+    (checked with interpret=False under eval_shape: nothing is compiled, so
+    this runs on the CPU). Interpret mode keeps taking any block size."""
+
+    @staticmethod
+    def _shape_only(t, d, dtype, block):
+        x = jnp.zeros((1, t, 1, d), dtype)
+        return jax.eval_shape(
+            lambda x: flash_attention(x, x, x, block_q=block, block_k=block,
+                                      interpret=False), x)
+
+    def test_block_64_is_rejected_naming_the_lane_width(self):
+        with pytest.raises(ValueError, match="multiple of 128"):
+            self._shape_only(512, 64, jnp.bfloat16, 64)
+
+    def test_whole_sequence_block_and_multiples_of_128_pass(self):
+        # eval_shape reaches pallas_call's own abstract evaluation: the
+        # request got past the Python boundary
+        assert self._shape_only(16, 8, jnp.float32, 16).shape == (1, 16, 1, 8)
+        assert self._shape_only(512, 64, jnp.bfloat16, 256).shape == (
+            1, 512, 1, 64)
+
+    def test_sequence_beyond_the_vmem_limit_is_rejected(self):
+        with pytest.raises(ValueError, match="ring_flash_attention"):
+            self._shape_only(16384, 128, jnp.float32, 128)
+        # the largest pairs that compiled on the chip stay accepted
+        self._shape_only(8192, 128, jnp.float32, 128)
+        self._shape_only(16384, 64, jnp.bfloat16, 128)
+
+
+class TestInterpretDefault:
+    @pytest.mark.parametrize("backend,expected", [("cpu", True),
+                                                  ("tpu", False)])
+    def test_cpu_interprets_tpu_compiles(self, monkeypatch, backend, expected):
+        from fl4health_tpu.kernels._platform import interpret_default
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert interpret_default() is expected
+
+    def test_any_other_backend_raises(self, monkeypatch):
+        from fl4health_tpu.kernels._platform import interpret_default
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            interpret_default()
+
+
+def test_under_a_traced_mesh_the_kernel_call_goes_manual(eight_devices):
+    """XLA cannot auto-partition a Mosaic custom call, so inside a program
+    traced for a mesh (RoundProgramBuilder.jit) the kernel wraps itself in a
+    shard_map, and the engine's vmap over clients (spmd_axis_name) makes the
+    batched axis the "clients" mesh axis — same numbers, one client slice
+    per device."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(eight_devices[:4]), ("clients",))
+    q, k, v = (jnp.stack([x, 0.5 * x, 2.0 * x, -x])
+               for x in _qkv(jax.random.PRNGKey(7), 1, 32, 2, 16))
+
+    def per_client(q, k, v):
+        return flash_attention(q, k, v, block_q=16, block_k=16)
+
+    def cohort(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jax.vmap(per_client, spmd_axis_name="clients")(q, k, v)
+
+    sharded = NamedSharding(mesh, P("clients"))
+    fn = jax.jit(cohort, in_shardings=sharded, out_shardings=sharded)
+    text = fn.lower(q, k, v).as_text()
+    assert "manual_computation" in text and '{"clients"}' in text
+    out = fn(q, k, v)
+    assert len(out.sharding.device_set) == 4
+    _assert_close(out, jax.vmap(per_client)(q, k, v))

@@ -4,6 +4,7 @@ transport — the per-silo latency histograms / failure counters were pinned
 for loopback/coordinator in PR 1 but never driven over the native framing
 path."""
 
+import os
 import struct
 import zlib
 
@@ -76,6 +77,36 @@ class TestNoNativeEnv:
         monkeypatch.setenv("FL4HEALTH_NO_NATIVE", "1")
         assert get_native() is None
         assert isinstance(get_framing(), PyFraming)
+
+
+class TestSourceHashKeyedBuild:
+    """The built object is named after a hash of the source, so a binary
+    from another version of ``_codec.cpp`` (a copied tree) is never the
+    file this source loads — whatever its mtime says."""
+
+    def test_stale_object_next_to_changed_source_is_not_loaded(
+        self, tmp_path, monkeypatch
+    ):
+        import subprocess
+
+        src = tmp_path / "_codec.cpp"
+        src.write_text("// v1\n")
+        stale = native._so_path(src)
+        stale.write_bytes(b"built from v1")
+        src.write_text("// v2\n")
+        # NEWER than the source: the old mtime rule would have loaded it
+        os.utime(stale, (src.stat().st_mtime + 60,) * 2)
+        assert native._so_path(src) != stale
+
+        def no_compiler(*a, **kw):
+            raise OSError("no compiler in this test")
+
+        monkeypatch.setattr(subprocess, "run", no_compiler)
+        # the v2 object does not exist and cannot be built: the answer is
+        # "no native codec", never the v1 binary
+        assert native._compile_native(src) is None
+        assert not native._so_path(src).exists()
+        assert not list(tmp_path.glob("*.tmp"))
 
 
 needs_native = pytest.mark.skipif(
@@ -202,8 +233,8 @@ class TestInt4Packing:
         )
 
         lib = get_native()
-        if lib is None or not hasattr(lib, "fl4h_pack_nibbles"):
-            pytest.skip("native nibble helpers unavailable")
+        if lib is None:
+            pytest.skip("native codec unavailable (no compiler)")
         from fl4health_tpu.transport import native
 
         for n in (0, 1, 2, 7, 100, 101):

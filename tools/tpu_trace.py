@@ -5,7 +5,7 @@ Usage: python tools/tpu_trace.py [timestamp-tag]
 Runs a small (8-client) CIFAR-CNN FedAvg config — the bench headline shape,
 shrunk so the trace stays readable — for 3 compiled rounds under
 ``jax.profiler.trace`` and prints ONE JSON line with the trace location and
-sizes. Called by tools/tpu_watch.py during a capture; SURVEY.md §5 names
+sizes. One process (it holds the chip while it traces); SURVEY.md §5 names
 profiling as a strictly-better-than-reference auxiliary (the reference has
 none beyond wall-clock logging).
 """

@@ -17,7 +17,7 @@ Exit codes follow the bundle-CLI convention: 0 ok, 1 no trace found,
 2 unreadable/corrupt/torn trace (with a diagnostic, never a traceback).
 
 No reference counterpart (SURVEY §5: the reference has no profiling);
-companion to the capture pipeline in tools/tpu_watch.py.
+companion to tools/tpu_trace.py.
 """
 
 from __future__ import annotations
