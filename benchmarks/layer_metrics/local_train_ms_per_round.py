@@ -1,0 +1,10 @@
+"""Client step: device self time per round of the ops under the program's
+``fl_stage::local_train`` scope (the vmapped local-train scan, the Mosaic
+flash calls among them), from the ops' metadata in the raw trace file."""
+
+
+def read(ctx):
+    from benchmarks.harness.spec import load_module
+
+    return load_module("layer_metrics", "stage_common",
+                       ctx["cell"].bench_dir).ms_per_round(ctx, "local_train")

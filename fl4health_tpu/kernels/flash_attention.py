@@ -173,6 +173,7 @@ def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret):
             jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v, mask)
 
 
@@ -285,6 +286,7 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         out_specs=pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tp, dp), q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, mask, do, lse, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
@@ -310,6 +312,7 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
             jax.ShapeDtypeStruct((bh, tp, dp), v.dtype),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, mask, do, lse, delta)
     return dq, dk, dv
 

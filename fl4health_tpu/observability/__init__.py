@@ -469,8 +469,13 @@ class Observability:
     # -- JAX hooks -------------------------------------------------------
     def fence(self, tree: Any) -> tuple[Any, float]:
         """``block_until_ready`` fence returning (tree, wait_seconds); a pure
-        pass-through when disabled — no new syncs on the disabled path."""
-        return synced(tree, enabled=self.enabled and self.sync_device)
+        pass-through when disabled — no new syncs on the disabled path. A
+        fence that does block is a ``device_fence`` span: the part of a
+        round its producer thread spends stopped, waiting for the device."""
+        if not (self.enabled and self.sync_device):
+            return tree, 0.0
+        with self.tracer.span("device_fence"):
+            return synced(tree)
 
     def maybe_profile(self, round_idx: int):
         """``jax.profiler.trace`` context for the chosen round, else no-op."""

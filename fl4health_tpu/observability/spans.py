@@ -14,6 +14,15 @@ Disabled-path contract: a disabled tracer's ``span()`` returns a shared
 no-op context manager — no allocation, no locking, no clock reads — so the
 round hot loop pays nothing when observability is off.
 
+One clock with the device: every span of an enabled tracer is also a
+``jax.profiler.TraceAnnotation`` named ``fl::<name>``, carrying the span's
+identifying args (:data:`ANNOTATION_ARGS`). Under a profiler session the
+span lands on its thread's line of the trace's ``/host:CPU`` plane, on the
+session's clock — the clock of the device planes — so an idle gap of the
+chip is named by the program's own span, not by a Python frame. With no
+session open an annotation is an atomic check. This module is the only
+place the program opens one.
+
 Crash safety: ``export()`` publishes a complete ``{"traceEvents": [...]}``
 envelope atomically at shutdown, but a process that DIES mid-run never
 reaches it. ``stream_to(path)`` additionally appends each event to ``path``
@@ -35,6 +44,13 @@ import time
 from typing import Any
 
 from fl4health_tpu.core.io import atomic_write
+
+# Prefix of every span's name in the profiler's trace, and the span args an
+# annotation carries: the identifiers that spans of one round (or one chunk
+# of rounds) share across threads. Measured values (byte counts, waits) stay
+# in the in-memory record only.
+ANNOTATION_PREFIX = "fl::"
+ANNOTATION_ARGS = ("round", "start_round", "rounds")
 
 
 def load_trace(path: str) -> dict:
@@ -92,7 +108,8 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """One live span; records a complete ("ph": "X") trace event on exit."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "_start_ns", "_depth")
+    __slots__ = ("tracer", "name", "cat", "args", "_start_ns", "_depth",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self.tracer = tracer
@@ -101,6 +118,7 @@ class Span:
         self.args = args
         self._start_ns = 0
         self._depth = 0
+        self._annotation = None
 
     def set(self, **args: Any) -> None:
         """Attach/override args mid-span (e.g. measured byte counts)."""
@@ -108,11 +126,15 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._depth = self.tracer._enter_depth()
+        # the annotation encloses the in-memory record on both sides
+        self._annotation = self.tracer._annotation(self.name, self.args)
+        self._annotation.__enter__()
         self._start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         self.tracer._exit_depth()
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
@@ -147,6 +169,20 @@ class Tracer:
         self._stream = None
         self._stream_path: str | None = None
         self._atexit_registered = False
+        self._annotation_cls = None  # jax.profiler.TraceAnnotation, lazily
+
+    def _annotation(self, name: str, args: dict):
+        """The profiler annotation of one span. ``jax`` is imported on the
+        first span of an enabled tracer, so ``load_trace`` and a disabled
+        tracer need no backend."""
+        if self._annotation_cls is None:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation_cls = TraceAnnotation
+        return self._annotation_cls(
+            ANNOTATION_PREFIX + name,
+            **{k: args[k] for k in ANNOTATION_ARGS if k in args},
+        )
 
     # -- cross-process metadata ------------------------------------------
     @property

@@ -176,23 +176,29 @@ class RoundPrefetcher:
         return sim._stage_cohort_chunk(start_round, k)
 
     def take(self, round_idx: int):
+        """Round ``round_idx``'s batches. The ``prefetch_wait`` span is the
+        time the round waited for them: the rest of the worker's staging on
+        a hit, the whole synchronous construction on a miss."""
         sim = self._sim
         pending, self._pending = self._pending, None
-        if getattr(sim, "_cohort_active", False):
-            if pending is not None and pending[0] == round_idx:
-                return pending[1].result()
-            return sim._stage_cohort_round(round_idx)
-        if pending is None or pending[0] != round_idx:
-            return self._place(sim._round_batches(round_idx))
-        (x_stack, y_stack), plan, batches = pending[1].result()
-        if x_stack is sim._x_train_stack and y_stack is sim._y_train_stack:
-            return batches
-        # data refreshed after staging: same plan, fresh gather
-        from fl4health_tpu.clients import engine
+        hit = pending is not None and pending[0] == round_idx
+        with sim.observability.span("prefetch_wait", round=round_idx,
+                                    hit=hit):
+            if getattr(sim, "_cohort_active", False):
+                if hit:
+                    return pending[1].result()
+                return sim._stage_cohort_round(round_idx)
+            if not hit:
+                return self._place(sim._round_batches(round_idx))
+            (x_stack, y_stack), plan, batches = pending[1].result()
+            if x_stack is sim._x_train_stack and y_stack is sim._y_train_stack:
+                return batches
+            # data refreshed after staging: same plan, fresh gather
+            from fl4health_tpu.clients import engine
 
-        return self._place(engine.gather_batches(
-            sim._x_train_stack, sim._y_train_stack, *plan
-        ))
+            return self._place(engine.gather_batches(
+                sim._x_train_stack, sim._y_train_stack, *plan
+            ))
 
     def close(self) -> None:
         self._pending = None

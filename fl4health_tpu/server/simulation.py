@@ -699,6 +699,8 @@ class FederatedSimulation:
         self._active_execution_mode = EXEC_PIPELINED
         self._consumer: RoundConsumer | None = None
         self._prefetcher: RoundPrefetcher | None = None
+        # fit()'s open `fit_prologue` span (see _fit_loop / _end_prologue)
+        self._prologue_span = None
         # cohort-slot ordering handle: the consumer sets this event once it
         # has scattered round r's rows into the registry, and the producer
         # waits on it before gathering round r+1's state (read-after-write
@@ -2164,9 +2166,22 @@ class FederatedSimulation:
         if sup is not None:
             sup.note_round(rnd)
 
+    def _end_prologue(self) -> None:
+        """Close fit()'s ``fit_prologue`` span if it is still open."""
+        span, self._prologue_span = self._prologue_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
     def _fit_loop(self, n_rounds: int) -> list[RoundRecord]:
         obs = self.observability
         obs.start()  # re-arm after a previous fit()'s shutdown (idempotent)
+        # Everything a fit() call does before its first round — mode
+        # selection, resume, manifest, the `introspect` walk, the driver's
+        # `setup` — is one span. It crosses into the per-round driver, whose
+        # first _run_round closes it; every other driver's ends at the
+        # hand-off below.
+        self._prologue_span = obs.span("fit_prologue", cat="fit")
+        self._prologue_span.__enter__()
         flight = obs.flight_recorder if obs.enabled else None
         if flight is not None:
             flight.clear()  # the black box records THIS run only
@@ -2217,6 +2232,7 @@ class FederatedSimulation:
             # a failed restore (all generations corrupt, config mismatch)
             # still publishes its evidence and disarms the hooks this
             # fit() armed — a CheckpointCorruptError IS a postmortem
+            self._end_prologue()
             self._dump_postmortem(resume_exc)
             obs.shutdown()
             raise
@@ -2315,6 +2331,10 @@ class FederatedSimulation:
                       "num_rounds": n_rounds, "execution_mode": mode,
                       "execution_mode_reason": mode_reason})
         self._sigterm_round = None
+        if (mode == EXEC_CHUNKED or self._cohort_active
+                or (self._async_active and n_rounds >= 1)):
+            # every driver but the dense per-round one (_fit_pipelined)
+            self._end_prologue()
 
         def _note_sigterm() -> None:
             # runs INSIDE the signal handler: the round the run was at
@@ -2354,9 +2374,11 @@ class FederatedSimulation:
             # self-contained postmortem bundle BEFORE obs.shutdown() below
             # clears the trace/event evidence. Never masks the original
             # failure.
+            self._end_prologue()
             self._dump_postmortem(e)
             raise
         finally:
+            self._end_prologue()  # a call that ran no round
             # shutdown (not just export) ALWAYS runs — even when a round
             # raises (ClientFailuresError): it detaches the compile monitor
             # and releases/clears the tracer this run enabled, so a retry in
@@ -2918,6 +2940,7 @@ class FederatedSimulation:
                 "jax_backend_compiles_seconds_total"
             ).value
         device_wait_s = 0.0
+        self._end_prologue()
         t0 = time.time()
         with obs.span("round", round=rnd):
             with obs.span("configure_fit", round=rnd):
@@ -2955,29 +2978,32 @@ class FederatedSimulation:
                 prefetcher.schedule(rnd + 1)
             telemetry = None
             with obs.span("fit_round", round=rnd) as fit_span:
-                if self._telemetry_enabled:
-                    (
-                        self.server_state,
-                        self.client_states,
-                        fit_losses,
-                        fit_metrics,
-                        per_client_fit_losses,
-                        telemetry,
-                    ) = self._fit_round_t(
-                        self.server_state, self.client_states, batches, mask,
-                        jnp.asarray(rnd, jnp.int32), val_batches,
-                    )
-                else:
-                    (
-                        self.server_state,
-                        self.client_states,
-                        fit_losses,
-                        fit_metrics,
-                        per_client_fit_losses,
-                    ) = self._fit_round(
-                        self.server_state, self.client_states, batches, mask,
-                        jnp.asarray(rnd, jnp.int32), val_batches,
-                    )
+                # `dispatch` is what the enqueue costs the host; the fence
+                # below is the wait for the device
+                with obs.span("dispatch", round=rnd, program="fit"):
+                    if self._telemetry_enabled:
+                        (
+                            self.server_state,
+                            self.client_states,
+                            fit_losses,
+                            fit_metrics,
+                            per_client_fit_losses,
+                            telemetry,
+                        ) = self._fit_round_t(
+                            self.server_state, self.client_states, batches,
+                            mask, jnp.asarray(rnd, jnp.int32), val_batches,
+                        )
+                    else:
+                        (
+                            self.server_state,
+                            self.client_states,
+                            fit_losses,
+                            fit_metrics,
+                            per_client_fit_losses,
+                        ) = self._fit_round(
+                            self.server_state, self.client_states, batches,
+                            mask, jnp.asarray(rnd, jnp.int32), val_batches,
+                        )
                 # Honest device time: the dispatch above returns at enqueue;
                 # fence (enabled path ONLY — disabled adds zero syncs) so the
                 # span covers actual device execution, not enqueue latency.
@@ -3012,32 +3038,33 @@ class FederatedSimulation:
                     )
             t1 = time.time()
             with obs.span("eval_round", round=rnd) as eval_span:
-                if self._telemetry_enabled:
-                    (
-                        self.client_states,
-                        eval_losses,
-                        eval_metrics,
-                        per_client_eval_losses,
-                        per_client_eval_metrics,
-                        ev_nonfinite,
-                    ) = self._eval_round_t(
-                        self.server_state, self.client_states, val_batches,
-                        val_counts,
-                    )
-                    telemetry = telemetry.replace(
-                        nonfinite_eval_loss=ev_nonfinite
-                    )
-                else:
-                    (
-                        self.client_states,
-                        eval_losses,
-                        eval_metrics,
-                        per_client_eval_losses,
-                        per_client_eval_metrics,
-                    ) = self._eval_round(
-                        self.server_state, self.client_states, val_batches,
-                        val_counts,
-                    )
+                with obs.span("dispatch", round=rnd, program="eval"):
+                    if self._telemetry_enabled:
+                        (
+                            self.client_states,
+                            eval_losses,
+                            eval_metrics,
+                            per_client_eval_losses,
+                            per_client_eval_metrics,
+                            ev_nonfinite,
+                        ) = self._eval_round_t(
+                            self.server_state, self.client_states,
+                            val_batches, val_counts,
+                        )
+                        telemetry = telemetry.replace(
+                            nonfinite_eval_loss=ev_nonfinite
+                        )
+                    else:
+                        (
+                            self.client_states,
+                            eval_losses,
+                            eval_metrics,
+                            per_client_eval_losses,
+                            per_client_eval_metrics,
+                        ) = self._eval_round(
+                            self.server_state, self.client_states,
+                            val_batches, val_counts,
+                        )
                 self.server_state = self.strategy.update_after_eval(
                     self.server_state, per_client_eval_losses,
                     per_client_eval_metrics, mask
@@ -3052,10 +3079,12 @@ class FederatedSimulation:
                     # value-identical to the val-eval one (pull is
                     # idempotent) but must be re-assigned: the input stack
                     # was donated.
-                    ev = (self._eval_round_t if self._telemetry_enabled
-                          else self._eval_round)(
-                        self.server_state, self.client_states, test[0], test[1]
-                    )
+                    with obs.span("dispatch", round=rnd, program="test"):
+                        ev = (self._eval_round_t if self._telemetry_enabled
+                              else self._eval_round)(
+                            self.server_state, self.client_states,
+                            test[0], test[1]
+                        )
                     self.client_states, test_losses, test_metrics = ev[:3]
                     # fence the test dispatch too — its device time belongs
                     # in device_wait_s, not misattributed to host_s
@@ -3156,11 +3185,17 @@ class FederatedSimulation:
                 self._finish_round(work)
 
     def _finish_round(self, work: "_RoundWork") -> None:
-        """Consumer half of one round: ONE fused device->host transfer of
-        the results tree, then failure-policy screen, checkpoint decisions,
-        RoundRecord construction and reporter I/O — all while the device
-        executes later rounds. Runs on the RoundConsumer thread in
-        submission (= round) order."""
+        """Consumer half of one round, as one ``epilogue`` span: from the
+        fused device->host transfer to the reporters."""
+        with self.observability.span("epilogue", round=work.round):
+            self._round_epilogue(work)
+
+    def _round_epilogue(self, work: "_RoundWork") -> None:
+        """ONE fused device->host transfer of the results tree, then
+        failure-policy screen, checkpoint decisions, RoundRecord
+        construction and reporter I/O — all while the device executes later
+        rounds. Runs on the RoundConsumer thread in submission (= round)
+        order."""
         obs = self.observability
         rnd = work.round
         # the single fused pull this round pays (replaces ~8 scattered

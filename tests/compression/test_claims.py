@@ -55,11 +55,15 @@ class TestAccuracyVsBytes:
         import tempfile
 
         from fl4health_tpu.observability import Observability
+        from fl4health_tpu.observability.registry import MetricsRegistry
 
         d = tempfile.mkdtemp()
+        # a private registry: the process-wide one still holds the round
+        # events of whichever test ran a sim without an output_dir before
         sim = make_cifar_sim(
             compression=CLAIM_CFG,
-            observability=Observability(enabled=True, output_dir=d),
+            observability=Observability(enabled=True, output_dir=d,
+                                        registry=MetricsRegistry()),
         )
         sim.fit(2)
         rounds = [

@@ -274,8 +274,12 @@ class TestTelemetryUnderPrecision:
 
     def test_round_events_carry_dtype_and_skips(self, tmp_path):
         from fl4health_tpu.observability import Observability
+        from fl4health_tpu.observability.registry import MetricsRegistry
 
-        obs = Observability(enabled=True, output_dir=str(tmp_path))
+        # a private registry: the process-wide one still holds the round
+        # events of whichever test ran a sim without an output_dir before
+        obs = Observability(enabled=True, output_dir=str(tmp_path),
+                            registry=MetricsRegistry())
         sim = make_sim(precision=PrecisionConfig("fp16"), observability=obs,
                        execution_mode="chunked")
         sim.fit(2)

@@ -1,9 +1,9 @@
-"""tools/trace_top_ops.py: Chrome-trace summarizer + fl_stage durations.
+"""tools/trace_top_ops.py: Chrome-trace summarizer.
 
 Pins the loader's exit-2 contract (missing / corrupt / torn traces get a
-diagnostic, never a traceback), the gzip round-trip, and the
-``stage_durations`` aggregation that roofline_report folds into the
-ledger as measured device time.
+diagnostic, never a traceback) and the gzip round-trip. Per-stage device
+time is read from the raw ``.xplane.pb`` (tests/observability/
+test_trace_readers.py, tests/tools/test_roofline_report.py).
 """
 
 import gzip
@@ -90,22 +90,6 @@ class TestLoad:
             trace_top_ops.load(str(path))
 
 
-class TestStageDurations:
-    def test_aggregates_by_fl_stage_marker(self, tmp_path):
-        durs = trace_top_ops.stage_durations(_trace())
-        # two local_train complete events (1200 + 300); the fusion's
-        # stage comes from args.long_name; copy.1 (unstaged) and the
-        # counter event (no dur) are excluded
-        assert durs == {"local_train": 1500.0, "server_update": 500.0}
-
-    def test_empty_for_unstaged_capture(self):
-        trace = {"traceEvents": [
-            {"ph": "X", "pid": 1, "tid": 2, "ts": 0, "dur": 10,
-             "name": "fusion.1"},
-        ]}
-        assert trace_top_ops.stage_durations(trace) == {}
-
-
 class TestSummarize:
     def test_lane_totals_and_top_ops(self):
         lines = trace_top_ops.summarize(_trace(), top=2)
@@ -121,10 +105,10 @@ class TestCli:
         return subprocess.run([sys.executable, TOOL, *argv],
                               capture_output=True, text=True)
 
-    def test_ok_trace_prints_stage_section(self, tmp_path):
+    def test_ok_trace_prints_lane_summary(self, tmp_path):
         out = self._run(_write_gz(tmp_path))
         assert out.returncode == 0
-        assert "== fl_stage device time ==" in out.stdout
+        assert "== /device:TPU:0 / XLA Ops:" in out.stdout
         assert "local_train" in out.stdout
 
     def test_missing_path_exits_2(self, tmp_path):

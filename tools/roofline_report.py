@@ -12,9 +12,14 @@ fusion headroom a hand-fused kernel could at most recover — ranked most
 headroom first.
 
 Analytic numbers work on any box (the attribution is a build-time property
-of the compiled program — no device run needed). When a real XProf capture
-exists, ``--trace`` adds measured per-stage device time by grouping trace
-ops on the ``fl_stage::`` marker (tools/trace_top_ops.py's summarizer).
+of the compiled program — no device run needed). When a real profiler
+capture exists, ``--trace`` adds measured per-stage device time. On a TPU
+the ``fl_stage::`` scope is not in an op event's name (that is its HLO
+text) but in the event metadata's ``tf_op`` stat of the raw ``.xplane.pb``;
+the benchmark's reader (``benchmarks/layer_metrics/stage_common.py`` over
+``benchmarks/xplane_meta.py``) sums each op's self time under the innermost
+scope of its name stack, so ``--trace`` takes the profile directory
+(``jax.profiler.start_trace``'s) or the ``.xplane.pb`` itself.
 
 Honesty rules (the repo-wide None-never-0.0 discipline):
 
@@ -26,7 +31,7 @@ Honesty rules (the repo-wide None-never-0.0 discipline):
   page.
 
     python tools/roofline_report.py artifacts/obs/metrics.jsonl
-    python tools/roofline_report.py metrics.jsonl --trace vm.trace.json.gz
+    python tools/roofline_report.py metrics.jsonl --trace artifacts/obs/xprof
     python tools/roofline_report.py metrics.jsonl --json
 
 Exit codes: 0 ok, 1 no stage events in the log (attribution off or
@@ -49,7 +54,6 @@ if _TOOLS not in sys.path:
     sys.path.insert(0, _TOOLS)
 
 import perf_report  # noqa: E402  (the shared table machinery)
-import trace_top_ops  # noqa: E402  (measured per-stage device time)
 
 
 def rank_stages(stages: list[dict]) -> list[dict]:
@@ -64,15 +68,31 @@ def rank_stages(stages: list[dict]) -> list[dict]:
     return sorted(stages, key=key)
 
 
-def attach_measured(stages: list[dict], trace: dict) -> list[dict]:
-    """Fold measured per-stage device time (us -> ms) into the ledger
-    rows. Stages absent from the capture keep no ``measured_ms`` field —
-    '-' in the table, absent in ``--json`` (never a fake zero)."""
-    durations = trace_top_ops.stage_durations(trace)
+def measured_stage_ms(path: str) -> dict[str, float]:
+    """stage -> device self milliseconds over the whole capture at ``path``
+    (a profile directory or an ``.xplane.pb``), by the benchmark's reader.
+    Raises ``OSError``/``ValueError``/``RuntimeError`` on a capture that is
+    missing, torn or not an xplane."""
+    from benchmarks import trace_reduce
+    from benchmarks.harness.spec import load_module
+
+    stage = load_module("layer_metrics", "stage_common")
+    xplane = (path if path.endswith(".xplane.pb")
+              else trace_reduce.find_xplane(path))
+    seconds = stage.by_stage(trace_reduce.load(xplane),
+                             stage.read_tf_ops(xplane))
+    return {k: v * 1e3 for k, v in seconds.items()}
+
+
+def attach_measured(stages: list[dict],
+                    measured: dict[str, float]) -> list[dict]:
+    """Fold measured per-stage device time (ms) into the ledger rows.
+    Stages absent from the capture keep no ``measured_ms`` field — '-' in
+    the table, absent in ``--json`` (never a fake zero)."""
     out = []
     for rec in stages:
-        if rec.get("stage") in durations:
-            rec = {**rec, "measured_ms": durations[rec["stage"]] / 1e3}
+        if rec.get("stage") in measured:
+            rec = {**rec, "measured_ms": measured[rec["stage"]]}
         out.append(rec)
     return out
 
@@ -116,8 +136,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("log", help="path to metrics.jsonl (or a bundle's "
                                 "events.tail.jsonl)")
     ap.add_argument("--trace", metavar="PATH",
-                    help="Chrome/XProf trace (.json or .json.gz) to fold "
-                         "measured per-stage device time into the ledger")
+                    help="profile directory or .xplane.pb of a TPU capture "
+                         "to fold measured per-stage device time into the "
+                         "ledger")
     ap.add_argument("--json", action="store_true",
                     help="emit the ranked ledger as JSON instead of a table")
     args = ap.parse_args(argv)
@@ -137,11 +158,11 @@ def main(argv: list[str] | None = None) -> int:
     measured = False
     if args.trace:
         try:
-            trace = trace_top_ops.load(args.trace)
-        except trace_top_ops.TraceError as e:
-            print(f"roofline_report: {e}", file=sys.stderr)
+            stages = attach_measured(stages, measured_stage_ms(args.trace))
+        except (OSError, ValueError, RuntimeError) as e:
+            print(f"roofline_report: cannot read trace {args.trace}: {e}",
+                  file=sys.stderr)
             return 2
-        stages = attach_measured(stages, trace)
         measured = any("measured_ms" in rec for rec in stages)
     ranked = rank_stages(stages)
     if args.json:

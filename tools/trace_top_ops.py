@@ -8,10 +8,9 @@ box where the tensorboard profile plugin can't be installed.
 
     python tools/trace_top_ops.py [trace.json.gz] [--top 15]
 
-Also exports :func:`stage_durations` — measured per-stage device time by
-grouping trace ops on the ``fl_stage::`` scope marker (observability/
-stages.py) — which ``tools/roofline_report.py`` consumes to put real
-milliseconds next to the analytic roofline ledger.
+Per-stage device time is not here: a TPU trace names its ops by HLO text,
+and the ``fl_stage::`` scope sits in the raw ``.xplane.pb``'s event metadata
+— ``tools/roofline_report.py --trace`` reads it from there.
 
 Exit codes follow the bundle-CLI convention: 0 ok, 1 no trace found,
 2 unreadable/corrupt/torn trace (with a diagnostic, never a traceback).
@@ -30,10 +29,6 @@ import sys
 from collections import defaultdict
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:  # script invocation: tools/ is sys.path[0]
-    sys.path.insert(0, REPO)
-
-from fl4health_tpu.observability.stages import stage_of  # noqa: E402
 
 
 class TraceError(Exception):
@@ -68,24 +63,6 @@ def load(path: str) -> dict:
             f"{type(trace).__name__}, expected a Chrome-trace object"
         )
     return trace
-
-
-def stage_durations(trace: dict) -> dict[str, float]:
-    """Aggregate complete-event (``ph == "X"``) durations (us) by the
-    ``fl_stage::`` stage on the op name — XLA propagates the named-scope
-    path into trace op names, so this is measured device time per spine
-    stage. Ops outside any stage are excluded (whole-lane totals live in
-    :func:`summarize`); empty dict when the capture has no staged ops."""
-    out: dict[str, float] = defaultdict(float)
-    for e in trace.get("traceEvents", []):
-        if e.get("ph") != "X" or "dur" not in e:
-            continue
-        name = e.get("name", "")
-        args = e.get("args") or {}
-        stage = stage_of(name) or stage_of(str(args.get("long_name", "")))
-        if stage:
-            out[stage] += float(e["dur"])
-    return dict(out)
 
 
 def summarize(trace: dict, top: int = 15) -> list[str]:
@@ -145,11 +122,6 @@ def main() -> int:
     print(f"# {path}")
     for line in summarize(trace, top):
         print(line)
-    stages = stage_durations(trace)
-    if stages:
-        print("== fl_stage device time ==")
-        for name, dur in sorted(stages.items(), key=lambda kv: -kv[1]):
-            print(f"  {dur / 1e3:9.2f} ms  {name}")
     return 0
 
 
