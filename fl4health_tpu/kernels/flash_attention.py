@@ -16,13 +16,28 @@ transformer and the ring-attention local block need). The sequence axis
 beyond one device is ring attention's job; this kernel is the fast local
 block.
 
+What each dtype gets (decided at trace time from the operands' dtype, no
+flag): bf16 Q, K, V, dO reach the MXU as bf16, and the float32 tiles the
+kernel computes (p, dS) are narrowed to bf16 just before their dots; float32
+operands stay float32 at ``Precision.HIGHEST``; anything else is widened to
+float32 first. Every dot accumulates in float32 (``preferred_element_type``),
+and the scores, ``scale``, the online-softmax state (``m``, ``l``, ``corr``,
+``lse``, ``delta``), ``exp`` and the accumulators are float32 whatever the
+operands are: the v5e has no bf16 VPU/EUP. The state is lane-dense: Mosaic
+keeps a ``[Bq, 1]`` value replicated across the 128 lanes, ``l`` is carried as
+128 per-lane partial sums that cross the lanes once after the last block, and
+dK/dV holds its score tile transposed so ``lse`` and ``delta`` meet it as
+rows. The loop over the streamed axis is unrolled (``_block_loop``): on the
+v5e its trip boundary, not a tile's arithmetic, set the pace.
+
 What Mosaic accepts (compiled on a TPU v5e, jax 0.9.0 / libtpu 0.0.34; both
 limits are checked in Python by ``_check_compilable`` so a request outside
 them fails with the reason, not with a compiler dump):
 
 - ``block_q`` / ``block_k`` are multiples of 128, or one block spans the
   whole padded sequence. The key-padding mask travels as ``[BH, 1, T]`` and
-  is sliced along the 128-wide lane axis at ``block_k`` offsets; 64 is not
+  is sliced along the 128-wide lane axis at ``block_k`` offsets (in dK/dV
+  ``lse`` and ``delta`` likewise, at ``block_q`` offsets); 64 is not
   lane-aligned and is rejected. Compiled and checked against dense
   attention: 128, 256, 512, 128/256, and whole-sequence blocks.
 - K/V (forward, dQ) and Q/dO (dK/dV) for one (batch, head) stay whole in
@@ -84,17 +99,34 @@ def _check_compilable(block_q: int, block_k: int, tp: int, dp: int,
 
 
 def _dot_precision(dtype) -> jax.lax.Precision:
-    """f32 inputs get faithful f32 dots; anything narrower keeps the MXU's
-    native fast path.
+    """f32 operands get faithful f32 dots; bf16 operands take the MXU's one
+    native pass.
 
     Measured on TPU v5e (KERNELS r5): with the default precision Mosaic
     lowers an f32 dot to a single bf16 MXU pass, costing ~1.4e-3 abs error
     against the dense f32 attention the kernel must be a drop-in for.
-    HIGHEST selects the multi-pass f32 algorithm for f32 operands only —
-    the bf16 training path (the perf headline) is unaffected.
-    """
+    HIGHEST selects the multi-pass f32 algorithm for f32 operands only.
+    bf16 operands are never widened: a widened operand at the default
+    precision is narrowed back for the same single pass, so the cast buys no
+    precision (PR 26: the same 1.78e-3 / 2.34e-3 worst error on out / dQ
+    either way on the chip)."""
     return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
+
+
+def _mxu_operand(x: jax.Array) -> jax.Array:
+    """bf16 and f32 reach the MXU as they arrive; anything else (f16, which
+    Mosaic cannot load on a v5e: interpret mode only) is widened to f32."""
+    return x if x.dtype in (jnp.bfloat16, jnp.float32) else x.astype(jnp.float32)
+
+
+def _dot(a, b, contract, precision):
+    """a . b over ``contract`` = (axis of a, axis of b), f32 accumulation.
+    ``a`` is a float32 tile computed in the kernel (p, dS) or an operand; it
+    takes ``b``'s dtype, so a bf16 block meets a bf16 tile."""
+    return jax.lax.dot_general(
+        a.astype(b.dtype), b, (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
 
 
 def _pad_axis(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
@@ -106,6 +138,42 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+# Score tiles of 128 x 128 that one loop trip handles as straight-line code.
+# Measured on the v5e (PR 26, bf16, D 64, blocks 128/128): the trip boundary,
+# not the tile's arithmetic, set the pace. Forward at T 2,048, ms a call by
+# blocks a trip: 40.3 (1), 23.5 (2), 20.4 (4), 14.3 (8), 10.6 (all 16, no loop
+# left); at T 8,192: 151 (1), 33.3 (16), 27.2 (32), 23.3 (all 64). Nothing
+# longer was measured, so longer axes loop over trips of 64.
+_TILES_PER_TRIP = 64
+
+
+def _block_loop(n_blocks: int, block: int, resident: int, step, carry):
+    """``carry = step(pl.ds(j * block, block), carry)`` for j in
+    range(n_blocks), each step a [resident, block] score tile (or its
+    transpose). Consecutive blocks worth up to _TILES_PER_TRIP tiles of
+    128 x 128 are one straight-line body, so the scheduler can start a
+    block's first dot while the one before is still in its softmax; a longer
+    axis loops over such trips and finishes with the remainder, so the code
+    stays bounded at any length and any block size."""
+    per_trip = max(1, _TILES_PER_TRIP
+                   // (pl.cdiv(resident, _LANE) * pl.cdiv(block, _LANE)))
+
+    def run(first, count, carry):
+        for u in range(count):
+            start = (first + u) * block
+            if not isinstance(start, int):
+                start = pl.multiple_of(start, block)
+            carry = step(pl.ds(start, block), carry)
+        return carry
+
+    if n_blocks <= per_trip:
+        return run(0, n_blocks, carry)
+    trips, rest = divmod(n_blocks, per_trip)
+    carry = jax.lax.fori_loop(
+        0, trips, lambda t, c: run(t * per_trip, per_trip, c), carry)
+    return run(trips * per_trip, rest, carry)
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
@@ -115,37 +183,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
     # Mosaic layout contract (learned on real silicon, KERNELS r5): every
     # block's trailing two dims must be (8k, 128k) or equal the array dims.
     # Row-per-(batch,head) vectors therefore travel as mask [BH, 1, Tp] and
-    # lse/delta [BH, Tp, 1], and all in-kernel state stays 2-D.
-    q = q_ref[0].astype(jnp.float32) * scale  # [Bq, Dp]
-    bq = q.shape[0]
-    n_kblocks = k_ref.shape[1] // block_k
+    # lse/delta [BH, Tp, 1] (dK/dV takes them the other way round: see
+    # _bwd_call), and all in-kernel state stays 2-D. Mosaic keeps a
+    # [Bq, 1] value replicated along the lanes, so m and corr meet the score
+    # tile without a broadcast; l is kept as 128 per-lane partial sums and
+    # crosses the lanes once, after the last block.
+    q = _mxu_operand(q_ref[0])  # [Bq, Dp]
+    bq, dp = q.shape
+    lanes = _LANE if block_k % _LANE == 0 else block_k
 
-    def body(j, carry):
-        m, l, acc = carry  # m,l: [Bq, 1]
-        kb = k_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        mk = mask_ref[0, :, pl.dslice(j * block_k, block_k)]  # [1, Bk]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )  # [Bq, Bk]
-        s = jnp.where(mk > 0, s, NEG_INF)
+    def step(ks, carry):
+        m, l, acc = carry  # m: [Bq, 1], l: [Bq, lanes], acc: [Bq, Dp], f32
+        kb = _mxu_operand(k_ref[0, ks, :])
+        vb = _mxu_operand(v_ref[0, ks, :])
+        keep = mask_ref[0, :, ks] > 0  # [1, Bk]
+        s = _dot(q, kb, (1, 1), precision) * scale  # [Bq, Bk]
+        s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mk > 0, p, 0.0)
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )
+        l = l * corr + p[:, :lanes]
+        for c in range(lanes, block_k, lanes):
+            l = l + p[:, c:c + lanes]
+        acc = acc * corr + _dot(p, vb, (1, 0), precision)
         return m_new, l, acc
 
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, q.shape[1]), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_kblocks, body, (m0, l0, a0))
-    denom = jnp.maximum(l, 1e-20)
+    m, l, acc = _block_loop(
+        k_ref.shape[1] // block_k, block_k, bq, step,
+        (jnp.full((bq, 1), NEG_INF, jnp.float32),
+         jnp.zeros((bq, lanes), jnp.float32),
+         jnp.zeros((bq, dp), jnp.float32)))
+    denom = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
     o_ref[0] = (acc / denom).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(denom)  # [Bq, 1]
 
@@ -183,77 +251,57 @@ def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, *, block_k, scale, precision):
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = _mxu_operand(q_ref[0])
+    do = _mxu_operand(do_ref[0])
     lse = lse_ref[0]  # [Bq, 1]
     delta = delta_ref[0]  # [Bq, 1] = rowsum(dO * O)
-    n_kblocks = k_ref.shape[1] // block_k
 
-    def body(j, dq):
-        kb = k_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        mk = mask_ref[0, :, pl.dslice(j * block_k, block_k)]  # [1, Bk]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        ) * scale
-        p = jnp.exp(s - lse)
-        p = jnp.where(mk > 0, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )
+    def step(ks, dq):
+        kb = _mxu_operand(k_ref[0, ks, :])
+        vb = _mxu_operand(v_ref[0, ks, :])
+        keep = mask_ref[0, :, ks] > 0  # [1, Bk]
+        s = _dot(q, kb, (1, 1), precision) * scale
+        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
+        dp = _dot(do, vb, (1, 1), precision)
+        # dS = p * (dP - delta) * scale; the scale waits for the sum
+        return dq + _dot(p * (dp - delta), kb, (1, 0), precision)
 
-    dq = jax.lax.fori_loop(
-        0, n_kblocks, body, jnp.zeros_like(q)
-    )
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = _block_loop(k_ref.shape[1] // block_k, block_k, q.shape[0], step,
+                     jnp.zeros(q.shape, jnp.float32))
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, block_q, scale, precision):
-    kb = k_ref[0].astype(jnp.float32)  # [Bk, Dp]
-    vb = v_ref[0].astype(jnp.float32)
-    mk = mask_ref[0]  # [1, Bk]
-    n_qblocks = q_ref.shape[1] // block_q
+    # The score tile is held transposed, [Bk, Bq]: dV += P^T dO and
+    # dK += dS^T Q are then plain row-major dots, and lse / delta meet the
+    # tile as rows [1, Bq] (a sublane broadcast) where the [Bq, Bk] form
+    # paid a [128, 128] transpose and 32 lane-broadcast permutes a tile
+    # (18.4 -> 14.3 ms a call on the v5e, PR 26).
+    kb = _mxu_operand(k_ref[0])  # [Bk, Dp]
+    vb = _mxu_operand(v_ref[0])
 
-    def body(i, carry):
+    def step(qs, carry):
         dk, dv = carry
-        q = q_ref[0, pl.dslice(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.dslice(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.dslice(i * block_q, block_q), :]  # [Bq, 1]
-        delta = delta_ref[0, pl.dslice(i * block_q, block_q), :]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        ) * scale
-        p = jnp.exp(s - lse)  # [Bq, Bk]
-        p = jnp.where(mk > 0, p, 0.0)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )
-        ds = p * (dp - delta) * scale  # [Bq, Bk]
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=precision
-        )
+        q = _mxu_operand(q_ref[0, qs, :])
+        do = _mxu_operand(do_ref[0, qs, :])
+        lse = lse_ref[0, :, qs]  # [1, Bq]
+        delta = delta_ref[0, :, qs]
+        pt = jnp.exp(_dot(kb, q, (1, 1), precision) * scale - lse)
+        dpt = _dot(vb, do, (1, 1), precision)
+        dv = dv + _dot(pt, do, (1, 0), precision)
+        dk = dk + _dot(pt * (dpt - delta), q, (1, 0), precision)
         return dk, dv
 
-    dk0 = jnp.zeros_like(kb)
-    dv0 = jnp.zeros_like(vb)
-    dk, dv = jax.lax.fori_loop(0, n_qblocks, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    zeros = jnp.zeros(kb.shape, jnp.float32)
+    dk, dv = _block_loop(q_ref.shape[1] // block_q, block_q, kb.shape[0],
+                         step, (zeros, zeros))
+    # A row of dK / dV depends on its own key alone, so the key-padding mask
+    # is one select on the sums: a padded key's row is zero, as when every
+    # p of that key was zeroed in the loop.
+    keep = keep_ref[0] > 0  # [Bk, 1]
+    dk_ref[0] = jnp.where(keep, dk * scale, 0.0).astype(dk_ref.dtype)
+    dv_ref[0] = jnp.where(keep, dv, 0.0).astype(dv_ref.dtype)
 
 
 def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
@@ -289,6 +337,9 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         name="flash_dq",
     )(q, k, v, mask, do, lse, delta)
 
+    # dK/dV sees queries along the lanes: lse and delta as rows [BH, 1, Tp]
+    # (sliced at block_q offsets like the forward's mask), the key mask as a
+    # column [BH, Tp, 1]. Same seven operands.
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
                                    scale=scale, precision=prec)
     dk, dv = pl.pallas_call(
@@ -298,10 +349,10 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
             pl.BlockSpec((1, tp, dp), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b, j: (b, 0, j)),
+            pl.BlockSpec((1, block_k, 1), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, tp, dp), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, tp, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, tp, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, tp), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, tp), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
@@ -313,7 +364,8 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         ],
         interpret=interpret,
         name="flash_dkv",
-    )(q, k, v, mask, do, lse, delta)
+    )(q, k, v, mask.reshape(bh, tp, 1), do, lse.reshape(bh, 1, tp),
+      delta.reshape(bh, 1, tp))
     return dq, dk, dv
 
 

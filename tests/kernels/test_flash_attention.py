@@ -89,6 +89,140 @@ def test_jit_and_vmap_compose():
     _assert_close(out[1], _dense_attention(q * 0.5, k, v))
 
 
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in (value if isinstance(value, (list, tuple)) else [value]):
+            if hasattr(item, "jaxpr") and hasattr(item.jaxpr, "eqns"):
+                yield item.jaxpr
+            elif hasattr(item, "eqns"):
+                yield item
+
+
+def _eqns_named(jaxpr, primitive):
+    """Every equation of ``primitive`` in ``jaxpr``, nested calls included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+        for sub in _sub_jaxprs(eqn):
+            found.extend(_eqns_named(sub, primitive))
+    return found
+
+
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.bfloat16, jax.lax.Precision.DEFAULT),
+    (jnp.float32, jax.lax.Precision.HIGHEST),
+])
+def test_dots_take_the_operands_dtype_and_accumulate_in_float32(dtype,
+                                                                precision):
+    """What proves the native-operand path engages: in the three kernels'
+    jaxprs every dot takes its operands in the dtype they arrived in (bf16
+    straight to the MXU, f32 at HIGHEST) and accumulates in float32."""
+    x = jnp.ones((1, 256, 2, 64), dtype)
+    mask = jnp.ones((1, 256), jnp.float32)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, mask, block_q=128, block_k=128)
+        return jnp.sum(out.astype(jnp.float32))
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    kernels = {eqn.params["name"]: eqn.params["jaxpr"]
+               for eqn in _eqns_named(traced.jaxpr, "pallas_call")}
+    assert sorted(kernels) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    # two blocks of the streamed axis, unrolled: dots a block x 2
+    n_dots = {"flash_fwd": 2 * 2, "flash_dq": 3 * 2, "flash_dkv": 4 * 2}
+    for name, kernel in kernels.items():
+        dots = _eqns_named(kernel, "dot_general")
+        assert len(dots) == n_dots[name], (name, len(dots))
+        for dot in dots:
+            assert [v.aval.dtype for v in dot.invars] == [dtype, dtype], name
+            assert dot.params["preferred_element_type"] == jnp.float32, name
+            assert set(dot.params["precision"]) == {precision}, name
+        # nothing of the softmax arithmetic runs below float32
+        narrow_math = [e.primitive.name for e in kernel.eqns
+                       if e.primitive.name in ("exp", "log", "max", "reduce_max",
+                                               "reduce_sum", "sub")
+                       and any(v.aval.dtype != jnp.float32 for v in e.outvars)]
+        assert not narrow_math, (name, narrow_math)
+
+
+_BF16_EPS = float(jnp.finfo(jnp.bfloat16).eps)  # 2**-7
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256)])
+def test_bf16_forward_and_gradients_match_float32_dense(bq, bk, d, masked):
+    """bf16 operands, float32 softmax and accumulators, against dense
+    attention computed in float32 from the same bf16 inputs. Tolerances from
+    bf16's epsilon (2**-7, half of it a rounding): the output is rounded once
+    to bf16 and p once before its dot, so |out - ref| <= eps * max|ref|; a
+    gradient adds the roundings of dO's product with p and of dS before its
+    dot to its own, so 2 * eps * max|ref|. A wrong mask or block offset moves
+    these by tenths."""
+    t = 256
+    keys = jax.random.split(jax.random.PRNGKey(d + bq), 4)
+    q, k, v, tgt = (jax.random.normal(kk, (1, t, 2, d)).astype(jnp.bfloat16)
+                    for kk in keys)
+    mask = ((jnp.arange(t)[None, :] < 150).astype(jnp.float32) if masked
+            else None)
+    w = (mask if masked else jnp.ones((1, t)))[..., None, None]
+
+    def loss(attend):
+        def f(q, k, v):
+            out = attend(q, k, v).astype(jnp.float32)
+            return jnp.sum(jnp.square((out - tgt.astype(jnp.float32)) * w)), out
+        return f
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, block_q=bq, block_k=bk)
+
+    def dense(q, k, v):
+        return _dense_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                pad_mask=mask)
+
+    (_, out_f), g_f = jax.value_and_grad(loss(flash), argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    (_, out_d), g_d = jax.value_and_grad(loss(dense), argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    assert out_f.dtype == jnp.float32 and g_f[0].dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(out_f * w), np.asarray(out_d * w), rtol=0,
+        atol=_BF16_EPS * float(jnp.max(jnp.abs(out_d * w))))
+    for got, ref in zip(g_f, g_d):
+        np.testing.assert_allclose(
+            np.asarray(got.astype(jnp.float32)), np.asarray(ref), rtol=0,
+            atol=2 * _BF16_EPS * float(jnp.max(jnp.abs(ref))))
+
+
+def test_long_axis_loops_over_bounded_trips():
+    """More key blocks than one trip unrolls (and a remainder that does not
+    fill a trip): the kernels loop over trips and still match dense."""
+    from fl4health_tpu.kernels.flash_attention import _TILES_PER_TRIP
+
+    t, blk = 8 * (_TILES_PER_TRIP * 2 + 3), 8
+    q, k, v = _qkv(jax.random.PRNGKey(8), 1, t, 1, 16)
+    mask = (jnp.arange(t)[None, :] < t - 20).astype(jnp.float32)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.square(
+            attend(q, k, v) * mask[..., None, None]))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, block_q=blk, block_k=blk)
+
+    def dense(q, k, v):
+        return _dense_attention(q, k, v, pad_mask=mask)
+
+    traced = jax.make_jaxpr(flash)(q, k, v)
+    assert _eqns_named(_eqns_named(traced.jaxpr, "pallas_call")[0]
+                       .params["jaxpr"], "scan")
+    _assert_close(flash(q, k, v)[0, :t - 20], dense(q, k, v)[0, :t - 20])
+    for got, ref in zip(jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)):
+        _assert_close(got, ref, atol=5e-4)
+
+
 @pytest.mark.slow
 def test_transformer_with_flash_attention_matches_dense():
     # the kernel as the transformer's attention core (models/transformer.py
