@@ -19,6 +19,7 @@ axis, so N simulated clients train as one SPMD program.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Any, Callable
 
@@ -28,7 +29,7 @@ import numpy as np
 import optax
 from flax import struct
 
-from fl4health_tpu.core.pytree import tree_nbytes
+from fl4health_tpu.core.pytree import merge_trees, split_by_path, tree_nbytes
 from fl4health_tpu.core.types import Params, PRNGKey, PyTree
 from fl4health_tpu.losses.containers import LossMeter
 from fl4health_tpu.precision import policy as precision_policy
@@ -97,15 +98,45 @@ class ModelDef:
     ``preds`` is a dict with at least key "prediction"; ``features`` is a dict
     of intermediate activations (reference predict() contract,
     basic_client.py:992).
+
+    ``per_client`` is THE predicate over dotted parameter paths that says
+    which leaves every client holds a copy of (trains, exchanges, has
+    optimizer state for). ``None`` means every leaf. Leaves outside it are
+    *shared*: they exist once (in the server state), reach the vmapped
+    client step unbatched and are never pulled, pushed, averaged or
+    updated (a frozen base under per-client adapters). A model with the
+    predicate also gives ``bind_shared(shared) -> apply``: the forward over
+    the per-client leaves alone (``apply``'s signature and results), closed
+    over the shared tree. The round program calls it once a round, outside
+    the client vmap and the local-step scan, so whatever it does to the
+    shared leaves before the forward (the cast of a base's matrices to the
+    compute type) happens once. :func:`merged_forward` is the plain form.
     """
 
     init: Callable[[PRNGKey, jax.Array], tuple[Params, Any]]
     apply: Callable[..., tuple[tuple[dict, dict], Any]]
+    per_client: Callable[[str], bool] | None = None
+    bind_shared: Callable[[Params], Callable[..., Any]] | None = None
+
+
+def merged_forward(apply):
+    """The plain ``ModelDef.bind_shared``: ``apply`` over the union of the
+    shared and the per-client leaves."""
+    def bind(shared):
+        return lambda params, model_state, x, **kwargs: apply(
+            merge_trees(shared, params), model_state, x, **kwargs)
+    return bind
 
 
 def from_flax(module, mutable: tuple[str, ...] = ("batch_stats",)) -> ModelDef:
     """Wrap a flax.linen module whose __call__ returns either an array or a
-    (preds_dict, features_dict) pair."""
+    (preds_dict, features_dict) pair. A module that defines
+    ``per_client_param(path) -> bool`` brings its own split of the parameters
+    into per-client and shared leaves (see :class:`ModelDef`); its
+    ``bind_shared(shared) -> (params, x) -> (preds, features)``, if it has
+    one, is the forward over the two halves (such a module has no mutable
+    collections), else the halves are merged and ``__call__`` runs."""
+    per_client = getattr(module, "per_client_param", None)
 
     def init(rng, sample_x):
         variables = module.init(
@@ -146,7 +177,47 @@ def from_flax(module, mutable: tuple[str, ...] = ("batch_stats",)) -> ModelDef:
             preds, features = {"prediction": out}, {}
         return (preds, features), new_state
 
-    return ModelDef(init=init, apply=apply)
+    bind = None
+    if per_client is not None:
+        bind = merged_forward(apply)
+        if hasattr(module, "bind_shared"):
+            def bind(shared):
+                forward = module.bind_shared(shared)
+                return lambda params, model_state, x, **kwargs: (
+                    forward(params, x), model_state)
+    return ModelDef(init=init, apply=apply, per_client=per_client,
+                    bind_shared=bind)
+
+
+def bind_shared(logic: "ClientLogic", shared: Params) -> "ClientLogic":
+    """Shallow-copy a ClientLogic over ``shared``: the clients' own
+    ``params`` hold the per-client leaves only, and ``shared`` (traced once,
+    outside the client vmap) fills in the rest. Gradients are taken with
+    respect to ``params`` alone, so the shared leaves get neither gradient
+    nor optimizer state."""
+    bound = copy.copy(logic)
+    bound.model = dataclasses.replace(
+        logic.model, apply=logic.model.bind_shared(shared))
+    return bound
+
+
+def init_split(model: ModelDef, rng: PRNGKey, sample_x):
+    """``model.init`` for a model with shared leaves, without ever holding
+    the shared leaves: returns (per-client params, model_state, the shared
+    tree as ``ShapeDtypeStruct``s, ``make_shared() -> shared tree``). Both
+    halves are the values ``model.init(rng, sample_x)`` would give (to the
+    rounding of a jitted sampler); each is computed under ``jit`` so the
+    other half is dead code."""
+    def half(which):
+        def f(r):
+            params, model_state = model.init(r, sample_x)
+            return split_by_path(params, model.per_client)[which], model_state
+        return f
+
+    per_client, model_state = jax.jit(half(0))(rng)
+    shared_abstract, _ = jax.eval_shape(half(1), rng)
+    return (per_client, model_state, shared_abstract,
+            lambda: jax.jit(half(1))(rng)[0])
 
 
 # ---------------------------------------------------------------------------
@@ -306,11 +377,21 @@ def create_train_state(
     logic: ClientLogic, tx: optax.GradientTransformation, rng: PRNGKey,
     sample_x: jax.Array,
     precision: Any = None,
+    init: tuple[Params, Any] | None = None,
 ) -> TrainState:
     """``precision`` (a PrecisionConfig, optional): params/opt state are
     ALWAYS created f32 master (init runs in the model's native dtypes); a
-    scaling policy additionally seeds the carried loss-scale state."""
-    params, model_state = logic.model.init(rng, sample_x)
+    scaling policy additionally seeds the carried loss-scale state.
+    ``init``: the first two results of :func:`init_split`, for a caller that
+    needs the other two as well."""
+    if logic.model.per_client is not None:
+        # the client's own leaves only; the shared ones are the caller's
+        # (server/simulation.py holds them once, in the server state)
+        params, model_state = (
+            init_split(logic.model, rng, sample_x)[:2] if init is None
+            else init)
+    else:
+        params, model_state = logic.model.init(rng, sample_x)
     return TrainState(
         params=params,
         opt_state=tx.init(params),

@@ -63,6 +63,43 @@ def merge_by_mask(mask: PyTree, if_true: PyTree, if_false: PyTree) -> PyTree:
     )
 
 
+def split_by_path(
+    tree: PyTree, predicate: Callable[[str], bool], prefix: str = ""
+) -> tuple[PyTree, PyTree]:
+    """Split a nested-mapping tree (flax params) into (leaves whose dotted
+    path satisfies ``predicate``, the others), each a nested dict that holds
+    only its own leaves; branches left empty are dropped. ``merge_trees``
+    is the inverse."""
+    yes, no = {}, {}
+    for key, value in tree.items():
+        path = f"{prefix}.{key}" if prefix else str(key)
+        if hasattr(value, "items"):
+            sub_yes, sub_no = split_by_path(value, predicate, path)
+            if sub_yes:
+                yes[key] = sub_yes
+            if sub_no:
+                no[key] = sub_no
+        elif predicate(path):
+            yes[key] = value
+        else:
+            no[key] = value
+    return yes, no
+
+
+def merge_trees(a: PyTree, b: PyTree) -> PyTree:
+    """Union of two nested-mapping trees with disjoint leaves (the two
+    halves ``split_by_path`` returned)."""
+    out = dict(a)
+    for key, value in b.items():
+        if key in out:
+            if not (hasattr(value, "items") and hasattr(out[key], "items")):
+                raise ValueError(f"merge_trees: both trees hold a leaf at {key!r}")
+            out[key] = merge_trees(out[key], value)
+        else:
+            out[key] = value
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Flat-vector round trips
 # ---------------------------------------------------------------------------

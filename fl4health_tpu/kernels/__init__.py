@@ -9,6 +9,7 @@ from fl4health_tpu.kernels.flash_attention import (
     flash_attention,
     flash_attention_lse,
 )
+from fl4health_tpu.kernels.selective_scan import selective_scan
 
 __all__ = [
     "fused_clipped_masked_sum",
@@ -16,4 +17,5 @@ __all__ = [
     "scaled_masked_sum",
     "flash_attention",
     "flash_attention_lse",
+    "selective_scan",
 ]

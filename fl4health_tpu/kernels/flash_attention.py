@@ -147,14 +147,18 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
 _TILES_PER_TRIP = 64
 
 
-def _block_loop(n_blocks: int, block: int, resident: int, step, carry):
+def _block_loop(n_blocks: int, block: int, resident: int, step, carry,
+                live=None):
     """``carry = step(pl.ds(j * block, block), carry)`` for j in
     range(n_blocks), each step a [resident, block] score tile (or its
     transpose). Consecutive blocks worth up to _TILES_PER_TRIP tiles of
     128 x 128 are one straight-line body, so the scheduler can start a
     block's first dot while the one before is still in its softmax; a longer
     axis loops over such trips and finishes with the remainder, so the code
-    stays bounded at any length and any block size."""
+    stays bounded at any length and any block size. ``live(start)`` (the
+    causal kernels: is any score of the block at ``start`` on or under the
+    diagonal) makes a block's step conditional; ``None`` traces the
+    unconditional loop."""
     per_trip = max(1, _TILES_PER_TRIP
                    // (pl.cdiv(resident, _LANE) * pl.cdiv(block, _LANE)))
 
@@ -163,7 +167,13 @@ def _block_loop(n_blocks: int, block: int, resident: int, step, carry):
             start = (first + u) * block
             if not isinstance(start, int):
                 start = pl.multiple_of(start, block)
-            carry = step(pl.ds(start, block), carry)
+            ks = pl.ds(start, block)
+            if live is None:
+                carry = step(ks, carry)
+            else:
+                carry = jax.lax.cond(
+                    live(start), lambda c, ks=ks: step(ks, c), lambda c: c,
+                    carry)
         return carry
 
     if n_blocks <= per_trip:
@@ -178,8 +188,18 @@ def _block_loop(n_blocks: int, block: int, resident: int, step, carry):
 # Forward kernel
 # ---------------------------------------------------------------------------
 
+def _on_or_under_diagonal(q_start, nq: int, k_start, nk: int, transposed):
+    """bool [nq, nk] (or its transpose): key position <= query position."""
+    shape = (nk, nq) if transposed else (nq, nk)
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                              1 if transposed else 0)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                              0 if transposed else 1)
+    return kpos <= qpos
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
-                scale, precision):
+                scale, precision, causal):
     # Mosaic layout contract (learned on real silicon, KERNELS r5): every
     # block's trailing two dims must be (8k, 128k) or equal the array dims.
     # Row-per-(batch,head) vectors therefore travel as mask [BH, 1, Tp] and
@@ -191,12 +211,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
     q = _mxu_operand(q_ref[0])  # [Bq, Dp]
     bq, dp = q.shape
     lanes = _LANE if block_k % _LANE == 0 else block_k
+    live = None
+    if causal:
+        q_start = pl.program_id(1) * bq
+        live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
 
     def step(ks, carry):
         m, l, acc = carry  # m: [Bq, 1], l: [Bq, lanes], acc: [Bq, Dp], f32
         kb = _mxu_operand(k_ref[0, ks, :])
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
+        if causal:
+            keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
+                                                block_k, False)
         s = _dot(q, kb, (1, 1), precision) * scale  # [Bq, Bk]
         s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -212,17 +239,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
         k_ref.shape[1] // block_k, block_k, bq, step,
         (jnp.full((bq, 1), NEG_INF, jnp.float32),
          jnp.zeros((bq, lanes), jnp.float32),
-         jnp.zeros((bq, dp), jnp.float32)))
+         jnp.zeros((bq, dp), jnp.float32)), live)
     denom = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
     o_ref[0] = (acc / denom).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(denom)  # [Bq, 1]
 
 
-def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret):
+def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret, causal):
     bh, tp, dp = q.shape
     grid = (bh, tp // block_q)
     kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
-                               precision=_dot_precision(q.dtype))
+                               precision=_dot_precision(q.dtype),
+                               causal=causal)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -250,29 +278,37 @@ def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, block_k, scale, precision):
+                   dq_ref, *, block_k, scale, precision, causal):
     q = _mxu_operand(q_ref[0])
     do = _mxu_operand(do_ref[0])
     lse = lse_ref[0]  # [Bq, 1]
     delta = delta_ref[0]  # [Bq, 1] = rowsum(dO * O)
+    bq = q.shape[0]
+    live = None
+    if causal:
+        q_start = pl.program_id(1) * bq
+        live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
 
     def step(ks, dq):
         kb = _mxu_operand(k_ref[0, ks, :])
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
+        if causal:
+            keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
+                                                block_k, False)
         s = _dot(q, kb, (1, 1), precision) * scale
         p = jnp.where(keep, jnp.exp(s - lse), 0.0)
         dp = _dot(do, vb, (1, 1), precision)
         # dS = p * (dP - delta) * scale; the scale waits for the sum
         return dq + _dot(p * (dp - delta), kb, (1, 0), precision)
 
-    dq = _block_loop(k_ref.shape[1] // block_k, block_k, q.shape[0], step,
-                     jnp.zeros(q.shape, jnp.float32))
+    dq = _block_loop(k_ref.shape[1] // block_k, block_k, bq, step,
+                     jnp.zeros(q.shape, jnp.float32), live)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, block_q, scale, precision):
+                    dk_ref, dv_ref, *, block_q, scale, precision, causal):
     # The score tile is held transposed, [Bk, Bq]: dV += P^T dO and
     # dK += dS^T Q are then plain row-major dots, and lse / delta meet the
     # tile as rows [1, Bq] (a sublane broadcast) where the [Bq, Bk] form
@@ -280,6 +316,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
     # (18.4 -> 14.3 ms a call on the v5e, PR 26).
     kb = _mxu_operand(k_ref[0])  # [Bk, Dp]
     vb = _mxu_operand(v_ref[0])
+    bk = kb.shape[0]
+    live = None
+    if causal:
+        k_start = pl.program_id(1) * bk
+        live = lambda q_start: q_start + (block_q - 1) >= k_start  # noqa: E731
 
     def step(qs, carry):
         dk, dv = carry
@@ -288,14 +329,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
         lse = lse_ref[0, :, qs]  # [1, Bq]
         delta = delta_ref[0, :, qs]
         pt = jnp.exp(_dot(kb, q, (1, 1), precision) * scale - lse)
+        if causal:
+            pt = jnp.where(_on_or_under_diagonal(qs.start, block_q, k_start,
+                                                 bk, True), pt, 0.0)
         dpt = _dot(vb, do, (1, 1), precision)
         dv = dv + _dot(pt, do, (1, 0), precision)
         dk = dk + _dot(pt * (dpt - delta), q, (1, 0), precision)
         return dk, dv
 
     zeros = jnp.zeros(kb.shape, jnp.float32)
-    dk, dv = _block_loop(q_ref.shape[1] // block_q, block_q, kb.shape[0],
-                         step, (zeros, zeros))
+    dk, dv = _block_loop(q_ref.shape[1] // block_q, block_q, bk,
+                         step, (zeros, zeros), live)
     # A row of dK / dV depends on its own key alone, so the key-padding mask
     # is one select on the sums: a padded key's row is zero, as when every
     # p of that key was zeroed in the loop.
@@ -305,7 +349,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
-              dlse):
+              dlse, causal):
     bh, tp, dp = q.shape
     # lse is a differentiable OUTPUT (ring-flash merge): its cotangent
     # enters the score gradient as dS = p*(dP - delta + dlse), i.e. the
@@ -318,7 +362,7 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
 
     prec = _dot_precision(q.dtype)
     dq_kernel = functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
-                                  precision=prec)
+                                  precision=prec, causal=causal)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(bh, tp // block_q),
@@ -341,7 +385,7 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
     # (sliced at block_q offsets like the forward's mask), the key mask as a
     # column [BH, Tp, 1]. Same seven operands.
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                                   scale=scale, precision=prec)
+                                   scale=scale, precision=prec, causal=causal)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(bh, tp // block_k),
@@ -373,26 +417,31 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
 # custom_vjp over padded [BH, Tp, Dp] internals
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_padded_lse(q, k, v, mask, block_q, block_k, scale, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_padded_lse(q, k, v, mask, block_q, block_k, scale, interpret,
+                      causal):
     """(out, lse) pair with lse a first-class differentiable output so
     partial-attention results can be merged exactly (ring-flash). The
     plain-``out`` path (flash_attention) wraps this and drops lse — its
     zero cotangent makes _bwd_call's dlse term vanish, so ONE custom_vjp
     serves both APIs."""
-    return _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret)
+    return _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret,
+                     causal)
 
 
-def _flash_padded_lse_fwd(q, k, v, mask, block_q, block_k, scale, interpret):
-    out, lse = _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret)
+def _flash_padded_lse_fwd(q, k, v, mask, block_q, block_k, scale, interpret,
+                          causal):
+    out, lse = _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret,
+                         causal)
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _flash_padded_lse_bwd(block_q, block_k, scale, interpret, res, cts):
+def _flash_padded_lse_bwd(block_q, block_k, scale, interpret, causal, res,
+                          cts):
     do, dlse = cts
     q, k, v, mask, out, lse = res
     dq, dk, dv = _bwd_call(q, k, v, mask, out, lse, do, block_q, block_k,
-                           scale, interpret, dlse=dlse)
+                           scale, interpret, dlse=dlse, causal=causal)
     return dq, dk, dv, None
 
 
@@ -407,17 +456,24 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    causal: bool = False,
 ) -> jax.Array:
     """Exact softmax attention, flash-style. q,k,v: [B, T, H, D];
     pad_mask: [B, T] with 1 = real token (key positions); returns
     [B, T, H, D]. Drop-in for ring_attention._dense_attention.
+
+    ``causal`` (static): a query sees the keys at its own position and
+    before; key blocks wholly above the diagonal are skipped in all three
+    kernels. ``k`` / ``v`` with a single head (``[B, T, 1, D]``) are shared
+    by every query head (multi-query attention): the wrapper broadcasts
+    them, and their gradient is the sum over the query heads.
 
     pad_mask is NON-differentiable: it is a binary padding indicator, and the
     custom VJP returns a zero cotangent for it (a soft/learned mask would get
     silent zero grads here — use the dense path for that; stop_gradient in
     the shared prep makes the contract explicit)."""
     out, _ = flash_attention_lse(q, k, v, pad_mask, block_q, block_k,
-                                 interpret)
+                                 interpret, causal)
     return out
 
 
@@ -429,6 +485,7 @@ def flash_attention_lse(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    causal: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """flash_attention returning (out [B,T,H,D], lse [B,H,T]) with lse a
     DIFFERENTIABLE output — the partial-softmax statistic that lets two
@@ -444,6 +501,9 @@ def flash_attention_lse(
     if interpret is None:
         interpret = interpret_default()
     b, t, h, d = q.shape
+    if k.shape[2] == 1 and h > 1:
+        k = jnp.broadcast_to(k, q.shape)
+        v = jnp.broadcast_to(v, q.shape)
     if pad_mask is None:
         pad_mask = jnp.ones((b, t), jnp.float32)
     scale = 1.0 / (d ** 0.5)
@@ -478,7 +538,7 @@ def flash_attention_lse(
 
     def padded(qp, kp, vp, maskp):
         return _flash_padded_lse(qp, kp, vp, maskp, block_q, block_k, scale,
-                                 interpret)
+                                 interpret, causal)
 
     mesh = jax.sharding.get_abstract_mesh()
     if not mesh.empty and mesh.are_all_axes_auto:

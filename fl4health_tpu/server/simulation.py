@@ -319,6 +319,30 @@ class FederatedSimulation:
         self.local_epochs = local_epochs
         self.local_steps = local_steps
         self.exchanger = exchanger or FullExchanger()
+        # Parameters that exist once (clients/engine.py ModelDef.per_client,
+        # strategies/shared_base.py): a model that declares shared leaves
+        # keeps them once, in the server state; client states, optimizer,
+        # exchanger and the inner strategy see the per-client leaves alone.
+        # A model without the predicate (every leaf per client) builds the
+        # exact programs it always did.
+        self._per_client = logic.model.per_client
+        if self._per_client is not None:
+            unsupported = {
+                "cohort": cohort, "async_config": async_config, "mesh": mesh,
+                "compression": compression, "early_stopping": early_stopping,
+                "flash_early_stopping": flash_early_stopping,
+            }
+            on = [k for k, v in unsupported.items() if v is not None]
+            if on:
+                raise NotImplementedError(
+                    "a model with shared parameters (ModelDef.per_client) "
+                    f"runs the synchronous round programs only; got {on}"
+                )
+            from fl4health_tpu.strategies.shared_base import SharedBaseStrategy
+
+            strategy = self.strategy = SharedBaseStrategy(
+                strategy, self._per_client
+            )
         # Compressed exchange (compression/: CompressionConfig): the lossy
         # client->server channel compiles INTO the round programs via a
         # CompressingStrategy wrapper, so chunked mode keeps one dispatch
@@ -795,6 +819,8 @@ class FederatedSimulation:
         self._init_states(_wire_zero1=True)
 
         self._build_compiled()
+        if self._per_client is not None:
+            self._report_parameter_split()
 
     # ------------------------------------------------------------------
     def _init_states(self, _wire_zero1: bool = False) -> None:
@@ -820,8 +846,17 @@ class FederatedSimulation:
             sample_x = jax.tree_util.tree_map(
                 lambda a: a[:1], self.datasets[0].x_train
             )
+        split_init = shared_abstract = None
+        if self._per_client is not None:
+            # the shared leaves stay abstract until the first fit() or
+            # set_global_params: a pretrained base is installed over them,
+            # and two copies of it need not fit the device
+            *split_init, shared_abstract, self._make_shared = (
+                engine.init_split(self.logic.model, init_rng, sample_x)
+            )
         proto = engine.create_train_state(
-            self.logic, self.tx, init_rng, sample_x, precision=self.precision
+            self.logic, self.tx, init_rng, sample_x, precision=self.precision,
+            init=split_init,
         )
         if (_wire_zero1 and self._program_builder.mesh is not None
                 and self.mesh_config.zero1):
@@ -841,7 +876,12 @@ class FederatedSimulation:
         self.client_states: TrainState = ptu.stack_clients(per_client)
         # self.strategy, not a local: zero1 wiring may have rebuilt the
         # chain around a ZeRO-sharded server optimizer
-        self.server_state = self.strategy.init(proto.params)
+        if shared_abstract is not None:
+            self.server_state = self.strategy.init_split(
+                proto.params, shared_abstract
+            )
+        else:
+            self.server_state = self.strategy.init(proto.params)
         if self._cohort_active:
             # bind the registry's prototype rows: client i's TrainState row
             # derives from (proto, fold_in(init_rng, i+1)) — the dense
@@ -1069,14 +1109,16 @@ class FederatedSimulation:
         self._async_plan = None  # the run's static event plan (host numpy)
         self._async_pending = None  # in-flight update buffer (device tree)
 
-    def _build_client_fns(self, collect_telemetry: bool):
+    def _build_client_fns(self, collect_telemetry: bool, logic=None):
         """Build the client-level (client_fit, client_eval) closures —
         pull -> local train -> push, and pull -> eval. ONE definition
         shared by the synchronous round programs (:meth:`_build_round_fns`)
         and the buffered-async event programs (:meth:`_build_async_fns`),
         so async and sync rounds run bit-identical client math by
-        construction."""
-        logic, tx, strategy, exchanger = self.logic, self.tx, self.strategy, self.exchanger
+        construction. ``logic`` replaces ``self.logic`` (the round programs
+        of a model with shared leaves pass it bound to them)."""
+        logic = logic if logic is not None else self.logic
+        tx, strategy, exchanger = self.tx, self.strategy, self.exchanger
         loss_keys = ("backward", *self._extra_keys())
         if collect_telemetry:
             # logic-declared telemetry channels (e.g. the DP clip fraction)
@@ -1196,8 +1238,19 @@ class FederatedSimulation:
         cell programs (``fl4health_tpu/sweep/``) pass it as a TRACED input
         so cells whose data partitions (and thus per-client train-set
         sizes) differ still share one compiled program."""
-        client_fit, client_eval = self._build_client_fns(collect_telemetry)
+        base_client_fit, base_client_eval = self._build_client_fns(
+            collect_telemetry)
         strategy = self.strategy
+        shared_fns = None
+        if self._per_client is not None:
+            def shared_fns(server_state):
+                """(client_fit, client_eval) over this round's shared
+                leaves: read from the server state and bound to the model's
+                forward once, here, outside the client vmap and the
+                local-step scan."""
+                return self._build_client_fns(
+                    collect_telemetry, engine.bind_shared(
+                        self.logic, strategy.shared_params(server_state)))
         baked_sample_counts = self.sample_counts
         spmd_axis = self._program_builder.spmd_axis_name
 
@@ -1219,6 +1272,8 @@ class FederatedSimulation:
             if sample_counts is None:
                 sample_counts = baked_sample_counts
             payload = strategy.client_payload(server_state, round_idx)
+            client_fit = (base_client_fit if shared_fns is None
+                          else shared_fns(server_state)[0])
             if inject_dropout:
                 # a dropped client is exactly an unsampled one: mask math,
                 # never a shape change
@@ -1298,6 +1353,8 @@ class FederatedSimulation:
 
         def eval_round(server_state, client_states, batches, eval_counts):
             gp = strategy.client_payload(server_state, jnp.zeros((), jnp.int32))
+            client_eval = (base_client_eval if shared_fns is None
+                           else shared_fns(server_state)[1])
             new_states, losses, metrics = jax.vmap(
                 client_eval, in_axes=(0, None, 0), spmd_axis_name=spmd_axis
             )(client_states, gp, batches)
@@ -1448,6 +1505,7 @@ class FederatedSimulation:
         Participation matches ``fit``: each round's mask is drawn from the
         same PRNG stream (fold_in(rng, 2000+round)) via the client manager.
         Pass ``mask`` ([clients] or [k, clients]) to pin it instead."""
+        self._ensure_shared()
         if self.train_data_provider is not None:
             raise ValueError(
                 "fit_chunk cannot honor train_data_provider (per-round data "
@@ -2192,6 +2250,7 @@ class FederatedSimulation:
             # re-run rounds absorb exactly once
             fleet.clear()
         self._last_epilogue_round = None  # per-run (RoundConsumer progress)
+        self._ensure_shared()
         mode, mode_reason = self._select_execution_mode(n_rounds)
         self._active_execution_mode = mode
         self._round_program_flops = None  # re-measured per fit() (mode-shaped)
@@ -4933,7 +4992,7 @@ class FederatedSimulation:
         if self._payload_bytes_cache is not None:
             return self._payload_bytes_cache
         tree_bytes = ptu.tree_nbytes
-        gp = self.strategy.global_params(self.server_state)
+        gp = self._client_part(self.strategy.global_params(self.server_state))
         try:
             payload = jax.eval_shape(
                 lambda s: self.strategy.client_payload(s, jnp.zeros((), jnp.int32)),
@@ -5431,7 +5490,54 @@ class FederatedSimulation:
 
     @property
     def global_params(self):
+        self._ensure_shared()
         return self.strategy.global_params(self.server_state)
+
+    def _ensure_shared(self) -> None:
+        """Give the shared leaves their initial values if nothing has been
+        installed over them yet (they are abstract until first needed)."""
+        if self._per_client is None:
+            return
+        from fl4health_tpu.strategies.shared_base import materialized
+
+        if not materialized(self.server_state):
+            self.server_state = self.server_state.replace(
+                shared=self._make_shared()
+            )
+
+    def _report_parameter_split(self) -> None:
+        """The predicate's counts, once at build: gauges for a scraped
+        metrics page and one ``parameter_split`` event."""
+        per_client, shared = self.strategy.split(
+            self.strategy.global_params(self.server_state)
+        )
+        down, up = self._payload_nbytes()
+        gauges = (
+            ("client_param_bytes", ptu.tree_nbytes(per_client),
+             "bytes of the leaves each client holds (trains, exchanges)"),
+            ("shared_param_bytes", ptu.tree_nbytes(shared),
+             "bytes of the leaves that exist once, outside every client's "
+             "copy"),
+            ("exchanged_bytes_per_round", (down + up) * self.n_clients,
+             "payload bytes down and up, all clients, one round"),
+        )
+        obs = self.observability
+        for name, value, text in gauges:
+            obs.gauge(name, help=text).set(float(value))
+        obs.log_event(
+            "parameter_split",
+            per_client_leaves=len(jax.tree_util.tree_leaves(per_client)),
+            shared_leaves=len(jax.tree_util.tree_leaves(shared)),
+            clients=self.n_clients,
+            **{name: value for name, value, _ in gauges},
+        )
+
+    def _client_part(self, params):
+        """The per-client leaves of a whole-model tree (the tree itself
+        when every leaf is per client)."""
+        if self._per_client is None:
+            return params
+        return ptu.split_by_path(params, self._per_client)[0]
 
     def set_global_params(self, params, broadcast_to_clients: bool = True) -> None:
         """Install externally-produced weights (warm-up injection, pretrained
@@ -5481,6 +5587,6 @@ class FederatedSimulation:
             n = self.n_clients
             self.client_states = self.client_states.replace(
                 params=jax.tree_util.tree_map(
-                    lambda x: jnp.stack([x] * n), params
+                    lambda x: jnp.stack([x] * n), self._client_part(params)
                 )
             )

@@ -48,6 +48,11 @@ def replace_global_params(strategy: "Strategy", server_state: Any, params) -> An
     The direct ``state.replace(params=...)`` only works on unwrapped
     states; every params-installation path (checkpoint import, evaluate
     server hydration) must go through this instead."""
+    own = getattr(strategy, "replace_global_params", None)
+    if own is not None:
+        # a wrapper that keeps part of the model outside its inner state
+        # (strategies/shared_base.py) installs a whole-model tree itself
+        return own(server_state, params)
     if hasattr(strategy, "inner") and hasattr(server_state, "inner"):
         return server_state.replace(inner=replace_global_params(
             strategy.inner, server_state.inner, params

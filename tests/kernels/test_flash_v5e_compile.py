@@ -1,6 +1,9 @@
-"""The three named Mosaic flash calls, compiled ahead of time for a v5e at the
-shapes of the benchmark's flash cell (4 clients vmapped over batch 8 x 12
-heads, T 2,048, D 64, bf16, blocks 128/128). Nothing runs: the TPU's compiler
+"""The named Mosaic calls of the benchmark's cells, compiled ahead of time for
+a v5e: the three flash calls at the flash cell's shapes (4 clients vmapped
+over batch 8 x 12 heads, T 2,048, D 64, bf16, blocks 128/128), the same three
+causal over one shared key/value head at the adapter cell's (20 heads of 128,
+blocks 512/512), and that cell's two selective-scan calls (4 clients x 2,048
+positions x 5,120 channels x 16 states). Nothing runs: the TPU's compiler
 works against a described chip. The only file that describes a topology;
 the description happens inside a module-scoped fixture, never at import."""
 
@@ -13,6 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from fl4health_tpu.kernels.flash_attention import flash_attention
+from fl4health_tpu.kernels.selective_scan import (BLOCK_T, UNROLL,
+                                                  _blocked_scan)
 
 CLIENTS, BATCH, SEQ, HEADS, HEAD_DIM = 4, 8, 2048, 12, 64
 ROWS = f"bf16[{CLIENTS},{BATCH * HEADS},{SEQ},{HEAD_DIM}]"
@@ -37,31 +42,34 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def mosaic_calls(one_chip):
-    """name of the kernel -> its custom-call instruction in the HLO of the
-    compiled forward or backward program."""
+def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
+                    block, causal):
+    """name of the kernel -> its custom-call instructions in the HLO of the
+    compiled forward and backward programs."""
     from jax.experimental.compilation_cache import compilation_cache
 
     def attend(q, k, v, mask):
-        return flash_attention(q, k, v, mask, 128, 128, interpret=False)
+        return flash_attention(q, k, v, mask, block, block, interpret=False,
+                               causal=causal)
 
     def loss(q, k, v, mask):
         return jnp.sum(jax.vmap(attend)(q, k, v, mask).astype(jnp.float32))
 
-    qkv = jax.ShapeDtypeStruct((CLIENTS, BATCH, SEQ, HEADS, HEAD_DIM),
-                               jnp.bfloat16, sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((CLIENTS, BATCH, SEQ), jnp.float32,
+    q = jax.ShapeDtypeStruct((clients, batch, seq, heads, head_dim),
+                             jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((clients, batch, seq, kv_heads, head_dim),
+                              jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((clients, batch, seq), jnp.float32,
                                 sharding=one_chip)
     # an executable compiled for a described chip cannot be read back from
     # the persistent cache without one
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        texts = [jax.jit(jax.vmap(attend)).lower(qkv, qkv, qkv, mask)
+        texts = [jax.jit(jax.vmap(attend)).lower(q, kv, kv, mask)
                  .compile().as_text(),
                  jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-                 .lower(qkv, qkv, qkv, mask).compile().as_text()]
+                 .lower(q, kv, kv, mask).compile().as_text()]
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
@@ -77,6 +85,42 @@ def mosaic_calls(one_chip):
     return calls
 
 
+@pytest.fixture(scope="module")
+def mosaic_calls(one_chip):
+    return _compiled_calls(one_chip, CLIENTS, BATCH, SEQ, HEADS, HEADS,
+                           HEAD_DIM, 128, causal=False)
+
+
+# the adapter cell's attention layer: 4 clients x batch 1 x 20 query heads of
+# 128 over ONE key/value head, T 2,048, causal, blocks 512/512
+CAUSAL_ROWS = "bf16[4,20,2048,128]"
+CAUSAL_KERNELS = {
+    "flash_fwd": (CAUSAL_ROWS, "f32[4,20,2048,1]"),
+    "flash_dq": (CAUSAL_ROWS,),
+    "flash_dkv": (CAUSAL_ROWS, CAUSAL_ROWS),
+}
+
+
+@pytest.fixture(scope="module")
+def causal_calls(one_chip):
+    return _compiled_calls(one_chip, 4, 1, 2048, 20, 1, 128, 512, causal=True)
+
+
+def _result_shapes(line):
+    result = line.split(" = ", 1)[1].split(" custom-call(", 1)[0]
+    return tuple(re.sub(r"\{[^}]*\}", "", s) for s in
+                 re.findall(r"(?:bf16|f32)\[[\d,]+\](?:\{[^}]*\})?", result))
+
+
+@pytest.mark.parametrize("name", sorted(CAUSAL_KERNELS))
+def test_causal_shared_head_call_compiles_for_the_v5e(causal_calls, name):
+    lines = causal_calls.get(name)
+    assert lines, f"no tpu_custom_call named {name}: {sorted(causal_calls)}"
+    for line in lines:
+        assert _result_shapes(line) == CAUSAL_KERNELS[name], (name, line)
+    assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_named_flash_call_compiles_for_the_v5e(mosaic_calls, name):
     lines = mosaic_calls.get(name)
@@ -88,3 +132,61 @@ def test_named_flash_call_compiles_for_the_v5e(mosaic_calls, name):
         assert shapes == KERNELS[name], (name, result)
     # the forward runs once in the forward program and once under grad
     assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
+# the adapter cell's Mamba mixers: 4 clients x batch 1 x 2,048 positions x
+# 5,120 channels (8 channel blocks of 640) x 16 states, bf16 operands
+SCAN_ROWS = "bf16[4,1,2048,5120]"
+SCAN_KERNELS = {
+    # y and the states at the starts of the 32 time blocks
+    "ssm_scan_fwd": (SCAN_ROWS, "f32[4,1,32,16,5120]"),
+    # dx, dDelta, dz, the per-lane partial sums of dB and dC, dA, dD
+    "ssm_scan_bwd": (SCAN_ROWS, SCAN_ROWS, SCAN_ROWS,
+                     "f32[4,1,8,2048,16,128]", "f32[4,1,8,2048,16,128]",
+                     "f32[4,1,16,5120]", "f32[4,1,1,5120]"),
+}
+
+
+@pytest.fixture(scope="module")
+def scan_calls(one_chip):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def scan(x, dt, a, b, c, d, z):
+        return _blocked_scan(x, dt, a, b, c, d, z, BLOCK_T, UNROLL, False)
+
+    clients = jax.vmap(scan, in_axes=(0, 0, None, 0, 0, None, 0))
+
+    def loss(*ops):
+        return jnp.sum(clients(*ops).astype(jnp.float32))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    seq, state = (4, 1, 2048, 5120), (4, 1, 2048, 16)
+    ops = (arg(seq, jnp.bfloat16), arg(seq, jnp.bfloat16),
+           arg((5120, 16), jnp.float32), arg(state, jnp.float32),
+           arg(state, jnp.float32), arg((5120,), jnp.float32),
+           arg(seq, jnp.bfloat16))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+            *ops).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    calls = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        for name in SCAN_KERNELS:
+            if re.search(rf"%\w*{name}_*[.\d]* = ", line):
+                calls.setdefault(name, []).append(line)
+    return calls
+
+
+@pytest.mark.parametrize("name", sorted(SCAN_KERNELS))
+def test_selective_scan_call_compiles_for_the_v5e(scan_calls, name):
+    lines = scan_calls.get(name)
+    assert lines and len(lines) == 1, (name, sorted(scan_calls))
+    assert _result_shapes(lines[0]) == SCAN_KERNELS[name], lines[0][:400]
