@@ -10,7 +10,10 @@ JAX FL simulation, and the numbers the sharding roadmap (arXiv:2004.13336)
 needs before splitting those programs across replicas.
 
 XLA exposes both through the AOT API at **build time** — zero per-round
-cost:
+cost, and once per compiled program: the introspector remembers each
+program's report and records it again, without lowering or compiling
+anything, when it is asked about the same program (a second ``fit()`` on one
+simulation):
 
 - ``compiled.cost_analysis()``: flops, transcendentals, bytes accessed;
 - ``compiled.memory_analysis()``: argument/output/temp/generated-code
@@ -38,6 +41,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
+import weakref
 from typing import Any
 
 from fl4health_tpu.observability import device_specs, hloscan
@@ -211,11 +215,31 @@ class ProgramIntrospector:
 
     One instance per :class:`~fl4health_tpu.observability.Observability`
     handle; reports accumulate in ``.reports`` (last introspection of a
-    name wins) and every capture lands in the registry + JSONL log."""
+    name wins) and every capture lands in the registry + JSONL log.
+
+    A report is a pure function of the compiled program, so each name's
+    last capture is remembered with what identifies that program: the
+    jitted object itself (through a weak reference: a dead one matches
+    nothing, so an ``id`` cannot be reused, and the entry keeps neither
+    the function nor the simulation its closure holds alive), the
+    abstract arguments it was lowered against, ``rounds_per_dispatch``,
+    ``mesh``, ``precision``, ``cohort_draw`` and whether stage attribution
+    was on. Asked for the same program again, ``introspect_jit`` records
+    the remembered report and does no other work; anything else differing
+    is a miss that runs the capture and replaces the entry. Only the
+    report is kept — never the executable, its HLO text or an array.
+    ``hits`` / ``misses`` count both outcomes, as does
+    ``fl_program_introspections_total{program, result}``."""
 
     def __init__(self, registry: MetricsRegistry):
         self.registry = registry
         self.reports: dict[str, ProgramReport] = {}
+        # name -> (weak ref to the jitted object, key, report) of that
+        # name's last capture
+        self._remembered: dict[
+            str, tuple[weakref.ref, tuple, ProgramReport]] = {}
+        self.hits = 0
+        self.misses = 0
 
     # -- capture ---------------------------------------------------------
     def introspect_jit(self, name: str, jitted: Any, args: tuple,
@@ -225,18 +249,33 @@ class ProgramIntrospector:
                        cohort_draw: str | None = None
                        ) -> ProgramReport | None:
         """AOT-lower and compile ``jitted`` against (abstracted) ``args``
-        and record the report. The compile goes through XLA's normal
+        and record the report — or, when this name's last capture was of
+        the same program (class docstring), record that report again and
+        touch nothing else: its ``compile_seconds`` and cache counts stay
+        those of the capture. The compile goes through XLA's normal
         ``compile_or_get_cached`` path, so with the persistent compilation
         cache enabled the later jit dispatch of the SAME program is a disk
         hit, not a second backend compile. Returns None (after logging) on
-        any failure — introspection must never take down a run."""
+        any failure — introspection must never take down a run — and a
+        failure is not remembered."""
         import jax
 
         try:
+            abstract = abstractify(args)
+            leaves, treedef = jax.tree_util.tree_flatten(abstract)
+            key = (treedef, tuple(leaves), rounds_per_dispatch, mesh,
+                   precision, cohort_draw, stage_attr.enabled())
+            jitted_ref = weakref.ref(jitted)
+            last = self._remembered.get(name)
+            hit = (last is not None and last[0]() is jitted
+                   and last[1] == key)
+            self._count(name, hit)
+            if hit:
+                return self.record(last[2])
             hits0 = self.registry.counter(_CACHE_HITS).value
             misses0 = self.registry.counter(_CACHE_MISSES).value
             t0 = time.perf_counter()
-            compiled = jitted.lower(*abstractify(args)).compile()
+            compiled = jitted.lower(*abstract).compile()
             compile_s = time.perf_counter() - t0
             d = jax.devices()[0]
             report = ProgramReport(
@@ -267,8 +306,21 @@ class ProgramIntrospector:
             logger.warning("program introspection failed for %r", name,
                            exc_info=True)
             return None
-        self.record(report)
-        return report
+        self._remembered[name] = (jitted_ref, key, report)
+        return self.record(report)
+
+    def _count(self, name: str, hit: bool) -> None:
+        if hit:
+            self.hits += 1
+        else:
+            self.misses += 1
+        self.registry.counter(
+            "fl_program_introspections_total",
+            help="introspect_jit calls: hit = the remembered report of the "
+                 "same compiled program was recorded again, miss = the "
+                 "program was lowered, compiled and analysed",
+            labels={"program": name, "result": "hit" if hit else "miss"},
+        ).inc()
 
     def record(self, report: ProgramReport) -> ProgramReport:
         """Register a report's numbers as ``fl_program_*`` gauges (labeled
@@ -350,3 +402,4 @@ class ProgramIntrospector:
 
     def clear(self) -> None:
         self.reports.clear()
+        self._remembered.clear()

@@ -2357,17 +2357,25 @@ class FederatedSimulation:
             if obs.introspection and n_rounds >= 1 and not self._async_active:
                 # compiled-program introspection at BUILD time: XLA
                 # cost/memory analysis, compile wall, cache attribution —
-                # zero per-round cost, measured MFU for every round record.
+                # zero per-round cost, measured MFU for every round record,
+                # and once per compiled program: a later fit() on the same
+                # programs records the remembered reports (``cached=``).
                 # (Async runs skip it: the event programs' work varies with
                 # the consumed buffer, so a single per-round FLOP number
                 # would be dishonest — staleness/cadence metrics carry the
                 # async story instead.)
-                with obs.span("introspect", cat="fit"):
+                with obs.span("introspect", cat="fit") as intro_span:
+                    intro = obs.introspector
+                    hits0, misses0 = intro.hits, intro.misses
                     # the chunked path dispatches checkpoint_every-round
                     # chunks when a snapshot checkpointer is attached —
                     # introspect the program shape fit() will actually run
                     self._introspect_programs(
                         mode, self._rounds_per_dispatch(n_rounds, start_round)
+                    )
+                    hits = intro.hits - hits0
+                    intro_span.set(
+                        cached=f"{hits}/{hits + intro.misses - misses0}"
                     )
         if flight is not None:
             # run-level provenance for the bundle header ("run" in
@@ -2786,8 +2794,14 @@ class FederatedSimulation:
         persistent compilation cache the later jit dispatch of the same
         program is a disk hit, not a second backend compile (without the
         cache this is one extra build-time compile per program — never a
-        per-round cost). Failures degrade to a warning: introspection must
-        not take down a run."""
+        per-round cost). Every ``fit()`` asks about every program, and the
+        introspector answers from its remembered report when the jitted
+        object, the abstract arguments and the descriptors are those of
+        its last capture (a second ``fit()`` on this simulation; a chunk
+        of another length or rebuilt programs are captured afresh), so the
+        reports, gauges, ``program`` events and ``_round_program_flops``
+        of each call read the same either way. Failures degrade to a
+        warning: introspection must not take down a run."""
         obs = self.observability
         intro = obs.introspector
         mesh_desc = self._program_builder.descriptor()

@@ -1,6 +1,8 @@
 """Compiled-program introspection: XLA cost/memory analysis capture,
 registry/JSONL recording, HBM headroom, and the device spec table."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -113,6 +115,18 @@ class TestProgramIntrospector:
         intro = ProgramIntrospector(MetricsRegistry())
         assert intro.introspect_jit("bad", object(), (jnp.ones(2),)) is None
 
+    def test_failure_is_not_remembered(self):
+        intro = ProgramIntrospector(MetricsRegistry())
+        x = jnp.ones((8, 8))
+        assert intro.introspect_jit("p", object(), (x, x)) is None
+        assert intro._remembered == {} and intro.reports == {}
+        # ... and does not displace a sound entry of the same name
+        f = _CountingJit()
+        rep = intro.introspect_jit("p", f, (x, x))
+        assert intro.introspect_jit("p", object(), (x, x)) is None
+        assert intro.introspect_jit("p", f, (x, x)) is rep
+        assert f.lowers == 1
+
     def test_round_flops_sums_per_round(self):
         reg = MetricsRegistry()
         intro = ProgramIntrospector(reg)
@@ -140,6 +154,205 @@ class TestProgramIntrospector:
                             lambda device=None: 1000)
         assert intro.hbm_headroom_bytes() == 1000 - 175
         assert reg.snapshot()["fl_hbm_headroom_bytes"] == 825.0
+
+
+class _CountingJit:
+    """A jitted function whose ``.lower`` calls are counted — the memo's
+    whole point is that a hit never reaches it."""
+
+    def __init__(self, fn=None):
+        self._jit = fn if fn is not None else _matmul_jit()
+        self.lowers = 0
+
+    def lower(self, *args, **kwargs):
+        self.lowers += 1
+        return self._jit.lower(*args, **kwargs)
+
+
+def _introspections(reg, program):
+    snap = reg.snapshot().get("fl_program_introspections_total", {})
+    return {result: int(snap.get(f'{{program="{program}",result="{result}"}}', 0))
+            for result in ("hit", "miss")}
+
+
+class TestRememberedReports:
+    """One capture per compiled program: asked again about the same jitted
+    object, abstract arguments and descriptors, ``introspect_jit`` records
+    the remembered report and lowers nothing."""
+
+    def test_second_identical_call_hits_without_lowering(self):
+        reg = MetricsRegistry()
+        intro = ProgramIntrospector(reg)
+        f = _CountingJit()
+        x = jnp.ones((32, 32))
+        first = intro.introspect_jit("mm", f, (x, x))
+        assert f.lowers == 1 and _introspections(reg, "mm") == {
+            "hit": 0, "miss": 1}
+        # the record a fresh fit() would have to rebuild: reports, gauges
+        intro.reports.clear()
+        reg.gauge("fl_program_flops", labels={"program": "mm"}).set(-1.0)
+        # concrete arrays or their ShapeDtypeStructs: the same program
+        second = intro.introspect_jit("mm", f, abstractify((x, x)))
+        assert f.lowers == 1
+        assert second == first and intro.reports["mm"] == first
+        assert second.compile_seconds == first.compile_seconds
+        assert reg.snapshot()["fl_program_flops"]['{program="mm"}'] == first.flops
+        events = [e for e in reg.events if e["event"] == "program"]
+        assert len(events) == 2
+        strip = lambda e: {k: v for k, v in e.items() if k != "ts"}  # noqa: E731
+        assert strip(events[0]) == strip(events[1])
+        assert _introspections(reg, "mm") == {"hit": 1, "miss": 1}
+        assert (intro.hits, intro.misses) == (1, 1)
+
+    @pytest.mark.parametrize("change", [
+        "shape", "dtype", "weak_type", "tree", "jitted",
+        "rounds_per_dispatch", "mesh", "precision", "cohort_draw",
+    ])
+    def test_any_difference_misses_and_replaces_the_entry(self, change):
+        reg = MetricsRegistry()
+        intro = ProgramIntrospector(reg)
+        f = _CountingJit(jax.jit(lambda t: t["a"] @ t["b"]))
+        x = jnp.ones((16, 16))
+        args, kw, g = ({"a": x, "b": x},), {}, f
+        first = intro.introspect_jit("p", f, args)
+        if change == "shape":
+            x2 = jnp.ones((8, 8))
+            args = ({"a": x2, "b": x2},)
+        elif change == "dtype":
+            x2 = jnp.ones((16, 16), jnp.bfloat16)
+            args = ({"a": x2, "b": x2},)
+        elif change == "weak_type":
+            weak = jax.ShapeDtypeStruct((16, 16), jnp.float32, weak_type=True)
+            args = ({"a": weak, "b": x},)
+        elif change == "tree":
+            args = ({"a": x, "b": x, "c": x},)
+        elif change == "jitted":
+            g = _CountingJit(jax.jit(lambda t: t["a"] @ t["b"]))
+        elif change == "rounds_per_dispatch":
+            kw = {"rounds_per_dispatch": 4}
+        elif change == "mesh":
+            kw = {"mesh": {"n_devices": 1, "axes": {"clients": 1}}}
+        elif change == "precision":
+            kw = {"precision": {"compute_dtype": "bfloat16"}}
+        elif change == "cohort_draw":
+            kw = {"cohort_draw": "in_graph"}
+        other = intro.introspect_jit("p", g, args, **kw)
+        assert other is not None and other is not first
+        assert sum(j.lowers for j in {f, g}) == 2
+        assert _introspections(reg, "p") == {"hit": 0, "miss": 2}
+        # the entry now describes the second program: it hits, the first
+        # program misses again
+        assert intro.introspect_jit("p", g, args, **kw) is other
+        assert _introspections(reg, "p") == {"hit": 1, "miss": 2}
+        intro.introspect_jit("p", f, ({"a": x, "b": x},))
+        assert _introspections(reg, "p") == {"hit": 1, "miss": 3}
+        assert sum(j.lowers for j in {f, g}) == 3
+
+    @pytest.mark.multichip
+    def test_leaf_sharding_is_part_of_the_program(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        devs = jax.devices()
+        if len(devs) < 2:
+            pytest.skip("needs 2 virtual devices")
+        mesh = Mesh(devs[:2], ("clients",))
+        f = _CountingJit(jax.jit(lambda a: a * 2.0))
+        intro = ProgramIntrospector(MetricsRegistry())
+        split, whole = (
+            jax.ShapeDtypeStruct((8, 4), jnp.float32,
+                                 sharding=NamedSharding(mesh, spec))
+            for spec in (P("clients"), P())
+        )
+        intro.introspect_jit("p", f, (split,))
+        intro.introspect_jit("p", f, (split,))
+        assert (f.lowers, intro.hits) == (1, 1)
+        intro.introspect_jit("p", f, (whole,))
+        assert (f.lowers, intro.hits, intro.misses) == (2, 1, 2)
+
+    def test_stage_attribution_toggle_misses(self):
+        from fl4health_tpu.observability import stages
+
+        intro = ProgramIntrospector(MetricsRegistry())
+        f = _CountingJit()
+        x = jnp.ones((8, 8))
+        was = stages.enabled()
+        try:
+            stages.set_enabled(True)
+            on = intro.introspect_jit("p", f, (x, x))
+            stages.set_enabled(False)
+            off = intro.introspect_jit("p", f, (x, x))
+            assert f.lowers == 2 and off.stages is None
+            assert on.stages is not None
+            assert intro.introspect_jit("p", f, (x, x)) is off
+            stages.set_enabled(True)
+            assert intro.introspect_jit("p", f, (x, x)).stages is not None
+            assert f.lowers == 3
+        finally:
+            stages.set_enabled(was)
+
+    def test_names_are_remembered_apart(self):
+        """fit's eval program runs under two names (validation and test
+        shapes): one jitted object, one entry per name."""
+        intro = ProgramIntrospector(MetricsRegistry())
+        f = _CountingJit()
+        a, b = jnp.ones((8, 8)), jnp.ones((16, 16))
+        intro.introspect_jit("eval", f, (a, a))
+        intro.introspect_jit("eval_test", f, (b, b))
+        intro.introspect_jit("eval", f, (a, a))
+        intro.introspect_jit("eval_test", f, (b, b))
+        assert f.lowers == 2 and (intro.hits, intro.misses) == (2, 2)
+
+    def test_clear_forgets(self):
+        intro = ProgramIntrospector(MetricsRegistry())
+        f = _CountingJit()
+        x = jnp.ones((8, 8))
+        intro.introspect_jit("p", f, (x, x))
+        intro.clear()
+        assert intro.reports == {} and intro._remembered == {}
+        intro.introspect_jit("p", f, (x, x))
+        assert f.lowers == 2
+
+    def test_entry_does_not_keep_the_program_alive(self):
+        """A round program's closure holds its simulation (and through it
+        the device state): an Observability handle that outlives the
+        simulation must not pin it. A dead entry matches nothing."""
+        import gc
+
+        intro = ProgramIntrospector(MetricsRegistry())
+        f = _CountingJit()
+        x = jnp.ones((8, 8))
+        intro.introspect_jit("p", f, (x, x))
+        (jitted_ref, _, _), = intro._remembered.values()
+        del f
+        gc.collect()
+        assert jitted_ref() is None
+        g = _CountingJit()
+        intro.introspect_jit("p", g, (x, x))
+        assert g.lowers == 1 and (intro.hits, intro.misses) == (0, 2)
+
+    def test_entry_holds_report_only(self):
+        """No executable, no HLO text, no concrete array: the memo must not
+        pin device memory or megabytes of text between fit() calls."""
+        intro = ProgramIntrospector(MetricsRegistry())
+        f = _CountingJit()
+        x = jnp.ones((8, 8))
+        rep = intro.introspect_jit("p", f, (x, x))
+        (jitted_ref, key, report), = intro._remembered.values()
+        assert jitted_ref() is f and report is rep
+
+        def flat(v):
+            if isinstance(v, (tuple, list)):
+                for item in v:
+                    yield from flat(item)
+            elif isinstance(v, dict):
+                yield from flat(list(v.values()))
+            else:
+                yield v
+
+        for v in flat([key, dataclasses.asdict(report)]):
+            assert not isinstance(v, (jax.Array, jax.stages.Compiled,
+                                      jax.stages.Lowered))
+            assert not (isinstance(v, str) and len(v) > 200)
 
 
 class TestProgramReport:

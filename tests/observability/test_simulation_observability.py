@@ -326,6 +326,92 @@ class TestProgramIntrospection:
         assert "eval_round_t_test" in obs.introspector.reports
 
 
+def _test_split_datasets():
+    x, y = synthetic_classification(jax.random.PRNGKey(1), 60, (4,), 2)
+    return [ClientDataset(x[:16], y[:16], x[32:40], y[32:40],
+                          x[48:54], y[48:54]),
+            ClientDataset(x[16:32], y[16:32], x[40:48], y[40:48],
+                          x[54:60], y[54:60])]
+
+
+class TestIntrospectionOncePerProgram:
+    """ISSUE 28: the round programs are built once, in the constructor, so
+    a second fit() on one simulation records the first call's reports
+    instead of lowering, loading and walking each program again."""
+
+    @pytest.mark.parametrize("test_split", [False, True])
+    def test_second_pipelined_fit_reuses_reports(self, test_split):
+        def run(introspection):
+            o = Observability(enabled=True, tracer=Tracer(),
+                              registry=MetricsRegistry(),
+                              per_round_spans=True,
+                              introspection=introspection)
+            kw = {"datasets": _test_split_datasets()} if test_split else {}
+            sim = _sim(observability=o, **kw)
+            # fit() continues from the state the last call left, numbers
+            # its rounds from 1 again and appends to the one history
+            sim.fit(2)
+            first = dict(o.introspector.reports)
+            return o, sim, first, list(sim.fit(2))
+
+        obs, sim, first, history = run(True)
+        n = 3 if test_split else 2
+        assert [s["args"]["cached"]
+                for s in obs.tracer.spans_named("introspect")] == [
+            f"0/{n}", f"{n}/{n}"]
+        assert len(first) == n and obs.introspector.reports == first
+        snap = obs.registry.snapshot()["fl_program_introspections_total"]
+        assert all(v == 1.0 for v in snap.values()) and len(snap) == 2 * n
+        # every call's log says what ran: the program events twice over
+        progs = [e["name"] for e in obs.registry.events
+                 if e["event"] == "program"]
+        assert len(progs) == 2 * n and set(progs) == set(first)
+        # the second call's rounds still carry measured FLOPs
+        assert sim._round_program_flops == pytest.approx(
+            sum(r.flops for r in first.values()))
+        rounds = [e for e in obs.registry.events if e["event"] == "round"]
+        assert [e["round"] for e in rounds] == [1, 2, 1, 2]
+        assert all(e["program_flops_round"] == rounds[0]["program_flops_round"]
+                   for e in rounds)
+        # ... and the trajectory is that of a run that never introspects
+        history_off = run(False)[3]
+        assert len(history) == 4
+        assert ([r.fit_losses for r in history]
+                == [r.fit_losses for r in history_off])
+        assert ([r.eval_losses for r in history]
+                == [r.eval_losses for r in history_off])
+        # the second call trained on: it did not replay the first
+        assert history[2].fit_losses != history[0].fit_losses
+
+    def test_chunked_fit_keyed_by_chunk_length(self):
+        obs = Observability(enabled=True, tracer=Tracer(),
+                            registry=MetricsRegistry())
+        sim = _sim(observability=obs)
+        for n in (2, 3, 3):
+            sim.fit(n)
+            assert sim._active_execution_mode == "chunked_scan"
+            assert obs.introspector.reports[
+                "fit_chunk_eval"].rounds_per_dispatch == n
+        assert [s["args"]["cached"]
+                for s in obs.tracer.spans_named("introspect")] == [
+            "0/1", "0/1", "1/1"]
+        assert obs.registry.snapshot()["fl_program_introspections_total"] == {
+            '{program="fit_chunk_eval",result="miss"}': 2.0,
+            '{program="fit_chunk_eval",result="hit"}': 1.0,
+        }
+
+    def test_rebuilt_simulation_on_one_handle_is_captured_afresh(self):
+        """Another simulation's programs are other jitted objects: sharing
+        the Observability handle must not hand it the first one's reports."""
+        obs = Observability(enabled=True, tracer=Tracer(),
+                            registry=MetricsRegistry(), per_round_spans=True)
+        _sim(observability=obs).fit(1)
+        _sim(observability=obs, local_steps=3).fit(1)
+        assert [s["args"]["cached"]
+                for s in obs.tracer.spans_named("introspect")] == [
+            "0/2", "0/2"]
+
+
 class TestDisabled:
     def test_disabled_default_no_artifacts_no_spans(self, tmp_path):
         sim = _sim()
