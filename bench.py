@@ -184,8 +184,8 @@ def analytic_transformer_round_flops(
 
     Thin wrapper over the single shared numerator rule in
     ``fl4health_tpu/observability/flops.py`` — the same convention
-    ``hloscan``'s shape-based dot counter and ``tools/flash_crossover.py``
-    use, so no two tools can disagree about the same model.
+    ``tools/flash_crossover.py`` uses, so no two tools can disagree about
+    the same model.
     """
     from fl4health_tpu.observability import flops as flops_rules
 
@@ -387,17 +387,6 @@ def compile_fit_round(sim):
         compile_seconds=compile_s,
         **analyze_compiled(compiled),
     )
-    if os.environ.get("FL4HEALTH_BENCH_STAGE_ATTRIBUTION") == "1":
-        # opt-in per-stage rows for the artifact (the introspector does
-        # this automatically inside fit(); bench builds its report from
-        # the AOT executable directly, so run the hloscan walk here)
-        from fl4health_tpu.observability import hloscan
-        from fl4health_tpu.observability import stages as stage_attr
-
-        if stage_attr.enabled():
-            report.stages = hloscan.analyze_compiled(
-                compiled, device_kind=report.device_kind
-            )
     return compiled, report
 
 
@@ -1746,13 +1735,6 @@ def _measure_config(model_kind: str, with_eager: bool) -> dict:
         ),
         "provenance": provenance_block(),
     }
-    # Opt-in per-stage roofline attribution (observability/hloscan.py):
-    # the compiled fit_round's flops/bytes split across fl_stage:: scopes.
-    # Null (never []) when attribution is off or the HLO walk declined —
-    # the ledger lane is tools/roofline_report.py; this embeds the same
-    # rows for artifact-only archaeology.
-    if os.environ.get("FL4HEALTH_BENCH_STAGE_ATTRIBUTION") == "1":
-        out["stage_attribution"] = prog.stages
     # Only meaningful against a real accelerator measurement: the bridge on
     # a CPU-fallback number would "model" nothing.
     if peak and achieved_flops:
@@ -2045,8 +2027,6 @@ def run_measurement() -> None:
         # never masquerade as a TPU capture)
         "provenance": cifar["provenance"],
     }
-    if "stage_attribution" in cifar:  # FL4HEALTH_BENCH_STAGE_ATTRIBUTION=1
-        record["stage_attribution"] = cifar["stage_attribution"]
     if "ops_overhead" in cifar:  # FL4HEALTH_BENCH_OPS=1
         # operations-plane fit() cost ({round_s_plain, round_s_ops_plane,
         # overhead_pct}) — tools/bench_gate.py bands overhead_pct

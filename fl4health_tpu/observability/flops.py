@@ -1,9 +1,8 @@
 """The single analytic-FLOP numerator rule.
 
-`bench.py`'s analytic MFU arms, `tools/flash_crossover.py`'s crossover
-model, and `observability/hloscan.py`'s shape-based dot counter must never
-disagree about the same matmul. This module is the one place the counting
-convention lives:
+`bench.py`'s analytic MFU arms and `tools/flash_crossover.py`'s crossover
+model must never disagree about the same matmul. This module is the one
+place the counting convention lives:
 
 - a dot/matmul of result shape ``M x N`` contracting over ``K`` costs
   ``2*M*N*K`` flops (multiply + add, the ``FL4HEALTH_BENCH_ANALYTIC_FLOPS``
@@ -16,23 +15,10 @@ a backend.
 
 from __future__ import annotations
 
-from math import prod
-from typing import Sequence
-
 # Backward pass ~= 2x forward for dense nets (dL/dx and dL/dW each cost a
 # forward-sized matmul), so train = 3x forward. Shared by bench.py and
 # tools/flash_crossover.py.
 TRAIN_STEP_FLOP_MULTIPLIER = 3.0
-
-
-def dot_flops(result_shape: Sequence[int], contracted: Sequence[int]) -> float:
-    """Flops of one dot: 2 * prod(result dims) * prod(contracted dims)."""
-    return 2.0 * prod(result_shape) * prod(contracted)
-
-
-def matmul_flops(m: int, k: int, n: int) -> float:
-    """Flops of one ``[m,k] @ [k,n]`` matmul: ``2*m*k*n``."""
-    return dot_flops((m, n), (k,))
 
 
 def transformer_fwd_flops_per_token(

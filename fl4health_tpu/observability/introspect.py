@@ -44,8 +44,7 @@ import time
 import weakref
 from typing import Any
 
-from fl4health_tpu.observability import device_specs, hloscan
-from fl4health_tpu.observability import stages as stage_attr
+from fl4health_tpu.observability import device_specs
 from fl4health_tpu.observability.registry import MetricsRegistry
 
 logger = logging.getLogger(__name__)
@@ -100,11 +99,6 @@ class ProgramReport:
     # None on f32 builds (omitted from as_dict/events like ``mesh``) — the
     # dtype a program's flops/MFU numbers are attributable to
     precision: dict | None = None
-    # per-stage cost attribution rows (observability/hloscan.py) when
-    # fl_stage attribution is enabled and the backend exposes HLO text;
-    # None otherwise (omitted from as_dict/events like ``mesh``, keeping
-    # attribution-off program records byte-identical to legacy)
-    stages: list | None = None
 
     @property
     def peak_hbm_bytes(self) -> int | None:
@@ -144,8 +138,6 @@ class ProgramReport:
             del d["precision"]
         if d.get("cohort_draw") is None:
             del d["cohort_draw"]
-        if d.get("stages") is None:
-            del d["stages"]
         d["peak_hbm_bytes"] = self.peak_hbm_bytes
         d["cache_hit"] = self.cache_hit
         roof = self.roofline()
@@ -223,11 +215,11 @@ class ProgramIntrospector:
     nothing, so an ``id`` cannot be reused, and the entry keeps neither
     the function nor the simulation its closure holds alive), the
     abstract arguments it was lowered against, ``rounds_per_dispatch``,
-    ``mesh``, ``precision``, ``cohort_draw`` and whether stage attribution
-    was on. Asked for the same program again, ``introspect_jit`` records
-    the remembered report and does no other work; anything else differing
-    is a miss that runs the capture and replaces the entry. Only the
-    report is kept — never the executable, its HLO text or an array.
+    ``mesh``, ``precision`` and ``cohort_draw``. Asked for the same
+    program again, ``introspect_jit`` records the remembered report and
+    does no other work; anything else differing is a miss that runs the
+    capture and replaces the entry. Only the report is kept — never the
+    executable, its HLO text or an array.
     ``hits`` / ``misses`` count both outcomes, as does
     ``fl_program_introspections_total{program, result}``."""
 
@@ -264,7 +256,7 @@ class ProgramIntrospector:
             abstract = abstractify(args)
             leaves, treedef = jax.tree_util.tree_flatten(abstract)
             key = (treedef, tuple(leaves), rounds_per_dispatch, mesh,
-                   precision, cohort_draw, stage_attr.enabled())
+                   precision, cohort_draw)
             jitted_ref = weakref.ref(jitted)
             last = self._remembered.get(name)
             hit = (last is not None and last[0]() is jitted
@@ -296,12 +288,6 @@ class ProgramIntrospector:
                     n_partitions=int((mesh or {}).get("n_devices", 1)),
                 ),
             )
-            if stage_attr.enabled():
-                report.stages = hloscan.analyze_compiled(
-                    compiled,
-                    device_kind=report.device_kind,
-                    n_partitions=int((mesh or {}).get("n_devices", 1)),
-                )
         except Exception:
             logger.warning("program introspection failed for %r", name,
                            exc_info=True)
@@ -347,26 +333,6 @@ class ProgramIntrospector:
         for gname, ghelp, value in gauges:
             if value is not None:
                 reg.gauge(gname, help=ghelp, labels=labels).set(float(value))
-        for row in report.stages or ():
-            slabels = {"program": report.name, "stage": row["stage"]}
-            reg.gauge(
-                "fl_stage_flops",
-                help="HLO-attributed FLOPs of one spine stage per dispatch",
-                labels=slabels,
-            ).set(float(row["flops"]))
-            reg.gauge(
-                "fl_stage_bytes",
-                help="HLO-attributed HBM bytes of one spine stage per dispatch",
-                labels=slabels,
-            ).set(float(row["bytes_accessed"]))
-            if "bound" in row:
-                # only when the device roofline is known — never fabricated
-                reg.gauge(
-                    "fl_stage_bound",
-                    help="1 = stage is compute-bound on this chip, 0 = HBM-bound",
-                    labels=slabels,
-                ).set(1.0 if row["bound"] == "compute" else 0.0)
-            reg.log_event("stage", program=report.name, **row)
         reg.log_event("program", **report.as_dict())
         return report
 

@@ -1,22 +1,20 @@
 """Stage naming for the aggregation spine — ``fl_stage::<name>`` scopes.
 
-ROADMAP item 5 gates every fused-kernel investment on profiles showing
-*which* stage of the clip -> quantize -> top-k -> robust-aggregate ->
-server-update spine XLA leaves on the table. Whole-program
-``cost_analysis()`` (observability/introspect.py) cannot answer that; this
-module gives each spine stage a name that survives into the compiled
-program, so ``observability/hloscan.py`` can attribute per-op flops/bytes
-back to it and ``tools/roofline_report.py`` can rank stages by fusion
-headroom.
+Which stage of the clip -> quantize -> top-k -> robust-aggregate ->
+server-update spine the device spends its time in is read from a profiler
+trace, not from a cost model: this module gives each spine stage a name
+that survives into the compiled program (each op's ``op_name`` metadata)
+and from there into the trace's op metadata, where
+``benchmarks/xplane_meta.py`` + ``layer_metrics/stage_common.py`` and
+``tools/roofline_report.py`` sum MEASURED device time per stage.
 
 Mechanism: :func:`stage` wraps a code region in ``jax.named_scope`` with
-the ``fl_stage::`` prefix. Named scopes are **metadata only** — they land
-in each HLO op's ``op_name`` path and in XProf trace op names, and change
-neither the math nor XLA's optimization decisions, so attribution-on
-trajectories stay bit-identical to attribution-off on every execution mode
-(pinned by tests/observability/test_stage_attribution.py). Autodiff and
+the ``fl_stage::`` prefix. Named scopes are **metadata only** — they change
+neither the math nor XLA's optimization decisions (pinned by
+tests/observability/test_stage_attribution.py, whose reference arm traces
+under :func:`disabled`; nothing else turns the scopes off). Autodiff and
 ``vmap``/``scan`` transforms preserve the name stack, so a stage's
-backward-pass ops attribute to the same stage as its forward ops.
+backward-pass ops carry the same stage as its forward ops.
 
 The canonical spine stages (:data:`SPINE_STAGES`):
 
@@ -31,29 +29,20 @@ The canonical spine stages (:data:`SPINE_STAGES`):
   optimizes (Xu et al., arXiv:2004.13336)
 - ``cohort_exchange`` — the in-graph cohort gather/scatter of the chunked
   registry window (server/simulation.py)
-
-Toggle: attribution defaults ON (zero runtime cost). Set
-``FL4HEALTH_STAGE_ATTRIBUTION=0`` in the environment, call
-:func:`set_enabled`, or use the :func:`disabled` context manager to turn
-the scopes (and hloscan's per-stage reports) off; the off path is the
-byte-exact legacy program.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import re
 from typing import Iterator
 
-# The marker hloscan greps for in HLO op_name metadata paths and
-# roofline_report greps for in XProf trace op names. "::" cannot appear in
-# a user module/function name the way "/" separators do, so the prefix
-# never collides with ordinary scope components.
+# The marker the trace readers look for in the ops' name stacks. "::"
+# cannot appear in a user module/function name the way "/" separators do,
+# so the prefix never collides with ordinary scope components.
 STAGE_PREFIX = "fl_stage::"
 
-# Canonical spine stage names, in pipeline order (the order the roofline
-# ledger lists them when headrooms tie).
+# Canonical spine stage names, in pipeline order.
 SPINE_STAGES = (
     "local_train",
     "dp_clip",
@@ -65,32 +54,15 @@ SPINE_STAGES = (
     "cohort_exchange",
 )
 
-# Ops outside any fl_stage scope attribute here (still real work — the
-# conservation check needs them on the ledger, never silently dropped).
-UNATTRIBUTED = "_unattributed"
-
 _STAGE_RE = re.compile(re.escape(STAGE_PREFIX) + r"([A-Za-z0-9_.\-]+)")
 
-_enabled = os.environ.get("FL4HEALTH_STAGE_ATTRIBUTION", "1") != "0"
-
-
-def enabled() -> bool:
-    """True when stage scopes are being applied (process-wide toggle)."""
-    return _enabled
-
-
-def set_enabled(on: bool) -> None:
-    """Flip stage attribution process-wide. Affects programs traced AFTER
-    the call — already-compiled programs keep whatever metadata they were
-    traced with."""
-    global _enabled
-    _enabled = bool(on)
+_enabled = True
 
 
 @contextlib.contextmanager
 def disabled() -> Iterator[None]:
-    """Temporarily trace without stage scopes (the bit-identity tests'
-    off arm)."""
+    """Trace without stage scopes: the reference arm of the bit-identity
+    tests, and nothing else."""
     global _enabled
     prev = _enabled
     _enabled = False
@@ -104,10 +76,9 @@ def disabled() -> Iterator[None]:
 def stage(name: str) -> Iterator[None]:
     """Scope a traced code region as spine stage ``name``.
 
-    A no-op (and zero-overhead at run time either way — named scopes are
-    trace-time metadata) when attribution is disabled. ``jax`` is imported
-    lazily so tools can import this module's parsing helpers without a
-    backend."""
+    Named scopes are trace-time metadata: nothing runs for them. ``jax``
+    is imported lazily so tools can import this module's parsing helpers
+    without a backend."""
     if not _enabled:
         yield
         return

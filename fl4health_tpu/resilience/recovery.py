@@ -182,6 +182,22 @@ def install_kill_hook(checkpointer, kill: KillPoint) -> None:
         return out
 
     checkpointer.save = save
+    if kill.signal_name != "SIGTERM":
+        return
+    # A SIGTERM bundle names the round the run was at when the signal
+    # arrived. A driver that hands its snapshots to the async writer (the
+    # chunked one) runs on while the writer thread saves and signals, and
+    # how far it gets is a race; so the thread that asks for the kill
+    # round's snapshot waits for it, and takes the signal there.
+    orig_snapshot = checkpointer.save_simulation_snapshot
+
+    def save_simulation_snapshot(trees, current_round, *args, writer=None,
+                                 **kwargs):
+        orig_snapshot(trees, current_round, *args, writer=writer, **kwargs)
+        if current_round == kill.round and writer is not None:
+            writer.flush()
+
+    checkpointer.save_simulation_snapshot = save_simulation_snapshot
 
 
 def install_scatter_kill_hook(sim, kill: KillPoint) -> None:

@@ -124,13 +124,12 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "roofline: stage-attribution / roofline-ledger lanes "
-        "(observability/stages.py named-scope markers + hloscan.py "
-        "HLO-walk attribution, tools/roofline_report.py + "
-        "tools/bench_gate.py). The tier-1-safe smoke subset (attribution "
-        "on/off bit-identity per execution mode, hloscan conservation "
-        "pins against cost_analysis, gate pass/regression fixtures) runs "
-        "by default; heavier conservation sweeps also carry 'slow'. "
+        "roofline: stage-scope lanes (observability/stages.py "
+        "named-scope markers, tools/roofline_report.py measured time per "
+        "stage from a trace, tools/bench_gate.py). The tier-1-safe subset "
+        "(scopes on/off bit-identity per execution mode, every spine "
+        "stage's scope found in its compiled round program, gate "
+        "pass/regression fixtures) runs by default. "
         "Select with -m roofline.",
     )
     config.addinivalue_line(

@@ -269,27 +269,6 @@ class TestRememberedReports:
         intro.introspect_jit("p", f, (whole,))
         assert (f.lowers, intro.hits, intro.misses) == (2, 1, 2)
 
-    def test_stage_attribution_toggle_misses(self):
-        from fl4health_tpu.observability import stages
-
-        intro = ProgramIntrospector(MetricsRegistry())
-        f = _CountingJit()
-        x = jnp.ones((8, 8))
-        was = stages.enabled()
-        try:
-            stages.set_enabled(True)
-            on = intro.introspect_jit("p", f, (x, x))
-            stages.set_enabled(False)
-            off = intro.introspect_jit("p", f, (x, x))
-            assert f.lowers == 2 and off.stages is None
-            assert on.stages is not None
-            assert intro.introspect_jit("p", f, (x, x)) is off
-            stages.set_enabled(True)
-            assert intro.introspect_jit("p", f, (x, x)).stages is not None
-            assert f.lowers == 3
-        finally:
-            stages.set_enabled(was)
-
     def test_names_are_remembered_apart(self):
         """fit's eval program runs under two names (validation and test
         shapes): one jitted object, one entry per name."""

@@ -357,13 +357,19 @@ class TestLiveRetuneDrill:
         assert doc["accepted"] == {"server_lr": 0.02}
         assert doc["applies"] == "next_round_boundary"
 
-        # zero recompiles: round 1 pays the XLA compiles, every later
-        # round INCLUDING the retuned one reuses the warm executables
+        # zero recompiles: every round after the first, INCLUDING the
+        # retuned one, reuses the warm executables. How many compiles round
+        # 1 itself counts is this process's history, not the claim: the
+        # introspector builds the round programs in the prologue, and the
+        # round's own dispatch compiles again only where JAX has not
+        # already built the same program in this process. That the counter
+        # behind ``compiles`` is live shows in the run's total.
         rounds = [e for e in obs_live.registry.events
                   if e["event"] == "round"]
         assert len(rounds) == 6
-        assert rounds[0]["compiles"] > 0
         assert [r["compiles"] for r in rounds[1:]] == [0] * 5
+        assert obs_live.registry.counter(
+            "jax_backend_compiles_total").value > 0
 
         # journaled three ways: admin JSONL event, journal, manifest
         admin_events = [e for e in obs_live.registry.events

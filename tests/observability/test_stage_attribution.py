@@ -1,42 +1,47 @@
-"""Stage attribution (observability/stages.py + hloscan.py).
+"""Stage scopes (observability/stages.py).
 
-The pinned contracts of the roofline ledger:
+The pinned contracts:
 
 - the ``fl_stage::`` named-scope markers are METADATA-ONLY — training is
-  bit-identical with attribution on vs off (params AND trajectories) on
+  bit-identical with the scopes on vs off (params AND trajectories) on
   every execution mode, including a cohort-slot run;
-- the HLO-walk attribution conserves against XLA's whole-program
-  ``cost_analysis`` within the pinned tolerances on the 4-client CIFAR
-  CNN config (the bench headline architecture) for ``fit_round`` and
-  ``fit_cohort_chunk``;
-- the spine stages actually land: ``local_train`` / ``server_update`` /
-  ``cohort_exchange`` rows appear where those seams execute, and the
-  ``fl_stage_*`` gauges + ``stage`` events reach the registry;
-- attribution-off runs keep their exact record shape (no ``stages`` key,
-  no stage events) — legacy logs stay byte-stable.
+- every spine stage's scope survives into the COMPILED program that runs
+  its seam (``compiled.as_text()`` holds each op's ``op_name`` name stack):
+  that text is where a profiler trace's op metadata comes from, so a scope
+  missing here reads ``null`` in ``local_train_ms_per_round`` /
+  ``server_update_ms_per_round`` and ``tools/roofline_report.py``;
+- both benchmark model families keep ``local_train`` and ``server_update``
+  in their per-round fit program.
 """
 
 import contextlib
-import json
+import functools
+import re
 
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 import optax
 
 from fl4health_tpu.clients import engine
+from fl4health_tpu.compression import CompressionConfig
 from fl4health_tpu.datasets.synthetic import synthetic_classification
 from fl4health_tpu.metrics import efficient
 from fl4health_tpu.metrics.base import MetricManager
-from fl4health_tpu.models.cnn import CifarNet, Mlp
+from fl4health_tpu.models.cnn import Mlp
+from fl4health_tpu.models.jamba import JambaClassifier
+from fl4health_tpu.models.transformer import TransformerClassifier
 from fl4health_tpu.observability import (
     MetricsRegistry,
     Observability,
     Tracer,
 )
-from fl4health_tpu.observability import hloscan
 from fl4health_tpu.observability import stages as stage_attr
+from fl4health_tpu.observability.introspect import abstractify
+from fl4health_tpu.privacy import dpsgd
+from fl4health_tpu.resilience import RobustFedAvg
 from fl4health_tpu.server.registry import CohortConfig
 from fl4health_tpu.server.simulation import ClientDataset, FederatedSimulation
 from fl4health_tpu.strategies.fedavg import FedAvg
@@ -46,14 +51,19 @@ pytestmark = pytest.mark.roofline
 N_CLASSES = 3
 
 
-def _mlp_sim(n=3, observability=None, cohort=None, mode="auto"):
+def _obs():
+    return Observability(enabled=True, tracer=Tracer(),
+                         registry=MetricsRegistry())
+
+
+def _mlp_sim(n=3, mode="auto", **kwargs):
     datasets = []
     for i in range(n):
         x, y = synthetic_classification(
             jax.random.PRNGKey(i), 40, (6,), N_CLASSES
         )
         datasets.append(ClientDataset(x[:32], y[:32], x[32:], y[32:]))
-    return FederatedSimulation(
+    args = dict(
         logic=engine.ClientLogic(
             engine.from_flax(Mlp(features=(12,), n_outputs=N_CLASSES)),
             engine.masked_cross_entropy,
@@ -65,57 +75,59 @@ def _mlp_sim(n=3, observability=None, cohort=None, mode="auto"):
         metrics=MetricManager((efficient.accuracy(),)),
         local_epochs=1,
         seed=5,
-        observability=observability,
-        cohort=cohort,
+        observability=_obs(),
         execution_mode=mode,
     )
+    args.update(kwargs)
+    return FederatedSimulation(**args)
 
 
-def _cifar_sim(observability, cohort=None, mode="auto"):
-    """The 4-client CIFAR CNN config (the bench headline architecture,
-    shrunk to 16 train rows/client so the CPU fit stays seconds)."""
+def _token_sim(module):
+    """Three clients of a toy token classifier on the per-round driver: the
+    benchmark cells' job at toy size."""
+    rng = np.random.default_rng(0)
     datasets = []
-    for i in range(4):
-        x = np.random.RandomState(i).randn(24, 32, 32, 3).astype("float32")
-        y = np.random.RandomState(100 + i).randint(
-            0, 10, size=(24,)
-        ).astype("int32")
-        datasets.append(ClientDataset(x[:16], y[:16], x[16:], y[16:]))
+    for n in (12, 20, 16):
+        x = rng.integers(1, 50, (n, 8)).astype(np.int32)
+        y = (x[:, 0] % N_CLASSES).astype(np.int32)
+        datasets.append(ClientDataset(x[:n - 4], y[:n - 4],
+                                      x[n - 4:], y[n - 4:]))
     return FederatedSimulation(
-        logic=engine.ClientLogic(
-            engine.from_flax(CifarNet()), engine.masked_cross_entropy
-        ),
-        tx=optax.sgd(0.05),
-        strategy=FedAvg(),
-        datasets=datasets,
-        batch_size=8,
-        metrics=MetricManager((efficient.accuracy(),)),
-        local_steps=2,
-        seed=0,
-        observability=observability,
-        cohort=cohort,
-        execution_mode=mode,
-    )
+        logic=engine.ClientLogic(engine.from_flax(module),
+                                 engine.masked_cross_entropy),
+        tx=optax.sgd(0.005), strategy=FedAvg(), datasets=datasets,
+        batch_size=4, metrics=MetricManager((efficient.accuracy(),)),
+        local_steps=2, seed=3, execution_mode="pipelined",
+        observability=_obs())
 
 
-def _obs(tmp_path, tag):
-    return Observability(
-        enabled=True,
-        output_dir=str(tmp_path / f"obs_{tag}"),
-        tracer=Tracer(),
-        registry=MetricsRegistry(),
-    )
+def _compiled_texts(sim):
+    """name -> optimised-HLO text of every round program ``fit()`` builds:
+    what the introspector is asked about at build time, compiled here in
+    its place."""
+    texts = {}
+
+    def compile_instead(name, jitted, args, **_):
+        texts[name] = jitted.lower(*abstractify(args)).compile().as_text()
+
+    sim.observability.introspector.introspect_jit = compile_instead
+    sim.fit(1)
+    return texts
+
+
+def _scopes(text):
+    return set(re.findall(re.escape(stage_attr.STAGE_PREFIX) + r"(\w+)", text))
 
 
 def _flat(tree):
     return np.asarray(jax.flatten_util.ravel_pytree(jax.device_get(tree))[0])
 
 
-def _run(tmp_path, tag, attribution_on, rounds=3, **kwargs):
-    ctx = (contextlib.nullcontext() if attribution_on
+def _run(scopes_on, rounds=3, **kwargs):
+    ctx = (contextlib.nullcontext() if scopes_on
            else stage_attr.disabled())
     with ctx:
-        sim = _mlp_sim(observability=_obs(tmp_path, tag), **kwargs)
+        sim = _mlp_sim(**kwargs)
         history = sim.fit(rounds)
     params = _flat(sim.strategy.global_params(sim.server_state))
     losses = np.asarray(
@@ -124,110 +136,126 @@ def _run(tmp_path, tag, attribution_on, rounds=3, **kwargs):
     return params, losses
 
 
+class TestStageOf:
+    def test_basic(self):
+        assert stage_attr.stage_of("jit(f)/fl_stage::dp_clip/add") == "dp_clip"
+
+    def test_innermost_wins(self):
+        path = "jit(f)/fl_stage::server_update/fl_stage::robust_aggregate/x"
+        assert stage_attr.stage_of(path) == "robust_aggregate"
+
+    def test_none_without_marker(self):
+        assert stage_attr.stage_of("jit(f)/transpose/add") is None
+        assert stage_attr.stage_of(None) is None
+        assert stage_attr.stage_of("") is None
+
+
 class TestBitIdentity:
-    """Attribution on vs off: params AND trajectories bitwise equal —
-    named scopes must never change what XLA computes."""
+    """Scopes on vs off: params AND trajectories bitwise equal — named
+    scopes must never change what XLA computes."""
 
-    def test_pipelined(self, tmp_path):
-        pa, la = _run(tmp_path, "pipe_on", True, mode="pipelined")
-        pb, lb = _run(tmp_path, "pipe_off", False, mode="pipelined")
-        np.testing.assert_array_equal(pa, pb)
-        np.testing.assert_array_equal(la, lb)
-
-    def test_chunked(self, tmp_path):
-        pa, la = _run(tmp_path, "chunk_on", True, mode="chunked")
-        pb, lb = _run(tmp_path, "chunk_off", False, mode="chunked")
-        np.testing.assert_array_equal(pa, pb)
-        np.testing.assert_array_equal(la, lb)
-
-    def test_cohort_chunked(self, tmp_path):
-        kw = dict(cohort=CohortConfig(slots=3), mode="chunked")
-        pa, la = _run(tmp_path, "co_on", True, **kw)
-        pb, lb = _run(tmp_path, "co_off", False, **kw)
+    @pytest.mark.parametrize("kwargs", [
+        dict(mode="pipelined"),
+        dict(mode="chunked"),
+        dict(cohort=CohortConfig(slots=3), mode="chunked"),
+    ], ids=["pipelined", "chunked", "cohort_chunked"])
+    def test_scopes_change_nothing(self, kwargs):
+        pa, la = _run(True, **kwargs)
+        pb, lb = _run(False, **kwargs)
         np.testing.assert_array_equal(pa, pb)
         np.testing.assert_array_equal(la, lb)
 
 
-class TestAttributionRecords:
-    def test_stages_rows_gauges_and_events_land(self, tmp_path):
-        obs = _obs(tmp_path, "rows")
-        sim = _mlp_sim(observability=obs, mode="pipelined")
-        sim.fit(2)
-        reports = obs.introspector.reports
-        fit = reports.get("fit_round_t") or reports["fit_round"]
-        assert fit.stages, "fit_round must carry attribution rows"
-        by_stage = {r["stage"]: r for r in fit.stages}
-        assert "local_train" in by_stage
-        assert "server_update" in by_stage
-        assert by_stage["local_train"]["flops"] > 0
-        # conservation against the whole-program cost analysis
-        cons = hloscan.conservation(fit.stages, fit.flops,
-                                    fit.bytes_accessed)
-        assert cons["ok"], cons
-        # gauges + events reached the registry
-        text = obs.registry.to_prometheus()
-        assert "fl_stage_flops" in text
-        assert 'stage="local_train"' in text
-        # fit() exported (and drained) the event log itself — read the
-        # metrics.jsonl it wrote
-        with open(tmp_path / "obs_rows" / "metrics.jsonl") as f:
-            events = [json.loads(line) for line in f]
-        stage_events = [e for e in events if e.get("event") == "stage"]
-        assert any(e["stage"] == "local_train" for e in stage_events)
-        # a stage event carries the full row (program + cost fields)
-        row = stage_events[0]
-        for key in ("program", "stage", "flops", "bytes_accessed"):
-            assert key in row
+class TestScopesInCompiledPrograms:
+    def test_client_step_and_server_update_on_both_drivers(self):
+        """The per-round fit program and the chunked scan both carry the two
+        scopes every cell's stage metrics read, and the backward pass of
+        the client step stays under ``local_train`` (autodiff keeps the
+        name stack)."""
+        per_round = _compiled_texts(_mlp_sim(mode="pipelined"))
+        chunk = _compiled_texts(_mlp_sim(mode="chunked"))
+        assert set(chunk) == {"fit_chunk_eval"}
+        for text in (per_round["fit_round_t"], chunk["fit_chunk_eval"]):
+            assert _scopes(text) == {"local_train", "server_update"}
+            assert any("fl_stage::local_train" in line and "transpose(" in line
+                       for line in text.splitlines())
 
-    def test_cohort_exchange_stage_lands_on_cohort_chunk(self, tmp_path):
-        obs = _obs(tmp_path, "cochunk")
-        sim = _mlp_sim(observability=obs, cohort=CohortConfig(slots=3),
-                       mode="chunked")
-        sim.fit(2)
-        chunk = obs.introspector.reports["fit_cohort_chunk"]
-        assert chunk.stages
-        names = {r["stage"] for r in chunk.stages}
-        assert "cohort_exchange" in names
-        assert "local_train" in names
-
-    def test_attribution_off_keeps_record_shape(self, tmp_path):
-        with stage_attr.disabled():
-            obs = _obs(tmp_path, "off")
-            sim = _mlp_sim(observability=obs, mode="pipelined")
-            sim.fit(2)
-            reports = obs.introspector.reports
-            fit = reports.get("fit_round_t") or reports["fit_round"]
-            assert fit.stages is None
-            # legacy record shape: no "stages" key, no stage events
-            assert "stages" not in fit.as_dict()
-        with open(tmp_path / "obs_off" / "metrics.jsonl") as f:
-            events = [json.loads(line) for line in f]
-        assert not [e for e in events if e.get("event") == "stage"]
-        assert "fl_stage_flops" not in obs.registry.to_prometheus()
+    def test_cohort_exchange_is_the_cohort_chunks_own(self):
+        """The in-graph gather/scatter of the registry window exists in the
+        chunk scan only: the slot programs beside it draw on the host."""
+        texts = _compiled_texts(
+            _mlp_sim(cohort=CohortConfig(slots=3), mode="chunked"))
+        assert _scopes(texts["fit_cohort_chunk"]) == {
+            "cohort_exchange", "local_train", "server_update"}
+        assert _scopes(texts["fit_round_t"]) == {"local_train",
+                                                 "server_update"}
 
 
-class TestConservationCifar:
-    """The acceptance pin: hloscan's per-stage sum reconciles with XLA's
-    whole-program cost analysis on the 4-client CIFAR CNN config, for
-    both the per-round program and the cohort chunk scan."""
+@functools.cache
+def _seam_text(seam):
+    """The compiled text of the smallest program that runs ``seam``."""
+    if seam == "fused_dp":
+        # the fused clip kernel is opt-in at its one call site and no client
+        # logic passes the flag (ROADMAP S8): the seam's smallest program is
+        # that call itself, under the clients' vmap the engine would put it in
+        grads = {"w": jnp.ones((2, 6, 4, 3)), "b": jnp.ones((2, 6, 3))}
 
-    def test_fit_round_and_fit_cohort_chunk_conserve(self, tmp_path):
-        obs = _obs(tmp_path, "cifar")
-        sim = _cifar_sim(obs, cohort=CohortConfig(slots=4), mode="chunked")
-        sim.fit(2)
-        reports = obs.introspector.reports
-        fit_name = ("fit_round_t" if "fit_round_t" in reports
-                    else "fit_round")
-        for name in (fit_name, "fit_cohort_chunk"):
-            rep = reports[name]
-            assert rep.stages, f"{name} must carry attribution rows"
-            assert {r["stage"] for r in rep.stages} >= {
-                "local_train", "server_update"
-            }
-            cons = hloscan.conservation(rep.stages, rep.flops,
-                                        rep.bytes_accessed)
-            assert cons["ok"], (name, cons)
-            assert cons["flops_rel_err"] <= hloscan.FLOPS_RTOL
-            assert cons["bytes_rel_err"] <= hloscan.BYTES_RTOL
-        chunk = reports["fit_cohort_chunk"]
-        assert {r["stage"] for r in chunk.stages} >= {"cohort_exchange"}
+        def clipped(g, rng):
+            return dpsgd.noisy_clipped_mean_grads(
+                g, jnp.ones((6,)), rng, 0.5, 1.0, use_fused_kernel=True)
+
+        return jax.jit(jax.vmap(clipped)).lower(
+            grads, jax.random.split(jax.random.PRNGKey(0), 2)
+        ).compile().as_text()
+    program, kwargs = {
+        "plain": ("fit_round_t", dict(mode="pipelined")),
+        "compressed": ("fit_round_t", dict(
+            mode="pipelined",
+            compression=CompressionConfig(topk_fraction=0.25, quant_bits=8,
+                                          rotation=True))),
+        "robust": ("fit_round_t", dict(mode="pipelined",
+                                       strategy=RobustFedAvg("median"))),
+        "cohort_chunk": ("fit_cohort_chunk", dict(
+            cohort=CohortConfig(slots=3), mode="chunked")),
+    }[seam]
+    return _compiled_texts(_mlp_sim(**kwargs))[program]
+
+
+SEAM_OF = {
+    "local_train": "plain",
+    "dp_clip": "fused_dp",
+    "rotation": "compressed",
+    "topk": "compressed",
+    "quantize": "compressed",
+    "robust_aggregate": "robust",
+    "server_update": "plain",
+    "cohort_exchange": "cohort_chunk",
+}
+
+
+@pytest.mark.parametrize("stage", stage_attr.SPINE_STAGES)
+def test_every_spine_stage_names_its_seam_in_the_compiled_program(stage):
+    assert stage in _scopes(_seam_text(SEAM_OF[stage]))
+
+
+@functools.cache
+def _model_fit_text(family):
+    if family == "transformer":
+        module = TransformerClassifier(
+            vocab_size=50, n_classes=N_CLASSES, d_model=16, n_heads=2,
+            n_layers=2, d_ff=32, max_len=8)
+    else:
+        # one Mamba layer and one attention layer over a shared base
+        module = JambaClassifier(
+            vocab_size=50, n_classes=N_CLASSES, d_model=16, n_layers=2,
+            d_ff=32, n_heads=2, n_kv_heads=1, d_state=4, dt_rank=4,
+            attn_layer_period=2, attn_layer_offset=1, lora_rank=2)
+    return _compiled_texts(_token_sim(module))["fit_round_t"]
+
+
+@pytest.mark.parametrize("stage", ["local_train", "server_update"])
+@pytest.mark.parametrize("family", ["transformer", "jamba"])
+def test_the_cells_model_families_keep_both_stage_scopes(family, stage):
+    """``local_train_ms_per_round`` and ``server_update_ms_per_round`` read
+    exactly these two strings out of a trace of ``fit_round_t``."""
+    assert f"fl_stage::{stage}" in _model_fit_text(family)

@@ -140,8 +140,9 @@ def _assert_sigterm_bundle(tmp_path, killed, ckpt_dir, kill_round,
     triggered the kill, so the signal can arrive with the run at a
     slightly later round — the verdict honestly names where the run WAS.
     The chunked mode records epilogues on the main thread (the thread the
-    signal interrupts), so there the signal round is exact
-    (``max_round=None``)."""
+    signal interrupts) and the kill hook makes that thread wait for the
+    kill round's save, which the async writer does, so there the signal
+    round is exact (``max_round=None``)."""
     import json
     import subprocess
     import sys as _sys
@@ -191,8 +192,9 @@ def test_sigterm_mid_fit_publishes_postmortem_bundle(tmp_path):
     the published bundle is complete and self-consistent — CRC-valid ring
     frame, loadable trace.json, verdict.json naming the kill round, and
     tools/postmortem.py renders it without the original process's state.
-    Chunked mode: the signal interrupts the SAME thread that records
-    epilogues, so the signal round is exactly the kill round."""
+    Chunked mode: the driver thread records the epilogues, waits in the
+    kill hook for the writer thread's save of the kill round and takes the
+    signal there, so the signal round is exactly the kill round."""
     ckpt_dir = tmp_path / "drill_ckpt"
     killed = _run(
         tmp_path, "sigterm", "sync_chunked_flightrec", 4, ckpt_dir,
@@ -265,6 +267,42 @@ def test_killpoint_registry_scatter_validation():
         )
     with pytest.raises(ValueError, match="registry_scatter"):
         install_scatter_kill_hook(_NoRegistry(), KillPoint(round=2))
+
+
+@pytest.mark.parametrize("signal_name,waits_at", [("SIGTERM", [2]),
+                                                 ("SIGKILL", [])])
+def test_sigterm_kill_hook_waits_for_the_kill_rounds_save(signal_name,
+                                                          waits_at):
+    """The chunked driver hands its snapshots to the writer thread and runs
+    on. A SIGTERM drill names the round the run was at, so the thread that
+    asks for the kill round's snapshot waits for the writer there, and
+    nowhere else; a SIGKILL drill takes the process wherever it is."""
+    from fl4health_tpu.resilience.recovery import KillPoint, install_kill_hook
+
+    class Writer:
+        def __init__(self):
+            self.submitted, self.flushed_after = [], []
+
+        def submit(self, fn, **kwargs):  # never runs the job: no signal here
+            self.submitted.append(kwargs["extra_meta"]["round"])
+
+        def flush(self):
+            self.flushed_after.append(self.submitted[-1])
+
+    class Checkpointer:
+        def save(self, **kwargs):
+            raise AssertionError("the writer never runs a job in this test")
+
+        def save_simulation_snapshot(self, trees, current_round, n_clients,
+                                     history, writer=None, fleet=None):
+            writer.submit(self.save, extra_meta={"round": current_round})
+
+    ckpt, writer = Checkpointer(), Writer()
+    install_kill_hook(ckpt, KillPoint(round=2, signal_name=signal_name))
+    for rnd in (1, 2, 3):
+        ckpt.save_simulation_snapshot({}, rnd, 4, [], writer=writer)
+    assert writer.submitted == [1, 2, 3]
+    assert writer.flushed_after == waits_at
 
 
 @pytest.mark.crash

@@ -1036,109 +1036,28 @@ def test_json_mode_carries_fleet_key(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# stage-attribution ledger (roofline PR): stage tables light up only when a
-# log carries 'stage' events — legacy logs keep their exact output shape
+# logs written before the cost-model ledger went (PR 29) still hold 'stage'
+# events: they are read past, never rendered
 
 
-def _stage_event(program, stage, **kw):
-    base = dict(ts=0.0, event="stage", program=program, stage=stage,
-                flops=1e9, transcendentals=0.0, bytes_accessed=1e6,
-                ops=3, custom_calls=0, fusion_headroom_bytes=1e5)
-    base.update(kw)
-    return base
-
-
-def _staged_log(tmp_path, stage_events):
-    path = tmp_path / "staged.jsonl"
-    with open(path, "w") as f:
-        f.write(json.dumps({"ts": 0, "event": "round", **_round(1)}) + "\n")
-        for e in stage_events:
-            f.write(json.dumps(e) + "\n")
-    return str(path)
-
-
-def test_latest_stages_dedupes_and_orders():
-    stages = perf_report._latest_stages([
-        _stage_event("fit_round", "_unattributed", flops=9e12),
-        _stage_event("eval_round", "local_train", flops=1.0),
-        _stage_event("fit_round", "local_train", flops=2.0),
-        # a second fit in the same log: the LATEST record wins
-        _stage_event("fit_round", "local_train", flops=8e9),
-        _stage_event("fit_round", "server_update", flops=3.0),
-    ])
-    keyed = [(s["program"], s["stage"]) for s in stages]
-    # program asc; within a program flops desc with _unattributed last
-    assert keyed == [
-        ("eval_round", "local_train"),
-        ("fit_round", "local_train"),
-        ("fit_round", "server_update"),
-        ("fit_round", "_unattributed"),
-    ]
-    assert stages[1]["flops"] == 8e9
-
-
-def test_render_stage_table_columns_and_honest_dashes():
-    table = perf_report.render_stage_table([
-        _stage_event("fit_round", "local_train",
-                     intensity_flops_per_byte=120.0, bound="compute",
-                     fusion_headroom_frac=0.25),
-        _stage_event("fit_round", "quantize"),
-    ])
-    lines = table.splitlines()
-    assert lines[0].split() == ["program", "stage", "flops", "bytes",
-                                "intensity", "bound", "headroom",
-                                "headroom%"]
-    assert "compute" in lines[2] and "25.0%" in lines[2]
-    # unknown-roofline row: bound renders '-', never a fabricated class
-    assert "-" in lines[3].split()
-    assert all(len(ln) == len(lines[0]) for ln in lines)
-
-
-def test_cli_stage_table_lights_up_with_stage_events(tmp_path):
-    path = _staged_log(tmp_path, [
-        _stage_event("fit_round", "local_train"),
-        _stage_event("fit_round", "server_update", flops=2e6),
-    ])
-    out = subprocess.run(
-        [sys.executable, str(REPO / "tools" / "perf_report.py"), path],
-        capture_output=True, text=True, check=True,
-    )
-    assert "local_train" in out.stdout and "server_update" in out.stdout
-    out = subprocess.run(
-        [sys.executable, str(REPO / "tools" / "perf_report.py"), path,
-         "--json"],
-        capture_output=True, text=True, check=True,
-    )
-    doc = json.loads(out.stdout)
-    assert [s["stage"] for s in doc["stages"]] == ["local_train",
-                                                   "server_update"]
-
-
-def test_cli_legacy_log_byte_stable_without_stage_events(tmp_path):
-    legacy = _log(tmp_path, [_round(1), _round(2)])
-    out = subprocess.run(
-        [sys.executable, str(REPO / "tools" / "perf_report.py"), legacy],
-        capture_output=True, text=True, check=True,
-    )
-    # exact legacy shape: round table + summary block, no stage ledger
-    rounds = perf_report.load_round_events(legacy)
-    expected = perf_report.render_table(rounds) + "\n\n" + "\n".join(
-        f"{k}: {v}" for k, v in perf_report.summarize(rounds).items()
-    ) + "\n"
-    assert out.stdout == expected
-    assert "stage" not in out.stdout
-    out = subprocess.run(
-        [sys.executable, str(REPO / "tools" / "perf_report.py"), legacy,
-         "--json"],
-        capture_output=True, text=True, check=True,
-    )
-    assert "stages" not in json.loads(out.stdout)
-
-
-def test_load_stage_events_round_trips(tmp_path):
-    path = _staged_log(tmp_path, [_stage_event("fit_round", "dp_clip")])
-    stages = perf_report.load_stage_events(path)
-    assert [s["stage"] for s in stages] == ["dp_clip"]
+def test_cli_reads_past_the_stage_events_of_an_older_log(tmp_path):
+    plain = _log(tmp_path, [_round(1), _round(2)])
+    older = tmp_path / "older.jsonl"
+    with open(plain) as f, open(older, "w") as out:
+        out.write(f.read())
+        out.write(json.dumps(dict(
+            ts=0.0, event="stage", program="fit_round", stage="local_train",
+            flops=1e9, bytes_accessed=1e6, fusion_headroom_bytes=1e5)) + "\n")
+    for flags in ([], ["--json"]):
+        got = [
+            subprocess.run(
+                [sys.executable, str(REPO / "tools" / "perf_report.py"),
+                 str(path), *flags],
+                capture_output=True, text=True, check=True,
+            ).stdout
+            for path in (plain, older)
+        ]
+        assert got[0] == got[1]
 
 
 # -- operations-plane columns (SLO engine + admin retune PR) ----------------

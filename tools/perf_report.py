@@ -339,61 +339,6 @@ def _fmt_program_cell(field: str, rec: dict) -> str:
     return str(int(v))
 
 
-def _latest_stages(stage_events: list[dict]) -> list[dict]:
-    """LAST record per (program, stage) — a log may hold several fits —
-    sorted by program, then flops descending with ``_unattributed`` last
-    (the reading order of a roofline ledger)."""
-    latest: dict[tuple, dict] = {}
-    for rec in stage_events:
-        if rec.get("program") and rec.get("stage"):
-            latest[(rec["program"], rec["stage"])] = rec
-
-    def order(rec: dict):
-        tail = rec["stage"] == "_unattributed"
-        return (rec["program"], tail, -float(rec.get("flops") or 0.0))
-
-    return sorted(latest.values(), key=order)
-
-
-def load_stage_events(path: str) -> list[dict]:
-    """The per-stage attribution records (observability/hloscan.py via
-    introspect), deduped to the latest report per (program, stage)."""
-    return _latest_stages(load_events(path).get("stage", []))
-
-
-def render_stage_table(stages: list[dict]) -> str:
-    """Per-stage roofline ledger table from ``stage`` events: attributed
-    flops/bytes, arithmetic intensity, bound classification (only when the
-    chip's roofline is known — never fabricated) and fusion headroom.
-    Rendered only when a log carries ``stage`` events, so legacy logs keep
-    their exact output shape."""
-    def fmt(rec, field, spec="{:.4g}"):
-        v = rec.get(field)
-        if v is None or (isinstance(v, float) and v != v):
-            return "-"
-        if isinstance(v, str):
-            return v
-        return spec.format(float(v))
-
-    return _render_generic_table(
-        ("program", "stage", "flops", "bytes", "intensity", "bound",
-         "headroom", "headroom%"),
-        (
-            [
-                str(rec.get("program", "-")),
-                str(rec.get("stage", "-")),
-                fmt(rec, "flops"),
-                fmt(rec, "bytes_accessed"),
-                fmt(rec, "intensity_flops_per_byte", "{:.3g}"),
-                fmt(rec, "bound"),
-                fmt(rec, "fusion_headroom_bytes"),
-                fmt(rec, "fusion_headroom_frac", "{:.1%}"),
-            ]
-            for rec in stages
-        ),
-    )
-
-
 def load_fault_events(path: str) -> list[dict]:
     """The ``fault`` injection records (resilience/faults.py FaultPlan
     host mirror), sorted by round."""
@@ -758,7 +703,6 @@ def main(argv: list[str] | None = None) -> int:
         events = load_events(args.log)  # ONE parse serves every table
         rounds = _sorted_rounds(events.get("round", []))
         programs = _latest_programs(events.get("program", []))
-        stages = _latest_stages(events.get("stage", []))
         faults = _sorted_rounds(events.get("fault", []))
         quarantine = _sorted_rounds(events.get("quarantine", []))
         recovery = _sorted_rounds(events.get("recovery", []))
@@ -805,9 +749,6 @@ def main(argv: list[str] | None = None) -> int:
         doc = {"summary": summarize(rounds), "rounds": rounds}
         if programs:
             doc["programs"] = programs
-        if stages:
-            # stage-attribution runs only — legacy JSON keeps its exact shape
-            doc["stages"] = stages
         if faults:
             doc["faults"] = faults
         if quarantine:
@@ -836,11 +777,6 @@ def main(argv: list[str] | None = None) -> int:
         # compiled program — legacy logs keep the exact old output shape
         print()
         print(render_program_table(programs))
-    if stages:
-        # stage-attribution runs only (observability/stages.py scopes on):
-        # the roofline ledger — legacy logs keep the exact old output shape
-        print()
-        print(render_stage_table(stages))
     if faults:
         # resilience chaos layer active: disclose what was injected
         print()

@@ -104,14 +104,6 @@ def build_report(bundle: dict) -> dict:
         "suspects": rank_suspects(ring, ledger=fleet),
         "wire": wire_stats(ring),
     }
-    stages = perf_report._latest_stages([
-        e for e in bundle.get("events") or [] if e.get("event") == "stage"
-    ])
-    if stages:
-        # stage-attribution runs only (observability/hloscan.py): the
-        # roofline ledger at the moment of death — pre-attribution bundles
-        # keep their exact report shape
-        report["stages"] = stages
     if fleet:
         clients = fleet.get("clients") or []
         part = [int(c.get("rounds_participated") or 0) for c in clients]
@@ -187,10 +179,6 @@ def render_text(report: dict) -> str:
     if report["timeline"]:
         lines.append("round timeline (flight ring):")
         lines.append(perf_report.render_table(report["timeline"]))
-        lines.append("")
-    if report.get("stages"):
-        lines.append("stage roofline ledger (at capture):")
-        lines.append(perf_report.render_stage_table(report["stages"]))
         lines.append("")
     onset = report.get("divergence_onset")
     if onset:

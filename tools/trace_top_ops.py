@@ -10,7 +10,7 @@ box where the tensorboard profile plugin can't be installed.
 
 Per-stage device time is not here: a TPU trace names its ops by HLO text,
 and the ``fl_stage::`` scope sits in the raw ``.xplane.pb``'s event metadata
-— ``tools/roofline_report.py --trace`` reads it from there.
+— ``tools/roofline_report.py`` reads it from there.
 
 Exit codes follow the bundle-CLI convention: 0 ok, 1 no trace found,
 2 unreadable/corrupt/torn trace (with a diagnostic, never a traceback).
