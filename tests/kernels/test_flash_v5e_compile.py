@@ -140,9 +140,12 @@ SCAN_ROWS = "bf16[4,1,2048,5120]"
 SCAN_KERNELS = {
     # y and the states at the starts of the 32 time blocks
     "ssm_scan_fwd": (SCAN_ROWS, "f32[4,1,32,16,5120]"),
-    # dx, dDelta, dz, the per-lane partial sums of dB and dC, dA, dD
+    # dx, dDelta, dz, the per-lane partial sums of dB and dC, dA, dD. The
+    # partial sums carry NO channel-block axis: the kernel sums the 8 channel
+    # blocks of a time block in VMEM, so what leaves the chip is B's own
+    # lane-splat size, 67 MB, and not 537 (f32[4,1,8,2048,16,128])
     "ssm_scan_bwd": (SCAN_ROWS, SCAN_ROWS, SCAN_ROWS,
-                     "f32[4,1,8,2048,16,128]", "f32[4,1,8,2048,16,128]",
+                     "f32[4,1,2048,16,128]", "f32[4,1,2048,16,128]",
                      "f32[4,1,16,5120]", "f32[4,1,1,5120]"),
 }
 

@@ -10,6 +10,7 @@ import pytest
 
 from fl4health_tpu.kernels.selective_scan import (BLOCK_T, SCOPE, UNROLL,
                                                   _blocked_scan,
+                                                  _channel_block,
                                                   selective_scan,
                                                   selective_scan_reference)
 
@@ -17,14 +18,19 @@ D_INNER, D_STATE = 24, 4
 NAMES = ("x", "dt", "a", "b", "c", "d", "z")
 
 
-def _operands(key, lead, t, dtype=jnp.float32):
+# clients batch x, dt, b, c, z; a and d are shared
+CLIENT_AXES = (0, 0, None, 0, 0, None, 0)
+
+
+def _operands(key, lead, t, dtype=jnp.float32, d_inner=D_INNER,
+              d_state=D_STATE):
     ks = jax.random.split(key, 7)
     n = jax.random.normal
-    x = n(ks[0], (*lead, t, D_INNER)).astype(dtype)
-    dt = jax.nn.softplus(n(ks[1], (*lead, t, D_INNER))).astype(dtype)
-    a = -jnp.exp(0.3 * n(ks[2], (D_INNER, D_STATE)))
-    b, c = n(ks[3], (*lead, t, D_STATE)), n(ks[4], (*lead, t, D_STATE))
-    d, z = n(ks[5], (D_INNER,)), n(ks[6], (*lead, t, D_INNER)).astype(dtype)
+    x = n(ks[0], (*lead, t, d_inner)).astype(dtype)
+    dt = jax.nn.softplus(n(ks[1], (*lead, t, d_inner))).astype(dtype)
+    a = -jnp.exp(0.3 * n(ks[2], (d_inner, d_state)))
+    b, c = n(ks[3], (*lead, t, d_state)), n(ks[4], (*lead, t, d_state))
+    d, z = n(ks[5], (d_inner,)), n(ks[6], (*lead, t, d_inner)).astype(dtype)
     return x, dt, a, b, c, d, z
 
 
@@ -61,8 +67,8 @@ def test_every_gradient_matches_under_vmap_and_checkpoint(t, chunk, wrap):
     def lift(fn):
         if "checkpoint" in wrap:
             fn = jax.checkpoint(fn)
-        if "vmap" in wrap:  # clients batch x, dt, b, c, z; a and d are shared
-            fn = jax.vmap(fn, in_axes=(0, 0, None, 0, 0, None, 0))
+        if "vmap" in wrap:
+            fn = jax.vmap(fn, in_axes=CLIENT_AXES)
         return lambda *args: jnp.sum(fn(*args) * weight)
 
     got = jax.grad(lift(chunked), argnums=tuple(range(7)))(*ops)
@@ -76,14 +82,10 @@ def test_every_gradient_matches_under_vmap_and_checkpoint(t, chunk, wrap):
 @pytest.mark.parametrize("d_inner,d_state", [(256, 16), (384, 8)])
 def test_channel_blocks_of_several_lane_chunks(d_inner, d_state):
     """d_inner a multiple of 128: the TPU's blocking (one [d_state, 128] tile
-    of B and C against every 128-lane chunk, dB and dC folded over chunks)."""
-    ks = jax.random.split(jax.random.PRNGKey(d_inner), 7)
-    n = jax.random.normal
-    ops = (n(ks[0], (2, 24, d_inner)),
-           jax.nn.softplus(n(ks[1], (2, 24, d_inner))),
-           -jnp.exp(0.3 * n(ks[2], (d_inner, d_state))),
-           n(ks[3], (2, 24, d_state)), n(ks[4], (2, 24, d_state)),
-           n(ks[5], (d_inner,)), n(ks[6], (2, 24, d_inner)))
+    of B and C against every 128-lane chunk, dB and dC folded over chunks);
+    each of these widths is ONE channel block of two or three chunks."""
+    ops = _operands(jax.random.PRNGKey(d_inner), (2,), 24, d_inner=d_inner,
+                    d_state=d_state)
     _close(_scan_at(*ops, block_t=8, unroll=8),
            selective_scan_reference(*ops))
     got = jax.grad(lambda *a: jnp.sum(_scan_at(*a, block_t=8, unroll=2)
@@ -93,6 +95,67 @@ def test_channel_blocks_of_several_lane_chunks(d_inner, d_state):
     for name, g, w in zip(NAMES, got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-3,
                                    rtol=2e-4, err_msg=name)
+
+
+# d_inner -> channel blocks: 1,152 = 3 x 384 (three lane chunks a block),
+# 896 = 7 x 128. 384 alone is ONE block of three chunks, as 256 is one of two.
+@pytest.mark.parametrize("d_inner,blocks", [(1152, 3), (896, 7)])
+def test_several_channel_blocks_and_time_blocks_at_once(d_inner, blocks):
+    """Channels innermost on the grid: every channel block's state waits in
+    VMEM while the others step through the same time block, dB and dC sum
+    over the channel blocks of a time block, dA and dD come round once a time
+    block. Three time blocks x three or seven channel blocks x two sequences
+    x three clients of different inputs, under vmap + checkpoint: a state or
+    an accumulator that leaks into the next sequence, channel block or time
+    block shows in the forward or in a gradient."""
+    d_state, t, block_t = 4, 24, 8
+    assert d_inner // _channel_block(d_inner, True) == blocks
+    ops = _operands(jax.random.PRNGKey(d_inner), (3, 2), t, d_inner=d_inner,
+                    d_state=d_state)
+    weight = jax.random.normal(jax.random.PRNGKey(9), ops[0].shape)
+
+    def lift(fn):
+        fn = jax.vmap(jax.checkpoint(fn), in_axes=CLIENT_AXES)
+        return lambda *args: jnp.sum(fn(*args) * weight)
+
+    def blocked(*args):
+        return _scan_at(*args, block_t=block_t, unroll=4)
+
+    _close(jax.vmap(blocked, in_axes=CLIENT_AXES)(*ops),
+           jax.vmap(selective_scan_reference, in_axes=CLIENT_AXES)(*ops),
+           tol=1e-4)
+    got = jax.grad(lift(blocked), argnums=tuple(range(7)))(*ops)
+    want = jax.grad(lift(selective_scan_reference),
+                    argnums=tuple(range(7)))(*ops)
+    for name, g, w in zip(NAMES, got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-3,
+                                   rtol=2e-4, err_msg=name)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_no_output_of_the_backward_call_has_a_channel_block_axis():
+    """What the backward call writes does not grow with the number of channel
+    blocks: the per-lane partial sums of dB and dC are [B, T, d_state, 128],
+    B's own lane-splat shape, whatever d_inner / block is (seven here)."""
+    d_inner, d_state, t = 896, 4, 24
+    ops = _operands(jax.random.PRNGKey(2), (2,), t, d_inner=d_inner,
+                    d_state=d_state)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(_scan_at(*a, block_t=8, unroll=4)),
+        argnums=tuple(range(7))))(*ops)
+    (bwd,) = [e for e in _pallas_calls(jaxpr.jaxpr)
+              if e.params["name"] == "ssm_scan_bwd"]
+    shapes = [v.aval.shape for v in bwd.outvars]
+    seq, part = (2, t, d_inner), (2, t, d_state, 128)
+    assert shapes == [seq, seq, seq, part, part, (2, d_state, d_inner),
+                      (2, 1, d_inner)]
 
 
 def test_the_compiled_kernel_refuses_a_width_off_the_lanes():
