@@ -111,12 +111,18 @@ class ModelDef:
     the client vmap and the local-step scan, so whatever it does to the
     shared leaves before the forward (the cast of a base's matrices to the
     compute type) happens once. :func:`merged_forward` is the plain form.
+
+    ``build_gauges(batch_shape, n_clients) -> {name: number}``, if the model
+    has one, gives static facts of its layers (a routed layer's experts held
+    and its row bound) that the simulation reports once at build, beside the
+    split's own counts.
     """
 
     init: Callable[[PRNGKey, jax.Array], tuple[Params, Any]]
     apply: Callable[..., tuple[tuple[dict, dict], Any]]
     per_client: Callable[[str], bool] | None = None
     bind_shared: Callable[[Params], Callable[..., Any]] | None = None
+    build_gauges: Callable[[tuple, int], dict] | None = None
 
 
 def merged_forward(apply):
@@ -186,7 +192,8 @@ def from_flax(module, mutable: tuple[str, ...] = ("batch_stats",)) -> ModelDef:
                 return lambda params, model_state, x, **kwargs: (
                     forward(params, x), model_state)
     return ModelDef(init=init, apply=apply, per_client=per_client,
-                    bind_shared=bind)
+                    bind_shared=bind,
+                    build_gauges=getattr(module, "build_gauges", None))
 
 
 def bind_shared(logic: "ClientLogic", shared: Params) -> "ClientLogic":

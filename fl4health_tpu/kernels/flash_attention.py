@@ -46,6 +46,15 @@ them fails with the reason, not with a compiler dump):
   per pair); f32 T=16384 D=128 (16 MiB) did not. Longer sequences are ring
   attention's job (``ring_flash_attention`` shards T first).
 
+Two head widths (PR 31): q and k share the query/key width D, v has its own
+Dv (``v.shape[-1]``), as latent attention has them (192 = 128 without
+positions + 64 rotary, against 128). S = Q K^T, dQ and dK contract or
+produce D lanes; P V, dP = dO V^T, dV, the output and dO are Dv wide. Each
+is padded to its own multiple (192 -> 256 lanes, 128 stays), so nothing of
+the value side pays for the wider key. ``scale`` (static, optional)
+replaces ``1 / sqrt(D)``: YaRN's ``mscale`` enters there. With Dv == D and
+no ``scale`` the calls trace to what they were before the second width.
+
 On the CPU backend the kernels run in Pallas interpret mode so the CPU suite
 exercises the same code path (house rule from kernels/dp_clip.py); interpret
 mode takes any block size.
@@ -72,8 +81,9 @@ _VMEM_PAIR_BYTES = 8 * 1024 * 1024
 
 
 def _check_compilable(block_q: int, block_k: int, tp: int, dp: int,
-                      dtype) -> None:
-    """Reject, with the reason, a request Mosaic cannot compile."""
+                      dvp: int, dtype) -> None:
+    """Reject, with the reason, a request Mosaic cannot compile. ``dp`` is
+    the padded query/key head width, ``dvp`` the padded value head width."""
     for name, block in (("block_q", block_q), ("block_k", block_k)):
         if block % _LANE != 0 and block != tp:
             raise ValueError(
@@ -85,16 +95,22 @@ def _check_compilable(block_q: int, block_k: int, tp: int, dp: int,
                 "compiled and checked on the chip. Other sizes run in "
                 "interpret mode (CPU) only."
             )
-    pair = 2 * tp * max(dp, _LANE) * jnp.dtype(dtype).itemsize
+    # K (query/key width) + V (value width) whole in the forward and dQ;
+    # Q (query/key width) + dO (value width) whole in dK/dV: the same sum
+    pair = (tp * (max(dp, _LANE) + max(dvp, _LANE))
+            * jnp.dtype(dtype).itemsize)
     if pair > _VMEM_PAIR_BYTES:
         raise ValueError(
-            f"flash_attention: sequence length {tp} (padded) at head_dim "
-            f"{dp} in {jnp.dtype(dtype).name} keeps {pair / 2**20:.1f} MiB "
-            "of whole-sequence K/V (and Q/dO) per (batch, head) in VMEM; "
-            f"the largest that compiles is {_VMEM_PAIR_BYTES / 2**20:.0f} "
-            "MiB (T=16384 bf16 / T=8192 f32 at head_dim <= 128, under "
-            "Mosaic's 16 MiB scoped-VMEM limit). Shard the sequence with "
-            "ring_flash_attention or use dense attention."
+            f"flash_attention: sequence length {tp} (padded) at a query/key "
+            f"head width of {dp} and a value head width of {dvp} (both "
+            f"padded) in {jnp.dtype(dtype).name} keeps "
+            f"{pair / 2**20:.1f} MiB of whole-sequence K (query/key width) "
+            "and V (value width), and in dK/dV of Q and dO, per (batch, "
+            f"head) in VMEM; the largest that compiles is "
+            f"{_VMEM_PAIR_BYTES / 2**20:.0f} MiB (T=16384 bf16 / T=8192 f32 "
+            "with both widths <= 128, under Mosaic's 16 MiB scoped-VMEM "
+            "limit). Shard the sequence with ring_flash_attention or use "
+            "dense attention."
         )
 
 
@@ -209,7 +225,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
     # tile without a broadcast; l is kept as 128 per-lane partial sums and
     # crosses the lanes once, after the last block.
     q = _mxu_operand(q_ref[0])  # [Bq, Dp]
-    bq, dp = q.shape
+    bq, dvp = q.shape[0], v_ref.shape[-1]
     lanes = _LANE if block_k % _LANE == 0 else block_k
     live = None
     if causal:
@@ -217,7 +233,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
         live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
 
     def step(ks, carry):
-        m, l, acc = carry  # m: [Bq, 1], l: [Bq, lanes], acc: [Bq, Dp], f32
+        m, l, acc = carry  # m: [Bq, 1], l: [Bq, lanes], acc: [Bq, Dvp], f32
         kb = _mxu_operand(k_ref[0, ks, :])
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
@@ -239,7 +255,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
         k_ref.shape[1] // block_k, block_k, bq, step,
         (jnp.full((bq, 1), NEG_INF, jnp.float32),
          jnp.zeros((bq, lanes), jnp.float32),
-         jnp.zeros((bq, dp), jnp.float32)), live)
+         jnp.zeros((bq, dvp), jnp.float32)), live)
     denom = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
     o_ref[0] = (acc / denom).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(denom)  # [Bq, 1]
@@ -247,6 +263,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
 
 def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret, causal):
     bh, tp, dp = q.shape
+    dvp = v.shape[-1]  # the value head width: v and the output
     grid = (bh, tp // block_q)
     kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
                                precision=_dot_precision(q.dtype),
@@ -257,15 +274,15 @@ def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret, causal):
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, tp, dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, tp, dp), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, tp, dvp), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, 1, tp), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dvp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tp, dp), q.dtype),
+            jax.ShapeDtypeStruct((bh, tp, dvp), q.dtype),
             jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -337,9 +354,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
         dk = dk + _dot(pt * (dpt - delta), q, (1, 0), precision)
         return dk, dv
 
-    zeros = jnp.zeros(kb.shape, jnp.float32)
+    dk0 = jnp.zeros(kb.shape, jnp.float32)
+    dv0 = dk0 if vb.shape == kb.shape else jnp.zeros(vb.shape, jnp.float32)
     dk, dv = _block_loop(q_ref.shape[1] // block_q, block_q, bk,
-                         step, (zeros, zeros), live)
+                         step, (dk0, dv0), live)
     # A row of dK / dV depends on its own key alone, so the key-padding mask
     # is one select on the sums: a padded key's row is zero, as when every
     # p of that key was zeroed in the loop.
@@ -351,6 +369,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
 def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
               dlse, causal):
     bh, tp, dp = q.shape
+    dvp = v.shape[-1]  # the value head width: v, o, do and dv
     # lse is a differentiable OUTPUT (ring-flash merge): its cotangent
     # enters the score gradient as dS = p*(dP - delta + dlse), i.e. the
     # delta slot carries (delta - dlse) — kernels unchanged. Plain
@@ -369,9 +388,9 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, tp, dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, tp, dp), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, tp, dvp), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, 1, tp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dvp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
@@ -392,19 +411,19 @@ def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         in_specs=[
             pl.BlockSpec((1, tp, dp), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dvp), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, 1), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, tp, dp), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, tp, dvp), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, tp), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, tp), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dvp), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tp, dp), k.dtype),
-            jax.ShapeDtypeStruct((bh, tp, dp), v.dtype),
+            jax.ShapeDtypeStruct((bh, tp, dvp), v.dtype),
         ],
         interpret=interpret,
         name="flash_dkv",
@@ -457,10 +476,16 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool | None = None,
     causal: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Exact softmax attention, flash-style. q,k,v: [B, T, H, D];
-    pad_mask: [B, T] with 1 = real token (key positions); returns
-    [B, T, H, D]. Drop-in for ring_attention._dense_attention.
+    """Exact softmax attention, flash-style. q, k: [B, T, H, D], v:
+    [B, T, H, Dv]; pad_mask: [B, T] with 1 = real token (key positions);
+    returns [B, T, H, Dv]. Drop-in for ring_attention._dense_attention.
+
+    The value head width ``Dv`` is ``v``'s own and may differ from the
+    query/key width ``D`` (latent attention: 192 and 128): S = Q K^T runs at
+    D, P V, dV and dP at Dv, each padded to its own lane multiple. ``scale``
+    (static) multiplies the scores; ``None`` is ``1 / sqrt(D)``.
 
     ``causal`` (static): a query sees the keys at its own position and
     before; key blocks wholly above the diagonal are skipped in all three
@@ -473,7 +498,7 @@ def flash_attention(
     silent zero grads here — use the dense path for that; stop_gradient in
     the shared prep makes the contract explicit)."""
     out, _ = flash_attention_lse(q, k, v, pad_mask, block_q, block_k,
-                                 interpret, causal)
+                                 interpret, causal, scale)
     return out
 
 
@@ -486,8 +511,9 @@ def flash_attention_lse(
     block_k: int = 128,
     interpret: bool | None = None,
     causal: bool = False,
+    scale: float | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """flash_attention returning (out [B,T,H,D], lse [B,H,T]) with lse a
+    """flash_attention returning (out [B,T,H,Dv], lse [B,H,T]) with lse a
     DIFFERENTIABLE output — the partial-softmax statistic that lets two
     attention results over disjoint key sets merge exactly:
     ``L = logsumexp_j(lse_j); out = sum_j exp(lse_j - L) * out_j``. This is
@@ -501,35 +527,39 @@ def flash_attention_lse(
     if interpret is None:
         interpret = interpret_default()
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     if k.shape[2] == 1 and h > 1:
         k = jnp.broadcast_to(k, q.shape)
-        v = jnp.broadcast_to(v, q.shape)
+        v = jnp.broadcast_to(v, (*q.shape[:3], dv))
     if pad_mask is None:
         pad_mask = jnp.ones((b, t), jnp.float32)
-    scale = 1.0 / (d ** 0.5)
-    # [B,T,H,D] -> [B*H, T, D]; pad T to the block grid, D per d_multiple
-    # below (64 for head_dim<=64, else the 128 lane width — dp is NOT
-    # guaranteed to be a multiple of 128).
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    # [B,T,H,D] -> [B*H, T, D]; pad T to the block grid and each head width
+    # (query/key D, value Dv) per d_multiple below (64 for a width <= 64,
+    # else the 128 lane width: a padded width is NOT guaranteed to be a
+    # multiple of 128).
     # T must divide by BOTH block sizes (the q grid tiles by block_q while
     # each kernel loops T/block_k key blocks) — lcm, not max: padding only to
     # max(block_q, block_k) would silently drop trailing key blocks for
     # non-dividing pairs like 48/32.
     t_multiple = math.lcm(block_q, block_k)
 
-    # D padding: blocks always span the full head dim, and a block dim equal
-    # to the array dim is legal on Mosaic whatever its size — so pad only to
-    # the sublane-packable 64 for the ubiquitous head_dim<=64 case instead
-    # of burning 2x FLOPs/VMEM traffic on 128-lane zero padding (the r5
-    # long-context config is exactly head_dim=64).
-    d_multiple = 64 if d <= 64 else _LANE
-
+    # Width padding: blocks always span a full head width (query/key or
+    # value), and a block dim equal to the array dim is legal on Mosaic
+    # whatever its size — so pad only to the sublane-packable 64 for the
+    # ubiquitous width<=64 case instead of burning 2x FLOPs/VMEM traffic on
+    # 128-lane zero padding (the r5 long-context config is exactly 64).
     def to_bh(x):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
-        return _pad_axis(_pad_axis(x, 2, d_multiple), 1, t_multiple)
+        width = x.shape[-1]
+        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, width)
+        return _pad_axis(_pad_axis(x, 2, 64 if width <= 64 else _LANE), 1,
+                         t_multiple)
 
     qp, kp, vp = to_bh(q), to_bh(k), to_bh(v)
     if not interpret:
-        _check_compilable(block_q, block_k, qp.shape[1], qp.shape[2], q.dtype)
+        _check_compilable(block_q, block_k, qp.shape[1], qp.shape[2],
+                          vp.shape[2], q.dtype)
     pad_mask = jax.lax.stop_gradient(pad_mask)
     maskp = _pad_axis(pad_mask.astype(jnp.float32), 1, t_multiple)
     # [BH, 1, Tp]: keys-per-row as the trailing (lane) dim — see _fwd_kernel's
@@ -553,6 +583,6 @@ def flash_attention_lse(
         padded = jax.shard_map(padded, mesh=mesh, in_specs=(P(),) * 4,
                                out_specs=(P(), P()), check_vma=False)
     out, lse = padded(qp, kp, vp, maskp)
-    out = out[:, :t, :d].reshape(b, h, t, d)
+    out = out[:, :t, :dv].reshape(b, h, t, dv)
     lse = lse[:, :t, 0].reshape(b, h, t)
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype), lse

@@ -1,0 +1,171 @@
+"""What the decoder families over a frozen base share (``models/jamba.py``,
+``models/deepseek.py``): RMSNorm, the adapted projection, SwiGLU, the
+declaration of a named parameter tree, the split into per-client adapters and
+a base held once, and the form the base takes for a round (its matrices cast
+to the compute type and written into one stack per run of layers).
+
+Every adapted projection is ``W x + (alpha / r) * B^T (A^T x)`` as
+``transformer.LoraDense`` has it. ``dims`` is a family's own dataclass of
+sizes; the functions here read ``dims.dtype`` (the compute type at float32
+parameters) and ``dims.lora_scale`` (alpha / rank, 0 without adapters).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from fl4health_tpu.utils.peft import per_client_predicate
+
+# leaves every client holds: the adapters and the classification head
+PER_CLIENT_MARKERS = ("lora_a", "lora_b", "score")
+PER_CLIENT = per_client_predicate(PER_CLIENT_MARKERS)
+F32 = jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# The mathematics: pure functions over a dict of leaves
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, scale, eps):
+    """x / rms(x) * scale, in float32 (the caller casts)."""
+    x = x.astype(F32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * scale
+
+
+def lora_dense(p, x, dims):
+    """x W + (alpha / r) * (x A) B in the compute type; no bias."""
+    dt = dims.dtype
+    x = x.astype(dt)
+    y = x @ p["kernel"].astype(dt)
+    if "lora_a" in p:
+        y = y + dims.lora_scale * ((x @ p["lora_a"].astype(dt))
+                                   @ p["lora_b"].astype(dt))
+    return y
+
+
+def swiglu(p, u, dims):
+    gated = jax.nn.silu(lora_dense(p["gate_proj"], u, dims)) * lora_dense(
+        p["up_proj"], u, dims)
+    return lora_dense(p["down_proj"], gated, dims)
+
+
+def dense_causal_attention(q, k, v, pad_mask, scale=None):
+    """The plain form (float32 softmax) for when a family is given no
+    ``attention_fn``: q / k [B, T, H, D], v [B, T, H, Dv], or k / v with one
+    shared head; ``scale`` None is ``1 / sqrt(D)``."""
+    t, d = q.shape[1], q.shape[-1]
+    k = jnp.broadcast_to(k, q.shape)
+    v = jnp.broadcast_to(v, (*q.shape[:3], v.shape[-1]))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=F32)
+    scores = (scores / jnp.sqrt(jnp.float32(d)) if scale is None
+              else scores * scale)
+    keep = (pad_mask[:, None, None, :] > 0) & (
+        jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])[None, None]
+    attn = jax.nn.softmax(jnp.where(keep, scores, jnp.finfo(F32).min),
+                          axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", attn, v)
+
+
+def last_token_logits(h, pad_mask, final_scale, score_kernel, eps):
+    """HF ``...ForSequenceClassification``'s head: the final-norm hidden
+    state at the last non-pad token through ``score`` (no bias), float32."""
+    h = rms_norm(h, final_scale, eps)
+    last = jnp.maximum(pad_mask.sum(axis=1).astype(jnp.int32) - 1, 0)
+    pooled = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+    logits = pooled @ score_kernel.astype(F32)
+    return {"prediction": logits.astype(F32)}, {"features": pooled}
+
+
+# ---------------------------------------------------------------------------
+# Parameter declaration
+# ---------------------------------------------------------------------------
+
+INITS = {"matrix": nn.initializers.lecun_normal(),
+         "zeros": nn.initializers.zeros, "ones": nn.initializers.ones,
+         "embed": nn.initializers.normal(stddev=0.02)}
+
+
+class Leaves(nn.Module):
+    """Declares a nested dict of parameters from ``spec``, a tuple of
+    ``(name, (shape, init))`` or ``(name, nested spec)`` pairs, and returns
+    it: flax holds the names, the families' functions do the work. ``init``
+    is a key of ``INITS`` or a family's own initializer."""
+
+    spec: tuple
+
+    @nn.compact
+    def __call__(self):
+        out = {}
+        for name, entry in self.spec:
+            if len(entry) == 2 and (isinstance(entry[1], str)
+                                    or callable(entry[1])):
+                shape, init = entry
+                out[name] = self.param(name, INITS.get(init, init), shape)
+            else:
+                out[name] = Leaves(entry, name=name)()
+        return out
+
+
+def norm_spec(width: int) -> tuple:
+    return (("scale", ((width,), "ones")),)
+
+
+def proj_spec(name: str, n_in: int, n_out: int, rank: int) -> tuple:
+    """A projection without bias; with ``rank`` its adapter beside it."""
+    leaves = [("kernel", ((n_in, n_out), "matrix"))]
+    if rank:
+        # lora_b starts at zero: the adapted model starts at the base model
+        leaves += [("lora_a", ((n_in, rank), "matrix")),
+                   ("lora_b", ((rank, n_out), "zeros"))]
+    return name, tuple(leaves)
+
+
+# ---------------------------------------------------------------------------
+# The base in the form a round consumes
+# ---------------------------------------------------------------------------
+
+def stack_by_writes(leaves):
+    """``jnp.stack`` as writes of its own into one buffer. XLA:TPU splits a
+    concatenate into one update per operand and gives the name stack to one
+    of them only, so a trace could give ``fl_layer::shared_cast`` 14 of the
+    base's 91 slices (PR 27); these updates all carry it. The price is the
+    buffer's zeros: one more write of the stack (2.86 GB for Jamba's base,
+    3.5 ms a round program on a v5e), behind a barrier or XLA folds it into a
+    pad that has no name either."""
+    out = jax.lax.optimization_barrier(
+        jnp.zeros((len(leaves), *leaves[0].shape), leaves[0].dtype))
+    for i, leaf in enumerate(leaves):
+        out = jax.lax.dynamic_update_slice(
+            out, leaf[None], (i,) + (0,) * leaf.ndim)
+    return out
+
+
+def stack_runs(tree, runs, stack=jnp.stack):
+    """The ``layers_<i>`` dicts of a tree (whole, or either half of the
+    split) stacked over each of ``runs`` (lists of layer indices that one
+    ``lax.scan`` covers), under ``runs/<k>``."""
+    out = {k: v for k, v in tree.items() if not k.startswith("layers_")}
+    stacked = {}
+    for k, run in enumerate(runs):
+        members = [tree[f"layers_{i}"] for i in run if f"layers_{i}" in tree]
+        if members:
+            stacked[str(k)] = jax.tree_util.tree_map(
+                lambda *leaves: stack(leaves), *members)
+    return {**out, "runs": stacked} if stacked else out
+
+
+def prepare_shared(shared, runs, dtype, is_matrix):
+    """The base as every client step of a round consumes it: the leaves that
+    ``is_matrix(names)`` says are matmul operands in the compute type (norms,
+    elementwise operands and the embedding's gather stay float32), the
+    layers stacked over their runs, each cast writing its slice of the
+    stack. ``names`` is the leaf's path as a list of keys."""
+    def cast(path, leaf):
+        names = [getattr(k, "key", None) for k in path]
+        return leaf.astype(dtype) if is_matrix(names) else leaf
+
+    return stack_runs(jax.tree_util.tree_map_with_path(cast, shared), runs,
+                      stack=stack_by_writes)

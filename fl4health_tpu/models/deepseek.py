@@ -1,0 +1,542 @@
+"""Latent-attention / routed-expert decoder for federated adapter fine-tuning
+(the DeepSeek-V2 family: DeepSeek-AI 2024, arXiv:2405.04434; HF
+``model_type: deepseek_v2``).
+
+Parity surface: /root/reference/examples/fedllm_example — LoRA adapters
+trained federally over a frozen causal LM that every client loads once.
+
+Layer ``l``: ``h <- h + MLA(RMSNorm(h))`` then ``h <- h + FFN_l(RMSNorm(h))``.
+
+MLA (multi-head latent attention, training form: no absorption, no cache):
+``c_q = RMSNorm(u W_qa)``, ``[q_nope | q_pe] = c_q W_qb`` per head;
+``[c_kv | k_pe] = u W_kva``, ``[k_nope | v] = RMSNorm(c_kv) W_kvb`` per
+head; ``q_pe`` and the ONE ``k_pe`` all heads share get rotary positions
+(YaRN's blended frequencies, halves layout); ``q = [q_nope | q_pe]``, ``k =
+[k_nope | k_pe]`` are ``qk_nope + qk_rope`` wide, ``v`` is ``v_head_dim``
+wide; causal softmax at ``(qk_nope + qk_rope)^-0.5 * mscale^2``; ``W_o``.
+
+FFN: the first ``first_k_dense`` layers a dense SwiGLU; the others
+``sum_i w_i E_i(u) + SwiGLU_shared(u)`` with the router of
+``group_limited_greedy``: softmax over all ``n_routed_experts`` in float32,
+the best ``topk_group`` of ``n_group`` groups by their largest score, the
+``top_k`` largest scores inside them, ``w_i = routed_scale * s_i`` (not
+renormalised). The module is told which experts it HOLDS (``experts_held``
+from ``first_expert_held``: a chip's share under expert parallelism); it
+routes over all of them and computes the held experts' part of the sum only,
+which is what goes on to the next layer.
+
+The routed part is dropless and does work in proportion to the assignments
+(``routed_experts``): the (token, choice) pairs that chose a held expert are
+sorted by expert and each expert runs over its own rows in tiles of
+``TILE_ROWS``, as many as its count needs (a loop with a data-dependent trip
+count), gathering its rows and scatter-adding its weighted results. Nothing
+has a capacity: every pair could be local and the loops would run that many
+tiles. The held experts are a frozen base here: the function's VJP gives the
+gradients of the tokens and of the combine weights (through which the router's
+input trains upstream adapters) and NONE for the expert matrices. Because the
+experts carry no client axis, a ``vmap`` over clients is met by folding the
+client axis into the rows (``jax.custom_batching.custom_vmap``): one sort and
+one set of loops over all clients' tokens.
+
+Built the way ``models/jamba.py`` is (a named parameter tree declared by a
+flax module, pure functions over one layer's dict, runs of layers as
+``lax.scan``s rematerialised under ``remat``, ``per_client_param`` /
+``bind_shared`` for the engine), on ``models/decoder_common.py``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from fl4health_tpu.core.pytree import merge_trees
+from fl4health_tpu.models import decoder_common as common
+from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
+                                                 swiglu)
+
+# rows of one expert's tile: [TILE_ROWS, d] x [d, f] reads the expert's matrix
+# once per tile, so a tile should hold an expert's usual load whole (about 154
+# rows at 4,096 tokens, 8 of 160 experts held) and no more
+TILE_ROWS = 256
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (Peng et al. 2023) as HF ``DeepseekV2YarnRotaryEmbedding`` has
+    it; ``factor`` 1 is plain rotary embedding."""
+
+    theta: float = 10000.0
+    factor: float = 1.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max_position: int = 4096
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, rope: RopeScaling) -> list[float]:
+    """The ``dim // 2`` inverse frequencies: interpolated (divided by
+    ``factor``) below the correction range, as published above it, a linear
+    ramp between."""
+    def correction_dim(rotations):
+        return (dim * math.log(rope.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    extra = [rope.theta ** (-2.0 * i / dim) for i in range(dim // 2)]
+    if rope.factor <= 1:
+        return extra
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    out = []
+    for i, f in enumerate(extra):
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        out.append(f / rope.factor * ramp + f * (1.0 - ramp))
+    return out
+
+
+def softmax_scale(qk_head_dim: int, rope: RopeScaling) -> float:
+    m = yarn_get_mscale(rope.factor, rope.mscale_all_dim)
+    return qk_head_dim ** -0.5 * m * m
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekDims:
+    """The sizes and static choices the layer functions read."""
+
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+    experts_held: int
+    first_expert_held: int
+    n_group: int
+    topk_group: int
+    top_k: int
+    routed_scale: float
+    rope: RopeScaling
+    rms_eps: float
+    lora_scale: float  # alpha / rank (0 without adapters)
+    dtype: Any
+    attention_fn: Any
+
+
+# ---------------------------------------------------------------------------
+# Latent attention
+# ---------------------------------------------------------------------------
+
+def rope_tables(t: int, dim: int, rope: RopeScaling):
+    """(cos, sin) [T, dim // 2], float32, times YaRN's cos/sin scale."""
+    inv = jnp.asarray(yarn_inv_freq(dim, rope), F32)
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv[None, :]
+    m = (yarn_get_mscale(rope.factor, rope.mscale)
+         / yarn_get_mscale(rope.factor, rope.mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def apply_rope(x, cos, sin):
+    """x [B, T, H, dim] in the halves layout ``[x1 | x2]`` -> ``[x1 cos - x2
+    sin | x2 cos + x1 sin]``, computed in float32. (HF permutes each
+    interleaved pair to this layout first; with seeded weights that is a
+    relabelling of ``W_qb``'s and ``W_kva``'s columns.)"""
+    x1, x2 = jnp.split(x.astype(F32), 2, axis=-1)
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def mla_attention(p, u, pad_mask, dims: DeepseekDims):
+    """``dims.attention_fn(q, k, v, pad_mask=mask, scale=s) -> out`` must be
+    causal and take a value width of its own, e.g.
+    ``functools.partial(kernels.flash_attention, causal=True, block_q=512,
+    block_k=512)``; ``None`` is the dense form."""
+    with jax.named_scope("fl_layer::mla_attention"):
+        b, t = u.shape[:2]
+        h, nope, rot = dims.n_heads, dims.qk_nope, dims.qk_rope
+        c_q = rms_norm(lora_dense(p["q_a_proj"], u, dims),
+                       p["q_a_layernorm"]["scale"], dims.rms_eps)
+        q = lora_dense(p["q_b_proj"], c_q, dims).reshape(b, t, h, nope + rot)
+        c_kv, k_pe = jnp.split(lora_dense(p["kv_a_proj_with_mqa"], u, dims),
+                               [dims.kv_lora_rank], axis=-1)
+        c_kv = rms_norm(c_kv, p["kv_a_layernorm"]["scale"], dims.rms_eps)
+        kv = lora_dense(p["kv_b_proj"], c_kv, dims).reshape(
+            b, t, h, nope + dims.v_head)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        cos, sin = rope_tables(t, rot, dims.rope)
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], axis=-1)
+        k_pe = apply_rope(k_pe[:, :, None, :], cos, sin)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (b, t, h, rot))], axis=-1)
+        attend = dims.attention_fn or common.dense_causal_attention
+        with jax.named_scope("fl_layer::mla_flash"):
+            out = attend(q, k, v, pad_mask=pad_mask,
+                         scale=softmax_scale(nope + rot, dims.rope))
+        return lora_dense(p["o_proj"], out.reshape(b, t, h * dims.v_head),
+                          dims)
+
+
+# ---------------------------------------------------------------------------
+# The routed-expert layer
+# ---------------------------------------------------------------------------
+
+def route(p, u, dims: DeepseekDims):
+    """``group_limited_greedy`` over ALL the layer's experts, in float32 at
+    full precision (a near tie decides which expert a token gets): u [N, d]
+    -> (idx [N, top_k] int32, w [N, top_k] float32 = routed_scale * score).
+    The router's matrix is [n_group, d, experts per group]: expert ``g * per
+    + e`` is column ``e`` of group ``g``."""
+    with jax.named_scope("fl_layer::moe_router"):
+        n = u.shape[0]
+        logits = jnp.einsum("nd,gde->nge", u.astype(F32),
+                            p["kernel"].astype(F32),
+                            precision=jax.lax.Precision.HIGHEST)
+        per = logits.shape[-1]
+        scores = jax.nn.softmax(logits.reshape(n, -1), axis=-1)
+        best = scores.reshape(n, dims.n_group, per).max(axis=-1)
+        _, groups = jax.lax.top_k(best, dims.topk_group)
+        keep = jnp.zeros((n, dims.n_group), bool).at[
+            jnp.arange(n)[:, None], groups].set(True)
+        kept = jnp.where(jnp.repeat(keep, per, axis=1), scores, 0.0)
+        w, idx = jax.lax.top_k(kept, dims.top_k)
+        return idx.astype(jnp.int32), w * dims.routed_scale
+
+
+def _expert(x, gate, up, down):
+    """One expert's SwiGLU over its rows, in the rows' type."""
+    with jax.named_scope("fl_layer::moe_experts"):
+        return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def _plan(idx, w, first: int, held: int):
+    """The (token, choice) pairs that chose a held expert, sorted by expert:
+    (order [N*K] the sorted pairs' flat positions, tok their tokens, w_sorted
+    their combine weights, both [N*K + TILE_ROWS], starts [held], counts
+    [held]). Pairs for experts held elsewhere sort behind every held expert's
+    rows and belong to no count."""
+    k = idx.shape[1]
+    key = jnp.where((idx >= first) & (idx < first + held), idx - first,
+                    held).reshape(-1)
+    order = jnp.argsort(key)
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    # a tile may reach past the last pair: TILE_ROWS rows of padding keep
+    # every dynamic slice in range (a clamped start would shift the tile)
+    tok = jnp.pad((order // k).astype(jnp.int32), (0, TILE_ROWS))
+    w_sorted = jnp.pad(w.reshape(-1)[order], (0, TILE_ROWS))
+    return order, tok, w_sorted, jnp.cumsum(counts) - counts, counts
+
+
+def _tile(t, start, count, tok, w_sorted):
+    """Tile ``t`` of the expert whose sorted rows are [start, start + count):
+    (position, tokens [TILE_ROWS], combine weights, which rows are its own)."""
+    pos = start + t * TILE_ROWS
+    rows = jax.lax.dynamic_slice(tok, (pos,), (TILE_ROWS,))
+    wt = jax.lax.dynamic_slice(w_sorted, (pos,), (TILE_ROWS,))
+    return pos, rows, wt, t * TILE_ROWS + jnp.arange(TILE_ROWS) < count
+
+
+def _tiles(count):
+    return (count + TILE_ROWS - 1) // TILE_ROWS
+
+
+def _routed_fwd(first, x, idx, w, *experts):
+    """x [N, d], idx / w [N, K], experts = (gate, up, down) per held expert
+    -> sum over the held experts chosen of w * E(x), [N, d] float32."""
+    held = len(experts) // 3
+    _, tok, w_sorted, starts, counts = _plan(idx, w, first, held)
+    y = jnp.zeros(x.shape, F32)
+    for e in range(held):
+        def tile(t, y, e=e):
+            _, rows, wt, live = _tile(t, starts[e], counts[e], tok, w_sorted)
+            out = _expert(x[rows], *experts[3 * e:3 * e + 3]).astype(F32)
+            return y.at[rows].add(
+                jnp.where(live[:, None], out * wt[:, None], 0.0))
+
+        y = jax.lax.fori_loop(0, _tiles(counts[e]), tile, y)
+    return y
+
+
+def _routed_bwd(first, x, idx, w, dy, *experts):
+    """(dx [N, d] float32, dw [N, K] float32) of ``_routed_fwd``: the same
+    tiles, each recomputing its expert's forward."""
+    held = len(experts) // 3
+    order, tok, w_sorted, starts, counts = _plan(idx, w, first, held)
+    dx, dw_sorted = jnp.zeros(x.shape, F32), jnp.zeros(w_sorted.shape, F32)
+    for e in range(held):
+        def tile(t, carry, e=e):
+            dx, dw_sorted = carry
+            pos, rows, wt, live = _tile(t, starts[e], counts[e], tok,
+                                        w_sorted)
+            _, vjp = jax.vjp(
+                lambda xr, wr: _expert(xr, *experts[3 * e:3 * e + 3]).astype(
+                    F32) * wr[:, None], x[rows], wt)
+            dxr, dwr = vjp(jnp.where(live[:, None], dy[rows], 0.0))
+            old = jax.lax.dynamic_slice(dw_sorted, (pos,), (TILE_ROWS,))
+            dw_sorted = jax.lax.dynamic_update_slice(
+                dw_sorted, jnp.where(live, dwr, old), (pos,))
+            return dx.at[rows].add(dxr.astype(F32)), dw_sorted
+
+        dx, dw_sorted = jax.lax.fori_loop(0, _tiles(counts[e]), tile,
+                                          (dx, dw_sorted))
+    dw = jnp.zeros(order.shape, F32).at[order].set(
+        dw_sorted[:order.shape[0]])
+    return dx, dw.reshape(w.shape)
+
+
+def _fold_clients(fn, n_row_args: int):
+    """``fn(*row_args, *experts)`` whose first ``n_row_args`` arguments and
+    every result have the rows as their leading axis, with a ``vmap`` rule
+    that folds a batch axis of the row arguments into the rows: the experts
+    carry no client axis, so C clients' tokens are ONE call's rows (routing
+    is per token: the mathematics is ``vmap``'s). Experts that do carry the
+    axis get the plain ``vmap``."""
+    folded = jax.custom_batching.custom_vmap(fn)
+
+    @folded.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if any(in_batched[n_row_args:]):
+            out = jax.vmap(fn, in_axes=[0 if b else None for b in in_batched]
+                           )(*args)
+        else:
+            rows = [a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+                    for a, b in zip(args[:n_row_args], in_batched)]
+            out = folded(*(a.reshape(-1, *a.shape[2:]) for a in rows),
+                         *args[n_row_args:])
+            out = jax.tree_util.tree_map(
+                lambda a: a.reshape(axis_size, -1, *a.shape[1:]), out)
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+
+    return folded
+
+
+@functools.lru_cache(maxsize=None)
+def _routed_fn(first: int):
+    fwd = _fold_clients(functools.partial(_routed_fwd, first), 3)
+    bwd = _fold_clients(functools.partial(_routed_bwd, first), 4)
+
+    @jax.custom_vjp
+    def routed(x, idx, w, *experts):
+        return fwd(x, idx, w, *experts)
+
+    def routed_fwd(x, idx, w, *experts):
+        return fwd(x, idx, w, *experts), (x, idx, w, experts)
+
+    def routed_bwd(res, dy):
+        x, idx, w, experts = res
+        dx, dw = bwd(x, idx, w, dy, *experts)
+        # the held experts are a frozen base: no gradient (module docstring)
+        return (dx.astype(x.dtype), None, dw) + (None,) * len(experts)
+
+    routed.defvjp(routed_fwd, routed_bwd)
+    return routed
+
+
+def routed_experts(x, idx, w, experts, first_expert_held: int):
+    """sum_k [idx_k held here] * w_k * E_{idx_k}(x): x [N, d] in the compute
+    type, idx [N, K] over all the layer's experts, w [N, K] float32,
+    ``experts`` the held ones' ``(gate [d, f], up [d, f], down [f, d])`` in
+    order from ``first_expert_held``. Returns [N, d] float32."""
+    flat = [m for triple in experts for m in triple]
+    return _routed_fn(int(first_expert_held))(x, idx, w, *flat)
+
+
+def moe(p, u, dims: DeepseekDims):
+    """The routed layer's part held here plus the shared experts."""
+    dt = dims.dtype
+    flat = u.reshape(-1, u.shape[-1])
+    with jax.named_scope("fl_layer::moe"):
+        idx, w = route(p["gate"], flat, dims)
+        experts = [tuple(p[f"experts_{j}"][name]["kernel"].astype(dt)
+                         for name in ("gate_proj", "up_proj", "down_proj"))
+                   for j in range(dims.experts_held)]
+        y = routed_experts(flat.astype(dt), idx, w, experts,
+                           dims.first_expert_held)
+    with jax.named_scope("fl_layer::shared_experts"):
+        shared = swiglu(p["shared_experts"], u, dims)
+    return y.reshape(u.shape).astype(dt) + shared
+
+
+def layer(p, h, pad_mask, routed: bool, dims: DeepseekDims):
+    u = rms_norm(h, p["input_layernorm"]["scale"], dims.rms_eps)
+    h = h + mla_attention(p["self_attn"], u, pad_mask, dims)
+    u = rms_norm(h, p["post_attention_layernorm"]["scale"], dims.rms_eps)
+    return h + (moe(p["mlp"], u, dims) if routed else swiglu(p["mlp"], u, dims))
+
+
+# ---------------------------------------------------------------------------
+# The module
+# ---------------------------------------------------------------------------
+
+class DeepseekV2Classifier(nn.Module):
+    """Input: integer token ids [B, T], id 0 = padding at the tail. The head
+    is HF ``DeepseekV2ForSequenceClassification``'s: the final-norm hidden
+    state at the last non-pad token through ``score`` (no bias)."""
+
+    vocab_size: int
+    n_classes: int
+    d_model: int = 64
+    n_layers: int = 3
+    first_k_dense: int = 1
+    d_ff: int = 128  # the leading dense layers' SwiGLU
+    n_heads: int = 4
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    d_expert: int = 32  # one routed expert's SwiGLU
+    n_routed_experts: int = 16  # the router's width
+    experts_held: int = 16
+    first_expert_held: int = 0
+    n_shared_experts: int = 2  # one SwiGLU of n_shared_experts * d_expert
+    n_group: int = 4
+    topk_group: int = 2
+    top_k: int = 2
+    routed_scaling_factor: float = 1.0
+    rope: RopeScaling = RopeScaling()
+    rms_eps: float = 1e-6
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    dtype: Any = jnp.float32
+    remat: bool = False  # rematerialise each layer on the backward pass
+    attention_fn: Any = None  # causal, value width of its own; None = dense
+
+    # -- structure ----------------------------------------------------------
+    @property
+    def dims(self) -> DeepseekDims:
+        if self.n_routed_experts % self.n_group:
+            raise ValueError(f"{self.n_routed_experts} experts do not divide "
+                             f"into {self.n_group} groups")
+        if not (0 <= self.first_expert_held and self.first_expert_held
+                + self.experts_held <= self.n_routed_experts):
+            raise ValueError(
+                f"experts {self.first_expert_held}.."
+                f"{self.first_expert_held + self.experts_held - 1} are not "
+                f"among the router's {self.n_routed_experts}")
+        return DeepseekDims(
+            self.d_model, self.n_heads, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.experts_held, self.first_expert_held, self.n_group,
+            self.topk_group, self.top_k,
+            self.routed_scaling_factor, self.rope, self.rms_eps,
+            self.lora_alpha / self.lora_rank if self.lora_rank else 0.0,
+            self.dtype, self.attention_fn)
+
+    def runs(self) -> list[list[int]]:
+        """The leading dense layers, then the expert layers: [[0], [1..4]]."""
+        k = min(self.first_k_dense, self.n_layers)
+        return [r for r in (list(range(k)), list(range(k, self.n_layers)))
+                if r]
+
+    def _layer_spec(self, routed: bool) -> tuple:
+        d, r, h = self.d_model, self.lora_rank, self.n_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        proj, norm = common.proj_spec, common.norm_spec
+
+        def mlp(width, rank):
+            return (proj("gate_proj", d, width, rank),
+                    proj("up_proj", d, width, rank),
+                    proj("down_proj", width, d, rank))
+
+        attn = ("self_attn", (
+            proj("q_a_proj", d, self.q_lora_rank, r),
+            ("q_a_layernorm", norm(self.q_lora_rank)),
+            proj("q_b_proj", self.q_lora_rank, h * qk, r),
+            proj("kv_a_proj_with_mqa", d,
+                 self.kv_lora_rank + self.qk_rope_head_dim, r),
+            ("kv_a_layernorm", norm(self.kv_lora_rank)),
+            proj("kv_b_proj", self.kv_lora_rank,
+                 h * (self.qk_nope_head_dim + self.v_head_dim), r),
+            proj("o_proj", h * self.v_head_dim, d, r)))
+        if routed:
+            # routed experts and the router are frozen and unadapted; every
+            # expert's leaves have names of their own
+            ffn = (("gate", (("kernel", (
+                (self.n_group, d, self.n_routed_experts // self.n_group),
+                "matrix")),)),
+                   *((f"experts_{j}", mlp(self.d_expert, 0))
+                     for j in range(self.experts_held)),
+                   ("shared_experts",
+                    mlp(self.n_shared_experts * self.d_expert, r)))
+        else:
+            ffn = mlp(self.d_ff, r)
+        return (("input_layernorm", norm(d)), attn,
+                ("post_attention_layernorm", norm(d)), ("mlp", ffn))
+
+    # -- forward ------------------------------------------------------------
+    @nn.compact
+    def __call__(self, x, train: bool = True):
+        del train  # no dropout, no batch statistics
+        d = self.d_model
+        spec = [("embed_tokens", (("embedding", ((self.vocab_size, d),
+                                                 "embed")),)),
+                ("norm", common.norm_spec(d)),
+                ("score", (("kernel", ((d, self.n_classes), "matrix")),))]
+        spec += [(f"layers_{i}", self._layer_spec(i >= self.first_k_dense))
+                 for i in range(self.n_layers)]
+        params = {name: common.Leaves(entry, name=name)()
+                  for name, entry in spec}
+        return self.forward(common.stack_runs(params, self.runs()), x)
+
+    def forward(self, stacked, x):
+        """``stacked``: the tree with its layers stacked by
+        ``decoder_common.stack_runs``; each run is one ``lax.scan``."""
+        dims = self.dims
+        pad_mask = (x > 0).astype(F32)
+        h = stacked["embed_tokens"]["embedding"][x].astype(self.dtype)
+        for k, run in enumerate(self.runs()):
+            routed = run[0] >= self.first_k_dense
+
+            def body(h_, p, routed=routed):
+                return layer(p, h_, pad_mask, routed, dims).astype(
+                    self.dtype), None
+
+            if self.remat:
+                body = jax.checkpoint(body)
+            h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
+        return common.last_token_logits(
+            h, pad_mask, stacked["norm"]["scale"], stacked["score"]["kernel"],
+            self.rms_eps)
+
+    # -- the split of the parameters (clients/engine.py ModelDef) ----------
+    def per_client_param(self, path: str) -> bool:
+        return common.PER_CLIENT(path)
+
+    def bind_shared(self, shared):
+        """``(per_client, x) -> (preds, features)`` over the base prepared
+        once: every projection's and expert's ``kernel`` in the compute type
+        (the router's stays float32, as the norms and the embedding do), the
+        layers stacked over their runs."""
+        with jax.named_scope("fl_layer::shared_cast"):
+            prepared = common.prepare_shared(
+                shared, self.runs(), self.dtype,
+                lambda names: names[-1] == "kernel" and names[-2] != "gate")
+        return lambda per_client, x: self.forward(
+            merge_trees(prepared, common.stack_runs(per_client, self.runs())),
+            x)
+
+    def build_gauges(self, batch_shape, n_clients: int) -> dict:
+        """Static facts of the routed layer for the simulation's build-time
+        gauges; ``batch_shape`` is one client's [B, T]."""
+        tokens = n_clients * math.prod(batch_shape)
+        return {"moe_experts_held": self.experts_held,
+                "moe_experts_total": self.n_routed_experts,
+                # rows the folded routed call is built to take: every choice
+                # of every token could be a held expert
+                "moe_assignment_rows_bound":
+                    tokens * min(self.top_k, self.experts_held)}

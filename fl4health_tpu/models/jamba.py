@@ -44,15 +44,13 @@ from flax import linen as nn
 
 from fl4health_tpu.core.pytree import merge_trees
 from fl4health_tpu.kernels.selective_scan import selective_scan
-from fl4health_tpu.utils.peft import per_client_predicate
+from fl4health_tpu.models import decoder_common as common
+from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
+                                                 swiglu)
 
-# leaves every client holds: the adapters and the classification head
-PER_CLIENT_MARKERS = ("lora_a", "lora_b", "score")
-_PER_CLIENT = per_client_predicate(PER_CLIENT_MARKERS)
 # projections that carry an adapter (the PEFT recipe of AI21's model card)
 ADAPTED = frozenset({"in_proj", "x_proj", "out_proj", "gate_proj", "up_proj",
                      "down_proj", "q_proj", "k_proj", "v_proj"})
-F32 = jnp.float32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,24 +74,6 @@ class JambaDims:
 # ---------------------------------------------------------------------------
 # The mathematics: pure functions over one layer's parameter dict
 # ---------------------------------------------------------------------------
-
-def rms_norm(x, scale, eps):
-    """x / rms(x) * scale, in float32 (the caller casts)."""
-    x = x.astype(F32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
-
-
-def lora_dense(p, x, dims: JambaDims):
-    """x W + (alpha / r) * (x A) B in the compute type; no bias."""
-    dt = dims.dtype
-    x = x.astype(dt)
-    y = x @ p["kernel"].astype(dt)
-    if "lora_a" in p:
-        y = y + dims.lora_scale * ((x @ p["lora_a"].astype(dt))
-                                   @ p["lora_b"].astype(dt))
-    return y
-
 
 def causal_depthwise_conv(p, x):
     """y_t = sum_j kernel[j] * x_{t - (K - 1) + j} + bias per channel (HF
@@ -123,20 +103,6 @@ def mamba_mixer(p, u, dims: JambaDims):
         return lora_dense(p["out_proj"], y, dims)
 
 
-def dense_causal_attention(q, k, v, pad_mask):
-    """The plain form (float32 softmax) for when no ``attention_fn`` is
-    given: q [B, T, H, D], k / v [B, T, H, D] or one shared head."""
-    t, d = q.shape[1], q.shape[-1]
-    k, v = jnp.broadcast_to(k, q.shape), jnp.broadcast_to(v, q.shape)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=F32) / jnp.sqrt(jnp.float32(d))
-    keep = (pad_mask[:, None, None, :] > 0) & (
-        jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])[None, None]
-    attn = jax.nn.softmax(jnp.where(keep, scores, jnp.finfo(F32).min),
-                          axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", attn, v)
-
-
 def causal_attention(p, u, pad_mask, dims: JambaDims):
     """``dims.attention_fn(q, k, v, pad_mask=mask) -> out`` must be causal,
     e.g. ``functools.partial(kernels.flash_attention, causal=True, block_q=512,
@@ -154,15 +120,9 @@ def causal_attention(p, u, pad_mask, dims: JambaDims):
         if 1 < dims.n_kv_heads < dims.n_heads:
             groups = dims.n_heads // dims.n_kv_heads
             k, v = (jnp.repeat(a, groups, axis=2) for a in (k, v))
-        attend = dims.attention_fn or dense_causal_attention
+        attend = dims.attention_fn or common.dense_causal_attention
         out = attend(q, k, v, pad_mask=pad_mask)
         return lora_dense(p["o_proj"], out.reshape(*out.shape[:-2], -1), dims)
-
-
-def swiglu(p, u, dims: JambaDims):
-    gated = jax.nn.silu(lora_dense(p["gate_proj"], u, dims)) * lora_dense(
-        p["up_proj"], u, dims)
-    return lora_dense(p["down_proj"], gated, dims)
 
 
 def layer(p, h, pad_mask, attention: bool, dims: JambaDims):
@@ -184,53 +144,8 @@ def _a_log_init(key, shape, dtype=F32):
         jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
 
 
-_INITS = {"matrix": nn.initializers.lecun_normal(),
-          "zeros": nn.initializers.zeros, "ones": nn.initializers.ones,
-          "a_log": _a_log_init, "embed": nn.initializers.normal(stddev=0.02)}
-
-
-class _Leaves(nn.Module):
-    """Declares a nested dict of parameters from ``spec``, a tuple of
-    ``(name, (shape, init name))`` or ``(name, nested spec)`` pairs, and
-    returns it: flax holds the names, the functions above do the work."""
-
-    spec: tuple
-
-    @nn.compact
-    def __call__(self):
-        out = {}
-        for name, entry in self.spec:
-            if len(entry) == 2 and isinstance(entry[1], str):
-                shape, init = entry
-                out[name] = self.param(name, _INITS[init], shape)
-            else:
-                out[name] = _Leaves(entry, name=name)()
-        return out
-
-
 def _proj_spec(name: str, n_in: int, n_out: int, rank: int):
-    leaves = [("kernel", ((n_in, n_out), "matrix"))]
-    if rank and name in ADAPTED:
-        # lora_b starts at zero: the adapted model starts at the base model
-        leaves += [("lora_a", ((n_in, rank), "matrix")),
-                   ("lora_b", ((rank, n_out), "zeros"))]
-    return name, tuple(leaves)
-
-
-def _stack_by_writes(leaves):
-    """``jnp.stack`` as writes of its own into one buffer. XLA:TPU splits a
-    concatenate into one update per operand and gives the name stack to one
-    of them only, so a trace could give ``fl_layer::shared_cast`` 14 of the
-    base's 91 slices (PR 27); these updates all carry it. The price is the
-    buffer's zeros: one more write of the stack (2.86 GB for the base, 3.5 ms
-    a round program on a v5e), behind a barrier or XLA folds it into a pad
-    that has no name either."""
-    out = jax.lax.optimization_barrier(
-        jnp.zeros((len(leaves), *leaves[0].shape), leaves[0].dtype))
-    for i, leaf in enumerate(leaves):
-        out = jax.lax.dynamic_update_slice(
-            out, leaf[None], (i,) + (0,) * leaf.ndim)
-    return out
+    return common.proj_spec(name, n_in, n_out, rank if name in ADAPTED else 0)
 
 
 class JambaClassifier(nn.Module):
@@ -281,7 +196,7 @@ class JambaClassifier(nn.Module):
 
     def _layer_spec(self, attention: bool) -> tuple:
         d, r = self.d_model, self.lora_rank
-        norm = lambda width: (("scale", ((width,), "ones")),)  # noqa: E731
+        norm = common.norm_spec
         if attention:
             hd = d // self.n_heads
             mixer = ("self_attn", (
@@ -300,7 +215,7 @@ class JambaClassifier(nn.Module):
                 ("c_layernorm", norm(n)),
                 ("dt_proj", (("kernel", ((rk, di), "matrix")),
                              ("bias", ((di,), "zeros")))),
-                ("A_log", ((di, n), "a_log")), ("D", ((di,), "ones")),
+                ("A_log", ((di, n), _a_log_init)), ("D", ((di,), "ones")),
                 _proj_spec("out_proj", di, d, r)))
         return (("input_layernorm", norm(d)), mixer,
                 ("pre_ff_layernorm", norm(d)),
@@ -315,24 +230,16 @@ class JambaClassifier(nn.Module):
         d = self.d_model
         spec = [("embed_tokens", (("embedding", ((self.vocab_size, d),
                                                  "embed")),)),
-                ("final_layernorm", (("scale", ((d,), "ones")),)),
+                ("final_layernorm", common.norm_spec(d)),
                 ("score", (("kernel", ((d, self.n_classes), "matrix")),))]
         spec += [(f"layers_{i}", self._layer_spec(self.is_attention(i)))
                  for i in range(self.n_layers)]
-        params = {name: _Leaves(entry, name=name)() for name, entry in spec}
+        params = {name: common.Leaves(entry, name=name)()
+                  for name, entry in spec}
         return self.forward(self.stack_runs(params), x)
 
-    def stack_runs(self, tree, stack=jnp.stack):
-        """The ``layers_<i>`` dicts of a tree (whole, or either half of the
-        split) stacked over each run of layers, under ``runs/<k>``."""
-        out = {k: v for k, v in tree.items() if not k.startswith("layers_")}
-        runs = {}
-        for k, run in enumerate(self.runs()):
-            members = [tree[f"layers_{i}"] for i in run if f"layers_{i}" in tree]
-            if members:
-                runs[str(k)] = jax.tree_util.tree_map(
-                    lambda *leaves: stack(leaves), *members)
-        return {**out, "runs": runs} if runs else out
+    def stack_runs(self, tree):
+        return common.stack_runs(tree, self.runs())
 
     def forward(self, stacked, x):
         """``stacked``: the tree with its layers stacked by ``stack_runs``.
@@ -353,15 +260,13 @@ class JambaClassifier(nn.Module):
             if self.remat:
                 body = jax.checkpoint(body)
             h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
-        h = rms_norm(h, stacked["final_layernorm"]["scale"], self.rms_eps)
-        last = jnp.maximum(pad_mask.sum(axis=1).astype(jnp.int32) - 1, 0)
-        pooled = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-        logits = pooled @ stacked["score"]["kernel"].astype(F32)
-        return {"prediction": logits.astype(F32)}, {"features": pooled}
+        return common.last_token_logits(
+            h, pad_mask, stacked["final_layernorm"]["scale"],
+            stacked["score"]["kernel"], self.rms_eps)
 
     # -- the split of the parameters (clients/engine.py ModelDef) ----------
     def per_client_param(self, path: str) -> bool:
-        return _PER_CLIENT(path)
+        return common.PER_CLIENT(path)
 
     def prepare_shared(self, shared):
         """The base in the form every client step of a round consumes: each
@@ -369,13 +274,9 @@ class JambaClassifier(nn.Module):
         norms, ``A_log``, ``D`` and the embedding stay float32: elementwise
         operands and a gather), the layers stacked over their runs, each
         cast writing its slice of the stack."""
-        def cast(path, leaf):
-            names = [getattr(k, "key", None) for k in path]
-            is_matrix = names[-1] == "kernel" and "conv1d" not in names
-            return leaf.astype(self.dtype) if is_matrix else leaf
-
-        return self.stack_runs(jax.tree_util.tree_map_with_path(cast, shared),
-                               stack=_stack_by_writes)
+        return common.prepare_shared(
+            shared, self.runs(), self.dtype,
+            lambda names: names[-1] == "kernel" and "conv1d" not in names)
 
     def bind_shared(self, shared):
         """``(per_client, x) -> (preds, features)`` over a base prepared

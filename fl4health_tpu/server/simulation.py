@@ -5535,6 +5535,15 @@ class FederatedSimulation:
             ("exchanged_bytes_per_round", (down + up) * self.n_clients,
              "payload bytes down and up, all clients, one round"),
         )
+        model = self.logic.model
+        if model.build_gauges is not None:
+            batch_shape = (self.batch_size,
+                           *self.datasets[0].x_train.shape[1:])
+            gauges += tuple(
+                (name, value, "static fact of the model's layers, from its "
+                 "build_gauges")
+                for name, value in model.build_gauges(
+                    batch_shape, self.n_clients).items())
         obs = self.observability
         for name, value, text in gauges:
             obs.gauge(name, help=text).set(float(value))

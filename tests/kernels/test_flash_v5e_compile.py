@@ -2,7 +2,9 @@
 a v5e: the three flash calls at the flash cell's shapes (4 clients vmapped
 over batch 8 x 12 heads, T 2,048, D 64, bf16, blocks 128/128), the same three
 causal over one shared key/value head at the adapter cell's (20 heads of 128,
-blocks 512/512), and that cell's two selective-scan calls (4 clients x 2,048
+blocks 512/512), the same three causal at latent attention's two head widths
+(128 heads, q and k 192 wide padded to 256 lanes, v 128, T 1,024, a static
+scale), and that cell's two selective-scan calls (4 clients x 2,048
 positions x 5,120 channels x 16 states). Nothing runs: the TPU's compiler
 works against a described chip. The only file that describes a topology;
 the description happens inside a module-scoped fixture, never at import."""
@@ -43,14 +45,14 @@ def one_chip():
 
 
 def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
-                    block, causal):
+                    block, causal, v_dim=None, scale=None):
     """name of the kernel -> its custom-call instructions in the HLO of the
     compiled forward and backward programs."""
     from jax.experimental.compilation_cache import compilation_cache
 
     def attend(q, k, v, mask):
         return flash_attention(q, k, v, mask, block, block, interpret=False,
-                               causal=causal)
+                               causal=causal, scale=scale)
 
     def loss(q, k, v, mask):
         return jnp.sum(jax.vmap(attend)(q, k, v, mask).astype(jnp.float32))
@@ -59,6 +61,9 @@ def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
                              jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((clients, batch, seq, kv_heads, head_dim),
                               jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((clients, batch, seq, kv_heads,
+                              v_dim or head_dim), jnp.bfloat16,
+                             sharding=one_chip)
     mask = jax.ShapeDtypeStruct((clients, batch, seq), jnp.float32,
                                 sharding=one_chip)
     # an executable compiled for a described chip cannot be read back from
@@ -66,10 +71,10 @@ def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        texts = [jax.jit(jax.vmap(attend)).lower(q, kv, kv, mask)
+        texts = [jax.jit(jax.vmap(attend)).lower(q, kv, v, mask)
                  .compile().as_text(),
                  jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-                 .lower(q, kv, kv, mask).compile().as_text()]
+                 .lower(q, kv, v, mask).compile().as_text()]
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
@@ -118,6 +123,32 @@ def test_causal_shared_head_call_compiles_for_the_v5e(causal_calls, name):
     assert lines, f"no tpu_custom_call named {name}: {sorted(causal_calls)}"
     for line in lines:
         assert _result_shapes(line) == CAUSAL_KERNELS[name], (name, line)
+    assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
+# latent attention of the expert cell: 4 clients x batch 1 x 128 heads, q / k
+# 192 wide (256 lanes as padded), v 128, T 1,024, causal, blocks 512/512, the
+# softmax scale with YaRN's mscale in it
+MLA_QK_ROWS, MLA_V_ROWS = "bf16[4,128,1024,256]", "bf16[4,128,1024,128]"
+MLA_KERNELS = {
+    "flash_fwd": (MLA_V_ROWS, "f32[4,128,1024,1]"),
+    "flash_dq": (MLA_QK_ROWS,),
+    "flash_dkv": (MLA_QK_ROWS, MLA_V_ROWS),
+}
+
+
+@pytest.fixture(scope="module")
+def mla_calls(one_chip):
+    return _compiled_calls(one_chip, 4, 1, 1024, 128, 128, 192, 512,
+                           causal=True, v_dim=128, scale=0.114721)
+
+
+@pytest.mark.parametrize("name", sorted(MLA_KERNELS))
+def test_two_width_call_compiles_for_the_v5e(mla_calls, name):
+    lines = mla_calls.get(name)
+    assert lines, f"no tpu_custom_call named {name}: {sorted(mla_calls)}"
+    for line in lines:
+        assert _result_shapes(line) == MLA_KERNELS[name], (name, line[:400])
     assert len(lines) == (2 if name == "flash_fwd" else 1)
 
 

@@ -28,9 +28,11 @@ import optax
 from fl4health_tpu.clients import engine
 from fl4health_tpu.compression import CompressionConfig
 from fl4health_tpu.datasets.synthetic import synthetic_classification
+from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.metrics import efficient
 from fl4health_tpu.metrics.base import MetricManager
 from fl4health_tpu.models.cnn import Mlp
+from fl4health_tpu.models.deepseek import DeepseekV2Classifier
 from fl4health_tpu.models.jamba import JambaClassifier
 from fl4health_tpu.models.transformer import TransformerClassifier
 from fl4health_tpu.observability import (
@@ -244,6 +246,18 @@ def _model_fit_text(family):
         module = TransformerClassifier(
             vocab_size=50, n_classes=N_CLASSES, d_model=16, n_heads=2,
             n_layers=2, d_ff=32, max_len=8)
+    elif family == "deepseek":
+        # one dense and one expert layer over a shared base, 4 of 8 experts
+        # held, through the flash calls at two head widths
+        module = DeepseekV2Classifier(
+            vocab_size=50, n_classes=N_CLASSES, d_model=16, n_layers=2,
+            d_ff=32, n_heads=2, q_lora_rank=8, kv_lora_rank=8,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, d_expert=8,
+            n_routed_experts=8, experts_held=4, first_expert_held=2,
+            n_group=4, topk_group=2, top_k=3, lora_rank=2, remat=True,
+            dtype=jnp.bfloat16,
+            attention_fn=functools.partial(flash_attention, causal=True,
+                                           block_q=8, block_k=8))
     else:
         # one Mamba layer and one attention layer over a shared base
         module = JambaClassifier(
@@ -254,8 +268,32 @@ def _model_fit_text(family):
 
 
 @pytest.mark.parametrize("stage", ["local_train", "server_update"])
-@pytest.mark.parametrize("family", ["transformer", "jamba"])
+@pytest.mark.parametrize("family", ["transformer", "jamba", "deepseek"])
 def test_the_cells_model_families_keep_both_stage_scopes(family, stage):
     """``local_train_ms_per_round`` and ``server_update_ms_per_round`` read
     exactly these two strings out of a trace of ``fit_round_t``."""
     assert f"fl_stage::{stage}" in _model_fit_text(family)
+
+
+@pytest.mark.parametrize("scope,op", [
+    ("mla_attention", "dot"), ("mla_flash", "dynamic_slice"),
+    ("moe", "dot_general"), ("moe_router", "reduce_max"),
+    ("moe_experts", "dot_general"), ("shared_experts", "dot"),
+    ("shared_cast", "convert")])
+def test_the_expert_familys_layer_scopes_reach_the_compiled_round(scope, op):
+    """``mla_attention_ms_per_round``, ``mla_flash_roofline_pct``,
+    ``moe_ms_per_round`` and ``moe_experts_roofline_pct`` read these strings
+    out of a trace of ``fit_round_t`` (``layer_metrics/layer_common.py``):
+    each is in the name stack of an op of the kind it should hold (the
+    interpreted kernel's block slices, the group maximum, the products), on
+    the forward and, but for the once-a-round cast, on the backward pass
+    (the routed layer's is a ``custom_vjp``'s own function)."""
+    lines = [line for line in _model_fit_text("deepseek").splitlines()
+             if f"fl_layer::{scope}" in line]
+    assert any(re.match(rf"\s*(ROOT )?%{op}", line) for line in lines), (
+        scope, len(lines))
+    if scope != "shared_cast":
+        assert any("transpose(" in line for line in lines), scope
+    if scope in ("moe_router", "moe_experts"):  # they nest in the layer's
+        assert all("fl_layer::moe/" in line or "fl_layer::moe)" in line
+                   for line in lines), scope
