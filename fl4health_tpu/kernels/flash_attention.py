@@ -35,25 +35,72 @@ limits are checked in Python by ``_check_compilable`` so a request outside
 them fails with the reason, not with a compiler dump):
 
 - ``block_q`` / ``block_k`` are multiples of 128, or one block spans the
-  whole padded sequence. The key-padding mask travels as ``[BH, 1, T]`` and
-  is sliced along the 128-wide lane axis at ``block_k`` offsets (in dK/dV
-  ``lse`` and ``delta`` likewise, at ``block_q`` offsets); 64 is not
-  lane-aligned and is rejected. Compiled and checked against dense
+  whole padded sequence. The key-padding mask travels as ``[BH, 1, T]``
+  (``[B, 1, T]`` lane-indexed) and is sliced along the 128-wide lane axis
+  at ``block_k`` offsets (in dK/dV ``lse`` and ``delta`` likewise, at
+  ``block_q`` offsets); 64 is not lane-aligned and is rejected. Compiled and checked against dense
   attention: 128, 256, 512, 128/256, and whole-sequence blocks.
 - K/V (forward, dQ) and Q/dO (dK/dV) for one (batch, head) stay whole in
-  VMEM, under Mosaic's 16 MiB scoped-VMEM limit. The largest pairs that
-  compiled: T=16384 at D<=128 in bf16 and T=8192 at D<=128 in f32 (8 MiB
+  VMEM (every part of K, or of Q, counted at its lane-padded width), under
+  Mosaic's 16 MiB scoped-VMEM limit. The largest pairs that compiled: T=16384 at D<=128 in bf16 and T=8192 at D<=128 in f32 (8 MiB
   per pair); f32 T=16384 D=128 (16 MiB) did not. Longer sequences are ring
   attention's job (``ring_flash_attention`` shards T first).
 
-Two head widths (PR 31): q and k share the query/key width D, v has its own
-Dv (``v.shape[-1]``), as latent attention has them (192 = 128 without
-positions + 64 rotary, against 128). S = Q K^T, dQ and dK contract or
-produce D lanes; P V, dP = dO V^T, dV, the output and dO are Dv wide. Each
-is padded to its own multiple (192 -> 256 lanes, 128 stays), so nothing of
-the value side pays for the wider key. ``scale`` (static, optional)
-replaces ``1 / sqrt(D)``: YaRN's ``mscale`` enters there. With Dv == D and
-no ``scale`` the calls trace to what they were before the second width.
+Two head widths: q and k share the query/key width D, v has its own Dv
+(``v.shape[-1]``), as latent attention has them (192 = 128 without positions
++ 64 rotary, against 128). S = Q K^T, dQ and dK contract or produce D lanes;
+P V, dP = dO V^T, dV, the output and dO are Dv wide. ``scale`` (static,
+optional) replaces ``1 / sqrt(D)``: YaRN's ``mscale`` enters there.
+
+The score as a sum of parts: ``q`` and ``k`` may be tuples of parts, ``S =
+sum_i q_i k_i^T``, each part's dot at its own width, added in float32 before
+the scale; dQ and dK come back per part. Latent attention passes ``(q_nope
+[B,T,H,128], q_pe [B,T,H,64])`` and ``(k_nope [B,T,H,128], k_pe
+[B,T,1,64])``: nothing is concatenated to 192 lanes or padded to 256.
+
+Two paths, chosen by the operands' shapes at trace time (``_lane_kinds``; no
+knob, the results are the same):
+
+- **lane-indexed**: every operand is addressed where the model holds it, by
+  a ``BlockSpec`` index map, and the head is a grid axis. ``[B, T, H, w]`` is
+  viewed as ``[B, T, H*w]`` (free) and head h is lane block h (``lane``: w a
+  multiple of 128); a part of k, or k and v, with ONE head under several
+  query heads is ``[B, T, w]`` at block ``(b, ., 0)`` for every head (``row``:
+  any w, the block spans the whole last axis; no ``broadcast_to``), and its
+  gradient is summed over the heads in float32 INSIDE the dK/dV call (the
+  head axis innermost, the output block resident across it: ``_store``); the
+  key-padding mask is ``[B, 1, Tp]`` at ``(b, 0, 0)``, not repeated per head.
+  The output is ``[B, T, H*Dv]``, which an output projection takes as it
+  is: no transpose before the call, none after, no pad of a width. The one
+  copy left is of a part of q narrower than 128 lanes over a shared part of k
+  (the rotary 64): it is laid out ``[B, H, T, w]`` (``head``), and its
+  gradient comes back so.
+- **transposed**: every operand copied to ``[B*H, Tp, Dpadded]`` (each width
+  padded to its own multiple: 64 for a width <= 64, else 128), one grid row
+  per (batch, head), a shared head broadcast first, parts concatenated first.
+  Any call whose shapes the rule below leaves out; with Dv == D, one part and
+  no ``scale`` it traces to what it was before the second width and before
+  the parts (the encoder's D = 64: ``test_equal_widths_and_no_scale_trace_to_
+  the_parents_program``).
+
+The rule: lane-indexed when v's width is a multiple of 128 and every part of
+q / k is a multiple of 128 wide with heads of its own, or is a shared
+one-head part of k (its q part may then be narrow, as above). So latent
+attention at the published widths and 20 heads of 128 over one key/value head
+take it; D = 64, or a narrow part with key heads of its own, does not.
+``count_call_sites`` counts the traced calls by path for a build-time gauge.
+
+What Mosaic accepted and refused for the 64-lane rotary part of q (compiled
+for a described v5e, jax 0.9.0): a ``(1, block_q, 64)`` block of ``[B, T,
+H*64]`` is REFUSED by the Pallas TPU lowering (a block's last two dims must
+divide by 8 and 128 or equal the array's); a ``(1, block_q, 128)`` block
+holding two heads' rotary lanes compiles, but makes two heads one grid step:
+the kernel body twice, 64-lane halves cut out of a 128-lane tile, and the
+no-position part's head axis stepping by two; ``[B, H, T, 64]`` with a
+``(None, 1, block_q, 64)`` block (the last dim equals the array's) compiles
+and leaves the kernel one head a step. The last is the form taken: the
+transpose is a third of q's bytes and XLA fuses it into the rotary fusion
+that computes the part anyway.
 
 On the CPU backend the kernels run in Pallas interpret mode so the CPU suite
 exercises the same code path (house rule from kernels/dp_clip.py); interpret
@@ -62,6 +109,8 @@ mode takes any block size.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 import math
 
@@ -214,33 +263,86 @@ def _on_or_under_diagonal(q_start, nq: int, k_start, nk: int, transposed):
     return kpos <= qpos
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
-                scale, precision, causal):
+def _layout_of(kinds, qs, v, mask):
+    """Where the operands lie and how the grid walks them. The transposed
+    path (``kinds`` None): every operand ``[B*H, Tp, w]``, one grid row per
+    (batch, head). The lane-indexed path: ``kinds = (q kinds, k kinds, v
+    kind, heads)``, each operand addressed where the model holds it
+    (``_spec``), the head a grid axis of its own. Returns the kinds, the
+    heads, B (or B*H), Tp, each part's and v's width a head, and the kinds of
+    the output and of the per-row statistics."""
+    q_kinds, k_kinds, v_kind, heads = kinds or (("row",), ("row",), "row",
+                                                None)
+
+    def width(x, kind):
+        return x.shape[-1] // (heads if kind == "lane" else 1)
+
+    out, vec = ("row", "row") if heads is None else ("lane", "head")
+    return (q_kinds, k_kinds, v_kind, heads, mask.shape[0], mask.shape[-1],
+            [width(x, kind) for x, kind in zip(qs, q_kinds)],
+            width(v, v_kind), out, vec)
+
+
+def _spec(kind, n, width, pos, whole=False):
+    """BlockSpec of ``n`` positions (``whole``: the one block that is all of
+    them) of one head of one operand. ``pos(*grid) -> (batch, head, block)``.
+    ``row``: ``[B, Tp, w]``, no head axis (the transposed path's ``[B*H, Tp,
+    w]``, and a part every head shares); ``lane``: ``[B, Tp, H*w]``, the
+    head a lane block; ``head``: ``[B, H, Tp, w]``, a width that is no lane
+    block."""
+    def at(*grid):
+        b, h, r = pos(*grid)
+        r = 0 if whole else r
+        return {"row": (b, r, 0), "lane": (b, r, h),
+                "head": (b, h, r, 0)}[kind]
+
+    return pl.BlockSpec((None, 1, n, width) if kind == "head"
+                        else (1, n, width), at)
+
+
+def _shape(kind, b, heads, tp, width):
+    if kind == "row":
+        return b, tp, width
+    return (b, tp, heads * width) if kind == "lane" else (b, heads, tp, width)
+
+
+def _scores(a_parts, b_parts, precision):
+    """sum_i a_i . b_i^T over the parts' own widths, in float32."""
+    s = _dot(a_parts[0], b_parts[0], (1, 1), precision)
+    for a, b in zip(a_parts[1:], b_parts[1:]):
+        s = s + _dot(a, b, (1, 1), precision)
+    return s
+
+
+def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
     # Mosaic layout contract (learned on real silicon, KERNELS r5): every
     # block's trailing two dims must be (8k, 128k) or equal the array dims.
-    # Row-per-(batch,head) vectors therefore travel as mask [BH, 1, Tp] and
-    # lse/delta [BH, Tp, 1] (dK/dV takes them the other way round: see
+    # Row-per-(batch,head) vectors therefore travel as mask [B, 1, Tp] and
+    # lse/delta [.., Tp, 1] (dK/dV takes them the other way round: see
     # _bwd_call), and all in-kernel state stays 2-D. Mosaic keeps a
     # [Bq, 1] value replicated along the lanes, so m and corr meet the score
     # tile without a broadcast; l is kept as 128 per-lane partial sums and
     # crosses the lanes once, after the last block.
-    q = _mxu_operand(q_ref[0])  # [Bq, Dp]
-    bq, dvp = q.shape[0], v_ref.shape[-1]
+    n = (len(refs) - 4) // 2  # parts of q and of k whose scores add
+    q_refs, k_refs = refs[:n], refs[n:2 * n]
+    v_ref, mask_ref, o_ref, lse_ref = refs[2 * n:]
+    qs = [_mxu_operand(r[0]) for r in q_refs]  # [Bq, w_i]
+    bq, dvp = qs[0].shape[0], v_ref.shape[-1]
     lanes = _LANE if block_k % _LANE == 0 else block_k
     live = None
     if causal:
-        q_start = pl.program_id(1) * bq
+        q_start = pl.program_id(q_axis) * bq
         live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
 
     def step(ks, carry):
         m, l, acc = carry  # m: [Bq, 1], l: [Bq, lanes], acc: [Bq, Dvp], f32
-        kb = _mxu_operand(k_ref[0, ks, :])
+        kbs = [_mxu_operand(r[0, ks, :]) for r in k_refs]
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
         if causal:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
                                                 block_k, False)
-        s = _dot(q, kb, (1, 1), precision) * scale  # [Bq, Bk]
+        s = _scores(qs, kbs, precision) * scale  # [Bq, Bk]
         s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
@@ -252,7 +354,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
         return m_new, l, acc
 
     m, l, acc = _block_loop(
-        k_ref.shape[1] // block_k, block_k, bq, step,
+        v_ref.shape[1] // block_k, block_k, bq, step,
         (jnp.full((bq, 1), NEG_INF, jnp.float32),
          jnp.zeros((bq, lanes), jnp.float32),
          jnp.zeros((bq, dvp), jnp.float32)), live)
@@ -261,215 +363,325 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, block_k,
     lse_ref[0] = m + jnp.log(denom)  # [Bq, 1]
 
 
-def _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret, causal):
-    bh, tp, dp = q.shape
-    dvp = v.shape[-1]  # the value head width: v and the output
-    grid = (bh, tp // block_q)
+def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
+              kinds):
+    (q_kinds, k_kinds, v_kind, heads, b, tp, widths, dvp, out,
+     vec) = _layout_of(kinds, qs, v, mask)
+    if heads is None:  # one grid row per (batch, head)
+        grid, pos = (b, tp // block_q), lambda b, i: (b, 0, i)
+    else:
+        grid, pos = (b, heads, tp // block_q), lambda b, h, i: (b, h, i)
     kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
-                               precision=_dot_precision(q.dtype),
-                               causal=causal)
+                               precision=_dot_precision(qs[0].dtype),
+                               causal=causal, q_axis=len(grid) - 1)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tp, dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, tp, dvp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, tp), lambda b, i: (b, 0, 0)),
+            *(_spec(kind, block_q, w, pos) for kind, w in zip(q_kinds, widths)),
+            *(_spec(kind, tp, w, pos, whole=True)
+              for kind, w in zip(k_kinds, widths)),
+            _spec(v_kind, tp, dvp, pos, whole=True),
+            _spec("row", 1, tp, pos, whole=True),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dvp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            _spec(out, block_q, dvp, pos),
+            _spec(vec, block_q, 1, pos),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tp, dvp), q.dtype),
-            jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct(_shape(out, b, heads, tp, dvp), v.dtype),
+            jax.ShapeDtypeStruct(_shape(vec, b, heads, tp, 1), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v, mask)
+    )(*qs, *ks, v, mask)
 
 
 # ---------------------------------------------------------------------------
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, block_k, scale, precision, causal):
-    q = _mxu_operand(q_ref[0])
+def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
+    n = (len(refs) - 5) // 3
+    q_refs, k_refs = refs[:n], refs[n:2 * n]
+    v_ref, mask_ref, do_ref, lse_ref, delta_ref = refs[2 * n:2 * n + 5]
+    dq_refs = refs[2 * n + 5:]
+    qs = [_mxu_operand(r[0]) for r in q_refs]
     do = _mxu_operand(do_ref[0])
     lse = lse_ref[0]  # [Bq, 1]
     delta = delta_ref[0]  # [Bq, 1] = rowsum(dO * O)
-    bq = q.shape[0]
+    bq = qs[0].shape[0]
     live = None
     if causal:
-        q_start = pl.program_id(1) * bq
+        q_start = pl.program_id(q_axis) * bq
         live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
 
-    def step(ks, dq):
-        kb = _mxu_operand(k_ref[0, ks, :])
+    def step(ks, dqs):
+        kbs = [_mxu_operand(r[0, ks, :]) for r in k_refs]
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
         if causal:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
                                                 block_k, False)
-        s = _dot(q, kb, (1, 1), precision) * scale
+        s = _scores(qs, kbs, precision) * scale
         p = jnp.where(keep, jnp.exp(s - lse), 0.0)
         dp = _dot(do, vb, (1, 1), precision)
         # dS = p * (dP - delta) * scale; the scale waits for the sum
-        return dq + _dot(p * (dp - delta), kb, (1, 0), precision)
+        ds = p * (dp - delta)
+        return tuple(dq + _dot(ds, kb, (1, 0), precision)
+                     for dq, kb in zip(dqs, kbs))
 
-    dq = _block_loop(k_ref.shape[1] // block_k, block_k, bq, step,
-                     jnp.zeros(q.shape, jnp.float32), live)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    dqs = _block_loop(v_ref.shape[1] // block_k, block_k, bq, step,
+                      tuple(jnp.zeros(q.shape, jnp.float32) for q in qs),
+                      live)
+    for dq_ref, dq in zip(dq_refs, dqs):
+        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, block_q, scale, precision, causal):
+def _store(ref, value, head_axis):
+    """Write one key block's gradient. ``head_axis`` None: the block is this
+    grid step's own. Else every head shares the part: its block is float32
+    and stays in VMEM across the head axis (the innermost), the first head
+    writes it and the others add to it."""
+    if head_axis is None:
+        ref[0] = value.astype(ref.dtype)
+        return
+    h = pl.program_id(head_axis)
+
+    @pl.when(h == 0)
+    def _():
+        ref[0] = value
+
+    @pl.when(h > 0)
+    def _():
+        ref[0] = ref[0] + value
+
+
+def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared):
     # The score tile is held transposed, [Bk, Bq]: dV += P^T dO and
     # dK += dS^T Q are then plain row-major dots, and lse / delta meet the
     # tile as rows [1, Bq] (a sublane broadcast) where the [Bq, Bk] form
     # paid a [128, 128] transpose and 32 lane-broadcast permutes a tile
     # (18.4 -> 14.3 ms a call on the v5e, PR 26).
-    kb = _mxu_operand(k_ref[0])  # [Bk, Dp]
+    n = (len(refs) - 6) // 3
+    q_refs, k_refs = refs[:n], refs[n:2 * n]
+    v_ref, keep_ref, do_ref, lse_ref, delta_ref = refs[2 * n:2 * n + 5]
+    dk_refs, dv_ref = refs[2 * n + 5:-1], refs[-1]
+    kbs = [_mxu_operand(r[0]) for r in k_refs]  # [Bk, w_i]
     vb = _mxu_operand(v_ref[0])
-    bk = kb.shape[0]
+    bk = vb.shape[0]
     live = None
     if causal:
         k_start = pl.program_id(1) * bk
         live = lambda q_start: q_start + (block_q - 1) >= k_start  # noqa: E731
 
-    def step(qs, carry):
-        dk, dv = carry
-        q = _mxu_operand(q_ref[0, qs, :])
-        do = _mxu_operand(do_ref[0, qs, :])
-        lse = lse_ref[0, :, qs]  # [1, Bq]
-        delta = delta_ref[0, :, qs]
-        pt = jnp.exp(_dot(kb, q, (1, 1), precision) * scale - lse)
+    def step(qs_, carry):
+        dks, dv = carry
+        qs = [_mxu_operand(r[0, qs_, :]) for r in q_refs]
+        do = _mxu_operand(do_ref[0, qs_, :])
+        lse = lse_ref[0, :, qs_]  # [1, Bq]
+        delta = delta_ref[0, :, qs_]
+        pt = jnp.exp(_scores(kbs, qs, precision) * scale - lse)
         if causal:
-            pt = jnp.where(_on_or_under_diagonal(qs.start, block_q, k_start,
+            pt = jnp.where(_on_or_under_diagonal(qs_.start, block_q, k_start,
                                                  bk, True), pt, 0.0)
         dpt = _dot(vb, do, (1, 1), precision)
         dv = dv + _dot(pt, do, (1, 0), precision)
-        dk = dk + _dot(pt * (dpt - delta), q, (1, 0), precision)
-        return dk, dv
+        dst = pt * (dpt - delta)
+        return tuple(dk + _dot(dst, q, (1, 0), precision)
+                     for dk, q in zip(dks, qs)), dv
 
-    dk0 = jnp.zeros(kb.shape, jnp.float32)
-    dv0 = dk0 if vb.shape == kb.shape else jnp.zeros(vb.shape, jnp.float32)
-    dk, dv = _block_loop(q_ref.shape[1] // block_q, block_q, bk,
-                         step, (dk0, dv0), live)
+    dks0 = tuple(jnp.zeros(kb.shape, jnp.float32) for kb in kbs)
+    # one zeros tile serves both where the widths agree: the program the
+    # equal-width calls traced to before the second width
+    dv0 = (dks0[0] if vb.shape == kbs[0].shape
+           else jnp.zeros(vb.shape, jnp.float32))
+    dks, dv = _block_loop(do_ref.shape[1] // block_q, block_q, bk,
+                          step, (dks0, dv0), live)
     # A row of dK / dV depends on its own key alone, so the key-padding mask
     # is one select on the sums: a padded key's row is zero, as when every
     # p of that key was zeroed in the loop.
     keep = keep_ref[0] > 0  # [Bk, 1]
-    dk_ref[0] = jnp.where(keep, dk * scale, 0.0).astype(dk_ref.dtype)
-    dv_ref[0] = jnp.where(keep, dv, 0.0).astype(dv_ref.dtype)
+    # shared: the k parts, then v
+    for dk_ref, dk, acc in zip(dk_refs, dks, shared):
+        _store(dk_ref, jnp.where(keep, dk * scale, 0.0), 2 if acc else None)
+    _store(dv_ref, jnp.where(keep, dv, 0.0), 2 if shared[-1] else None)
 
 
-def _bwd_call(q, k, v, mask, o, lse, do, block_q, block_k, scale, interpret,
-              dlse, causal):
-    bh, tp, dp = q.shape
-    dvp = v.shape[-1]  # the value head width: v, o, do and dv
+def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
+              dlse, causal, kinds):
+    (q_kinds, k_kinds, v_kind, heads, b, tp, widths, dvp, out,
+     vec) = _layout_of(kinds, qs, v, mask)
     # lse is a differentiable OUTPUT (ring-flash merge): its cotangent
     # enters the score gradient as dS = p*(dP - delta + dlse), i.e. the
     # delta slot carries (delta - dlse) — kernels unchanged. Plain
     # flash_attention reaches here with dlse = zeros (custom_vjp
     # instantiates the dropped output's cotangent).
-    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                     keepdims=True)
-             - dlse.astype(jnp.float32))  # [BH, Tp, 1]
+    if heads is None:
+        delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                         axis=-1, keepdims=True)
+                 - dlse.astype(jnp.float32))  # [BH, Tp, 1]
+        grid, pos = (b, tp // block_q), lambda b, i: (b, 0, i)
+    else:
+        prod = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+            b, tp, heads, dvp)
+        delta = (jnp.transpose(jnp.sum(prod, axis=-1), (0, 2, 1))[..., None]
+                 - dlse.astype(jnp.float32))  # [B, H, Tp, 1]
+        grid, pos = (b, heads, tp // block_q), lambda b, h, i: (b, h, i)
 
-    prec = _dot_precision(q.dtype)
+    prec = _dot_precision(qs[0].dtype)
     dq_kernel = functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
-                                  precision=prec, causal=causal)
-    dq = pl.pallas_call(
+                                  precision=prec, causal=causal,
+                                  q_axis=len(grid) - 1)
+    q_specs = [_spec(kind, block_q, w, pos) for kind, w in zip(q_kinds, widths)]
+    dqs = pl.pallas_call(
         dq_kernel,
-        grid=(bh, tp // block_q),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, tp, dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, tp, dvp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, tp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, dvp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            *q_specs,
+            *(_spec(kind, tp, w, pos, whole=True)
+              for kind, w in zip(k_kinds, widths)),
+            _spec(v_kind, tp, dvp, pos, whole=True),
+            _spec("row", 1, tp, pos, whole=True),
+            _spec(out, block_q, dvp, pos),
+            _spec(vec, block_q, 1, pos),
+            _spec(vec, block_q, 1, pos),
         ],
-        out_specs=pl.BlockSpec((1, block_q, dp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, tp, dp), q.dtype),
+        out_specs=q_specs,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs],
         interpret=interpret,
         name="flash_dq",
-    )(q, k, v, mask, do, lse, delta)
+    )(*qs, *ks, v, mask, do, lse, delta)
 
-    # dK/dV sees queries along the lanes: lse and delta as rows [BH, 1, Tp]
+    # dK/dV sees queries along the lanes: lse and delta as rows [.., 1, Tp]
     # (sliced at block_q offsets like the forward's mask), the key mask as a
-    # column [BH, Tp, 1]. Same seven operands.
+    # column [B, Tp, 1]. On the lane-indexed path the head is the innermost
+    # grid axis, so a part every head shares keeps its float32 block in VMEM
+    # while the heads add to it (_store).
+    if heads is None:
+        grid, pos = (b, tp // block_k), lambda b, j: (b, 0, j)
+        shared = (False,) * (len(ks) + 1)
+    else:
+        grid, pos = (b, tp // block_k, heads), lambda b, j, h: (b, h, j)
+        shared = tuple(kind == "row" for kind in (*k_kinds, v_kind))
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                                   scale=scale, precision=prec, causal=causal)
-    dk, dv = pl.pallas_call(
+                                   scale=scale, precision=prec, causal=causal,
+                                   shared=shared)
+    k_specs = [_spec(kind, block_k, w, pos) for kind, w in zip(k_kinds, widths)]
+    v_spec = _spec(v_kind, block_k, dvp, pos)
+    *dks, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, tp // block_k),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tp, dp), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dvp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, 1), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, tp, dvp), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, tp), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, tp), lambda b, j: (b, 0, 0)),
+            *(_spec(kind, tp, w, pos, whole=True)
+              for kind, w in zip(q_kinds, widths)),
+            *k_specs,
+            v_spec,
+            _spec("row", block_k, 1, pos),
+            _spec(out, tp, dvp, pos, whole=True),
+            _spec(vec, 1, tp, pos, whole=True),
+            _spec(vec, 1, tp, pos, whole=True),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dvp), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tp, dp), k.dtype),
-            jax.ShapeDtypeStruct((bh, tp, dvp), v.dtype),
-        ],
+        out_specs=[*k_specs, v_spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape,
+                                        jnp.float32 if acc else x.dtype)
+                   for x, acc in zip((*ks, v), shared)],
         interpret=interpret,
         name="flash_dkv",
-    )(q, k, v, mask.reshape(bh, tp, 1), do, lse.reshape(bh, 1, tp),
-      delta.reshape(bh, 1, tp))
-    return dq, dk, dv
+    )(*qs, *ks, v, mask.reshape(b, tp, 1), do,
+      lse.reshape(*lse.shape[:-2], 1, tp),
+      delta.reshape(*delta.shape[:-2], 1, tp))
+    return dqs, dks, dv
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp over padded [BH, Tp, Dp] internals
+# custom_vjp over the operands as the kernels address them
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_padded_lse(q, k, v, mask, block_q, block_k, scale, interpret,
-                      causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_lse(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
+               kinds):
     """(out, lse) pair with lse a first-class differentiable output so
     partial-attention results can be merged exactly (ring-flash). The
     plain-``out`` path (flash_attention) wraps this and drops lse — its
     zero cotangent makes _bwd_call's dlse term vanish, so ONE custom_vjp
-    serves both APIs."""
-    return _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret,
-                     causal)
+    serves both APIs, and both layouts: ``qs`` / ``ks`` are tuples of parts
+    laid out as ``kinds`` says (``_layout_of``; None: padded ``[BH, Tp,
+    Dp]``, out the same and lse ``[BH, Tp, 1]``; else out ``[B, Tp, H*Dv]``
+    and lse ``[B, H, Tp, 1]``)."""
+    return _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret,
+                     causal, kinds)
 
 
-def _flash_padded_lse_fwd(q, k, v, mask, block_q, block_k, scale, interpret,
-                          causal):
-    out, lse = _fwd_call(q, k, v, mask, block_q, block_k, scale, interpret,
-                         causal)
-    return (out, lse), (q, k, v, mask, out, lse)
+def _flash_lse_fwd(qs, ks, v, mask, block_q, block_k, scale, interpret,
+                   causal, kinds):
+    out, lse = _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret,
+                         causal, kinds)
+    return (out, lse), (qs, ks, v, mask, out, lse)
 
 
-def _flash_padded_lse_bwd(block_q, block_k, scale, interpret, causal, res,
-                          cts):
+def _flash_lse_bwd(block_q, block_k, scale, interpret, causal, kinds, res,
+                   cts):
     do, dlse = cts
-    q, k, v, mask, out, lse = res
-    dq, dk, dv = _bwd_call(q, k, v, mask, out, lse, do, block_q, block_k,
-                           scale, interpret, dlse=dlse, causal=causal)
-    return dq, dk, dv, None
+    qs, ks, v, mask, out, lse = res
+    dqs, dks, dv = _bwd_call(qs, ks, v, mask, out, lse, do, block_q, block_k,
+                             scale, interpret, dlse=dlse, causal=causal,
+                             kinds=kinds)
+    # a shared part's gradient was summed over the heads in float32
+    return (tuple(dqs), tuple(dk.astype(k.dtype) for dk, k in zip(dks, ks)),
+            dv.astype(v.dtype), None)
 
 
-_flash_padded_lse.defvjp(_flash_padded_lse_fwd, _flash_padded_lse_bwd)
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+# flash_attention_lse calls traced while a counter is open, by the path each
+# took: a fact of the traced program, for a build-time gauge
+_site_counters: list = []
+
+
+@contextlib.contextmanager
+def count_call_sites():
+    """Counts, while open, the ``flash_attention`` / ``flash_attention_lse``
+    calls TRACED, by path: ``{"lane_indexed": n, "transposed": m}``. A run
+    of layers under ``lax.scan`` traces its call once."""
+    sites = collections.Counter(lane_indexed=0, transposed=0)
+    _site_counters.append(sites)
+    try:
+        yield sites
+    finally:
+        _site_counters.remove(sites)
+
+
+def _lane_kinds(qs, ks, v):
+    """How each operand lies for the lane-indexed path (``_spec``'s kinds:
+    (q kinds, k kinds, v kind, heads)), or None where the shapes leave the
+    transposed path. A part with one head under several query heads is
+    shared (``row``, any width: its block spans its whole last axis); a head
+    width that is a multiple of 128 lanes is a lane block of ``[B, T, H*w]``
+    (``lane``); a narrower part of q is laid out ``[B, H, T, w]`` (``head``:
+    the one copy the path makes) if its part of k is shared. Anything else
+    (a narrow part of k or a narrow v with heads of its own: the encoder's
+    D = 64) is the transposed path's."""
+    heads = qs[0].shape[2]
+
+    def kind(x, narrow=None):
+        if x.shape[2] == 1 and heads > 1:
+            return "row"
+        return "lane" if x.shape[3] % _LANE == 0 else narrow
+
+    k_kinds = tuple(kind(k) for k in ks)
+    q_kinds = tuple(kind(q, "head" if kk == "row" else None)
+                    for q, kk in zip(qs, k_kinds))
+    kinds = (q_kinds, k_kinds, kind(v), heads)
+    if v.shape[3] % _LANE or None in q_kinds + k_kinds or "row" in q_kinds:
+        return None
+    return kinds
 
 
 def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
+    q: jax.Array | tuple,
+    k: jax.Array | tuple,
     v: jax.Array,
     pad_mask: jax.Array | None = None,
     block_q: int = 128,
@@ -484,14 +696,22 @@ def flash_attention(
 
     The value head width ``Dv`` is ``v``'s own and may differ from the
     query/key width ``D`` (latent attention: 192 and 128): S = Q K^T runs at
-    D, P V, dV and dP at Dv, each padded to its own lane multiple. ``scale``
-    (static) multiplies the scores; ``None`` is ``1 / sqrt(D)``.
+    D, P V, dV and dP at Dv. ``scale`` (static) multiplies the scores;
+    ``None`` is ``1 / sqrt(D)``.
+
+    ``q`` and ``k`` may be tuples of parts whose scores add: ``S = sum_i q_i
+    k_i^T`` (latent attention: a part without positions and a rotary part),
+    summed in float32 before the scale; ``D`` is the parts' widths together
+    and the gradients come back per part.
 
     ``causal`` (static): a query sees the keys at its own position and
     before; key blocks wholly above the diagonal are skipped in all three
-    kernels. ``k`` / ``v`` with a single head (``[B, T, 1, D]``) are shared
-    by every query head (multi-query attention): the wrapper broadcasts
-    them, and their gradient is the sum over the query heads.
+    kernels. ``k`` / ``v``, or a part of ``k``, with a single head (``[B, T,
+    1, D]``) is shared by every query head (multi-query attention), and its
+    gradient is the sum over the query heads.
+
+    Which of the two paths a call takes follows from its shapes (module
+    docstring; ``_lane_kinds``); the results are the same.
 
     pad_mask is NON-differentiable: it is a binary padding indicator, and the
     custom VJP returns a zero cotangent for it (a soft/learned mask would get
@@ -503,8 +723,8 @@ def flash_attention(
 
 
 def flash_attention_lse(
-    q: jax.Array,
-    k: jax.Array,
+    q: jax.Array | tuple,
+    k: jax.Array | tuple,
     v: jax.Array,
     pad_mask: jax.Array | None = None,
     block_q: int = 128,
@@ -526,6 +746,90 @@ def flash_attention_lse(
     flash_attention."""
     if interpret is None:
         interpret = interpret_default()
+    qs = tuple(q) if isinstance(q, (tuple, list)) else (q,)
+    ks = tuple(k) if isinstance(k, (tuple, list)) else (k,)
+    if len(qs) != len(ks) or any(
+            a.shape[-1] != b.shape[-1] for a, b in zip(qs, ks)):
+        raise ValueError(
+            "flash_attention: q and k are parts whose scores add, so they "
+            f"come in pairs of one width; got {[a.shape for a in qs]} and "
+            f"{[b.shape for b in ks]}")
+    if scale is None:
+        scale = 1.0 / (sum(a.shape[-1] for a in qs) ** 0.5)
+    kinds = _lane_kinds(qs, ks, v)
+    for sites in _site_counters:
+        sites["transposed" if kinds is None else "lane_indexed"] += 1
+    if kinds is not None:
+        return _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret,
+                             causal, scale, kinds)
+    # the transposed path copies anyway: one part each, a part of k with one
+    # head under parts with heads of their own broadcast to theirs
+    k_heads = max(a.shape[2] for a in ks)
+    q = jnp.concatenate(qs, axis=-1)
+    k = jnp.concatenate(
+        [jnp.broadcast_to(a, (*a.shape[:2], k_heads, a.shape[3])) for a in ks],
+        axis=-1)
+    return _transposed(q, k, v, pad_mask, block_q, block_k, interpret, causal,
+                       scale)
+
+
+def _sharded(call, n_operands: int):
+    """Inside a program traced for a mesh (parallel/program.py): XLA cannot
+    auto-partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), so the call goes manual. The specs say
+    "replicated" for these per-client operands; the engine's vmap over
+    clients (spmd_axis_name="clients") turns its batched axis into
+    P("clients"), so each chip runs the kernel on its own clients only.
+    Already-manual contexts (ring attention's shard_map body) call the
+    kernel directly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.are_all_axes_auto:
+        return call
+    return jax.shard_map(call, mesh=mesh, in_specs=(P(),) * n_operands,
+                         out_specs=(P(), P()), check_vma=False)
+
+
+def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
+                  scale, kinds):
+    """The operands where the model holds them: nothing is transposed,
+    padded or broadcast but a narrow part of q (``_lane_kinds``) and, where
+    the blocks do not divide it, the sequence."""
+    q_kinds, k_kinds, v_kind, h = kinds
+    b, t = qs[0].shape[:2]
+    dv = v.shape[-1]
+    t_multiple = math.lcm(block_q, block_k)
+
+    def lay(x, kind):
+        x = (jnp.transpose(x, (0, 2, 1, 3)) if kind == "head"
+             else x.reshape(b, t, -1))
+        return _pad_axis(x, x.ndim - 2, t_multiple)
+
+    qs = tuple(lay(x, kind) for x, kind in zip(qs, q_kinds))
+    ks = tuple(lay(x, kind) for x, kind in zip(ks, k_kinds))
+    v = lay(v, v_kind)
+    tp = v.shape[1]
+    if not interpret:
+        _check_compilable(
+            block_q, block_k, tp,
+            sum(pl.cdiv(a.shape[-1] // (h if kind == "lane" else 1), _LANE)
+                * _LANE for a, kind in zip(ks, k_kinds)), dv, v.dtype)
+    if pad_mask is None:
+        pad_mask = jnp.ones((b, t), jnp.float32)
+    pad_mask = jax.lax.stop_gradient(pad_mask)
+    # [B, 1, Tp]: one row a batch entry, every head's block is the same one
+    maskp = _pad_axis(pad_mask.astype(jnp.float32), 1, t_multiple)[:, None, :]
+
+    def call(qs, ks, v, maskp):
+        return _flash_lse(qs, ks, v, maskp, block_q, block_k, scale,
+                          interpret, causal, kinds)
+
+    out, lse = _sharded(call, 4)(qs, ks, v, maskp)
+    return out[:, :t].reshape(b, t, h, dv), lse[:, :, :t, 0]
+
+
+def _transposed(q, k, v, pad_mask, block_q, block_k, interpret, causal,
+                scale):
+    """Every operand copied to ``[B*H, T, Dpadded]``."""
     b, t, h, d = q.shape
     dv = v.shape[-1]
     if k.shape[2] == 1 and h > 1:
@@ -533,8 +837,6 @@ def flash_attention_lse(
         v = jnp.broadcast_to(v, (*q.shape[:3], dv))
     if pad_mask is None:
         pad_mask = jnp.ones((b, t), jnp.float32)
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
     # [B,T,H,D] -> [B*H, T, D]; pad T to the block grid and each head width
     # (query/key D, value Dv) per d_multiple below (64 for a width <= 64,
     # else the 128 lane width: a padded width is NOT guaranteed to be a
@@ -567,22 +869,10 @@ def flash_attention_lse(
     maskp = jnp.repeat(maskp, h, axis=0)[:, None, :]
 
     def padded(qp, kp, vp, maskp):
-        return _flash_padded_lse(qp, kp, vp, maskp, block_q, block_k, scale,
-                                 interpret, causal)
+        return _flash_lse((qp,), (kp,), vp, maskp, block_q, block_k, scale,
+                          interpret, causal, None)
 
-    mesh = jax.sharding.get_abstract_mesh()
-    if not mesh.empty and mesh.are_all_axes_auto:
-        # Inside a program traced for a mesh (parallel/program.py): XLA
-        # cannot auto-partition a Mosaic custom call ("Mosaic kernels cannot
-        # be automatically partitioned"), so the call goes manual. The specs
-        # say "replicated" for these per-client operands; the engine's vmap
-        # over clients (spmd_axis_name="clients") turns its batched axis
-        # into P("clients"), so each chip runs the kernel on its own clients
-        # only. Already-manual contexts (ring attention's shard_map body)
-        # call the kernel directly.
-        padded = jax.shard_map(padded, mesh=mesh, in_specs=(P(),) * 4,
-                               out_specs=(P(), P()), check_vma=False)
-    out, lse = padded(qp, kp, vp, maskp)
+    out, lse = _sharded(padded, 4)(qp, kp, vp, maskp)
     out = out[:, :t, :dv].reshape(b, h, t, dv)
     lse = lse[:, :t, 0].reshape(b, h, t)
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype), lse

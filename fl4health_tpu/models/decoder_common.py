@@ -12,10 +12,13 @@ parameters) and ``dims.lora_scale`` (alpha / rank, 0 without adapters).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from fl4health_tpu.kernels.flash_attention import count_call_sites
 from fl4health_tpu.utils.peft import per_client_predicate
 
 # leaves every client holds: the adapters and the classification head
@@ -55,17 +58,22 @@ def swiglu(p, u, dims):
 def dense_causal_attention(q, k, v, pad_mask, scale=None):
     """The plain form (float32 softmax) for when a family is given no
     ``attention_fn``: q / k [B, T, H, D], v [B, T, H, Dv], or k / v with one
-    shared head; ``scale`` None is ``1 / sqrt(D)``."""
-    t, d = q.shape[1], q.shape[-1]
-    k = jnp.broadcast_to(k, q.shape)
-    v = jnp.broadcast_to(v, (*q.shape[:3], v.shape[-1]))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=F32)
+    shared head; q and k may be tuples of parts whose scores add (D is their
+    widths together, a part of k may have the one head alone); ``scale``
+    None is ``1 / sqrt(D)``."""
+    qs = q if isinstance(q, (tuple, list)) else (q,)
+    ks = k if isinstance(k, (tuple, list)) else (k,)
+    t, d = qs[0].shape[1], sum(a.shape[-1] for a in qs)
+    v = jnp.broadcast_to(v, (*qs[0].shape[:3], v.shape[-1]))
+    scores = functools.reduce(jnp.add, (
+        jnp.einsum("bqhd,bkhd->bhqk", a, jnp.broadcast_to(c, a.shape),
+                   preferred_element_type=F32) for a, c in zip(qs, ks)))
     scores = (scores / jnp.sqrt(jnp.float32(d)) if scale is None
               else scores * scale)
     keep = (pad_mask[:, None, None, :] > 0) & (
         jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])[None, None]
     attn = jax.nn.softmax(jnp.where(keep, scores, jnp.finfo(F32).min),
-                          axis=-1).astype(q.dtype)
+                          axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", attn, v)
 
 
@@ -77,6 +85,18 @@ def last_token_logits(h, pad_mask, final_scale, score_kernel, eps):
     pooled = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     logits = pooled @ score_kernel.astype(F32)
     return {"prediction": logits.astype(F32)}, {"features": pooled}
+
+
+def flash_call_site_gauges(module, batch_shape) -> dict:
+    """How many ``kernels.flash_attention`` calls one trace of ``module``'s
+    forward holds on each of the kernel's two paths (a run of layers under
+    ``lax.scan`` traces its call once): the choice follows from shapes at
+    trace time, so it is a fact of the build. Traced abstractly: nothing is
+    allocated or run."""
+    with count_call_sites() as sites:
+        jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                       jax.ShapeDtypeStruct(tuple(batch_shape), jnp.int32))
+    return {f"flash_calls_{path}": n for path, n in sites.items()}
 
 
 # ---------------------------------------------------------------------------

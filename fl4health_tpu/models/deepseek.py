@@ -14,6 +14,9 @@ head; ``q_pe`` and the ONE ``k_pe`` all heads share get rotary positions
 (YaRN's blended frequencies, halves layout); ``q = [q_nope | q_pe]``, ``k =
 [k_nope | k_pe]`` are ``qk_nope + qk_rope`` wide, ``v`` is ``v_head_dim``
 wide; causal softmax at ``(qk_nope + qk_rope)^-0.5 * mscale^2``; ``W_o``.
+Neither ``q`` nor ``k`` is ever assembled: the score is ``q_nope k_nope^T +
+q_pe k_pe^T``, the attention function takes the parts, and each part comes
+out of a projection of its own columns (``_head_columns``).
 
 FFN: the first ``first_k_dense`` layers a dense SwiGLU; the others
 ``sum_i w_i E_i(u) + SwiGLU_shared(u)`` with the router of
@@ -159,32 +162,50 @@ def apply_rope(x, cos, sin):
                            axis=-1).astype(x.dtype)
 
 
+def _head_columns(p, widths):
+    """A projection whose output is ``n_heads`` groups of ``sum(widths)``
+    columns, as one projection per width: part ``i`` holds every head's
+    columns ``[offset_i, offset_i + widths[i])``, heads outermost. Slicing
+    the matrix (and the adapter's ``lora_b``) instead of the product leaves
+    each part ``[.., n_heads * width]`` the way its matmul writes it, which is
+    where the flash calls read it; a column of a product is the same sum
+    either way."""
+    def cut(m, lo, w):
+        return m.reshape(m.shape[0], -1, sum(widths))[:, :, lo:lo + w].reshape(
+            m.shape[0], -1)
+
+    offsets = [sum(widths[:i]) for i in range(len(widths))]
+    return [{k: cut(m, lo, w) if k in ("kernel", "lora_b") else m
+             for k, m in p.items()} for lo, w in zip(offsets, widths)]
+
+
 def mla_attention(p, u, pad_mask, dims: DeepseekDims):
-    """``dims.attention_fn(q, k, v, pad_mask=mask, scale=s) -> out`` must be
-    causal and take a value width of its own, e.g.
-    ``functools.partial(kernels.flash_attention, causal=True, block_q=512,
-    block_k=512)``; ``None`` is the dense form."""
+    """``dims.attention_fn((q_nope, q_pe), (k_nope, k_pe), v, pad_mask=mask,
+    scale=s) -> out`` must be causal, add the scores of the two parts (the
+    rotary part of k has ONE head, which every query head shares) and take a
+    value width of its own, e.g. ``functools.partial(kernels.flash_attention,
+    causal=True, block_q=512, block_k=512)``; ``None`` is the dense form.
+    Nothing is concatenated, broadcast or sliced on the way: every part is
+    ``[B, T, heads, width]`` as a view of what its projection wrote."""
     with jax.named_scope("fl_layer::mla_attention"):
         b, t = u.shape[:2]
         h, nope, rot = dims.n_heads, dims.qk_nope, dims.qk_rope
         c_q = rms_norm(lora_dense(p["q_a_proj"], u, dims),
                        p["q_a_layernorm"]["scale"], dims.rms_eps)
-        q = lora_dense(p["q_b_proj"], c_q, dims).reshape(b, t, h, nope + rot)
+        q_nope, q_pe = (lora_dense(part, c_q, dims).reshape(b, t, h, -1)
+                        for part in _head_columns(p["q_b_proj"], (nope, rot)))
         c_kv, k_pe = jnp.split(lora_dense(p["kv_a_proj_with_mqa"], u, dims),
                                [dims.kv_lora_rank], axis=-1)
         c_kv = rms_norm(c_kv, p["kv_a_layernorm"]["scale"], dims.rms_eps)
-        kv = lora_dense(p["kv_b_proj"], c_kv, dims).reshape(
-            b, t, h, nope + dims.v_head)
-        k_nope, v = kv[..., :nope], kv[..., nope:]
+        k_nope, v = (lora_dense(part, c_kv, dims).reshape(b, t, h, -1)
+                     for part in _head_columns(p["kv_b_proj"],
+                                               (nope, dims.v_head)))
         cos, sin = rope_tables(t, rot, dims.rope)
-        q = jnp.concatenate(
-            [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], axis=-1)
+        q_pe = apply_rope(q_pe, cos, sin)
         k_pe = apply_rope(k_pe[:, :, None, :], cos, sin)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_pe, (b, t, h, rot))], axis=-1)
         attend = dims.attention_fn or common.dense_causal_attention
         with jax.named_scope("fl_layer::mla_flash"):
-            out = attend(q, k, v, pad_mask=pad_mask,
+            out = attend((q_nope, q_pe), (k_nope, k_pe), v, pad_mask=pad_mask,
                          scale=softmax_scale(nope + rot, dims.rope))
         return lora_dense(p["o_proj"], out.reshape(b, t, h * dims.v_head),
                           dims)
@@ -531,12 +552,14 @@ class DeepseekV2Classifier(nn.Module):
             x)
 
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
-        """Static facts of the routed layer for the simulation's build-time
-        gauges; ``batch_shape`` is one client's [B, T]."""
+        """Static facts of the routed layer, and which path the forward's
+        flash calls take, for the simulation's build-time gauges;
+        ``batch_shape`` is one client's [B, T]."""
         tokens = n_clients * math.prod(batch_shape)
         return {"moe_experts_held": self.experts_held,
                 "moe_experts_total": self.n_routed_experts,
                 # rows the folded routed call is built to take: every choice
                 # of every token could be a held expert
                 "moe_assignment_rows_bound":
-                    tokens * min(self.top_k, self.experts_held)}
+                    tokens * min(self.top_k, self.experts_held),
+                **common.flash_call_site_gauges(self, batch_shape)}

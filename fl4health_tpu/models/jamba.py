@@ -286,3 +286,9 @@ class JambaClassifier(nn.Module):
             prepared = self.prepare_shared(shared)
         return lambda per_client, x: self.forward(
             merge_trees(prepared, self.stack_runs(per_client)), x)
+
+    def build_gauges(self, batch_shape, n_clients: int) -> dict:
+        """Which path the forward's flash calls take, for the simulation's
+        build-time gauges; ``batch_shape`` is one client's [B, T]."""
+        del n_clients
+        return common.flash_call_site_gauges(self, batch_shape)

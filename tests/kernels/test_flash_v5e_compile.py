@@ -2,9 +2,11 @@
 a v5e: the three flash calls at the flash cell's shapes (4 clients vmapped
 over batch 8 x 12 heads, T 2,048, D 64, bf16, blocks 128/128), the same three
 causal over one shared key/value head at the adapter cell's (20 heads of 128,
-blocks 512/512), the same three causal at latent attention's two head widths
-(128 heads, q and k 192 wide padded to 256 lanes, v 128, T 1,024, a static
-scale), and that cell's two selective-scan calls (4 clients x 2,048
+blocks 512/512: lane-indexed, the shared head's gradients one float32 array a
+client), the same three causal over latent attention's parts (128 heads; q
+and k as 128 lanes without positions and 64 rotary, the rotary key ONE head;
+v 128, T 1,024, a static scale: lane-indexed, nothing padded to 256), and the
+adapter cell's two selective-scan calls (4 clients x 2,048
 positions x 5,120 channels x 16 states). Nothing runs: the TPU's compiler
 works against a described chip. The only file that describes a topology;
 the description happens inside a module-scoped fixture, never at import."""
@@ -45,9 +47,11 @@ def one_chip():
 
 
 def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
-                    block, causal, v_dim=None, scale=None):
+                    block, causal, v_dim=None, scale=None, shared_part=None):
     """name of the kernel -> its custom-call instructions in the HLO of the
-    compiled forward and backward programs."""
+    compiled forward and backward programs. ``shared_part``: q and k are two
+    parts, ``head_dim`` wide with ``kv_heads`` heads and ``shared_part`` wide
+    with ONE key head."""
     from jax.experimental.compilation_cache import compilation_cache
 
     def attend(q, k, v, mask):
@@ -57,13 +61,14 @@ def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
     def loss(q, k, v, mask):
         return jnp.sum(jax.vmap(attend)(q, k, v, mask).astype(jnp.float32))
 
-    q = jax.ShapeDtypeStruct((clients, batch, seq, heads, head_dim),
-                             jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((clients, batch, seq, kv_heads, head_dim),
-                              jnp.bfloat16, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((clients, batch, seq, kv_heads,
-                              v_dim or head_dim), jnp.bfloat16,
-                             sharding=one_chip)
+    def arg(n_heads, width):
+        return jax.ShapeDtypeStruct((clients, batch, seq, n_heads, width),
+                                    jnp.bfloat16, sharding=one_chip)
+
+    q, kv = arg(heads, head_dim), arg(kv_heads, head_dim)
+    if shared_part:
+        q, kv = (q, arg(heads, shared_part)), (kv, arg(1, shared_part))
+    v = arg(kv_heads, v_dim or head_dim)
     mask = jax.ShapeDtypeStruct((clients, batch, seq), jnp.float32,
                                 sharding=one_chip)
     # an executable compiled for a described chip cannot be read back from
@@ -97,12 +102,15 @@ def mosaic_calls(one_chip):
 
 
 # the adapter cell's attention layer: 4 clients x batch 1 x 20 query heads of
-# 128 over ONE key/value head, T 2,048, causal, blocks 512/512
-CAUSAL_ROWS = "bf16[4,20,2048,128]"
+# 128 over ONE key/value head, T 2,048, causal, blocks 512/512. Lane-indexed:
+# q, the output and dQ are [.., T, 20 * 128] as the projections hold them, the
+# per-row statistic [.., 20, T, 1]; dK and dV of the shared head leave the
+# call summed over the 20 heads in float32, one [T, 128] array a client
+CAUSAL_ROWS, CAUSAL_SHARED = "bf16[4,1,2048,2560]", "f32[4,1,2048,128]"
 CAUSAL_KERNELS = {
-    "flash_fwd": (CAUSAL_ROWS, "f32[4,20,2048,1]"),
+    "flash_fwd": (CAUSAL_ROWS, "f32[4,1,20,2048,1]"),
     "flash_dq": (CAUSAL_ROWS,),
-    "flash_dkv": (CAUSAL_ROWS, CAUSAL_ROWS),
+    "flash_dkv": (CAUSAL_SHARED, CAUSAL_SHARED),
 }
 
 
@@ -127,24 +135,27 @@ def test_causal_shared_head_call_compiles_for_the_v5e(causal_calls, name):
 
 
 # latent attention of the expert cell: 4 clients x batch 1 x 128 heads, q / k
-# 192 wide (256 lanes as padded), v 128, T 1,024, causal, blocks 512/512, the
-# softmax scale with YaRN's mscale in it
-MLA_QK_ROWS, MLA_V_ROWS = "bf16[4,128,1024,256]", "bf16[4,128,1024,128]"
+# as two parts (128 lanes without positions; 64 rotary, the key's ONE head),
+# v 128, T 1,024, causal, blocks 512/512, the softmax scale with YaRN's mscale
+# in it. Lane-indexed: no row is 256 wide; the rotary part of q and its
+# gradient lie [.., 128 heads, T, 64]; the rotary key's gradient is ONE
+# float32 [T, 64] array a client, summed over the heads inside the call
+MLA_ROWS = "bf16[4,1,1024,16384]"
 MLA_KERNELS = {
-    "flash_fwd": (MLA_V_ROWS, "f32[4,128,1024,1]"),
-    "flash_dq": (MLA_QK_ROWS,),
-    "flash_dkv": (MLA_QK_ROWS, MLA_V_ROWS),
+    "flash_fwd": (MLA_ROWS, "f32[4,1,128,1024,1]"),
+    "flash_dq": (MLA_ROWS, "bf16[4,1,128,1024,64]"),
+    "flash_dkv": (MLA_ROWS, "f32[4,1,1024,64]", MLA_ROWS),
 }
 
 
 @pytest.fixture(scope="module")
 def mla_calls(one_chip):
-    return _compiled_calls(one_chip, 4, 1, 1024, 128, 128, 192, 512,
-                           causal=True, v_dim=128, scale=0.114721)
+    return _compiled_calls(one_chip, 4, 1, 1024, 128, 128, 128, 512,
+                           causal=True, scale=0.114721, shared_part=64)
 
 
 @pytest.mark.parametrize("name", sorted(MLA_KERNELS))
-def test_two_width_call_compiles_for_the_v5e(mla_calls, name):
+def test_two_part_call_compiles_for_the_v5e(mla_calls, name):
     lines = mla_calls.get(name)
     assert lines, f"no tpu_custom_call named {name}: {sorted(mla_calls)}"
     for line in lines:

@@ -211,6 +211,55 @@ def test_forward_and_adapter_gradients_match_the_reference(seeded, attention,
     assert float(jnp.abs(got["layers_0/mlp/down_proj/lora_b"]).max()) > 0
 
 
+def _top_level_eqns(jaxpr):
+    """Every equation outside the Pallas kernels' bodies."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _top_level_eqns(sub)
+
+
+def test_latent_attention_hands_the_flash_calls_its_parts_where_they_lie():
+    """At the published head widths (128 without positions + 64 rotary, 128
+    values) the flash entry is lane-indexed: between the projections and the
+    calls nothing is padded, concatenated to 192 lanes or broadcast over
+    the heads, and the one transpose is of the rotary part of q (64 lanes a
+    head are no lane block: it lies [B, heads, T, 64])."""
+    heads, t = 6, 256
+    cfg = dict(CFG, num_attention_heads=heads, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128)
+    module = _module(cfg, attention_fn=functools.partial(
+        flash_attention, causal=True, block_q=128, block_k=128))
+    gauges = module.build_gauges((1, t), 4)
+    # the dense run and the expert run trace their call once each
+    assert gauges["flash_calls_lane_indexed"] == 2
+    assert gauges["flash_calls_transposed"] == 0
+    assert _module(cfg).build_gauges((1, t), 4)["flash_calls_lane_indexed"] == 0
+
+    x = jax.ShapeDtypeStruct((1, t), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)["params"]
+    u = jax.ShapeDtypeStruct((1, t, cfg["hidden_size"]), jnp.float32)
+    mask = jax.ShapeDtypeStruct((1, t), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, u, m: ds.mla_attention(p, u, m, module.dims))(
+        params["layers_0"]["self_attn"], u, mask).jaxpr
+    moved = [(e.primitive.name, tuple(e.invars[0].aval.shape),
+              tuple(e.outvars[0].aval.shape))
+             for e in _top_level_eqns(jaxpr)
+             if e.primitive.name in ("transpose", "pad", "broadcast_in_dim",
+                                     "concatenate")
+             # an array with the heads in it (as an axis, or in the lanes)
+             and any(d in (heads, heads * 128, heads * 64)
+                     for d in e.outvars[0].aval.shape)]
+    rope_halves = ("concatenate", (1, t, heads, 32), (1, t, heads, 64))
+    assert [m for m in moved if m != rope_halves] == [
+        ("transpose", (1, t, heads, 64), (1, heads, t, 64))], moved
+    assert sum(e.primitive.name == "pallas_call"
+               for e in _top_level_eqns(jaxpr)) == 1
+
+
 def test_the_module_brings_its_own_split_and_cast(seeded):
     _, tree, x = seeded
     module = _module(dtype=jnp.bfloat16)
@@ -244,7 +293,8 @@ def test_the_module_brings_its_own_split_and_cast(seeded):
     assert float(jnp.max(jnp.abs(got - want))) < 0.25
     assert module.build_gauges((1, 20), 4) == {
         "moe_experts_held": 8, "moe_experts_total": 40,
-        "moe_assignment_rows_bound": 4 * 20 * 6}
+        "moe_assignment_rows_bound": 4 * 20 * 6,
+        "flash_calls_lane_indexed": 0, "flash_calls_transposed": 0}
 
 
 def test_experts_outside_the_router_are_refused():

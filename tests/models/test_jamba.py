@@ -153,6 +153,24 @@ def test_the_module_brings_its_own_split_and_cast(seeded):
     assert cast["embed_tokens/embedding"].dtype == jnp.float32
 
 
+@pytest.mark.parametrize("d_model,attention,want", [
+    # 4 heads of 128 over one key/value head: read where the projections
+    # wrote them, the shared head by index
+    (512, "flash", {"lane_indexed": 1, "transposed": 0}),
+    # 4 heads of 8: copied to [B*H, T, 64]
+    (32, "flash", {"lane_indexed": 0, "transposed": 1}),
+    (512, "dense", {"lane_indexed": 0, "transposed": 0}),
+])
+def test_build_gauges_count_the_flash_call_sites_by_path(d_model, attention,
+                                                         want):
+    """One attention layer in the period of four: one call site (the runs of
+    Mamba layers hold none), on the path its head width gives."""
+    fn = (functools.partial(flash_attention, causal=True, block_q=8,
+                            block_k=8) if attention == "flash" else None)
+    gauges = _module(fn).clone(d_model=d_model).build_gauges((2, 20), 4)
+    assert gauges == {f"flash_calls_{k}": v for k, v in want.items()}
+
+
 def test_bfloat16_compute_stays_near_float32(seeded):
     flat, tree, x = seeded
     got = _module(dtype=jnp.bfloat16).apply({"params": tree}, x)[0]["prediction"]
