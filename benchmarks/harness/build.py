@@ -36,14 +36,15 @@ def make_inputs(cell: Cell, seed: int):
     from, made from the seed."""
     ref = load_module("reference", cell.family, cell.bench_dir)
     w0 = datagen.make_weights(ref.param_spec(cell.cfg, cell.job), seed)
-    data = datagen.make_data(ref.input_spec(cell.cfg, cell.job), cell.job, seed)
+    objective = load_module("reference/objectives", cell.objective["name"],
+                            cell.bench_dir)
+    data = datagen.make_data(ref.input_spec(cell.cfg, cell.job), cell.job, seed,
+                             objective.targets, cell.objective)
     return ref, w0, data, datagen.client_rows(cell.job)
 
 
 def build_sim(cell: Cell, seed: int, w0: dict, data, rows, reporters=()):
     from fl4health_tpu.clients import engine
-    from fl4health_tpu.metrics import efficient
-    from fl4health_tpu.metrics.base import MetricManager
     from fl4health_tpu.observability import Observability
     from fl4health_tpu.server.simulation import (ClientDataset,
                                                  FederatedSimulation)
@@ -54,8 +55,11 @@ def build_sim(cell: Cell, seed: int, w0: dict, data, rows, reporters=()):
     x_tr, y_tr, x_va, y_va = data
     datasets = [ClientDataset(x_tr[i, :n], y_tr[i, :n], x_va[i], y_va[i])
                 for i, n in enumerate(rows)]
-    # the strategy and the clients' optimizer are adapters found by the names
-    # in the traffic file, as the model family is by the configuration's
+    # the strategy, the clients' optimizer and their objective (loss and
+    # metric) are adapters found by the names in the traffic file, as the
+    # model family is by the configuration's
+    objective = load_module("objectives", cell.objective["name"],
+                            cell.bench_dir)
     strategy = load_module("strategies", cell.strategy["name"],
                            cell.bench_dir).build(cell.strategy, job)
     tx = load_module("optimizers", cell.optimizer["name"],
@@ -66,13 +70,12 @@ def build_sim(cell: Cell, seed: int, w0: dict, data, rows, reporters=()):
 
         mesh = MeshConfig(**job["mesh"])
     sim = FederatedSimulation(
-        logic=engine.ClientLogic(engine.from_flax(module),
-                                 engine.masked_cross_entropy),
+        logic=objective.build_logic(engine.from_flax(module), cell.cfg, job),
         tx=tx,
         strategy=strategy,
         datasets=datasets,
         batch_size=int(job["batch"]),
-        metrics=MetricManager((efficient.accuracy(),)),
+        metrics=objective.build_metrics(cell.cfg, job),
         local_steps=int(job["local_steps"]),
         seed=datagen.seed31(seed),
         execution_mode=job["execution_mode"],

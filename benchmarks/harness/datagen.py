@@ -55,18 +55,22 @@ def client_rows(job: dict) -> list[int]:
     return rows
 
 
-def make_data(inp: dict, job: dict, seed: int):
-    """(x_train [C, n_max, ...], y_train [C, n_max], x_val, y_val) on the
-    device. Rows beyond a client's own count exist in the stacked array and
-    are never indexed. ``inp`` is the reference's ``input_spec``."""
+def make_data(inp: dict, job: dict, seed: int, targets, objective: dict):
+    """(x_train [C, n_max, ...], y_train [C, n_max, ...], x_val, y_val) on
+    the device. Rows beyond a client's own count exist in the stacked array
+    and are never indexed. ``inp`` is the reference's ``input_spec``;
+    ``targets`` is the plain side of the job's objective
+    (``reference/objectives/<name>.py``), ``objective`` the traffic file's
+    entry: what the clients learn is drawn here, with the data, so the
+    program and the reference start from the same targets."""
     n_clients, n_val = int(job["clients"]), int(job["val_examples"])
     n_max = max(client_rows(job))
     n = n_max + n_val
-    classes = int(inp["classes"])
 
     @jax.jit
     def gen(key):
-        k_x, k_y, k_len, k_pat = jax.random.split(key, 4)
+        k_x, _, k_len, _ = jax.random.split(key, 4)
+        length = None
         if inp["kind"] == "tokens":
             seq, vocab = int(inp["seq"]), int(inp["vocab"])
             tok = jax.random.randint(k_x, (n_clients, n, seq), 1, vocab,
@@ -74,25 +78,15 @@ def make_data(inp: dict, job: dict, seed: int):
             lo = max(1, int(seq * float(inp.get("min_len_frac", 1.0))))
             length = jax.random.randint(k_len, (n_clients, n, 1), lo, seq + 1)
             x = jnp.where(jnp.arange(seq)[None, None, :] < length, tok, 0)
-            # the label is a function of the text, so the task is learnable
-            y = (x[..., 0] + x[..., 1]) % classes
         elif inp["kind"] == "images":
             hw, ch = int(inp["hw"]), int(inp["channels"])
-            y = jax.random.randint(k_y, (n_clients, n), 0, classes, jnp.int32)
-            shards = int(inp.get("label_shards") or 0)
-            if shards:
-                # label-sorted shards (McMahan et al. 2017's non-IID split):
-                # a client holds ``shards`` consecutive classes, and the
-                # classes follow the client's index through the cohort
-                first = (jnp.arange(n_clients) * classes) // n_clients
-                y = (first[:, None] + y % shards) % classes
-            pattern = jax.random.normal(k_pat, (classes, hw, hw, ch),
-                                        jnp.float32)
-            x = (jax.random.normal(k_x, (n_clients, n, hw, hw, ch),
-                                   jnp.float32) + 0.5 * pattern[y])
+            x = jax.random.normal(k_x, (n_clients, n, hw, hw, ch), jnp.float32)
         else:
             raise ValueError(f"unknown data kind {inp['kind']!r}")
-        return (x[:, :n_max], y[:, :n_max].astype(jnp.int32),
-                x[:, n_max:], y[:, n_max:].astype(jnp.int32))
+        # the targets' own key: a fold beside the split above (kept four
+        # wide, though two of its keys are no longer drawn from), so an
+        # objective that draws nothing leaves every bit where it was
+        x, y = targets(jax.random.fold_in(key, 4), x, length, inp, objective)
+        return x[:, :n_max], y[:, :n_max], x[:, n_max:], y[:, n_max:]
 
     return gen(jax.random.fold_in(jax.random.PRNGKey(seed31(seed)), 7))

@@ -39,8 +39,8 @@ def load_module(kind: str, name: str, bench_dir: str = BENCH_DIR):
 
 
 def named(entry) -> dict:
-    """A traffic file names a strategy or an optimizer by a bare name or by
-    an object with ``name`` and its parameters."""
+    """A traffic file names a strategy, an optimizer or an objective by a
+    bare name or by an object with ``name`` and its parameters."""
     return {"name": entry} if isinstance(entry, str) else dict(entry)
 
 
@@ -74,6 +74,12 @@ class Cell:
         self.compute_dtype = self.cfg["compute_dtype"]
         self.strategy = named(self.job["strategy"])
         self.optimizer = named(self.job["optimizer"])
+        # what the clients minimise: their loss, its targets and their metric,
+        # found by this name on both sides (objectives/,
+        # reference/objectives/). A traffic file that names none gets the
+        # one ``defaults.json`` names
+        self.objective = named(self.job.get("objective") or load_json(
+            os.path.join(self.bench_dir, "defaults.json"))["objective"])
 
     def metrics(self, group: str) -> list[dict]:
         """The ``end_to_end`` or ``per_layer`` metrics this cell reports."""

@@ -123,14 +123,18 @@ def reference_rounds(cell: Cell, seed: int, numerics: str = "FLOAT32") -> dict:
     ref_mod, w0, data, rows = build.make_inputs(cell, seed)
     num = load_module("reference", "numerics", cell.bench_dir)
     nm = num.FLOAT32 if numerics == "FLOAT32" else num.CONTROLS[numerics]
-    # the plain strategy and optimizer are found by the traffic file's names
+    # the plain strategy, optimizer and objective are found by the traffic
+    # file's names
     strategy = load_module("reference/strategies", cell.strategy["name"],
                            cell.bench_dir)
     opt_mod = load_module("reference/optimizers", cell.optimizer["name"],
                           cell.bench_dir)
+    objective = load_module("reference/objectives", cell.objective["name"],
+                            cell.bench_dir)
     return strategy.run(
         lambda p, x, nm_: ref_mod.forward(p, x, cell.cfg, job, nm_),
-        w0, data[0], data[1], rows, batch=int(job["batch"]),
+        w0, data[0], data[1], rows, loss=objective.loss,
+        batch=int(job["batch"]),
         steps=int(job["local_steps"]), optimizer=(opt_mod, cell.optimizer),
         seed=build.datagen.seed31(seed), calls=job["check_calls"],
         client_block=int(job.get("reference_client_block", 1)), nm=nm,

@@ -1,6 +1,9 @@
 """Program build, inside the prologue: mean per ``fit()`` call of the
-``fl::introspect`` spans within its ``fl::fit_prologue``: the walk over the
-round programs' HLO text (``observability/hloscan.py``)."""
+``fl::introspect`` spans within its ``fl::fit_prologue``: the capture of the
+round programs' reports (``observability/introspect.py``: lower, cache load,
+``cost_analysis`` / ``memory_analysis``) in a simulation's first call,
+milliseconds of argument building in later ones, which record the remembered
+reports."""
 
 
 def read(ctx):

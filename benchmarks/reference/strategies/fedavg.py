@@ -40,23 +40,18 @@ def index_plan(seed: int, round_idx: int, client: int, n: int, batch: int,
     return orders.reshape(n_epochs * per_epoch, batch)[:steps]
 
 
-def cross_entropy(logits, labels):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
-
-
-def make_block_fn(forward, opt_mod, opt: dict, nm):
-    """jitted (params, states [Cb,...], xb [Cb,S,B,...], yb [Cb,S,B], w [Cb])
-    -> (sum_i w_i * params_i, sum_i w_i * loss_i, new states)."""
+def make_block_fn(forward, loss, opt_mod, opt: dict, nm):
+    """jitted (params, states [Cb,...], xb [Cb,S,B,...], yb [Cb,S,B,...],
+    w [Cb]) -> (sum_i w_i * params_i, sum_i w_i * loss_i, new states)."""
 
     def client(params, state, xb, yb):
         def step(carry, batch):
             p, state = carry
             x, y = batch
-            loss, g = jax.value_and_grad(
-                lambda q: cross_entropy(forward(q, x, nm), y))(p)
+            value, g = jax.value_and_grad(
+                lambda q: loss(forward(q, x, nm), x, y, nm))(p)
             p, state = opt_mod.update(p, g, state, opt)
-            return (nm.master(p), state), loss
+            return (nm.master(p), state), value
         (p, state), losses = jax.lax.scan(step, (params, state), (xb, yb))
         return p, state, jnp.mean(losses)
 
@@ -78,13 +73,15 @@ def leaf_norms(params: dict, base: dict) -> dict:
     return {k: float(v) for k, v in out.items()}
 
 
-def run(forward, w0: dict, x_train, y_train, n_train, *, batch: int,
+def run(forward, w0: dict, x_train, y_train, n_train, *, loss, batch: int,
         steps: int, optimizer, seed: int, calls, client_block: int,
         nm, strategy: dict | None = None, server=None) -> dict:
     """Follow ``calls`` (a list of rounds-per-fit, e.g. [1, 2]) from ``w0``.
 
-    ``x_train`` [C, n_max, ...] and ``y_train`` [C, n_max] are device arrays,
-    ``n_train`` the per-client row counts. Returns per-round losses (in
+    ``x_train`` [C, n_max, ...] and ``y_train`` [C, n_max, ...] are device
+    arrays, ``n_train`` the per-client row counts, ``loss(out, x, y, nm)`` the
+    step's loss from ``forward``'s output (the plain side of the job's
+    objective, ``reference/objectives/``). Returns per-round losses (in
     order) and, after each call, the per-leaf norms of the global weights'
     change from ``w0``. Clients run ``client_block`` at a time so the
     reference fits beside nothing else on the device. ``nm`` is the
@@ -97,7 +94,7 @@ def run(forward, w0: dict, x_train, y_train, n_train, *, batch: int,
     n_clients = len(n_train)
     if n_clients % client_block:
         raise ValueError("client_block must divide the number of clients")
-    block = make_block_fn(forward, optimizer[0], optimizer[1], nm)
+    block = make_block_fn(forward, loss, optimizer[0], optimizer[1], nm)
     w_all = np.asarray(n_train, np.float32)
     total = float(w_all.sum())
     params = nm.master(w0)

@@ -31,19 +31,19 @@ def split(params: dict, trainable_names) -> tuple[dict, dict]:
     return train, {k: v for k, v in params.items() if k not in train}
 
 
-def make_block_fn(forward, opt_mod, opt: dict, nm):
+def make_block_fn(forward, loss, opt_mod, opt: dict, nm):
     """jitted (trainable, frozen, states [Cb,...], xb [Cb,S,B,...],
-    yb [Cb,S,B], w [Cb]) -> (sum_i w_i * trainable_i, sum_i w_i * loss_i,
+    yb [Cb,S,B,...], w [Cb]) -> (sum_i w_i * trainable_i, sum_i w_i * loss_i,
     new states)."""
 
     def client(train, frozen, state, xb, yb):
         def step(carry, batch):
             p, state = carry
             x, y = batch
-            loss, g = jax.value_and_grad(lambda q: _fedavg.cross_entropy(
-                forward({**frozen, **q}, x, nm), y))(p)
+            value, g = jax.value_and_grad(lambda q: loss(
+                forward({**frozen, **q}, x, nm), x, y, nm))(p)
             p, state = opt_mod.update(p, g, state, opt)
-            return (nm.master(p), state), loss
+            return (nm.master(p), state), value
         (p, state), losses = jax.lax.scan(step, (train, state), (xb, yb))
         return p, state, jnp.mean(losses)
 
@@ -58,7 +58,7 @@ def make_block_fn(forward, opt_mod, opt: dict, nm):
     return block
 
 
-def run(forward, w0: dict, x_train, y_train, n_train, *, batch: int,
+def run(forward, w0: dict, x_train, y_train, n_train, *, loss, batch: int,
         steps: int, optimizer, seed: int, calls, client_block: int,
         nm, strategy: dict, server=None) -> dict:
     """``fedavg.run``'s contract; ``strategy["trainable"]`` names the path
@@ -69,7 +69,7 @@ def run(forward, w0: dict, x_train, y_train, n_train, *, batch: int,
     n_clients = len(n_train)
     if n_clients % client_block:
         raise ValueError("client_block must divide the number of clients")
-    block = make_block_fn(forward, optimizer[0], optimizer[1], nm)
+    block = make_block_fn(forward, loss, optimizer[0], optimizer[1], nm)
     w_all = np.asarray(n_train, np.float32)
     total = float(w_all.sum())
     train0, frozen = split(w0, strategy["trainable"])

@@ -35,10 +35,29 @@ def test_real_cell_resolves_from_its_two_files(name):
     n_params = sum(math.prod(s) for s, _ in spec.values())
     flops = load_module("flops", cell.family).train_step_flops(cell.cfg, cell.job)
     assert n_params > 5e5 and flops > 1e9
-    # BERT-base body at the published vocabulary: 85.05 M in the blocks,
-    # 23.44 M token embeddings, positions, final LN and head
-    pos = int(cell.job.get("max_positions") or 512)
-    assert n_params == 85054464 + 30522 * 768 + pos * 768 + 2 * 768 + 768 * 4 + 4
+    # the configuration's own count: leaf for leaf what the program's module
+    # of this family holds at these sizes (an abstract init, nothing built)
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import build
+
+    module = load_module("families", cell.family).build_module(cell.cfg,
+                                                               cell.job)
+    inp = ref.input_spec(cell.cfg, cell.job)
+    x = jax.ShapeDtypeStruct((int(cell.job["batch"]), int(inp["seq"])),
+                             jnp.int32)
+    held = build.flatten(jax.eval_shape(
+        lambda k, a: module.init(k, a, train=False), jax.random.PRNGKey(0),
+        x)["params"])
+    assert ({k: tuple(v.shape) for k, v in held.items()}
+            == {k: tuple(shape) for k, (shape, _) in spec.items()})
+    if cell.workload["config"] == "encoder_base":
+        # BERT-base body at the published vocabulary: 85.05 M in the blocks,
+        # 23.44 M token embeddings, positions, final LN and head
+        pos = int(cell.job.get("max_positions") or 512)
+        assert n_params == (85054464 + 30522 * 768 + pos * 768 + 2 * 768
+                            + 768 * 4 + 4)
     assert (cell.chips == 4) == bool(cell.job.get("mesh"))
     assert load_module("families", cell.family).build_module
     assert load_module("strategies", cell.strategy["name"]).build
