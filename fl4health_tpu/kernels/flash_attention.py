@@ -102,6 +102,15 @@ and leaves the kernel one head a step. The last is the form taken: the
 transpose is a third of q's bytes and XLA fuses it into the rotary fusion
 that computes the part anyway.
 
+Under a rematerialised layer: the forward rule of the ``custom_vjp`` gives
+``out`` and ``lse`` names (``SAVED_NAMES``, through ``core/remat.py``), so a
+``jax.checkpoint`` whose policy keeps those names saves the two arrays the
+backward reads of the forward and the layer's recompute holds no ``flash_fwd``
+(per byte kept the dearest thing a layer recomputes: 4 T T D FLOPs for T D
+2 bytes). ``lse`` is kept as ``[.., Tp]``, not as the ``[.., Tp, 1]`` the
+kernel writes: a trailing axis of 1 is padded 128-fold in HBM. Where no
+policy asks for the names they lower to nothing.
+
 On the CPU backend the kernels run in Pallas interpret mode so the CPU suite
 exercises the same code path (house rule from kernels/dp_clip.py); interpret
 mode takes any block size.
@@ -119,9 +128,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
+from fl4health_tpu.core.remat import named
 from fl4health_tpu.kernels._platform import interpret_default
 
 _LANE = 128
+# What the backward reads of the forward, under the names a remat policy may
+# keep (core/remat.py): ``out``, and ``lse`` without its trailing axis of 1.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
+SAVED_NAMES = (FLASH_OUT, FLASH_LSE)
 NEG_INF = -1e30
 # Whole-sequence operand pair (K+V, or Q+dO) a compiled kernel may keep in
 # VMEM: the largest that compiled under the 16 MiB scoped limit (see the
@@ -510,6 +524,8 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared):
 
 def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
               dlse, causal, kinds):
+    """``lse`` comes as the forward rule kept it, ``[.., Tp]``; ``dlse`` as
+    the result's cotangent, ``[.., Tp, 1]``."""
     (q_kinds, k_kinds, v_kind, heads, b, tp, widths, dvp, out,
      vec) = _layout_of(kinds, qs, v, mask)
     # lse is a differentiable OUTPUT (ring-flash merge): its cotangent
@@ -551,7 +567,7 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs],
         interpret=interpret,
         name="flash_dq",
-    )(*qs, *ks, v, mask, do, lse, delta)
+    )(*qs, *ks, v, mask, do, lse.reshape(*lse.shape, 1), delta)
 
     # dK/dV sees queries along the lanes: lse and delta as rows [.., 1, Tp]
     # (sliced at block_q offsets like the forward's mask), the key mask as a
@@ -589,7 +605,7 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         interpret=interpret,
         name="flash_dkv",
     )(*qs, *ks, v, mask.reshape(b, tp, 1), do,
-      lse.reshape(*lse.shape[:-2], 1, tp),
+      lse.reshape(*lse.shape[:-1], 1, tp),
       delta.reshape(*delta.shape[:-2], 1, tp))
     return dqs, dks, dv
 
@@ -615,9 +631,19 @@ def _flash_lse(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
 
 def _flash_lse_fwd(qs, ks, v, mask, block_q, block_k, scale, interpret,
                    causal, kinds):
+    """The forward rule names what the backward reads of it, so a remat
+    policy can keep exactly that (``SAVED_NAMES``) and the layer's recompute
+    holds no second ``flash_fwd``; no policy, no effect. ``lse`` is kept as
+    ``[.., Tp]``: the kernel writes ``[.., Tp, 1]``, whose trailing axis of 1
+    is padded 128-fold in HBM tiles, which one layer's copy can afford and a
+    copy per kept layer cannot. The result's ``lse`` is a view of the kept
+    one, so a recompute that reads it (ring-flash's merge) needs no kernel
+    either."""
     out, lse = _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret,
                          causal, kinds)
-    return (out, lse), (qs, ks, v, mask, out, lse)
+    out = named(out, FLASH_OUT)
+    lse = named(lse.reshape(lse.shape[:-1]), FLASH_LSE)
+    return (out, lse.reshape(*lse.shape, 1)), (qs, ks, v, mask, out, lse)
 
 
 def _flash_lse_bwd(block_q, block_k, scale, interpret, causal, kinds, res,

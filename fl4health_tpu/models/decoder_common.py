@@ -18,13 +18,28 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from fl4health_tpu.kernels.flash_attention import count_call_sites
+from fl4health_tpu.core import remat as remat_names
+from fl4health_tpu.kernels.flash_attention import (SAVED_NAMES as FLASH_SAVED,
+                                                   count_call_sites)
 from fl4health_tpu.utils.peft import per_client_predicate
 
 # leaves every client holds: the adapters and the classification head
 PER_CLIENT_MARKERS = ("lora_a", "lora_b", "score")
 PER_CLIENT = per_client_predicate(PER_CLIENT_MARKERS)
 F32 = jnp.float32
+
+# What each family's rematerialised layer keeps (core/remat.py), spelled
+# here once. Both keep the flash calls' ``out`` / ``lse``: per byte kept the
+# dearest thing a layer would recompute. Latent attention also keeps its
+# output stream ``h + o_proj(out)``: a frozen ``o_proj``'s backward reads
+# none of its own product (the adapters' gradients read ``out`` and the
+# ``[T, r]`` product ``out A``), so with the stream kept the recompute holds
+# no product of ``o_proj``'s kernel at all. Jamba's mixers' stream and its
+# scan's residuals are not kept: thirteen layers of them want memory that
+# has to be freed first (ROADMAP S9).
+MLA_STREAM = "mla_stream"
+JAMBA_REMAT_KEEPS = FLASH_SAVED
+DEEPSEEK_REMAT_KEEPS = (*FLASH_SAVED, MLA_STREAM)
 
 
 # ---------------------------------------------------------------------------
@@ -87,16 +102,28 @@ def last_token_logits(h, pad_mask, final_scale, score_kernel, eps):
     return {"prediction": logits.astype(F32)}, {"features": pooled}
 
 
-def flash_call_site_gauges(module, batch_shape) -> dict:
-    """How many ``kernels.flash_attention`` calls one trace of ``module``'s
-    forward holds on each of the kernel's two paths (a run of layers under
-    ``lax.scan`` traces its call once): the choice follows from shapes at
-    trace time, so it is a fact of the build. Traced abstractly: nothing is
-    allocated or run."""
+def remat_layers(body, remat: bool, keeps):
+    """``body`` rematerialised on the backward pass, less what ``keeps``
+    names, if ``remat``."""
+    return (jax.checkpoint(body, policy=remat_names.keep(keeps)) if remat
+            else body)
+
+
+def attention_gauges(module, batch_shape, n_clients: int, keeps) -> dict:
+    """Facts of ``module``'s build that follow from shapes at trace time:
+    how many ``kernels.flash_attention`` calls one trace of its forward
+    holds on each of the kernel's two paths (a run of layers under
+    ``lax.scan`` traces its call once), and what its remat sites keep of a
+    layer (``core.remat.saved_gauges``; ``keeps`` is the family's list,
+    zeros without ``module.remat``). Traced abstractly: nothing is allocated
+    or run."""
+    x = jax.ShapeDtypeStruct(tuple(batch_shape), jnp.int32)
     with count_call_sites() as sites:
-        jax.eval_shape(module.init, jax.random.PRNGKey(0),
-                       jax.ShapeDtypeStruct(tuple(batch_shape), jnp.int32))
-    return {f"flash_calls_{path}": n for path, n in sites.items()}
+        variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    return {**{f"flash_calls_{path}": n for path, n in sites.items()},
+            **remat_names.saved_gauges(
+                lambda v, x: module.apply(v, x)[0]["prediction"],
+                (variables, x), keeps if module.remat else (), n_clients)}
 
 
 # ---------------------------------------------------------------------------

@@ -44,7 +44,10 @@ one set of loops over all clients' tokens.
 Built the way ``models/jamba.py`` is (a named parameter tree declared by a
 flax module, pure functions over one layer's dict, runs of layers as
 ``lax.scan``s rematerialised under ``remat``, ``per_client_param`` /
-``bind_shared`` for the engine), on ``models/decoder_common.py``.
+``bind_shared`` for the engine), on ``models/decoder_common.py``. What a
+rematerialised layer keeps (``decoder_common.DEEPSEEK_REMAT_KEEPS``): the
+flash calls' ``out`` / ``lse`` and the stream ``h + MLA(...)``, so its
+recompute runs no flash forward and no product of ``o_proj``'s kernel.
 """
 
 from __future__ import annotations
@@ -59,6 +62,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fl4health_tpu.core.pytree import merge_trees
+from fl4health_tpu.core.remat import named
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
                                                  swiglu)
@@ -394,7 +398,10 @@ def moe(p, u, dims: DeepseekDims):
 
 def layer(p, h, pad_mask, routed: bool, dims: DeepseekDims):
     u = rms_norm(h, p["input_layernorm"]["scale"], dims.rms_eps)
-    h = h + mla_attention(p["self_attn"], u, pad_mask, dims)
+    # named for the remat site: with the stream kept, the layer's second half
+    # is recomputed from it and o_proj's product is in no recompute
+    h = named(h + mla_attention(p["self_attn"], u, pad_mask, dims),
+              common.MLA_STREAM)
     u = rms_norm(h, p["post_attention_layernorm"]["scale"], dims.rms_eps)
     return h + (moe(p["mlp"], u, dims) if routed else swiglu(p["mlp"], u, dims))
 
@@ -527,8 +534,8 @@ class DeepseekV2Classifier(nn.Module):
                 return layer(p, h_, pad_mask, routed, dims).astype(
                     self.dtype), None
 
-            if self.remat:
-                body = jax.checkpoint(body)
+            body = common.remat_layers(body, self.remat,
+                                       common.DEEPSEEK_REMAT_KEEPS)
             h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
         return common.last_token_logits(
             h, pad_mask, stacked["norm"]["scale"], stacked["score"]["kernel"],
@@ -552,9 +559,9 @@ class DeepseekV2Classifier(nn.Module):
             x)
 
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
-        """Static facts of the routed layer, and which path the forward's
-        flash calls take, for the simulation's build-time gauges;
-        ``batch_shape`` is one client's [B, T]."""
+        """Static facts of the routed layer, which path the forward's flash
+        calls take and what the remat sites keep, for the simulation's
+        build-time gauges; ``batch_shape`` is one client's [B, T]."""
         tokens = n_clients * math.prod(batch_shape)
         return {"moe_experts_held": self.experts_held,
                 "moe_experts_total": self.n_routed_experts,
@@ -562,4 +569,5 @@ class DeepseekV2Classifier(nn.Module):
                 # of every token could be a held expert
                 "moe_assignment_rows_bound":
                     tokens * min(self.top_k, self.experts_held),
-                **common.flash_call_site_gauges(self, batch_shape)}
+                **common.attention_gauges(self, batch_shape, n_clients,
+                                          common.DEEPSEEK_REMAT_KEEPS)}

@@ -22,7 +22,9 @@ has it), declared by a flax module, and the mathematics is a set of pure
 functions over one layer's dict. Consecutive layers of one kind run as ONE
 ``lax.scan`` over their stacked dicts (13 Mamba layers compile as two bodies,
 not thirteen), each layer rematerialised on the backward pass under
-``remat``. ``dtype`` is the compute type at float32 parameters.
+``remat``, less the flash calls' ``out`` / ``lse``, which are kept
+(``decoder_common.JAMBA_REMAT_KEEPS``): the attention layer's recompute runs
+no flash forward. ``dtype`` is the compute type at float32 parameters.
 
 The module brings the split of its parameters with it
 (``per_client_param``: adapters and head per client, the base shared) and the
@@ -257,8 +259,8 @@ class JambaClassifier(nn.Module):
                 return layer(p, h_, pad_mask, attention, dims).astype(
                     self.dtype), None
 
-            if self.remat:
-                body = jax.checkpoint(body)
+            body = common.remat_layers(body, self.remat,
+                                       common.JAMBA_REMAT_KEEPS)
             h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
         return common.last_token_logits(
             h, pad_mask, stacked["final_layernorm"]["scale"],
@@ -288,7 +290,8 @@ class JambaClassifier(nn.Module):
             merge_trees(prepared, self.stack_runs(per_client)), x)
 
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
-        """Which path the forward's flash calls take, for the simulation's
-        build-time gauges; ``batch_shape`` is one client's [B, T]."""
-        del n_clients
-        return common.flash_call_site_gauges(self, batch_shape)
+        """Which path the forward's flash calls take and what the remat
+        sites keep, for the simulation's build-time gauges; ``batch_shape``
+        is one client's [B, T]."""
+        return common.attention_gauges(self, batch_shape, n_clients,
+                                       common.JAMBA_REMAT_KEEPS)

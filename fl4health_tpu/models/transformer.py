@@ -25,6 +25,15 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from fl4health_tpu.core import remat as remat_names
+from fl4health_tpu.kernels.flash_attention import SAVED_NAMES as FLASH_SAVED
+
+# What a rematerialised encoder block keeps (core/remat.py): the flash
+# calls' ``out`` / ``lse``, the dearest thing a block would recompute per
+# byte kept. With the dense core, or an ``attention_fn`` that names nothing,
+# the list names nothing and the whole block is recomputed.
+REMAT_KEEPS = FLASH_SAVED
+
 
 class LoraDense(nn.Module):
     """Dense with an additive low-rank adapter: y = xW + s * (x A) B.
@@ -170,8 +179,9 @@ class TransformerClassifier(nn.Module):
     attention_fn: Any = None  # e.g. ring attention for long contexts
     remat: bool = False  # rematerialize each encoder block on the backward
     # pass: activation memory drops from O(n_layers * T * d_model) to one
-    # layer's worth at the cost of a second forward — the standard TPU
-    # HBM-for-FLOPs trade for big-model configs (jax.checkpoint).
+    # layer's worth plus what ``REMAT_KEEPS`` names, at the cost of a second
+    # forward less the flash kernel's (its ``out`` / ``lse`` are kept) — the
+    # standard TPU HBM-for-FLOPs trade for big-model configs (jax.checkpoint).
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -184,7 +194,9 @@ class TransformerClassifier(nn.Module):
         )
         h = (tok + pos[None, : x.shape[1]]).astype(self.dtype)
         # static_argnums counts the module itself: (self, h, pad_mask, train)
-        block_cls = nn.remat(EncoderBlock, static_argnums=(3,)) if self.remat else EncoderBlock
+        block_cls = nn.remat(
+            EncoderBlock, static_argnums=(3,),
+            policy=remat_names.keep(REMAT_KEEPS)) if self.remat else EncoderBlock
         for i in range(self.n_layers):
             h = block_cls(
                 self.d_model, self.n_heads, self.d_ff, self.lora_rank,
@@ -196,3 +208,16 @@ class TransformerClassifier(nn.Module):
         pooled = (h * pad_mask[..., None]).sum(axis=1) / denom
         logits = nn.Dense(self.n_classes, name="classifier")(pooled)
         return {"prediction": logits.astype(jnp.float32)}, {"features": pooled}
+
+    def build_gauges(self, batch_shape, n_clients: int) -> dict:
+        """What the remat sites keep (``ModelDef.build_gauges``: a fact of the
+        build, from one abstract trace); ``batch_shape`` is one client's
+        [B, T]."""
+        keeps = REMAT_KEEPS if self.remat else ()
+        x = jax.ShapeDtypeStruct(tuple(batch_shape), jnp.int32)
+        # without remat there is no site: nothing is traced
+        variables = keeps and jax.eval_shape(
+            lambda x: self.init(jax.random.PRNGKey(0), x, train=False), x)
+        return remat_names.saved_gauges(
+            lambda v, x: self.apply(v, x, train=False)[0]["prediction"],
+            (variables, x), keeps, n_clients)

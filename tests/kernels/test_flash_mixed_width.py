@@ -95,15 +95,23 @@ def test_lse_is_the_scaled_scores_logsumexp():
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5)
 
 
-# sha256 of str(jax.make_jaxpr(...)) of the two programs below, taken on the
-# commit before the second width (3c40b88, jax 0.9.0): the three accepted
-# cells' flash calls are these calls at other sizes. A PR that changes the
-# kernels on purpose takes the values anew and says so.
+# sha256 of str(jax.make_jaxpr(...)) of the two programs below (jax 0.9.0):
+# the accepted cells' transposed flash calls are these calls at other sizes.
+# A PR that changes the kernels on purpose takes the values anew and says so.
+# Taken on the commit before the second width (3c40b88: c57913b1... /
+# 2f8edc45...), and anew at PR 38, whose forward rule names ``out`` and
+# ``lse`` for the remat policies and keeps ``lse`` without its trailing axis
+# of 1: against 63cb4cf the two jaxprs differ by the two ``name`` equations
+# and by reshapes of ``lse`` ([BH, Tp, 1] -> [BH, Tp] once; from there to
+# [BH, Tp, 1] for the result and for dQ, and to [BH, 1, Tp] for dK/dV where
+# it was reshaped from [BH, Tp, 1]) and by nothing else
+# (tests/kernels/test_flash_remat_names.py holds the names to lowering to
+# nothing).
 BEFORE_THE_SECOND_WIDTH = {
     "causal, one shared key/value head, bfloat16":
-        "c57913b1f9a6a3d67440a59dc2dc601530e2b9a7f0d602332f384de27b66b477",
+        "9aaf82a8db1a8a11c93575092b43a44da50d0bc81ff30afb6b372db965e922eb",
     "not causal, float32, blocks 32/16":
-        "2f8edc4578231e800ade9e21c511ae70dd998e9931af57aff86e0cc46bc6a04a",
+        "b6474f1b85f6bf7eb1df7ea816db387f8f89b351dfde8fd620334c8977023ed3",
 }
 
 

@@ -18,9 +18,11 @@ from benchmarks.harness import build, datagen
 from benchmarks.harness.spec import load_module
 from fl4health_tpu.clients import engine
 from fl4health_tpu.core import pytree as ptu
+from fl4health_tpu.core import remat as remat_names
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.models import deepseek as ds
 from fl4health_tpu.models.decoder_common import rms_norm, swiglu
+from tests.models.remat_probe import eqns, pallas_calls, products_with
 
 REF = load_module("reference", "deepseek_v2_classifier")
 NM = load_module("reference", "numerics").FLOAT32
@@ -211,14 +213,62 @@ def test_forward_and_adapter_gradients_match_the_reference(seeded, attention,
     assert float(jnp.abs(got["layers_0/mlp/down_proj/lora_b"]).max()) > 0
 
 
-def _top_level_eqns(jaxpr):
-    """Every equation outside the Pallas kernels' bodies."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        if eqn.primitive.name == "pallas_call":
-            continue
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _top_level_eqns(sub)
+@pytest.mark.parametrize("site", ["kept", "bare"])
+def test_a_rematerialised_layer_recomputes_neither_flash_nor_o_proj(
+        seeded, site, monkeypatch):
+    """Two runs of layers, each one traced layer: with the kernel's results
+    and the attention's output stream kept (the site's policy) the gradient
+    holds ONE ``flash_fwd`` and ONE product with ``o_proj``'s kernel a
+    traced layer, the forward's; under a bare ``jax.checkpoint`` (what the
+    site was) two of each. A value head of 24 makes the kernel's shape,
+    [4 * 24, 32], no other matrix's. The gradients are those without remat
+    (at the seeded widths, the reference's: the test above)."""
+    if site == "bare":
+        monkeypatch.setattr(remat_names, "keep", lambda names: None)
+    cfg = dict(CFG, v_head_dim=24)
+    tree, x = build.nest(_weights(cfg, 11)), seeded[2]
+    fn = functools.partial(flash_attention, causal=True, block_q=8, block_k=8)
+
+    def grad(remat):
+        module = _module(cfg, attention_fn=fn, remat=remat)
+        per_client, shared = ptu.split_by_path(tree, module.per_client_param)
+        forward = module.bind_shared(shared)
+        return jax.grad(lambda p: jnp.sum(jnp.square(
+            forward(p, x)[0]["prediction"]))), per_client
+
+    fn_remat, per_client = grad(True)
+    jaxpr = jax.make_jaxpr(fn_remat)(per_client).jaxpr
+    twice = 1 if site == "kept" else 2
+    assert {name: pallas_calls(jaxpr, name)
+            for name in ("flash_fwd", "flash_dq", "flash_dkv")} == {
+        "flash_fwd": 2 * twice, "flash_dq": 2, "flash_dkv": 2}
+    assert products_with(jaxpr, (4 * 24, 32)) == 2 * twice
+    # the other four projections ARE recomputed: kv_b_proj's no-position
+    # columns [8, 4 * 8] in the forward and in the recompute of each run
+    assert products_with(jaxpr, (8, 4 * 8)) == 4
+    got, want = fn_remat(per_client), grad(False)[0](per_client)
+    for k, v in build.flatten(want).items():
+        np.testing.assert_allclose(np.asarray(build.flatten(got)[k]),
+                                   np.asarray(v), atol=3e-5, rtol=2e-4,
+                                   err_msg=k)
+
+
+@pytest.mark.parametrize("attention,names,per_client", [
+    # out [B * 4, Tp, 64] (a value head of 8 padded to 64 lanes), the
+    # statistic [B * 4, Tp], 20 positions padded to the blocks of 8, and the
+    # stream [B, T, 32]
+    ("flash", 3, 8 * 24 * 64 * 4 + 8 * 24 * 4 + 2 * 20 * 32 * 4),
+    # the dense form names nothing of its own: the stream alone
+    ("dense", 1, 2 * 20 * 32 * 4),
+])
+def test_build_gauges_say_what_a_rematerialised_layer_keeps(attention, names,
+                                                            per_client):
+    fn = (functools.partial(flash_attention, causal=True, block_q=8,
+                            block_k=8) if attention == "flash" else None)
+    gauges = _module(attention_fn=fn, remat=True).build_gauges((2, 20), 4)
+    assert gauges["remat_saved_names"] == names
+    assert gauges["remat_saved_bytes_per_layer"] == 4 * per_client
+    assert gauges["flash_calls_transposed"] == 2 * (attention == "flash")
 
 
 def test_latent_attention_hands_the_flash_calls_its_parts_where_they_lie():
@@ -247,7 +297,7 @@ def test_latent_attention_hands_the_flash_calls_its_parts_where_they_lie():
         params["layers_0"]["self_attn"], u, mask).jaxpr
     moved = [(e.primitive.name, tuple(e.invars[0].aval.shape),
               tuple(e.outvars[0].aval.shape))
-             for e in _top_level_eqns(jaxpr)
+             for e in eqns(jaxpr)
              if e.primitive.name in ("transpose", "pad", "broadcast_in_dim",
                                      "concatenate")
              # an array with the heads in it (as an axis, or in the lanes)
@@ -257,7 +307,7 @@ def test_latent_attention_hands_the_flash_calls_its_parts_where_they_lie():
     assert [m for m in moved if m != rope_halves] == [
         ("transpose", (1, t, heads, 64), (1, heads, t, 64))], moved
     assert sum(e.primitive.name == "pallas_call"
-               for e in _top_level_eqns(jaxpr)) == 1
+               for e in eqns(jaxpr)) == 1
 
 
 def test_the_module_brings_its_own_split_and_cast(seeded):
@@ -294,7 +344,8 @@ def test_the_module_brings_its_own_split_and_cast(seeded):
     assert module.build_gauges((1, 20), 4) == {
         "moe_experts_held": 8, "moe_experts_total": 40,
         "moe_assignment_rows_bound": 4 * 20 * 6,
-        "flash_calls_lane_indexed": 0, "flash_calls_transposed": 0}
+        "flash_calls_lane_indexed": 0, "flash_calls_transposed": 0,
+        "remat_saved_names": 0, "remat_saved_bytes_per_layer": 0}
 
 
 def test_experts_outside_the_router_are_refused():

@@ -15,8 +15,10 @@ from benchmarks.harness import build, datagen
 from benchmarks.harness.spec import load_module
 from fl4health_tpu.clients import engine
 from fl4health_tpu.core import pytree as ptu
+from fl4health_tpu.core import remat as remat_names
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.models.jamba import JambaClassifier, mamba_mixer
+from tests.models.remat_probe import pallas_calls
 
 REF = load_module("reference", "jamba_classifier")
 NM = load_module("reference", "numerics").FLOAT32
@@ -116,6 +118,38 @@ def test_forward_and_adapter_gradients_match_the_reference(seeded, attention,
                                    atol=3e-5, rtol=2e-4, err_msg=k)
 
 
+@pytest.mark.parametrize("site", ["kept", "bare"])
+def test_the_rematerialised_attention_layer_runs_its_flash_forward_once(
+        seeded, site, monkeypatch):
+    """One attention layer in the four: ONE ``flash_fwd`` in the gradient
+    with the kernel's results kept (the site's policy), two under a bare
+    ``jax.checkpoint`` (what the site was); the gradients are those without
+    remat (and so the reference's: the test above)."""
+    _, tree, x = seeded
+    if site == "bare":
+        monkeypatch.setattr(remat_names, "keep", lambda names: None)
+    fn = functools.partial(flash_attention, causal=True, block_q=8, block_k=8)
+
+    def grad(remat):
+        module = _module(fn, remat)
+        per_client, shared = ptu.split_by_path(tree, module.per_client_param)
+        forward = module.bind_shared(shared)
+        return jax.grad(lambda p: jnp.sum(jnp.square(
+            forward(p, x)[0]["prediction"]))), per_client
+
+    fn_remat, per_client = grad(True)
+    jaxpr = jax.make_jaxpr(fn_remat)(per_client).jaxpr
+    assert {name: pallas_calls(jaxpr, name)
+            for name in ("flash_fwd", "flash_dq", "flash_dkv")} == {
+        "flash_fwd": 1 if site == "kept" else 2, "flash_dq": 1,
+        "flash_dkv": 1}
+    got, want = fn_remat(per_client), grad(False)[0](per_client)
+    for k, v in build.flatten(want).items():
+        np.testing.assert_allclose(np.asarray(build.flatten(got)[k]),
+                                   np.asarray(v), atol=3e-5, rtol=2e-4,
+                                   err_msg=k)
+
+
 @pytest.mark.parametrize("period,offset,n_layers", [(3, 0, 3), (3, 2, 3),
                                                     (2, 1, 5), (14, 7, 2)])
 def test_attention_layers_wherever_the_pattern_puts_them(period, offset,
@@ -168,7 +202,28 @@ def test_build_gauges_count_the_flash_call_sites_by_path(d_model, attention,
     fn = (functools.partial(flash_attention, causal=True, block_q=8,
                             block_k=8) if attention == "flash" else None)
     gauges = _module(fn).clone(d_model=d_model).build_gauges((2, 20), 4)
-    assert gauges == {f"flash_calls_{k}": v for k, v in want.items()}
+    assert gauges == {**{f"flash_calls_{k}": v for k, v in want.items()},
+                      "remat_saved_names": 0,
+                      "remat_saved_bytes_per_layer": 0}
+
+
+@pytest.mark.parametrize("d_model,attention,names,per_client", [
+    # lane-indexed: out [B, Tp, 4 * 128] and the statistic [B, 4, Tp],
+    # 20 positions padded to the blocks of 8
+    (512, "flash", 2, 2 * 24 * 512 * 4 + 2 * 4 * 24 * 4),
+    # transposed: out [B * 4, Tp, 64] (a head of 8 padded to 64 lanes)
+    (32, "flash", 2, 8 * 24 * 64 * 4 + 8 * 24 * 4),
+    # the dense form names nothing: the whole layer is recomputed
+    (32, "dense", 0, 0),
+])
+def test_build_gauges_say_what_a_rematerialised_layer_keeps(
+        d_model, attention, names, per_client):
+    fn = (functools.partial(flash_attention, causal=True, block_q=8,
+                            block_k=8) if attention == "flash" else None)
+    gauges = _module(fn, remat=True).clone(d_model=d_model).build_gauges(
+        (2, 20), 4)
+    assert gauges["remat_saved_names"] == names
+    assert gauges["remat_saved_bytes_per_layer"] == 4 * per_client
 
 
 def test_bfloat16_compute_stays_near_float32(seeded):

@@ -10,12 +10,15 @@ import optax
 import pytest
 
 from fl4health_tpu.clients import engine
+from fl4health_tpu.core import remat as remat_names
 from fl4health_tpu.datasets.synthetic import synthetic_text_classification
 from fl4health_tpu.metrics import efficient
+from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.metrics.base import MetricManager
 from fl4health_tpu.models.transformer import LoraDense, TransformerClassifier
 from fl4health_tpu.server.simulation import ClientDataset, FederatedSimulation
 from fl4health_tpu.strategies.fedopt import FedOpt
+from tests.models.remat_probe import pallas_calls
 from fl4health_tpu.utils.peft import (
     lora_exchanger,
     lora_trainable_mask,
@@ -209,3 +212,58 @@ class TestRemat:
         fb = ravel_pytree(sq(b))[0]
         np.testing.assert_allclose(np.asarray(fa), np.asarray(fb),
                                    atol=1e-5, rtol=1e-5)
+
+    @staticmethod
+    def _flash_model(**kw):
+        import functools
+
+        return small_model(attention_fn=functools.partial(
+            flash_attention, block_q=8, block_k=8), **kw)
+
+    @pytest.mark.parametrize("site", ["kept", "bare"])
+    def test_a_rematerialised_block_runs_its_flash_forward_once(
+            self, site, monkeypatch):
+        """Two blocks: two ``flash_fwd`` in the gradient with the kernel's
+        results kept (the site's policy), four under a bare ``nn.remat``
+        (what the site was); the gradients are those without remat."""
+        from jax.flatten_util import ravel_pytree
+
+        if site == "bare":
+            monkeypatch.setattr(remat_names, "keep", lambda names: None)
+        a, b = self._flash_model(), self._flash_model(remat=True)
+        x, _ = synthetic_text_classification(
+            jax.random.PRNGKey(2), 4, VOCAB, SEQ, CLASSES
+        )
+        v = a.init(jax.random.PRNGKey(3), x, train=False)
+
+        def sq(model):
+            return jax.grad(lambda p: jnp.sum(jnp.square(
+                model.apply(p, x, train=False)[0]["prediction"])))
+
+        calls = {name: pallas_calls(jax.make_jaxpr(sq(b))(v).jaxpr, name)
+                 for name in ("flash_fwd", "flash_dq", "flash_dkv")}
+        assert calls == {"flash_fwd": 2 if site == "kept" else 4,
+                         "flash_dq": 2, "flash_dkv": 2}
+        assert pallas_calls(jax.make_jaxpr(sq(a))(v).jaxpr, "flash_fwd") == 2
+        np.testing.assert_allclose(
+            np.asarray(ravel_pytree(sq(a)(v))[0]),
+            np.asarray(ravel_pytree(sq(b)(v))[0]), atol=1e-5, rtol=1e-5)
+
+    def test_build_gauges_say_what_a_block_keeps(self):
+        """``out`` [B*H, Tp, 64] (a head of 16 padded to 64 lanes) and the
+        statistic [B*H, Tp], one of each a block, over the clients."""
+        b, heads, clients = 3, 2, 4
+        want = clients * (b * heads * SEQ * 64 * 4 + b * heads * SEQ * 4)
+        gauges = engine.from_flax(self._flash_model(remat=True)).build_gauges(
+            (b, SEQ), clients)
+        assert gauges == {"remat_saved_names": 2,
+                          "remat_saved_bytes_per_layer": want}
+        bf16 = self._flash_model(remat=True, dtype=jnp.bfloat16)
+        assert bf16.build_gauges((b, SEQ), clients)[
+            "remat_saved_bytes_per_layer"] == clients * (
+                b * heads * SEQ * 64 * 2 + b * heads * SEQ * 4)
+        nothing = {"remat_saved_names": 0, "remat_saved_bytes_per_layer": 0}
+        # no remat: no site; the dense core names nothing
+        assert self._flash_model().build_gauges((b, SEQ), clients) == nothing
+        assert small_model(remat=True).build_gauges((b, SEQ),
+                                                    clients) == nothing
