@@ -558,9 +558,11 @@ def make_train_step(logic: ClientLogic, tx: optax.GradientTransformation,
             )
 
     def step(state: TrainState, ctx: Any, batch: Batch):
-        state = _mask_tree(
-            logic.update_before_step(state, ctx, batch), state, batch.step_mask
-        )
+        # ``fl_layer::optimizer``: the update itself and the selects that
+        # make a padding step a no-op, every one a pass over the state
+        before = logic.update_before_step(state, ctx, batch)
+        with stage_attr.layer("optimizer"):
+            state = _mask_tree(before, state, batch.step_mask)
         rng, step_rng = jax.random.split(state.rng)
         batch = logic.augment(batch, jax.random.fold_in(step_rng, 0xA6), ctx)
         finite = None
@@ -603,22 +605,24 @@ def make_train_step(logic: ClientLogic, tx: optax.GradientTransformation,
                 logic.value_and_grads(state, ctx, batch, step_rng)
             )
             grads = logic.transform_gradients(grads, state, ctx)
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-
         keep = batch.step_mask  # padding steps must not move anything
-        # a non-finite scaled gradient additionally skips the optimizer
-        # step (master weights, optimizer state and batch stats untouched)
-        keep_update = keep if finite is None else keep * finite
-        new_state = state.replace(
-            params=_mask_tree(new_params, state.params, keep_update),
-            opt_state=_mask_tree(new_opt_state, state.opt_state, keep_update),
-            model_state=_mask_tree(
-                new_model_state, state.model_state, keep_update
-            ),
-            rng=rng,
-            step=state.step + keep_update.astype(jnp.int32),
-        )
+        with stage_attr.layer("optimizer"):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            # a non-finite scaled gradient additionally skips the optimizer
+            # step (master weights, optimizer state and batch stats untouched)
+            keep_update = keep if finite is None else keep * finite
+            new_state = state.replace(
+                params=_mask_tree(new_params, state.params, keep_update),
+                opt_state=_mask_tree(
+                    new_opt_state, state.opt_state, keep_update),
+                model_state=_mask_tree(
+                    new_model_state, state.model_state, keep_update
+                ),
+                rng=rng,
+                step=state.step + keep_update.astype(jnp.int32),
+            )
         if scaling:
             # scaler state advances on REAL steps only (padding steps are
             # full no-ops); it advances on skipped steps too — that is how

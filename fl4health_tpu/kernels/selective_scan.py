@@ -66,8 +66,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fl4health_tpu.kernels._platform import interpret_default
+from fl4health_tpu.observability import stages
 
-SCOPE = "fl_layer::ssm_scan"
+SCOPE = stages.LAYER_PREFIX + "ssm_scan"
 _LANE = 128
 F32 = jnp.float32
 
@@ -356,7 +357,7 @@ UNROLL = 64
 def _blocked_scan(x, dt, a, b, c, d, z, block_t, unroll, interpret):
     """``selective_scan`` at a given time block (a multiple of ``unroll``,
     and of 16 for 16-bit operands)."""
-    with jax.named_scope(SCOPE):
+    with stages.layer("ssm_scan"):
         t = x.shape[1]
         # a padded position has Delta = 0 and x = 0 and leaves s as it is
         pad = (-t) % block_t

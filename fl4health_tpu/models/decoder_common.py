@@ -21,6 +21,7 @@ from flax import linen as nn
 from fl4health_tpu.core import remat as remat_names
 from fl4health_tpu.kernels.flash_attention import (SAVED_NAMES as FLASH_SAVED,
                                                    count_call_sites)
+from fl4health_tpu.observability.stages import layer as part
 from fl4health_tpu.utils.peft import per_client_predicate
 
 # leaves every client holds: the adapters and the classification head
@@ -48,9 +49,10 @@ DEEPSEEK_REMAT_KEEPS = (*FLASH_SAVED, MLA_STREAM)
 
 def rms_norm(x, scale, eps):
     """x / rms(x) * scale, in float32 (the caller casts)."""
-    x = x.astype(F32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
+    with part("norm"):
+        x = x.astype(F32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + eps) * scale
 
 
 def lora_dense(p, x, dims):
@@ -59,8 +61,10 @@ def lora_dense(p, x, dims):
     x = x.astype(dt)
     y = x @ p["kernel"].astype(dt)
     if "lora_a" in p:
-        y = y + dims.lora_scale * ((x @ p["lora_a"].astype(dt))
-                                   @ p["lora_b"].astype(dt))
+        with part("lora"):
+            delta = dims.lora_scale * ((x @ p["lora_a"].astype(dt))
+                                       @ p["lora_b"].astype(dt))
+        y = y + delta
     return y
 
 
@@ -96,10 +100,17 @@ def last_token_logits(h, pad_mask, final_scale, score_kernel, eps):
     """HF ``...ForSequenceClassification``'s head: the final-norm hidden
     state at the last non-pad token through ``score`` (no bias), float32."""
     h = rms_norm(h, final_scale, eps)
-    last = jnp.maximum(pad_mask.sum(axis=1).astype(jnp.int32) - 1, 0)
-    pooled = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-    logits = pooled @ score_kernel.astype(F32)
-    return {"prediction": logits.astype(F32)}, {"features": pooled}
+    with part("head"):
+        last = jnp.maximum(pad_mask.sum(axis=1).astype(jnp.int32) - 1, 0)
+        pooled = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+        logits = pooled @ score_kernel.astype(F32)
+        return {"prediction": logits.astype(F32)}, {"features": pooled}
+
+
+def embed_tokens(embedding, x, dtype):
+    """The rows of ``embedding`` at the token ids ``x``, in ``dtype``."""
+    with part("embed"):
+        return embedding[x].astype(dtype)
 
 
 def remat_layers(body, remat: bool, keeps):

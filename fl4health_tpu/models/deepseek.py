@@ -66,6 +66,7 @@ from fl4health_tpu.core.remat import named
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
                                                  swiglu)
+from fl4health_tpu.observability.stages import layer as part
 
 # rows of one expert's tile: [TILE_ROWS, d] x [d, f] reads the expert's matrix
 # once per tile, so a tile should hold an expert's usual load whole (about 154
@@ -191,7 +192,7 @@ def mla_attention(p, u, pad_mask, dims: DeepseekDims):
     causal=True, block_q=512, block_k=512)``; ``None`` is the dense form.
     Nothing is concatenated, broadcast or sliced on the way: every part is
     ``[B, T, heads, width]`` as a view of what its projection wrote."""
-    with jax.named_scope("fl_layer::mla_attention"):
+    with part("mla_attention"):
         b, t = u.shape[:2]
         h, nope, rot = dims.n_heads, dims.qk_nope, dims.qk_rope
         c_q = rms_norm(lora_dense(p["q_a_proj"], u, dims),
@@ -208,7 +209,7 @@ def mla_attention(p, u, pad_mask, dims: DeepseekDims):
         q_pe = apply_rope(q_pe, cos, sin)
         k_pe = apply_rope(k_pe[:, :, None, :], cos, sin)
         attend = dims.attention_fn or common.dense_causal_attention
-        with jax.named_scope("fl_layer::mla_flash"):
+        with part("mla_flash"):
             out = attend((q_nope, q_pe), (k_nope, k_pe), v, pad_mask=pad_mask,
                          scale=softmax_scale(nope + rot, dims.rope))
         return lora_dense(p["o_proj"], out.reshape(b, t, h * dims.v_head),
@@ -225,7 +226,7 @@ def route(p, u, dims: DeepseekDims):
     -> (idx [N, top_k] int32, w [N, top_k] float32 = routed_scale * score).
     The router's matrix is [n_group, d, experts per group]: expert ``g * per
     + e`` is column ``e`` of group ``g``."""
-    with jax.named_scope("fl_layer::moe_router"):
+    with part("moe_router"):
         n = u.shape[0]
         logits = jnp.einsum("nd,gde->nge", u.astype(F32),
                             p["kernel"].astype(F32),
@@ -243,7 +244,7 @@ def route(p, u, dims: DeepseekDims):
 
 def _expert(x, gate, up, down):
     """One expert's SwiGLU over its rows, in the rows' type."""
-    with jax.named_scope("fl_layer::moe_experts"):
+    with part("moe_experts"):
         return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
@@ -384,14 +385,14 @@ def moe(p, u, dims: DeepseekDims):
     """The routed layer's part held here plus the shared experts."""
     dt = dims.dtype
     flat = u.reshape(-1, u.shape[-1])
-    with jax.named_scope("fl_layer::moe"):
+    with part("moe"):
         idx, w = route(p["gate"], flat, dims)
         experts = [tuple(p[f"experts_{j}"][name]["kernel"].astype(dt)
                          for name in ("gate_proj", "up_proj", "down_proj"))
                    for j in range(dims.experts_held)]
         y = routed_experts(flat.astype(dt), idx, w, experts,
                            dims.first_expert_held)
-    with jax.named_scope("fl_layer::shared_experts"):
+    with part("shared_experts"):
         shared = swiglu(p["shared_experts"], u, dims)
     return y.reshape(u.shape).astype(dt) + shared
 
@@ -403,7 +404,11 @@ def layer(p, h, pad_mask, routed: bool, dims: DeepseekDims):
     h = named(h + mla_attention(p["self_attn"], u, pad_mask, dims),
               common.MLA_STREAM)
     u = rms_norm(h, p["post_attention_layernorm"]["scale"], dims.rms_eps)
-    return h + (moe(p["mlp"], u, dims) if routed else swiglu(p["mlp"], u, dims))
+    if routed:
+        return h + moe(p["mlp"], u, dims)
+    with part("mlp"):
+        ff = swiglu(p["mlp"], u, dims)
+    return h + ff
 
 
 # ---------------------------------------------------------------------------
@@ -526,7 +531,8 @@ class DeepseekV2Classifier(nn.Module):
         ``decoder_common.stack_runs``; each run is one ``lax.scan``."""
         dims = self.dims
         pad_mask = (x > 0).astype(F32)
-        h = stacked["embed_tokens"]["embedding"][x].astype(self.dtype)
+        h = common.embed_tokens(stacked["embed_tokens"]["embedding"], x,
+                                self.dtype)
         for k, run in enumerate(self.runs()):
             routed = run[0] >= self.first_k_dense
 
@@ -550,7 +556,7 @@ class DeepseekV2Classifier(nn.Module):
         once: every projection's and expert's ``kernel`` in the compute type
         (the router's stays float32, as the norms and the embedding do), the
         layers stacked over their runs."""
-        with jax.named_scope("fl_layer::shared_cast"):
+        with part("shared_cast"):
             prepared = common.prepare_shared(
                 shared, self.runs(), self.dtype,
                 lambda names: names[-1] == "kernel" and names[-2] != "gate")

@@ -49,6 +49,7 @@ from fl4health_tpu.kernels.selective_scan import selective_scan
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
                                                  swiglu)
+from fl4health_tpu.observability.stages import layer as part
 
 # projections that carry an adapter (the PEFT recipe of AI21's model card)
 ADAPTED = frozenset({"in_proj", "x_proj", "out_proj", "gate_proj", "up_proj",
@@ -86,7 +87,7 @@ def causal_depthwise_conv(p, x):
 
 
 def mamba_mixer(p, u, dims: JambaDims):
-    with jax.named_scope("fl_layer::mamba_mixer"):
+    with part("mamba_mixer"):
         x, z = jnp.split(lora_dense(p["in_proj"], u, dims), 2, axis=-1)
         x = jax.nn.silu(causal_depthwise_conv(p["conv1d"], x)).astype(dims.dtype)
         delta, b, c = jnp.split(
@@ -110,7 +111,7 @@ def causal_attention(p, u, pad_mask, dims: JambaDims):
     e.g. ``functools.partial(kernels.flash_attention, causal=True, block_q=512,
     block_k=512)``; it gets ``k`` / ``v`` with one head when the model has
     one, as many as ``q`` otherwise."""
-    with jax.named_scope("fl_layer::attention"):
+    with part("attention"):
         head_dim = dims.d_model // dims.n_heads
 
         def heads(name, n):
@@ -132,7 +133,9 @@ def layer(p, h, pad_mask, attention: bool, dims: JambaDims):
     h = h + (causal_attention(p["self_attn"], u, pad_mask, dims) if attention
              else mamba_mixer(p["mamba"], u, dims))
     u = rms_norm(h, p["pre_ff_layernorm"]["scale"], dims.rms_eps)
-    return h + swiglu(p["feed_forward"], u, dims)
+    with part("mlp"):
+        ff = swiglu(p["feed_forward"], u, dims)
+    return h + ff
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +254,8 @@ class JambaClassifier(nn.Module):
         temporaries for the round where this form takes 9.9: PR 27.)"""
         dims = self.dims
         pad_mask = (x > 0).astype(F32)
-        h = stacked["embed_tokens"]["embedding"][x].astype(self.dtype)
+        h = common.embed_tokens(stacked["embed_tokens"]["embedding"], x,
+                                self.dtype)
         for k, run in enumerate(self.runs()):
             attention = self.is_attention(run[0])
 
@@ -284,7 +288,7 @@ class JambaClassifier(nn.Module):
         """``(per_client, x) -> (preds, features)`` over a base prepared
         here, once: the client's own leaves are stacked at each call (they
         are small)."""
-        with jax.named_scope("fl_layer::shared_cast"):
+        with part("shared_cast"):
             prepared = self.prepare_shared(shared)
         return lambda per_client, x: self.forward(
             merge_trees(prepared, self.stack_runs(per_client)), x)

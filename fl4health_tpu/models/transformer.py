@@ -27,12 +27,20 @@ from flax import linen as nn
 
 from fl4health_tpu.core import remat as remat_names
 from fl4health_tpu.kernels.flash_attention import SAVED_NAMES as FLASH_SAVED
+from fl4health_tpu.observability.stages import layer as part
 
 # What a rematerialised encoder block keeps (core/remat.py): the flash
 # calls' ``out`` / ``lse``, the dearest thing a block would recompute per
 # byte kept. With the dense core, or an ``attention_fn`` that names nothing,
 # the list names nothing and the whole block is recomputed.
 REMAT_KEEPS = FLASH_SAVED
+
+
+def _master_as(param, dtype):
+    """A float32 master in the compute type: for a module with a ``dtype`` of
+    its own, what ``precision.policy.cast_model_def`` does for the others."""
+    with part("param_cast"):
+        return param.astype(dtype)
 
 
 class LoraDense(nn.Module):
@@ -57,7 +65,8 @@ class LoraDense(nn.Module):
             "kernel", nn.initializers.lecun_normal(), (in_features, self.features)
         )
         bias = self.param("bias", nn.initializers.zeros, (self.features,))
-        y = x.astype(self.dtype) @ kernel.astype(self.dtype) + bias.astype(self.dtype)
+        y = (x.astype(self.dtype) @ _master_as(kernel, self.dtype)
+             + _master_as(bias, self.dtype))
         if self.rank > 0:
             lora_a = self.param(
                 "lora_a",
@@ -68,10 +77,12 @@ class LoraDense(nn.Module):
                 "lora_b", nn.initializers.zeros, (self.rank, self.features)
             )
             scale = self.alpha / self.rank
-            y = y + scale * (
-                (x.astype(self.dtype) @ lora_a.astype(self.dtype))
-                @ lora_b.astype(self.dtype)
-            )
+            with part("lora"):
+                delta = scale * (
+                    (x.astype(self.dtype) @ lora_a.astype(self.dtype))
+                    @ lora_b.astype(self.dtype)
+                )
+            y = y + delta
         return y
 
 
@@ -99,33 +110,34 @@ class MultiHeadSelfAttention(nn.Module):
             f"d_model={self.d_model} must divide by n_heads={self.n_heads}"
         )
         head_dim = self.d_model // self.n_heads
-        dense = lambda name: LoraDense(  # noqa: E731
-            self.d_model, rank=self.lora_rank, dtype=self.dtype, name=name
-        )
-        q = dense("q_proj")(x)
-        k = dense("k_proj")(x)
-        v = dense("v_proj")(x)
-
-        def split(t):
-            return t.reshape(*t.shape[:-1], self.n_heads, head_dim)
-
-        q, k, v = split(q), split(k), split(v)
-        if self.attention_fn is not None:
-            out = self.attention_fn(q, k, v, pad_mask=pad_mask)
-        else:
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
-                jnp.asarray(head_dim, self.dtype)
+        with part("attention"):
+            dense = lambda name: LoraDense(  # noqa: E731
+                self.d_model, rank=self.lora_rank, dtype=self.dtype, name=name
             )
-            neg = jnp.asarray(jnp.finfo(jnp.float32).min, scores.dtype)
-            scores = jnp.where(pad_mask[:, None, None, :] > 0, scores, neg)
-            attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(
-                self.dtype
-            )
-            if train and self.dropout_rate > 0:
-                attn = nn.Dropout(self.dropout_rate, deterministic=False)(attn)
-            out = jnp.einsum("bhqk,bkhd->bqhd", attn, v)
-        out = out.reshape(*out.shape[:-2], self.d_model)
-        return dense("o_proj")(out)
+            q = dense("q_proj")(x)
+            k = dense("k_proj")(x)
+            v = dense("v_proj")(x)
+
+            def split(t):
+                return t.reshape(*t.shape[:-1], self.n_heads, head_dim)
+
+            q, k, v = split(q), split(k), split(v)
+            if self.attention_fn is not None:
+                out = self.attention_fn(q, k, v, pad_mask=pad_mask)
+            else:
+                scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
+                    jnp.asarray(head_dim, self.dtype)
+                )
+                neg = jnp.asarray(jnp.finfo(jnp.float32).min, scores.dtype)
+                scores = jnp.where(pad_mask[:, None, None, :] > 0, scores, neg)
+                attn = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(
+                    self.dtype
+                )
+                if train and self.dropout_rate > 0:
+                    attn = nn.Dropout(self.dropout_rate, deterministic=False)(attn)
+                out = jnp.einsum("bhqk,bkhd->bqhd", attn, v)
+            out = out.reshape(*out.shape[:-2], self.d_model)
+            return dense("o_proj")(out)
 
 
 class EncoderBlock(nn.Module):
@@ -140,7 +152,8 @@ class EncoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, pad_mask, train: bool):
         # Pre-LN (stable at small scale, standard for from-scratch training).
-        h = nn.LayerNorm(name="ln_attn")(x)
+        with part("norm"):
+            h = nn.LayerNorm(name="ln_attn")(x)
         h = MultiHeadSelfAttention(
             self.d_model, self.n_heads, self.lora_rank, self.dtype,
             self.dropout_rate, self.attention_fn, name="attn",
@@ -148,12 +161,15 @@ class EncoderBlock(nn.Module):
         if train and self.dropout_rate > 0:
             h = nn.Dropout(self.dropout_rate, deterministic=False)(h)
         x = x + h
-        h = nn.LayerNorm(name="ln_mlp")(x)
-        h = LoraDense(self.d_ff, rank=self.lora_rank, dtype=self.dtype, name="ff_in")(h)
-        h = nn.gelu(h)
-        h = LoraDense(
-            self.d_model, rank=self.lora_rank, dtype=self.dtype, name="ff_out"
-        )(h)
+        with part("norm"):
+            h = nn.LayerNorm(name="ln_mlp")(x)
+        with part("mlp"):
+            h = LoraDense(self.d_ff, rank=self.lora_rank, dtype=self.dtype,
+                          name="ff_in")(h)
+            h = nn.gelu(h)
+            h = LoraDense(
+                self.d_model, rank=self.lora_rank, dtype=self.dtype, name="ff_out"
+            )(h)
         if train and self.dropout_rate > 0:
             h = nn.Dropout(self.dropout_rate, deterministic=False)(h)
         return x + h
@@ -186,13 +202,14 @@ class TransformerClassifier(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = True):
         pad_mask = (x > 0).astype(jnp.float32)
-        tok = nn.Embed(self.vocab_size, self.d_model, name="tok_embed")(x)
-        pos = self.param(
-            "pos_embed",
-            nn.initializers.normal(stddev=0.02),
-            (self.max_len, self.d_model),
-        )
-        h = (tok + pos[None, : x.shape[1]]).astype(self.dtype)
+        with part("embed"):
+            tok = nn.Embed(self.vocab_size, self.d_model, name="tok_embed")(x)
+            pos = self.param(
+                "pos_embed",
+                nn.initializers.normal(stddev=0.02),
+                (self.max_len, self.d_model),
+            )
+            h = (tok + pos[None, : x.shape[1]]).astype(self.dtype)
         # static_argnums counts the module itself: (self, h, pad_mask, train)
         block_cls = nn.remat(
             EncoderBlock, static_argnums=(3,),
@@ -203,11 +220,14 @@ class TransformerClassifier(nn.Module):
                 self.dtype, self.dropout_rate, self.attention_fn,
                 name=f"layer_{i}",
             )(h, pad_mask, train)
-        h = nn.LayerNorm(name="ln_final")(h.astype(jnp.float32))
-        denom = jnp.maximum(pad_mask.sum(axis=1, keepdims=True), 1.0)
-        pooled = (h * pad_mask[..., None]).sum(axis=1) / denom
-        logits = nn.Dense(self.n_classes, name="classifier")(pooled)
-        return {"prediction": logits.astype(jnp.float32)}, {"features": pooled}
+        with part("norm"):
+            h = nn.LayerNorm(name="ln_final")(h.astype(jnp.float32))
+        with part("head"):
+            denom = jnp.maximum(pad_mask.sum(axis=1, keepdims=True), 1.0)
+            pooled = (h * pad_mask[..., None]).sum(axis=1) / denom
+            logits = nn.Dense(self.n_classes, name="classifier")(pooled)
+            return ({"prediction": logits.astype(jnp.float32)},
+                    {"features": pooled})
 
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
         """What the remat sites keep (``ModelDef.build_gauges``: a fact of the

@@ -40,6 +40,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from fl4health_tpu.observability import stages
+
 _DTYPE_ALIASES = {
     "f32": "float32", "fp32": "float32", "float32": "float32",
     "bf16": "bfloat16", "bfloat16": "bfloat16",
@@ -216,7 +218,8 @@ def cast_model_def(model_def: Any, compute_dtype) -> Any:
 
     def apply(params, model_state, x, train=True, rng=None, **kwargs):
         if train:
-            params = cast_floats(params, compute_dtype)
+            with stages.layer("param_cast"):
+                params = cast_floats(params, compute_dtype)
             x = cast_floats(x, compute_dtype)
         return inner_apply(params, model_state, x, train=train, rng=rng,
                            **kwargs)

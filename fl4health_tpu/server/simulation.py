@@ -1355,9 +1355,11 @@ class FederatedSimulation:
             gp = strategy.client_payload(server_state, jnp.zeros((), jnp.int32))
             client_eval = (base_client_eval if shared_fns is None
                            else shared_fns(server_state)[1])
-            new_states, losses, metrics = jax.vmap(
-                client_eval, in_axes=(0, None, 0), spmd_axis_name=spmd_axis
-            )(client_states, gp, batches)
+            with stage_attr.stage("evaluate"):
+                new_states, losses, metrics = jax.vmap(
+                    client_eval, in_axes=(0, None, 0),
+                    spmd_axis_name=spmd_axis
+                )(client_states, gp, batches)
             agg_losses = {
                 k: jnp.sum(v * eval_counts) / jnp.maximum(jnp.sum(eval_counts), 1.0)
                 for k, v in losses.items()

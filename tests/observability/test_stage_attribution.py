@@ -25,96 +25,17 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from fl4health_tpu.clients import engine
 from fl4health_tpu.compression import CompressionConfig
-from fl4health_tpu.datasets.synthetic import synthetic_classification
-from fl4health_tpu.kernels.flash_attention import flash_attention
-from fl4health_tpu.metrics import efficient
-from fl4health_tpu.metrics.base import MetricManager
-from fl4health_tpu.models.cnn import Mlp
-from fl4health_tpu.models.deepseek import DeepseekV2Classifier
-from fl4health_tpu.models.jamba import JambaClassifier
-from fl4health_tpu.models.transformer import TransformerClassifier
-from fl4health_tpu.observability import (
-    MetricsRegistry,
-    Observability,
-    Tracer,
-)
 from fl4health_tpu.observability import stages as stage_attr
-from fl4health_tpu.observability.introspect import abstractify
 from fl4health_tpu.privacy import dpsgd
 from fl4health_tpu.resilience import RobustFedAvg
 from fl4health_tpu.server.registry import CohortConfig
-from fl4health_tpu.server.simulation import ClientDataset, FederatedSimulation
-from fl4health_tpu.strategies.fedavg import FedAvg
+from tests.observability.round_programs import compiled_texts as _compiled_texts
+from tests.observability.round_programs import family_module
+from tests.observability.round_programs import mlp_sim as _mlp_sim
+from tests.observability.round_programs import token_sim as _token_sim
 
 pytestmark = pytest.mark.roofline
-
-N_CLASSES = 3
-
-
-def _obs():
-    return Observability(enabled=True, tracer=Tracer(),
-                         registry=MetricsRegistry())
-
-
-def _mlp_sim(n=3, mode="auto", **kwargs):
-    datasets = []
-    for i in range(n):
-        x, y = synthetic_classification(
-            jax.random.PRNGKey(i), 40, (6,), N_CLASSES
-        )
-        datasets.append(ClientDataset(x[:32], y[:32], x[32:], y[32:]))
-    args = dict(
-        logic=engine.ClientLogic(
-            engine.from_flax(Mlp(features=(12,), n_outputs=N_CLASSES)),
-            engine.masked_cross_entropy,
-        ),
-        tx=optax.sgd(0.05),
-        strategy=FedAvg(),
-        datasets=datasets,
-        batch_size=8,
-        metrics=MetricManager((efficient.accuracy(),)),
-        local_epochs=1,
-        seed=5,
-        observability=_obs(),
-        execution_mode=mode,
-    )
-    args.update(kwargs)
-    return FederatedSimulation(**args)
-
-
-def _token_sim(module):
-    """Three clients of a toy token classifier on the per-round driver: the
-    benchmark cells' job at toy size."""
-    rng = np.random.default_rng(0)
-    datasets = []
-    for n in (12, 20, 16):
-        x = rng.integers(1, 50, (n, 8)).astype(np.int32)
-        y = (x[:, 0] % N_CLASSES).astype(np.int32)
-        datasets.append(ClientDataset(x[:n - 4], y[:n - 4],
-                                      x[n - 4:], y[n - 4:]))
-    return FederatedSimulation(
-        logic=engine.ClientLogic(engine.from_flax(module),
-                                 engine.masked_cross_entropy),
-        tx=optax.sgd(0.005), strategy=FedAvg(), datasets=datasets,
-        batch_size=4, metrics=MetricManager((efficient.accuracy(),)),
-        local_steps=2, seed=3, execution_mode="pipelined",
-        observability=_obs())
-
-
-def _compiled_texts(sim):
-    """name -> optimised-HLO text of every round program ``fit()`` builds:
-    what the introspector is asked about at build time, compiled here in
-    its place."""
-    texts = {}
-
-    def compile_instead(name, jitted, args, **_):
-        texts[name] = jitted.lower(*abstractify(args)).compile().as_text()
-
-    sim.observability.introspector.introspect_jit = compile_instead
-    sim.fit(1)
-    return texts
 
 
 def _scopes(text):
@@ -177,8 +98,11 @@ class TestScopesInCompiledPrograms:
         per_round = _compiled_texts(_mlp_sim(mode="pipelined"))
         chunk = _compiled_texts(_mlp_sim(mode="chunked"))
         assert set(chunk) == {"fit_chunk_eval"}
-        for text in (per_round["fit_round_t"], chunk["fit_chunk_eval"]):
-            assert _scopes(text) == {"local_train", "server_update"}
+        both = {"local_train", "server_update"}
+        # the chunk scan holds the evaluation round too
+        for text, scopes in ((per_round["fit_round_t"], both),
+                             (chunk["fit_chunk_eval"], both | {"evaluate"})):
+            assert _scopes(text) == scopes
             assert any("fl_stage::local_train" in line and "transpose(" in line
                        for line in text.splitlines())
 
@@ -188,7 +112,7 @@ class TestScopesInCompiledPrograms:
         texts = _compiled_texts(
             _mlp_sim(cohort=CohortConfig(slots=3), mode="chunked"))
         assert _scopes(texts["fit_cohort_chunk"]) == {
-            "cohort_exchange", "local_train", "server_update"}
+            "cohort_exchange", "local_train", "server_update", "evaluate"}
         assert _scopes(texts["fit_round_t"]) == {"local_train",
                                                  "server_update"}
 
@@ -217,6 +141,7 @@ def _seam_text(seam):
                                           rotation=True))),
         "robust": ("fit_round_t", dict(mode="pipelined",
                                        strategy=RobustFedAvg("median"))),
+        "plain_eval": ("eval_round_t", dict(mode="pipelined")),
         "cohort_chunk": ("fit_cohort_chunk", dict(
             cohort=CohortConfig(slots=3), mode="chunked")),
     }[seam]
@@ -232,6 +157,7 @@ SEAM_OF = {
     "robust_aggregate": "robust",
     "server_update": "plain",
     "cohort_exchange": "cohort_chunk",
+    "evaluate": "plain_eval",
 }
 
 
@@ -242,29 +168,7 @@ def test_every_spine_stage_names_its_seam_in_the_compiled_program(stage):
 
 @functools.cache
 def _model_fit_text(family):
-    if family == "transformer":
-        module = TransformerClassifier(
-            vocab_size=50, n_classes=N_CLASSES, d_model=16, n_heads=2,
-            n_layers=2, d_ff=32, max_len=8)
-    elif family == "deepseek":
-        # one dense and one expert layer over a shared base, 4 of 8 experts
-        # held, through the flash calls at two head widths
-        module = DeepseekV2Classifier(
-            vocab_size=50, n_classes=N_CLASSES, d_model=16, n_layers=2,
-            d_ff=32, n_heads=2, q_lora_rank=8, kv_lora_rank=8,
-            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, d_expert=8,
-            n_routed_experts=8, experts_held=4, first_expert_held=2,
-            n_group=4, topk_group=2, top_k=3, lora_rank=2, remat=True,
-            dtype=jnp.bfloat16,
-            attention_fn=functools.partial(flash_attention, causal=True,
-                                           block_q=8, block_k=8))
-    else:
-        # one Mamba layer and one attention layer over a shared base
-        module = JambaClassifier(
-            vocab_size=50, n_classes=N_CLASSES, d_model=16, n_layers=2,
-            d_ff=32, n_heads=2, n_kv_heads=1, d_state=4, dt_rank=4,
-            attn_layer_period=2, attn_layer_offset=1, lora_rank=2)
-    return _compiled_texts(_token_sim(module))["fit_round_t"]
+    return _compiled_texts(_token_sim(family_module(family)))["fit_round_t"]
 
 
 @pytest.mark.parametrize("stage", ["local_train", "server_update"])

@@ -298,3 +298,198 @@ def test_flash_classify_goes_by_signature_not_by_name(small, kind, name):
     assert head != renamed.split(" = ", 1)[0]
     assert flash.classify(renamed) == flash.classify(old)
     assert tr.short_name(renamed) == name
+
+
+# -- pass_common, the part and coverage metrics -----------------------------
+PART_METRICS = ("attention", "mlp", "norm", "embed", "head", "optimizer",
+                "param_cast", "mamba_mixer", "lora", "moe_router",
+                "shared_experts")
+TRAIN = "jit(fit_round)/vmap(fl_stage::local_train)/while/body/closed_call/"
+# (name stack, stage, pass, parts): what both sides' parsers must say
+NAME_STACKS = [
+    (TRAIN + "jvp(Enc)/layer_0/attn/fl_layer::attention/q_proj/"
+     "fl_layer::param_cast/convert_element_type:",
+     "local_train", "forward", {"attention", "param_cast"}),
+    (TRAIN + "transpose(jvp(Enc))/jvp(Enc)/checkpoint/rematted_computation/"
+     "layer_1/fl_layer::norm/ln_mlp/reduce_sum:",
+     "local_train", "recompute", {"norm"}),
+    (TRAIN + "transpose(jvp(Jamba.forward))/while/body/closed_call/checkpoint/"
+     "fl_layer::mamba_mixer/fl_layer::ssm_scan/broadcast_in_dim:",
+     "local_train", "backward", {"mamba_mixer", "ssm_scan"}),
+    # a fusion of two ops carries both stacks
+    (TRAIN + "transpose(jvp(Jamba.forward))/checkpoint/fl_layer::attention/"
+     "reshape;checkpoint/fl_layer::attention/fl_layer::lora/mul:",
+     "local_train", "backward", {"attention", "lora"}),
+    (TRAIN + "fl_layer::optimizer/sub:", "local_train", "update",
+     {"optimizer"}),
+    ("jit(fit_round)/vmap(fl_stage::local_train)/while:",
+     "local_train", "update", set()),
+    ("jit(eval_round)/fl_stage::evaluate/vmap(Deepseek.forward)/"
+     "fl_layer::mla_attention/fl_layer::mla_flash/jvp(flash_fwd)/pallas_call:",
+     "evaluate", None, {"mla_attention", "mla_flash"}),
+    ("jit(fit_round)/fl_layer::shared_cast/Jamba.prepare_shared/"
+     "convert_element_type:", None, None, {"shared_cast"}),
+    ("jit(fit_round)/fl_stage::server_update/transpose(jvp(f))/mul:",
+     "server_update", None, set()),
+    ("", None, None, set()), (None, None, None, set()),
+]
+
+
+@pytest.mark.parametrize("tf_op,stage,pas,parts", NAME_STACKS)
+def test_the_benchmarks_parsers_agree_with_the_programs(tf_op, stage, pas,
+                                                        parts):
+    """The benchmark may not import the program, so it keeps its own copy
+    of ``stage_of`` / ``layers_of`` / ``pass_of``: one table holds both."""
+    from fl4health_tpu.observability import stages
+
+    assert (stages.stage_of(tf_op), stages.pass_of(tf_op),
+            set(stages.layers_of(tf_op))) == (stage, pas, parts)
+    common = reader("stage_common")
+    assert common.stage_of(tf_op) == (stage or common.UNATTRIBUTED)
+    assert reader("pass_common").pass_of(tf_op) == pas
+    assert reader("layer_common").layers_of(tf_op) == parts
+
+
+def test_the_benchmarks_pass_markers_are_the_programs():
+    from fl4health_tpu.observability import stages
+
+    assert reader("pass_common").PASS_MARKERS == stages.PASS_MARKERS
+    assert reader("pass_common").PASSES == stages.PASSES
+
+
+def synthetic_step():
+    """One window of 1,000 ns: a scan's ``while`` around a forward product
+    in nested parts, a recomputed and a backward one, the update, an op of
+    no part, then the evaluation program and an op of no stage."""
+    ops = {"%while": (0, 600, "jit(f)/vmap(fl_stage::local_train)/while:"),
+           "%fwd": (0, 100, TRAIN + "jvp(m)/fl_layer::mamba_mixer/"
+                                    "fl_layer::norm/mul:"),
+           "%again": (100, 180, TRAIN + "transpose(jvp(m))/checkpoint/"
+                      "rematted_computation/fl_layer::mamba_mixer/dot:"),
+           "%bwd": (180, 400, TRAIN + "transpose(jvp(m))/checkpoint/"
+                    "fl_layer::mlp/dot:"),
+           "%sgd": (400, 450, TRAIN + "fl_layer::optimizer/sub:"),
+           "%loss": (450, 500, TRAIN + "jvp(m)/reduce_sum:"),
+           "%eval": (700, 850, "jit(e)/fl_stage::evaluate/vmap(m)/"
+                     "fl_layer::mlp/dot:"),
+           "%cast": (900, 950, "jit(f)/fl_layer::shared_cast/convert:")}
+    lane = tr.DeviceLane([tr.Event(k, s, e) for k, (s, e, _) in ops.items()],
+                         [], [])
+    trace = tr.Trace({0: lane},
+                     {"main#0": [tr.Event(tr.WINDOW_ANNOTATION, 0, 1000)]})
+    return trace, {"/device:TPU:0": {k: v[2] for k, v in ops.items()}}
+
+
+def test_passes_and_coverage_on_a_synthetic_lane():
+    common = reader("pass_common")
+    trace, names = synthetic_step()
+    tab = common.table(trace, names)
+    ns = lambda d: {k: round(v * 1e9) for k, v in d.items()}  # noqa: E731
+    # the while keeps the 100 ns its body does not cover: bookkeeping, update
+    assert ns(common.by_pass(tab)) == {"forward": 150, "recompute": 80,
+                                       "backward": 220, "update": 150}
+    # the four passes conserve the stage's self time
+    stage = reader("stage_common").by_stage(trace, names)
+    assert sum(common.by_pass(tab).values()) == pytest.approx(
+        stage["local_train"])
+    assert round(stage["local_train"] * 1e9) == 600
+    by = {part: ns(cols) for part, cols in
+          common.by_layer_and_pass(trace, names).items()}
+    assert by == {
+        "mamba_mixer": {"forward": 100, "recompute": 80},
+        "norm": {"forward": 100},
+        "mlp": {"backward": 220, "evaluate": 150},
+        "optimizer": {"update": 50},
+        common.UNSCOPED: {"forward": 50, "update": 100},
+        # nesting counts %fwd for both its parts and once for coverage
+        common.TOTAL: {"forward": 150, "recompute": 80, "backward": 220,
+                       "update": 150, "evaluate": 150}}
+
+
+def _synthetic_ctx(tmp_path, monkeypatch):
+    """The synthetic step where a traced run's readers look: its name stacks
+    stand in for the trace file's."""
+    trace, names = synthetic_step()
+    monkeypatch.setattr(reader("stage_common"), "read_tf_ops",
+                        lambda path: names)
+    monkeypatch.setattr(reader("layer_common"), "read_tf_ops",
+                        lambda path: names)
+    folder = tmp_path / ".bench_cache" / "trace" / "toy" / "plugins" / "profile" / "x"
+    folder.mkdir(parents=True)
+    (folder / "host.xplane.pb").write_bytes(b"")
+    return ctx_of(trace, tmp_path, 2)
+
+
+def test_the_new_metrics_on_a_synthetic_lane(tmp_path, monkeypatch):
+    ctx = _synthetic_ctx(tmp_path, monkeypatch)
+    got = {m: reader(m).read(ctx) for m in (
+        "forward_ms_per_round", "recompute_ms_per_round",
+        "backward_ms_per_round", "eval_ms_per_round", "unstaged_device_pct",
+        "unscoped_local_train_pct", "mlp_ms_per_round", "norm_ms_per_round",
+        "optimizer_ms_per_round", "mamba_mixer_ms_per_round",
+        "attention_ms_per_round")}
+    ms = 1e-6 / 2  # ns of the window -> ms per round of two
+    assert got == pytest.approx({
+        "forward_ms_per_round": 150 * ms, "recompute_ms_per_round": 80 * ms,
+        "backward_ms_per_round": 220 * ms, "eval_ms_per_round": 150 * ms,
+        # %cast of 800 ns busy; the while's own 100 ns and %loss of 600
+        "unstaged_device_pct": 100 * 50 / 800,
+        "unscoped_local_train_pct": 100 * 150 / 600,
+        "mlp_ms_per_round": 370 * ms, "norm_ms_per_round": 100 * ms,
+        "optimizer_ms_per_round": 50 * ms,
+        "mamba_mixer_ms_per_round": 180 * ms,
+        "attention_ms_per_round": None})
+
+
+@pytest.fixture(scope="module")
+def jamba_small(tmp_path_factory):
+    """PR 27's fixture: a toy adapter cell's 2-round call on a v5e, remat,
+    the scopes that program had (``mamba_mixer``, ``ssm_scan``,
+    ``attention``, ``shared_cast``) and none of the later ones."""
+    root = tmp_path_factory.mktemp("jamba_small")
+    path = unpack("trace_jamba_small.xplane.pb.xz", root)
+    return {"root": root, "path": path, "trace": tr.load(path)}
+
+
+def test_the_new_metrics_read_an_older_programs_trace_or_none(spans_small,
+                                                              jamba_small):
+    """A parent that lacks the scopes gives ``None`` for what reads them and
+    does not raise; the pass and the coverage are read from JAX's own
+    markers and the accepted stages, which an older program has too."""
+    new = [m["name"] for m in load_json(os.path.join(
+        REPO, "BENCHMARK.json"))["per_layer"]]
+    new = new[new.index("eval_ms_per_round"):]
+    assert set(new) == {f"{p}_ms_per_round" for p in PART_METRICS} | {
+        "eval_ms_per_round", "unstaged_device_pct", "forward_ms_per_round",
+        "backward_ms_per_round", "recompute_ms_per_round",
+        "unscoped_local_train_pct"}
+    enc = ctx_of(spans_small["trace"], spans_small["root"], 3)
+    got = {m: reader(m).read(enc) for m in new}
+    reads = {m for m, v in got.items() if v is not None}
+    assert reads == {"forward_ms_per_round", "backward_ms_per_round",
+                     "unstaged_device_pct", "unscoped_local_train_pct"}
+    # seq128, no remat: forward + backward + update = local_train's 360.65
+    assert got["unscoped_local_train_pct"] == pytest.approx(100.0)
+    assert got["unstaged_device_pct"] == pytest.approx(7.5, abs=0.3)
+    assert 0.45 < got["forward_ms_per_round"] / got["backward_ms_per_round"] < 0.6
+    common = reader("pass_common")
+    by = common.by_pass(common.of_run(enc))
+    assert set(by) == {"forward", "backward", "update"}
+    assert sum(by.values()) * 1e3 / 3 == pytest.approx(360.65, rel=1e-4)
+
+    ada = ctx_of(jamba_small["trace"], jamba_small["root"], 2)
+    got = {m: reader(m).read(ada) for m in new}
+    assert {m for m, v in got.items() if v is not None} == {
+        "forward_ms_per_round", "backward_ms_per_round",
+        "recompute_ms_per_round", "unstaged_device_pct",
+        "unscoped_local_train_pct", "attention_ms_per_round",
+        "mamba_mixer_ms_per_round"}
+    stage = reader("stage_common")
+    by_stage = stage.by_stage(jamba_small["trace"],
+                              stage.read_tf_ops(jamba_small["path"]))
+    by = common.by_pass(common.of_run(ada))
+    assert sum(by.values()) == pytest.approx(by_stage["local_train"])
+    assert got["recompute_ms_per_round"] == pytest.approx(
+        by["recompute"] * 1e3 / 2)
+    assert got["mamba_mixer_ms_per_round"] > reader(
+        "ssm_scan_ms_per_round").read(ada) > 0
