@@ -75,30 +75,60 @@ knob, the results are the same):
   copy left is of a part of q narrower than 128 lanes over a shared part of k
   (the rotary 64): it is laid out ``[B, H, T, w]`` (``head``), and its
   gradient comes back so.
+  **Packed** (PR 40): heads of 64 (32) lanes ride g = 2 (4) to a lane block
+  of the same ``[B, T, H*w]`` view. The block is ``(1, n, 128)`` at lane
+  block ``h // g``, the grid's head axis walks the H / g groups, and a grid
+  step runs the kernel's body once a head over the SAME 128-lane tiles, the
+  heads told apart by a lane mask (``_head_lanes``), not by cutting 64-lane
+  halves out of a tile: ``S_j = (q . lanes_j) k^T`` contracts all 128 lanes
+  (the other heads' lanes of q are zero, so the sum is ``q_j k_j^T`` plus
+  exact zeros; q is masked once a tile, outside the loop over key blocks; a
+  64-deep and a 128-deep contraction cost the 128 x 128 MXU the same pass,
+  so this is the transposed path's pass count); ``P_j v`` is ``[Bq, 128]``
+  with ``P_j v_j`` in lanes_j, and the g results are merged by lane
+  (``_merge``) into ONE float32 accumulator with a per-lane rescale and
+  leave as one lane-dense block; ``dS_j k`` likewise; in dK/dV k and v are
+  masked once a key block and ``dS_j^T q``, ``P_j^T dO`` merged the same
+  way. The statistics (``m``, ``l``, ``lse``, ``delta``) stay per head,
+  ``[B, H, Tp, 1]`` with g of them a block; the key mask is the group's.
+  Outside the kernels delta = rowsum(dO O) a head is a product of the
+  float32 ``dO * O`` with the heads' 0/1 membership matrix (``_bwd_call``:
+  64 lanes are no lane tile, so a reshape-and-reduce made XLA lay the
+  product out T-minor first).
+  Where a step holds one head (g = 1: the other kinds) ``_head_lanes`` is
+  ``[None]`` and nothing of this is traced: the other calls lower to the text
+  they did.
 - **transposed**: every operand copied to ``[B*H, Tp, Dpadded]`` (each width
   padded to its own multiple: 64 for a width <= 64, else 128), one grid row
   per (batch, head), a shared head broadcast first, parts concatenated first.
   Any call whose shapes the rule below leaves out; with Dv == D, one part and
   no ``scale`` it traces to what it was before the second width and before
-  the parts (the encoder's D = 64: ``test_equal_widths_and_no_scale_trace_to_
-  the_parents_program``).
+  the parts (``test_equal_widths_and_no_scale_trace_to_the_parents_
+  program``).
 
-The rule: lane-indexed when v's width is a multiple of 128 and every part of
-q / k is a multiple of 128 wide with heads of its own, or is a shared
-one-head part of k (its q part may then be narrow, as above). So latent
+The rule: packed when every part of q / k and v is 64, or is 32, lanes wide
+with heads of its own, as many on each, a multiple of g. Else lane-indexed
+when v's width is a multiple of 128 and every part of q / k is a multiple of
+128 wide with heads of its own, or is a shared one-head part of k (its q part
+may then be narrow, as above). So the encoder's 12 heads of 64, latent
 attention at the published widths and 20 heads of 128 over one key/value head
-take it; D = 64, or a narrow part with key heads of its own, does not.
-``count_call_sites`` counts the traced calls by path for a build-time gauge.
+take it; an odd count of narrow heads, 80 or 96 lanes, 16 and under (8 bodies
+a step and more: never built), a narrow v beside wider q / k, or a narrow part
+with FEWER key heads than query heads does not. ``count_call_sites`` counts
+the traced calls by path for a build-time gauge.
 
-What Mosaic accepted and refused for the 64-lane rotary part of q (compiled
-for a described v5e, jax 0.9.0): a ``(1, block_q, 64)`` block of ``[B, T,
-H*64]`` is REFUSED by the Pallas TPU lowering (a block's last two dims must
-divide by 8 and 128 or equal the array's); a ``(1, block_q, 128)`` block
-holding two heads' rotary lanes compiles, but makes two heads one grid step:
-the kernel body twice, 64-lane halves cut out of a 128-lane tile, and the
-no-position part's head axis stepping by two; ``[B, H, T, 64]`` with a
-``(None, 1, block_q, 64)`` block (the last dim equals the array's) compiles
-and leaves the kernel one head a step. The last is the form taken: the
+What Mosaic accepted and refused for narrow heads (compiled for a described
+v5e, jax 0.9.0): a ``(1, block_q, 64)`` block of ``[B, T, H*64]`` is REFUSED
+by the Pallas TPU lowering (a block's last two dims must divide by 8 and 128
+or equal the array's); a ``(1, block_q, 128)`` block holding two heads' lanes
+compiles, and so do the packed kernels at 4 clients x 8 x 2,048 x 12 x 64 and
+at 32 lanes, bf16 and f32, causal and not, blocks 128/128: a ``jnp.where`` of
+a bf16 tile under an iota lane mask, the ``(None, g, block_q, 1)`` and
+``(None, g, 1, Tp)`` blocks of the per-head statistics
+(``tests/kernels/test_flash_v5e_compile.py``). For the 64-lane ROTARY part of
+q over a shared key (latent attention) packing would make the no-position
+part's head axis step by two; there ``[B, H, T, 64]`` with a ``(None, 1,
+block_q, 64)`` block (the last dim equals the array's) is the form taken: the
 transpose is a third of q's bytes and XLA fuses it into the rotary fusion
 that computes the part anyway.
 
@@ -132,6 +162,11 @@ from fl4health_tpu.core.remat import named
 from fl4health_tpu.kernels._platform import interpret_default
 
 _LANE = 128
+# Head widths that ride 128 // w to a lane block (``_lane_kinds``): the two
+# that were built and compiled for the v5e (64 also measured there). A
+# kernel's body runs once a head of its step, so 16 lanes and under (8 heads
+# a step and more) stay transposed.
+_PACKED_WIDTHS = (64, 32)
 # What the backward reads of the forward, under the names a remat policy may
 # keep (core/remat.py): ``out``, and ``lse`` without its trailing axis of 1.
 FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
@@ -281,36 +316,40 @@ def _layout_of(kinds, qs, v, mask):
     """Where the operands lie and how the grid walks them. The transposed
     path (``kinds`` None): every operand ``[B*H, Tp, w]``, one grid row per
     (batch, head). The lane-indexed path: ``kinds = (q kinds, k kinds, v
-    kind, heads)``, each operand addressed where the model holds it
-    (``_spec``), the head a grid axis of its own. Returns the kinds, the
-    heads, B (or B*H), Tp, each part's and v's width a head, and the kinds of
-    the output and of the per-row statistics."""
-    q_kinds, k_kinds, v_kind, heads = kinds or (("row",), ("row",), "row",
-                                                None)
+    kind, head steps, group)``, each operand addressed where the model holds
+    it (``_spec``), the head a grid axis of its own whose step holds
+    ``group`` heads (more than one: narrow heads packed side by side in a
+    128-lane block). Returns the kinds, the head steps, the group, B (or
+    B*H), Tp, each part's and v's width a head step, and the kinds of the
+    output and of the per-row statistics."""
+    q_kinds, k_kinds, v_kind, heads, group = kinds or (
+        ("row",), ("row",), "row", None, 1)
 
     def width(x, kind):
         return x.shape[-1] // (heads if kind == "lane" else 1)
 
     out, vec = ("row", "row") if heads is None else ("lane", "head")
-    return (q_kinds, k_kinds, v_kind, heads, mask.shape[0], mask.shape[-1],
-            [width(x, kind) for x, kind in zip(qs, q_kinds)],
+    return (q_kinds, k_kinds, v_kind, heads, group, mask.shape[0],
+            mask.shape[-1], [width(x, kind) for x, kind in zip(qs, q_kinds)],
             width(v, v_kind), out, vec)
 
 
-def _spec(kind, n, width, pos, whole=False):
+def _spec(kind, n, width, pos, whole=False, group=1):
     """BlockSpec of ``n`` positions (``whole``: the one block that is all of
-    them) of one head of one operand. ``pos(*grid) -> (batch, head, block)``.
-    ``row``: ``[B, Tp, w]``, no head axis (the transposed path's ``[B*H, Tp,
-    w]``, and a part every head shares); ``lane``: ``[B, Tp, H*w]``, the
-    head a lane block; ``head``: ``[B, H, Tp, w]``, a width that is no lane
-    block."""
+    them) of one head step of one operand. ``pos(*grid) -> (batch, head step,
+    block)``. ``row``: ``[B, Tp, w]``, no head axis (the transposed path's
+    ``[B*H, Tp, w]``, and a part every head shares); ``lane``: ``[B, Tp,
+    H*w]``, the head step a lane block (one head a multiple of 128 lanes
+    wide, or the ``group`` narrow heads that fill 128); ``head``: ``[B, H, Tp,
+    w]``, a width that is no lane block, ``group`` heads a block (the per-row
+    statistics of packed heads)."""
     def at(*grid):
         b, h, r = pos(*grid)
         r = 0 if whole else r
         return {"row": (b, r, 0), "lane": (b, r, h),
                 "head": (b, h, r, 0)}[kind]
 
-    return pl.BlockSpec((None, 1, n, width) if kind == "head"
+    return pl.BlockSpec((None, group, n, width) if kind == "head"
                         else (1, n, width), at)
 
 
@@ -328,7 +367,37 @@ def _scores(a_parts, b_parts, precision):
     return s
 
 
-def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
+def _head_lanes(group: int, rows: int):
+    """One entry for each of the ``group`` heads a grid step holds: the bool
+    ``[rows, 128]`` mask of the head's lanes in a 128-lane tile that packs
+    ``group`` heads side by side, or ``[None]`` where a step holds one head
+    (nothing is then masked or merged, and nothing is traced for it)."""
+    if group == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1)
+    width = _LANE // group
+    return [(lane >= j * width) & (lane < (j + 1) * width)
+            for j in range(group)]
+
+
+def _only(x, lanes):
+    """``x`` with every lane but one head's zeroed: a dot that contracts
+    all 128 lanes of it against an unmasked tile sums that head's products
+    and exact zeros."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _merge(per_head, head_lanes):
+    """One tile that holds, in each head's lanes, that head's result:
+    ``per_head[j]`` is right in ``head_lanes[j]`` alone (its other lanes
+    hold its rows against the other heads' columns, which nothing reads)."""
+    out = per_head[0]
+    for x, lanes in zip(per_head[1:], head_lanes[1:]):
+        out = jnp.where(lanes, x, out)
+    return out
+
+
+def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1):
     # Mosaic layout contract (learned on real silicon, KERNELS r5): every
     # block's trailing two dims must be (8k, 128k) or equal the array dims.
     # Row-per-(batch,head) vectors therefore travel as mask [B, 1, Tp] and
@@ -342,6 +411,11 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
     v_ref, mask_ref, o_ref, lse_ref = refs[2 * n:]
     qs = [_mxu_operand(r[0]) for r in q_refs]  # [Bq, w_i]
     bq, dvp = qs[0].shape[0], v_ref.shape[-1]
+    # the heads of this step: one, or ``group`` that share each 128-lane
+    # tile. The body below runs once a head over the SAME tiles; q is
+    # masked to a head's lanes once, outside the loop over key blocks
+    heads = _head_lanes(group, bq)
+    qs_of = [[_only(q, lanes_j) for q in qs] for lanes_j in heads]
     lanes = _LANE if block_k % _LANE == 0 else block_k
     live = None
     if causal:
@@ -349,37 +423,48 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
         live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
 
     def step(ks, carry):
-        m, l, acc = carry  # m: [Bq, 1], l: [Bq, lanes], acc: [Bq, Dvp], f32
+        # a head: m [Bq, 1], l [Bq, lanes]; acc: [Bq, Dvp], all f32
+        stats, acc = carry
         kbs = [_mxu_operand(r[0, ks, :]) for r in k_refs]
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
         if causal:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
                                                 block_k, False)
-        s = _scores(qs, kbs, precision) * scale  # [Bq, Bk]
-        s = jnp.where(keep, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + p[:, :lanes]
-        for c in range(lanes, block_k, lanes):
-            l = l + p[:, c:c + lanes]
-        acc = acc * corr + _dot(p, vb, (1, 0), precision)
-        return m_new, l, acc
+        new_stats, ps, corrs = [], [], []
+        for (m, l), qs_j in zip(stats, qs_of):
+            s = _scores(qs_j, kbs, precision) * scale  # [Bq, Bk]
+            s = jnp.where(keep, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p[:, :lanes]
+            for c in range(lanes, block_k, lanes):
+                l = l + p[:, c:c + lanes]
+            new_stats.append((m_new, l))
+            ps.append(p)
+            corrs.append(corr)
+        # P_j V is right in head j's lanes: one accumulator, each lane
+        # rescaled by its own head's correction
+        acc = acc * _merge(corrs, heads) + _merge(
+            [_dot(p, vb, (1, 0), precision) for p in ps], heads)
+        return tuple(new_stats), acc
 
-    m, l, acc = _block_loop(
+    stats, acc = _block_loop(
         v_ref.shape[1] // block_k, block_k, bq, step,
-        (jnp.full((bq, 1), NEG_INF, jnp.float32),
-         jnp.zeros((bq, lanes), jnp.float32),
+        (tuple((jnp.full((bq, 1), NEG_INF, jnp.float32),
+                jnp.zeros((bq, lanes), jnp.float32)) for _ in heads),
          jnp.zeros((bq, dvp), jnp.float32)), live)
-    denom = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
-    o_ref[0] = (acc / denom).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(denom)  # [Bq, 1]
+    denoms = [jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
+              for _, l in stats]
+    o_ref[0] = (acc / _merge(denoms, heads)).astype(o_ref.dtype)
+    for j, ((m, _), denom) in enumerate(zip(stats, denoms)):
+        lse_ref[j] = m + jnp.log(denom)  # [Bq, 1]
 
 
 def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
               kinds):
-    (q_kinds, k_kinds, v_kind, heads, b, tp, widths, dvp, out,
+    (q_kinds, k_kinds, v_kind, heads, group, b, tp, widths, dvp, out,
      vec) = _layout_of(kinds, qs, v, mask)
     if heads is None:  # one grid row per (batch, head)
         grid, pos = (b, tp // block_q), lambda b, i: (b, 0, i)
@@ -387,7 +472,8 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
         grid, pos = (b, heads, tp // block_q), lambda b, h, i: (b, h, i)
     kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
                                precision=_dot_precision(qs[0].dtype),
-                               causal=causal, q_axis=len(grid) - 1)
+                               causal=causal, q_axis=len(grid) - 1,
+                               group=group)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -400,11 +486,12 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
         ],
         out_specs=[
             _spec(out, block_q, dvp, pos),
-            _spec(vec, block_q, 1, pos),
+            _spec(vec, block_q, 1, pos, group=group),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(_shape(out, b, heads, tp, dvp), v.dtype),
-            jax.ShapeDtypeStruct(_shape(vec, b, heads, tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct(_shape(vec, b, (heads or 1) * group, tp,
+                                        1), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -415,16 +502,20 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
+def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
+                   group=1):
     n = (len(refs) - 5) // 3
     q_refs, k_refs = refs[:n], refs[n:2 * n]
     v_ref, mask_ref, do_ref, lse_ref, delta_ref = refs[2 * n:2 * n + 5]
     dq_refs = refs[2 * n + 5:]
     qs = [_mxu_operand(r[0]) for r in q_refs]
     do = _mxu_operand(do_ref[0])
-    lse = lse_ref[0]  # [Bq, 1]
-    delta = delta_ref[0]  # [Bq, 1] = rowsum(dO * O)
     bq = qs[0].shape[0]
+    heads = _head_lanes(group, bq)
+    # a head: its lanes of q and dO, lse [Bq, 1], delta [Bq, 1] = rowsum(dO O)
+    per_head = [([_only(q, lanes_j) for q in qs], _only(do, lanes_j),
+                 lse_ref[j], delta_ref[j])
+                for j, lanes_j in enumerate(heads)]
     live = None
     if causal:
         q_start = pl.program_id(q_axis) * bq
@@ -437,13 +528,18 @@ def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1):
         if causal:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
                                                 block_k, False)
-        s = _scores(qs, kbs, precision) * scale
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dp = _dot(do, vb, (1, 1), precision)
-        # dS = p * (dP - delta) * scale; the scale waits for the sum
-        ds = p * (dp - delta)
-        return tuple(dq + _dot(ds, kb, (1, 0), precision)
-                     for dq, kb in zip(dqs, kbs))
+        dss = []
+        for qs_j, do_j, lse, delta in per_head:
+            s = _scores(qs_j, kbs, precision) * scale
+            p = jnp.where(keep, jnp.exp(s - lse), 0.0)
+            dp = _dot(do_j, vb, (1, 1), precision)
+            # dS = p * (dP - delta) * scale; the scale waits for the sum
+            dss.append(p * (dp - delta))
+        # dS_j K is right in head j's lanes
+        return tuple(
+            dq + _merge([_dot(ds, kb, (1, 0), precision) for ds in dss],
+                        heads)
+            for dq, kb in zip(dqs, kbs))
 
     dqs = _block_loop(v_ref.shape[1] // block_k, block_k, bq, step,
                       tuple(jnp.zeros(q.shape, jnp.float32) for q in qs),
@@ -471,7 +567,8 @@ def _store(ref, value, head_axis):
         ref[0] = ref[0] + value
 
 
-def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared):
+def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
+                    k_axis=1, group=1):
     # The score tile is held transposed, [Bk, Bq]: dV += P^T dO and
     # dK += dS^T Q are then plain row-major dots, and lse / delta meet the
     # tile as rows [1, Bq] (a sublane broadcast) where the [Bq, Bk] form
@@ -484,26 +581,39 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared):
     kbs = [_mxu_operand(r[0]) for r in k_refs]  # [Bk, w_i]
     vb = _mxu_operand(v_ref[0])
     bk = vb.shape[0]
+    # k and v masked to a head's lanes once, outside the loop over query
+    # blocks; the q / dO tiles meet them unmasked
+    heads = _head_lanes(group, bk)
+    per_head = [([_only(kb, lanes_j) for kb in kbs], _only(vb, lanes_j))
+                for lanes_j in heads]
     live = None
     if causal:
-        k_start = pl.program_id(1) * bk
+        k_start = pl.program_id(k_axis) * bk
         live = lambda q_start: q_start + (block_q - 1) >= k_start  # noqa: E731
 
     def step(qs_, carry):
         dks, dv = carry
         qs = [_mxu_operand(r[0, qs_, :]) for r in q_refs]
         do = _mxu_operand(do_ref[0, qs_, :])
-        lse = lse_ref[0, :, qs_]  # [1, Bq]
-        delta = delta_ref[0, :, qs_]
-        pt = jnp.exp(_scores(kbs, qs, precision) * scale - lse)
-        if causal:
-            pt = jnp.where(_on_or_under_diagonal(qs_.start, block_q, k_start,
-                                                 bk, True), pt, 0.0)
-        dpt = _dot(vb, do, (1, 1), precision)
-        dv = dv + _dot(pt, do, (1, 0), precision)
-        dst = pt * (dpt - delta)
-        return tuple(dk + _dot(dst, q, (1, 0), precision)
-                     for dk, q in zip(dks, qs)), dv
+        pts, dpts, deltas = [], [], []
+        for j, (kbs_j, vb_j) in enumerate(per_head):
+            lse = lse_ref[j, :, qs_]  # [1, Bq]
+            deltas.append(delta_ref[j, :, qs_])
+            pt = jnp.exp(_scores(kbs_j, qs, precision) * scale - lse)
+            if causal:
+                pt = jnp.where(_on_or_under_diagonal(
+                    qs_.start, block_q, k_start, bk, True), pt, 0.0)
+            pts.append(pt)
+            dpts.append(_dot(vb_j, do, (1, 1), precision))
+        # P_j^T dO and dS_j^T Q are right in head j's lanes
+        dv = dv + _merge([_dot(pt, do, (1, 0), precision) for pt in pts],
+                         heads)
+        dsts = [pt * (dpt - delta)
+                for pt, dpt, delta in zip(pts, dpts, deltas)]
+        return tuple(
+            dk + _merge([_dot(dst, q, (1, 0), precision) for dst in dsts],
+                        heads)
+            for dk, q in zip(dks, qs)), dv
 
     dks0 = tuple(jnp.zeros(kb.shape, jnp.float32) for kb in kbs)
     # one zeros tile serves both where the widths agree: the program the
@@ -526,7 +636,7 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
               dlse, causal, kinds):
     """``lse`` comes as the forward rule kept it, ``[.., Tp]``; ``dlse`` as
     the result's cotangent, ``[.., Tp, 1]``."""
-    (q_kinds, k_kinds, v_kind, heads, b, tp, widths, dvp, out,
+    (q_kinds, k_kinds, v_kind, heads, group, b, tp, widths, dvp, out,
      vec) = _layout_of(kinds, qs, v, mask)
     # lse is a differentiable OUTPUT (ring-flash merge): its cotangent
     # enters the score gradient as dS = p*(dP - delta + dlse), i.e. the
@@ -539,16 +649,29 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
                  - dlse.astype(jnp.float32))  # [BH, Tp, 1]
         grid, pos = (b, tp // block_q), lambda b, i: (b, 0, i)
     else:
-        prod = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-            b, tp, heads, dvp)
-        delta = (jnp.transpose(jnp.sum(prod, axis=-1), (0, 2, 1))[..., None]
-                 - dlse.astype(jnp.float32))  # [B, H, Tp, 1]
+        prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+        if group == 1:  # a head is whole lane tiles: a free view, a reduce
+            delta = jnp.transpose(
+                jnp.sum(prod.reshape(b, tp, heads, dvp), axis=-1), (0, 2, 1))
+        else:
+            # A packed head is 64 or 32 of a tile's 128 lanes, so [.., H, w]
+            # is no free view: XLA lays the float32 product out T-minor
+            # before it reduces (43 ms a round in the flash cell, PR 40). A
+            # product with the heads' 0/1 membership matrix sums the same
+            # lanes on the MXU where they lie; at HIGHEST the float32
+            # operand is taken whole (three bf16 pieces), the matrix exact.
+            n_heads, width = heads * group, dvp // group
+            member = (jnp.arange(n_heads * width)[:, None] // width
+                      == jnp.arange(n_heads)[None, :]).astype(jnp.float32)
+            delta = jnp.einsum("btk,kh->bht", prod, member,
+                               precision=jax.lax.Precision.HIGHEST)
+        delta = delta[..., None] - dlse.astype(jnp.float32)  # [B, H, Tp, 1]
         grid, pos = (b, heads, tp // block_q), lambda b, h, i: (b, h, i)
 
     prec = _dot_precision(qs[0].dtype)
     dq_kernel = functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
                                   precision=prec, causal=causal,
-                                  q_axis=len(grid) - 1)
+                                  q_axis=len(grid) - 1, group=group)
     q_specs = [_spec(kind, block_q, w, pos) for kind, w in zip(q_kinds, widths)]
     dqs = pl.pallas_call(
         dq_kernel,
@@ -560,8 +683,8 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
             _spec(v_kind, tp, dvp, pos, whole=True),
             _spec("row", 1, tp, pos, whole=True),
             _spec(out, block_q, dvp, pos),
-            _spec(vec, block_q, 1, pos),
-            _spec(vec, block_q, 1, pos),
+            _spec(vec, block_q, 1, pos, group=group),
+            _spec(vec, block_q, 1, pos, group=group),
         ],
         out_specs=q_specs,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs],
@@ -571,18 +694,24 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
 
     # dK/dV sees queries along the lanes: lse and delta as rows [.., 1, Tp]
     # (sliced at block_q offsets like the forward's mask), the key mask as a
-    # column [B, Tp, 1]. On the lane-indexed path the head is the innermost
-    # grid axis, so a part every head shares keeps its float32 block in VMEM
-    # while the heads add to it (_store).
+    # column [B, Tp, 1]. Where every head shares a part, the head is the
+    # innermost grid axis, so the part's float32 block stays in VMEM while
+    # the heads add to it (_store). Where no part is shared the key block is
+    # innermost, as on the transposed path: a head step's whole-sequence Q
+    # and dO are then fetched once, not once a key block.
+    shared = tuple(heads is not None and kind == "row"
+                   for kind in (*k_kinds, v_kind))
+    k_axis = 1  # where the key block is in the grid
     if heads is None:
         grid, pos = (b, tp // block_k), lambda b, j: (b, 0, j)
-        shared = (False,) * (len(ks) + 1)
-    else:
+    elif any(shared):
         grid, pos = (b, tp // block_k, heads), lambda b, j, h: (b, h, j)
-        shared = tuple(kind == "row" for kind in (*k_kinds, v_kind))
+    else:
+        grid, pos = (b, heads, tp // block_k), lambda b, h, j: (b, h, j)
+        k_axis = 2
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
                                    scale=scale, precision=prec, causal=causal,
-                                   shared=shared)
+                                   shared=shared, group=group, k_axis=k_axis)
     k_specs = [_spec(kind, block_k, w, pos) for kind, w in zip(k_kinds, widths)]
     v_spec = _spec(v_kind, block_k, dvp, pos)
     *dks, dv = pl.pallas_call(
@@ -595,8 +724,8 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
             v_spec,
             _spec("row", block_k, 1, pos),
             _spec(out, tp, dvp, pos, whole=True),
-            _spec(vec, 1, tp, pos, whole=True),
-            _spec(vec, 1, tp, pos, whole=True),
+            _spec(vec, 1, tp, pos, whole=True, group=group),
+            _spec(vec, 1, tp, pos, whole=True, group=group),
         ],
         out_specs=[*k_specs, v_spec],
         out_shape=[jax.ShapeDtypeStruct(x.shape,
@@ -681,15 +810,25 @@ def count_call_sites():
 
 def _lane_kinds(qs, ks, v):
     """How each operand lies for the lane-indexed path (``_spec``'s kinds:
-    (q kinds, k kinds, v kind, heads)), or None where the shapes leave the
-    transposed path. A part with one head under several query heads is
-    shared (``row``, any width: its block spans its whole last axis); a head
-    width that is a multiple of 128 lanes is a lane block of ``[B, T, H*w]``
-    (``lane``); a narrower part of q is laid out ``[B, H, T, w]`` (``head``:
-    the one copy the path makes) if its part of k is shared. Anything else
-    (a narrow part of k or a narrow v with heads of its own: the encoder's
-    D = 64) is the transposed path's."""
+    (q kinds, k kinds, v kind, head steps, heads a step)), or None where the
+    shapes leave the transposed path. A part with one head under several
+    query heads is shared (``row``, any width: its block spans its whole
+    last axis); a head width that is a multiple of 128 lanes is a lane block
+    of ``[B, T, H*w]`` (``lane``); a narrower part of q is laid out ``[B, H,
+    T, w]`` (``head``: the one copy the path makes) if its part of k is
+    shared. Packed: every part of q / k and v ``_PACKED_WIDTHS`` wide alike
+    (g = 128 // w heads fill a lane block), with heads of their own, as many
+    on each, a multiple of g: the same ``lane`` blocks, g heads a step.
+    Anything else (an odd count of narrow heads, 80 or 96 lanes, a narrow
+    part of k or a narrow v with fewer heads than q) is the transposed
+    path's."""
     heads = qs[0].shape[2]
+    width = v.shape[3]
+    if (width in _PACKED_WIDTHS and heads % (_LANE // width) == 0
+            and all(x.shape[2:] == (heads, width) for x in (*qs, *ks, v))):
+        group = _LANE // width
+        return (("lane",) * len(qs), ("lane",) * len(ks), "lane",
+                heads // group, group)
 
     def kind(x, narrow=None):
         if x.shape[2] == 1 and heads > 1:
@@ -699,7 +838,7 @@ def _lane_kinds(qs, ks, v):
     k_kinds = tuple(kind(k) for k in ks)
     q_kinds = tuple(kind(q, "head" if kk == "row" else None)
                     for q, kk in zip(qs, k_kinds))
-    kinds = (q_kinds, k_kinds, kind(v), heads)
+    kinds = (q_kinds, k_kinds, kind(v), heads, 1)
     if v.shape[3] % _LANE or None in q_kinds + k_kinds or "row" in q_kinds:
         return None
     return kinds
@@ -820,8 +959,8 @@ def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
     """The operands where the model holds them: nothing is transposed,
     padded or broadcast but a narrow part of q (``_lane_kinds``) and, where
     the blocks do not divide it, the sequence."""
-    q_kinds, k_kinds, v_kind, h = kinds
-    b, t = qs[0].shape[:2]
+    q_kinds, k_kinds, v_kind, steps, _ = kinds
+    b, t, h = qs[0].shape[:3]
     dv = v.shape[-1]
     t_multiple = math.lcm(block_q, block_k)
 
@@ -837,8 +976,9 @@ def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
     if not interpret:
         _check_compilable(
             block_q, block_k, tp,
-            sum(pl.cdiv(a.shape[-1] // (h if kind == "lane" else 1), _LANE)
-                * _LANE for a, kind in zip(ks, k_kinds)), dv, v.dtype)
+            sum(pl.cdiv(a.shape[-1] // (steps if kind == "lane" else 1),
+                        _LANE) * _LANE for a, kind in zip(ks, k_kinds)),
+            dv, v.dtype)
     if pad_mask is None:
         pad_mask = jnp.ones((b, t), jnp.float32)
     pad_mask = jax.lax.stop_gradient(pad_mask)

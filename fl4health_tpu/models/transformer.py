@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fl4health_tpu.core import remat as remat_names
-from fl4health_tpu.kernels.flash_attention import SAVED_NAMES as FLASH_SAVED
+from fl4health_tpu.kernels.flash_attention import (SAVED_NAMES as FLASH_SAVED,
+                                                   count_call_sites)
 from fl4health_tpu.observability.stages import layer as part
 
 # What a rematerialised encoder block keeps (core/remat.py): the flash
@@ -230,14 +231,17 @@ class TransformerClassifier(nn.Module):
                     {"features": pooled})
 
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
-        """What the remat sites keep (``ModelDef.build_gauges``: a fact of the
-        build, from one abstract trace); ``batch_shape`` is one client's
-        [B, T]."""
+        """Which path the forward's flash calls take (one call a block; none
+        with the dense core) and what the remat sites keep
+        (``ModelDef.build_gauges``: facts of the build, from abstract traces);
+        ``batch_shape`` is one client's [B, T]."""
         keeps = REMAT_KEEPS if self.remat else ()
         x = jax.ShapeDtypeStruct(tuple(batch_shape), jnp.int32)
-        # without remat there is no site: nothing is traced
-        variables = keeps and jax.eval_shape(
-            lambda x: self.init(jax.random.PRNGKey(0), x, train=False), x)
-        return remat_names.saved_gauges(
-            lambda v, x: self.apply(v, x, train=False)[0]["prediction"],
-            (variables, x), keeps, n_clients)
+        with count_call_sites() as sites:
+            variables = jax.eval_shape(
+                lambda x: self.init(jax.random.PRNGKey(0), x, train=False), x)
+        return {**{f"flash_calls_{path}": n for path, n in sites.items()},
+                **remat_names.saved_gauges(
+                    lambda v, x: self.apply(v, x, train=False)[0][
+                        "prediction"],
+                    (variables, x), keeps, n_clients)}

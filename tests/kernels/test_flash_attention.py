@@ -109,16 +109,20 @@ def _eqns_named(jaxpr, primitive):
     return found
 
 
+@pytest.mark.parametrize("heads", [2, 3])
 @pytest.mark.parametrize("dtype,precision", [
     (jnp.bfloat16, jax.lax.Precision.DEFAULT),
     (jnp.float32, jax.lax.Precision.HIGHEST),
 ])
 def test_dots_take_the_operands_dtype_and_accumulate_in_float32(dtype,
-                                                                precision):
+                                                                precision,
+                                                                heads):
     """What proves the native-operand path engages: in the three kernels'
     jaxprs every dot takes its operands in the dtype they arrived in (bf16
-    straight to the MXU, f32 at HIGHEST) and accumulates in float32."""
-    x = jnp.ones((1, 256, 2, 64), dtype)
+    straight to the MXU, f32 at HIGHEST) and accumulates in float32. Two
+    heads of 64 are packed in one lane block, so a grid step runs the body
+    twice: the same dots a head as three heads on the transposed path."""
+    x = jnp.ones((1, 256, heads, 64), dtype)
     mask = jnp.ones((1, 256), jnp.float32)
 
     def loss(q, k, v):
@@ -129,8 +133,10 @@ def test_dots_take_the_operands_dtype_and_accumulate_in_float32(dtype,
     kernels = {eqn.params["name"]: eqn.params["jaxpr"]
                for eqn in _eqns_named(traced.jaxpr, "pallas_call")}
     assert sorted(kernels) == ["flash_dkv", "flash_dq", "flash_fwd"]
-    # two blocks of the streamed axis, unrolled: dots a block x 2
-    n_dots = {"flash_fwd": 2 * 2, "flash_dq": 3 * 2, "flash_dkv": 4 * 2}
+    # two blocks of the streamed axis, unrolled: dots a block and head x 2
+    a_step = 2 if heads == 2 else 1
+    n_dots = {"flash_fwd": 2 * 2 * a_step, "flash_dq": 3 * 2 * a_step,
+              "flash_dkv": 4 * 2 * a_step}
     for name, kernel in kernels.items():
         dots = _eqns_named(kernel, "dot_general")
         assert len(dots) == n_dots[name], (name, len(dots))

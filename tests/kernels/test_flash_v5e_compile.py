@@ -1,6 +1,8 @@
 """The named Mosaic calls of the benchmark's cells, compiled ahead of time for
 a v5e: the three flash calls at the flash cell's shapes (4 clients vmapped
-over batch 8 x 12 heads, T 2,048, D 64, bf16, blocks 128/128), the same three
+over batch 8 x 12 heads, T 2,048, D 64, bf16, blocks 128/128: lane-indexed,
+two heads of 64 packed in a 128-lane block of ``[.., T, 768]``, and read by
+the benchmark's ``flash_common.classify`` as 32 rows of 768), the same three
 causal over one shared key/value head at the adapter cell's (20 heads of 128,
 blocks 512/512: lane-indexed, the shared head's gradients one float32 array a
 client), the same three causal over latent attention's parts (128 heads; q
@@ -19,15 +21,18 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from benchmarks.harness.spec import load_module
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.kernels.selective_scan import (BLOCK_T, UNROLL,
                                                   _blocked_scan)
 
 CLIENTS, BATCH, SEQ, HEADS, HEAD_DIM = 4, 8, 2048, 12, 64
-ROWS = f"bf16[{CLIENTS},{BATCH * HEADS},{SEQ},{HEAD_DIM}]"
+# q, k, v, the output and the three gradients lie as the projections hold
+# them, [.., T, 12 * 64]; the per-row statistic a head, [.., 12, T, 1]
+ROWS = f"bf16[{CLIENTS},{BATCH},{SEQ},{HEADS * HEAD_DIM}]"
 # kernel name -> the result types its custom call must have
 KERNELS = {
-    "flash_fwd": (ROWS, f"f32[{CLIENTS},{BATCH * HEADS},{SEQ},1]"),
+    "flash_fwd": (ROWS, f"f32[{CLIENTS},{BATCH},{HEADS},{SEQ},1]"),
     "flash_dq": (ROWS,),
     "flash_dkv": (ROWS, ROWS),
 }
@@ -44,6 +49,17 @@ def one_chip():
     except Exception as e:  # noqa: BLE001  whatever the plugin raises here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _text_as_a_trace_names_ops(compiled):
+    """The optimised HLO with each operand's shape before its name: the form
+    in which a profile names a device op, which the benchmark's readers
+    parse (``as_text()`` leaves the operands' shapes out)."""
+    from jax._src.lib import xla_client
+
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(options)
 
 
 def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
@@ -76,10 +92,9 @@ def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        texts = [jax.jit(jax.vmap(attend)).lower(q, kv, v, mask)
-                 .compile().as_text(),
-                 jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-                 .lower(q, kv, v, mask).compile().as_text()]
+        texts = [_text_as_a_trace_names_ops(
+            jax.jit(fn).lower(q, kv, v, mask).compile())
+            for fn in (jax.vmap(attend), jax.grad(loss, argnums=(0, 1, 2)))]
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
@@ -174,6 +189,21 @@ def test_named_flash_call_compiles_for_the_v5e(mosaic_calls, name):
         assert shapes == KERNELS[name], (name, result)
     # the forward runs once in the forward program and once under grad
     assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_the_benchmarks_reader_knows_the_flash_cells_calls(mosaic_calls,
+                                                           name):
+    """``flash_ms_per_round`` / ``flash_roofline_pct`` find the flash calls
+    by their signature: the HLO line of each compiled call, as a trace names
+    the op, classifies as its kind over clients x batch = 32 rows of T 2,048
+    x 768 = 12 heads x 64 lanes of bf16. ``flops/flash_attention.py`` is
+    linear in rows x d, so 32 x 768 counts what 384 x 64 did."""
+    flash_common = load_module("layer_metrics", "flash_common")
+    for line in mosaic_calls[name]:
+        assert flash_common.classify(line.strip()) == (
+            name.removeprefix("flash_"), CLIENTS * BATCH, SEQ,
+            HEADS * HEAD_DIM, 2), line[:400]
 
 
 # the adapter cell's Mamba mixers: 4 clients x batch 1 x 2,048 positions x

@@ -250,20 +250,44 @@ class TestRemat:
             np.asarray(ravel_pytree(sq(b)(v))[0]), atol=1e-5, rtol=1e-5)
 
     def test_build_gauges_say_what_a_block_keeps(self):
-        """``out`` [B*H, Tp, 64] (a head of 16 padded to 64 lanes) and the
-        statistic [B*H, Tp], one of each a block, over the clients."""
+        """``out`` [B*H, Tp, 64] (a head of 16 padded to 64 lanes: the
+        transposed path, one call a block) and the statistic [B*H, Tp], one
+        of each a block, over the clients."""
         b, heads, clients = 3, 2, 4
         want = clients * (b * heads * SEQ * 64 * 4 + b * heads * SEQ * 4)
+        transposed = {"flash_calls_lane_indexed": 0,
+                      "flash_calls_transposed": 2}
         gauges = engine.from_flax(self._flash_model(remat=True)).build_gauges(
             (b, SEQ), clients)
-        assert gauges == {"remat_saved_names": 2,
+        assert gauges == {**transposed, "remat_saved_names": 2,
                           "remat_saved_bytes_per_layer": want}
         bf16 = self._flash_model(remat=True, dtype=jnp.bfloat16)
         assert bf16.build_gauges((b, SEQ), clients)[
             "remat_saved_bytes_per_layer"] == clients * (
                 b * heads * SEQ * 64 * 2 + b * heads * SEQ * 4)
         nothing = {"remat_saved_names": 0, "remat_saved_bytes_per_layer": 0}
-        # no remat: no site; the dense core names nothing
-        assert self._flash_model().build_gauges((b, SEQ), clients) == nothing
-        assert small_model(remat=True).build_gauges((b, SEQ),
-                                                    clients) == nothing
+        # no remat: no site; the dense core names nothing and calls nothing
+        assert self._flash_model().build_gauges((b, SEQ), clients) == {
+            **transposed, **nothing}
+        assert small_model(remat=True).build_gauges((b, SEQ), clients) == {
+            "flash_calls_lane_indexed": 0, "flash_calls_transposed": 0,
+            **nothing}
+
+    def test_at_the_flash_cells_shape_no_call_is_transposed(self):
+        """BERT-base widths under the long-document job (4 clients x batch 8
+        x 2,048 tokens, 12 heads of 64, bf16, remat, blocks 128/128): every
+        block's call addresses q / k / v in the projections' [B, T, 768],
+        two heads a lane block, and a block keeps ``out`` so, not padded or
+        transposed, with the statistic [B, 12, Tp]."""
+        import functools
+
+        module = TransformerClassifier(
+            vocab_size=30522, n_classes=4, d_model=768, n_heads=12,
+            n_layers=12, d_ff=3072, max_len=2048, dtype=jnp.bfloat16,
+            remat=True, attention_fn=functools.partial(
+                flash_attention, block_q=128, block_k=128))
+        assert module.build_gauges((8, 2048), 4) == {
+            "flash_calls_lane_indexed": 12, "flash_calls_transposed": 0,
+            "remat_saved_names": 2,
+            "remat_saved_bytes_per_layer": 4 * (8 * 2048 * 768 * 2
+                                                + 8 * 12 * 2048 * 4)}
