@@ -70,6 +70,13 @@ knob, the results are the same):
   gradient is summed over the heads in float32 INSIDE the dK/dV call (the
   head axis innermost, the output block resident across it: ``_store``); the
   key-padding mask is ``[B, 1, Tp]`` at ``(b, 0, 0)``, not repeated per head.
+  **Grouped** key/value heads (grouped-query attention: ``Hkv`` heads of a
+  multiple of 128 lanes under ``H = rep * Hkv`` query heads) are the same
+  ``[B, T, Hkv*w]`` view with query head h reading lane block ``h // rep``
+  (``grouped``; no ``repeat``), and dK / dV sum a group's ``rep`` heads in
+  float32 inside the dK/dV call as the shared part sums all of them: the
+  head axis innermost, the block resident across the ``rep`` consecutive
+  steps that map to it.
   The output is ``[B, T, H*Dv]``, which an output projection takes as it
   is: no transpose before the call, none after, no pad of a width. The one
   copy left is of a part of q narrower than 128 lanes over a shared part of k
@@ -112,9 +119,11 @@ when v's width is a multiple of 128 and every part of q / k is a multiple of
 128 wide with heads of its own, or is a shared one-head part of k (its q part
 may then be narrow, as above). So the encoder's 12 heads of 64, latent
 attention at the published widths and 20 heads of 128 over one key/value head
-take it; an odd count of narrow heads, 80 or 96 lanes, 16 and under (8 bodies
-a step and more: never built), a narrow v beside wider q / k, or a narrow part
-with FEWER key heads than query heads does not. ``count_call_sites`` counts
+take it, and so do 32 query heads over 2 key/value heads of 128 (grouped);
+an odd count of narrow heads, 80 or 96 lanes, 16 and under (8 bodies a step
+and more: never built), a narrow v beside wider q / k, or a narrow part with
+FEWER key heads than query heads (repeated to the query heads first) does
+not. ``count_call_sites`` counts
 the traced calls by path for a build-time gauge.
 
 What Mosaic accepted and refused for narrow heads (compiled for a described
@@ -319,33 +328,47 @@ def _layout_of(kinds, qs, v, mask):
     kind, head steps, group)``, each operand addressed where the model holds
     it (``_spec``), the head a grid axis of its own whose step holds
     ``group`` heads (more than one: narrow heads packed side by side in a
-    128-lane block). Returns the kinds, the head steps, the group, B (or
-    B*H), Tp, each part's and v's width a head step, and the kinds of the
-    output and of the per-row statistics."""
-    q_kinds, k_kinds, v_kind, heads, group = kinds or (
-        ("row",), ("row",), "row", None, 1)
+    128-lane block), ``rep`` query heads to a key/value head of a ``grouped``
+    operand. Returns the kinds, the head steps, the group, rep, B (or B*H),
+    Tp, each part's and v's width a head step, and the kinds of the output
+    and of the per-row statistics."""
+    q_kinds, k_kinds, v_kind, heads, group, rep = kinds or (
+        ("row",), ("row",), "row", None, 1, 1)
 
     def width(x, kind):
-        return x.shape[-1] // (heads if kind == "lane" else 1)
+        return x.shape[-1] // _lane_blocks(kind, heads, rep)
 
     out, vec = ("row", "row") if heads is None else ("lane", "head")
-    return (q_kinds, k_kinds, v_kind, heads, group, mask.shape[0],
+    return (q_kinds, k_kinds, v_kind, heads, group, rep, mask.shape[0],
             mask.shape[-1], [width(x, kind) for x, kind in zip(qs, q_kinds)],
             width(v, v_kind), out, vec)
 
 
-def _spec(kind, n, width, pos, whole=False, group=1):
+def _lane_blocks(kind, heads, rep):
+    """How many head blocks an operand's last axis holds: ``heads`` of its
+    own (``lane``), one for every ``rep`` query heads (``grouped``), else
+    one."""
+    if kind == "lane":
+        return heads
+    return heads // rep if kind == "grouped" else 1
+
+
+def _spec(kind, n, width, pos, whole=False, group=1, rep=1):
     """BlockSpec of ``n`` positions (``whole``: the one block that is all of
     them) of one head step of one operand. ``pos(*grid) -> (batch, head step,
     block)``. ``row``: ``[B, Tp, w]``, no head axis (the transposed path's
     ``[B*H, Tp, w]``, and a part every head shares); ``lane``: ``[B, Tp,
     H*w]``, the head step a lane block (one head a multiple of 128 lanes
-    wide, or the ``group`` narrow heads that fill 128); ``head``: ``[B, H, Tp,
+    wide, or the ``group`` narrow heads that fill 128); ``grouped``: ``[B,
+    Tp, (H / rep)*w]``, a key/value head that ``rep`` consecutive query heads
+    read, head step h at lane block ``h // rep``; ``head``: ``[B, H, Tp,
     w]``, a width that is no lane block, ``group`` heads a block (the per-row
     statistics of packed heads)."""
     def at(*grid):
         b, h, r = pos(*grid)
         r = 0 if whole else r
+        if kind == "grouped":
+            return b, r, h // rep
         return {"row": (b, r, 0), "lane": (b, r, h),
                 "head": (b, h, r, 0)}[kind]
 
@@ -464,7 +487,7 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1):
 
 def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
               kinds):
-    (q_kinds, k_kinds, v_kind, heads, group, b, tp, widths, dvp, out,
+    (q_kinds, k_kinds, v_kind, heads, group, rep, b, tp, widths, dvp, out,
      vec) = _layout_of(kinds, qs, v, mask)
     if heads is None:  # one grid row per (batch, head)
         grid, pos = (b, tp // block_q), lambda b, i: (b, 0, i)
@@ -479,9 +502,9 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
         grid=grid,
         in_specs=[
             *(_spec(kind, block_q, w, pos) for kind, w in zip(q_kinds, widths)),
-            *(_spec(kind, tp, w, pos, whole=True)
+            *(_spec(kind, tp, w, pos, whole=True, rep=rep)
               for kind, w in zip(k_kinds, widths)),
-            _spec(v_kind, tp, dvp, pos, whole=True),
+            _spec(v_kind, tp, dvp, pos, whole=True, rep=rep),
             _spec("row", 1, tp, pos, whole=True),
         ],
         out_specs=[
@@ -548,15 +571,19 @@ def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
         dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _store(ref, value, head_axis):
+def _store(ref, value, head_axis, every=None):
     """Write one key block's gradient. ``head_axis`` None: the block is this
-    grid step's own. Else every head shares the part: its block is float32
-    and stays in VMEM across the head axis (the innermost), the first head
-    writes it and the others add to it."""
+    grid step's own. Else the heads share the part, all of them (``every``
+    None) or each run of ``every`` consecutive ones (grouped key/value
+    heads): its block is float32 and stays in VMEM across those steps of the
+    head axis (the innermost), the first head writes it and the others add
+    to it."""
     if head_axis is None:
         ref[0] = value.astype(ref.dtype)
         return
     h = pl.program_id(head_axis)
+    if every is not None:
+        h = h % every
 
     @pl.when(h == 0)
     def _():
@@ -568,7 +595,7 @@ def _store(ref, value, head_axis):
 
 
 def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
-                    k_axis=1, group=1):
+                    k_axis=1, group=1, rep=1):
     # The score tile is held transposed, [Bk, Bq]: dV += P^T dO and
     # dK += dS^T Q are then plain row-major dots, and lse / delta meet the
     # tile as rows [1, Bq] (a sublane broadcast) where the [Bq, Bk] form
@@ -627,16 +654,20 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
     # p of that key was zeroed in the loop.
     keep = keep_ref[0] > 0  # [Bk, 1]
     # shared: the k parts, then v
-    for dk_ref, dk, acc in zip(dk_refs, dks, shared):
-        _store(dk_ref, jnp.where(keep, dk * scale, 0.0), 2 if acc else None)
-    _store(dv_ref, jnp.where(keep, dv, 0.0), 2 if shared[-1] else None)
+    def store(ref, value, kind):
+        _store(ref, value, 2 if kind else None,
+               rep if kind == "grouped" else None)
+
+    for dk_ref, dk, kind in zip(dk_refs, dks, shared):
+        store(dk_ref, jnp.where(keep, dk * scale, 0.0), kind)
+    store(dv_ref, jnp.where(keep, dv, 0.0), shared[-1])
 
 
 def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
               dlse, causal, kinds):
     """``lse`` comes as the forward rule kept it, ``[.., Tp]``; ``dlse`` as
     the result's cotangent, ``[.., Tp, 1]``."""
-    (q_kinds, k_kinds, v_kind, heads, group, b, tp, widths, dvp, out,
+    (q_kinds, k_kinds, v_kind, heads, group, rep, b, tp, widths, dvp, out,
      vec) = _layout_of(kinds, qs, v, mask)
     # lse is a differentiable OUTPUT (ring-flash merge): its cotangent
     # enters the score gradient as dS = p*(dP - delta + dlse), i.e. the
@@ -678,9 +709,9 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         grid=grid,
         in_specs=[
             *q_specs,
-            *(_spec(kind, tp, w, pos, whole=True)
+            *(_spec(kind, tp, w, pos, whole=True, rep=rep)
               for kind, w in zip(k_kinds, widths)),
-            _spec(v_kind, tp, dvp, pos, whole=True),
+            _spec(v_kind, tp, dvp, pos, whole=True, rep=rep),
             _spec("row", 1, tp, pos, whole=True),
             _spec(out, block_q, dvp, pos),
             _spec(vec, block_q, 1, pos, group=group),
@@ -694,13 +725,14 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
 
     # dK/dV sees queries along the lanes: lse and delta as rows [.., 1, Tp]
     # (sliced at block_q offsets like the forward's mask), the key mask as a
-    # column [B, Tp, 1]. Where every head shares a part, the head is the
-    # innermost grid axis, so the part's float32 block stays in VMEM while
-    # the heads add to it (_store). Where no part is shared the key block is
+    # column [B, Tp, 1]. Where every head shares a part, or every ``rep``
+    # consecutive heads a key/value head, the head is the innermost grid
+    # axis, so the part's float32 block stays in VMEM while the heads that
+    # read it add to it (_store). Where no part is shared the key block is
     # innermost, as on the transposed path: a head step's whole-sequence Q
     # and dO are then fetched once, not once a key block.
-    shared = tuple(heads is not None and kind == "row"
-                   for kind in (*k_kinds, v_kind))
+    shared = tuple(kind if heads is not None and kind in ("row", "grouped")
+                   else None for kind in (*k_kinds, v_kind))
     k_axis = 1  # where the key block is in the grid
     if heads is None:
         grid, pos = (b, tp // block_k), lambda b, j: (b, 0, j)
@@ -711,9 +743,11 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         k_axis = 2
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
                                    scale=scale, precision=prec, causal=causal,
-                                   shared=shared, group=group, k_axis=k_axis)
-    k_specs = [_spec(kind, block_k, w, pos) for kind, w in zip(k_kinds, widths)]
-    v_spec = _spec(v_kind, block_k, dvp, pos)
+                                   shared=shared, group=group, k_axis=k_axis,
+                                   rep=rep)
+    k_specs = [_spec(kind, block_k, w, pos, rep=rep)
+               for kind, w in zip(k_kinds, widths)]
+    v_spec = _spec(v_kind, block_k, dvp, pos, rep=rep)
     *dks, dv = pl.pallas_call(
         dkv_kernel,
         grid=grid,
@@ -810,8 +844,12 @@ def count_call_sites():
 
 def _lane_kinds(qs, ks, v):
     """How each operand lies for the lane-indexed path (``_spec``'s kinds:
-    (q kinds, k kinds, v kind, head steps, heads a step)), or None where the
-    shapes leave the transposed path. A part with one head under several
+    (q kinds, k kinds, v kind, head steps, heads a step, query heads a
+    grouped key/value head)), or None where the shapes leave the transposed
+    path. A part of k, or v, a multiple of 128 lanes wide with FEWER heads
+    than q (each serving ``rep`` consecutive query heads: grouped-query
+    attention) is ``grouped``: the same ``[B, T, Hkv*w]`` view, query head h
+    at lane block ``h // rep``. A part with one head under several
     query heads is shared (``row``, any width: its block spans its whole
     last axis); a head width that is a multiple of 128 lanes is a lane block
     of ``[B, T, H*w]`` (``lane``); a narrower part of q is laid out ``[B, H,
@@ -820,26 +858,35 @@ def _lane_kinds(qs, ks, v):
     (g = 128 // w heads fill a lane block), with heads of their own, as many
     on each, a multiple of g: the same ``lane`` blocks, g heads a step.
     Anything else (an odd count of narrow heads, 80 or 96 lanes, a narrow
-    part of k or a narrow v with fewer heads than q) is the transposed
-    path's."""
+    part of k or a narrow v with fewer heads than q, whose heads are then
+    repeated first) is the transposed path's."""
     heads = qs[0].shape[2]
     width = v.shape[3]
     if (width in _PACKED_WIDTHS and heads % (_LANE // width) == 0
             and all(x.shape[2:] == (heads, width) for x in (*qs, *ks, v))):
         group = _LANE // width
         return (("lane",) * len(qs), ("lane",) * len(ks), "lane",
-                heads // group, group)
+                heads // group, group, 1)
+
+    # key/value heads that each serve ``rep`` consecutive query heads
+    kv_heads = {x.shape[2] for x in (*ks, v)} - {1, heads}
+    if len(kv_heads) > 1 or any(heads % n for n in kv_heads):
+        return None
+    rep = heads // min(kv_heads, default=heads)
 
     def kind(x, narrow=None):
         if x.shape[2] == 1 and heads > 1:
             return "row"
-        return "lane" if x.shape[3] % _LANE == 0 else narrow
+        if x.shape[3] % _LANE:
+            return narrow
+        return "lane" if x.shape[2] == heads else "grouped"
 
     k_kinds = tuple(kind(k) for k in ks)
     q_kinds = tuple(kind(q, "head" if kk == "row" else None)
                     for q, kk in zip(qs, k_kinds))
-    kinds = (q_kinds, k_kinds, kind(v), heads, 1)
-    if v.shape[3] % _LANE or None in q_kinds + k_kinds or "row" in q_kinds:
+    kinds = (q_kinds, k_kinds, kind(v), heads, 1, rep)
+    if (v.shape[3] % _LANE or None in q_kinds + k_kinds
+            or {"row", "grouped"} & set(q_kinds)):
         return None
     return kinds
 
@@ -928,7 +975,15 @@ def flash_attention_lse(
         return _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret,
                              causal, scale, kinds)
     # the transposed path copies anyway: one part each, a part of k with one
-    # head under parts with heads of their own broadcast to theirs
+    # head under parts with heads of their own broadcast to theirs, grouped
+    # key/value heads repeated to the query heads that read them
+    heads = qs[0].shape[2]
+
+    def spread(a):
+        return (jnp.repeat(a, heads // a.shape[2], axis=2)
+                if 1 < a.shape[2] < heads else a)
+
+    ks, v = [spread(a) for a in ks], spread(v)
     k_heads = max(a.shape[2] for a in ks)
     q = jnp.concatenate(qs, axis=-1)
     k = jnp.concatenate(
@@ -959,7 +1014,7 @@ def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
     """The operands where the model holds them: nothing is transposed,
     padded or broadcast but a narrow part of q (``_lane_kinds``) and, where
     the blocks do not divide it, the sequence."""
-    q_kinds, k_kinds, v_kind, steps, _ = kinds
+    q_kinds, k_kinds, v_kind, steps, _, rep = kinds
     b, t, h = qs[0].shape[:3]
     dv = v.shape[-1]
     t_multiple = math.lcm(block_q, block_k)
@@ -976,7 +1031,7 @@ def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
     if not interpret:
         _check_compilable(
             block_q, block_k, tp,
-            sum(pl.cdiv(a.shape[-1] // (steps if kind == "lane" else 1),
+            sum(pl.cdiv(a.shape[-1] // _lane_blocks(kind, steps, rep),
                         _LANE) * _LANE for a, kind in zip(ks, k_kinds)),
             dv, v.dtype)
     if pad_mask is None:

@@ -1,5 +1,5 @@
 """What the decoder families over a frozen base share (``models/jamba.py``,
-``models/deepseek.py``): RMSNorm, the adapted projection, SwiGLU, the
+``models/deepseek.py``, ``models/nemotron_h.py``): RMSNorm, the adapted projection, SwiGLU, the
 declaration of a named parameter tree, the split into per-client adapters and
 a base held once, and the form the base takes for a round (its matrices cast
 to the compute type and written into one stack per run of layers).
@@ -41,6 +41,10 @@ F32 = jnp.float32
 MLA_STREAM = "mla_stream"
 JAMBA_REMAT_KEEPS = FLASH_SAVED
 DEEPSEEK_REMAT_KEEPS = (*FLASH_SAVED, MLA_STREAM)
+# Nemotron-H's blocks are one mixer each: the attention block keeps the flash
+# calls' pair; a Mamba-2 or an expert block keeps nothing (the chunked scan's
+# chunk states are 4 MB a sequence and chunk, its decay tiles far more)
+NEMOTRON_REMAT_KEEPS = FLASH_SAVED
 
 
 # ---------------------------------------------------------------------------
@@ -201,14 +205,26 @@ def stack_by_writes(leaves):
     return out
 
 
+def _scan_member(tree, entry):
+    """What one trip of a run's ``lax.scan`` consumes: layer ``entry``'s
+    dict, or for a tuple of layer indices (a unit of unlike blocks that
+    repeats) the dict of its members' dicts under their place in the unit."""
+    if isinstance(entry, int):
+        return tree.get(f"layers_{entry}")
+    return {str(j): tree[f"layers_{i}"] for j, i in enumerate(entry)
+            if f"layers_{i}" in tree} or None
+
+
 def stack_runs(tree, runs, stack=jnp.stack):
     """The ``layers_<i>`` dicts of a tree (whole, or either half of the
-    split) stacked over each of ``runs`` (lists of layer indices that one
-    ``lax.scan`` covers), under ``runs/<k>``."""
+    split) stacked over each of ``runs`` (lists of what one ``lax.scan``
+    covers, a trip an entry: a layer index, or a tuple of them), under
+    ``runs/<k>``."""
     out = {k: v for k, v in tree.items() if not k.startswith("layers_")}
     stacked = {}
     for k, run in enumerate(runs):
-        members = [tree[f"layers_{i}"] for i in run if f"layers_{i}" in tree]
+        members = [m for m in (_scan_member(tree, e) for e in run)
+                   if m is not None]
         if members:
             stacked[str(k)] = jax.tree_util.tree_map(
                 lambda *leaves: stack(leaves), *members)

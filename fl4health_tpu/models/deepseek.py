@@ -242,10 +242,18 @@ def route(p, u, dims: DeepseekDims):
         return idx.astype(jnp.int32), w * dims.routed_scale
 
 
-def _expert(x, gate, up, down):
-    """One expert's SwiGLU over its rows, in the rows' type."""
+def swiglu_expert(x, gate, up, down):
+    """An expert body: SwiGLU over the expert's rows, in the rows' type
+    (three matrices an expert: this family's)."""
     with part("moe_experts"):
         return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def relu2_expert(x, up, down):
+    """An expert body: ``relu(x W_up)^2 W_down`` over the expert's rows (two
+    matrices an expert: ``models/nemotron_h.py``'s, on the latent width)."""
+    with part("moe_experts"):
+        return jnp.square(jax.nn.relu(x @ up)) @ down
 
 
 def _plan(idx, w, first: int, held: int):
@@ -280,16 +288,17 @@ def _tiles(count):
     return (count + TILE_ROWS - 1) // TILE_ROWS
 
 
-def _routed_fwd(first, x, idx, w, *experts):
-    """x [N, d], idx / w [N, K], experts = (gate, up, down) per held expert
-    -> sum over the held experts chosen of w * E(x), [N, d] float32."""
-    held = len(experts) // 3
+def _routed_fwd(first, body, n, x, idx, w, *experts):
+    """x [N, d], idx / w [N, K], experts = ``n`` matrices per held expert, in
+    ``body``'s order -> sum over the held experts chosen of w * body(x,
+    *matrices), [N, d] float32."""
+    held = len(experts) // n
     _, tok, w_sorted, starts, counts = _plan(idx, w, first, held)
     y = jnp.zeros(x.shape, F32)
     for e in range(held):
         def tile(t, y, e=e):
             _, rows, wt, live = _tile(t, starts[e], counts[e], tok, w_sorted)
-            out = _expert(x[rows], *experts[3 * e:3 * e + 3]).astype(F32)
+            out = body(x[rows], *experts[n * e:n * e + n]).astype(F32)
             return y.at[rows].add(
                 jnp.where(live[:, None], out * wt[:, None], 0.0))
 
@@ -297,10 +306,10 @@ def _routed_fwd(first, x, idx, w, *experts):
     return y
 
 
-def _routed_bwd(first, x, idx, w, dy, *experts):
+def _routed_bwd(first, body, n, x, idx, w, dy, *experts):
     """(dx [N, d] float32, dw [N, K] float32) of ``_routed_fwd``: the same
     tiles, each recomputing its expert's forward."""
-    held = len(experts) // 3
+    held = len(experts) // n
     order, tok, w_sorted, starts, counts = _plan(idx, w, first, held)
     dx, dw_sorted = jnp.zeros(x.shape, F32), jnp.zeros(w_sorted.shape, F32)
     for e in range(held):
@@ -309,7 +318,7 @@ def _routed_bwd(first, x, idx, w, dy, *experts):
             pos, rows, wt, live = _tile(t, starts[e], counts[e], tok,
                                         w_sorted)
             _, vjp = jax.vjp(
-                lambda xr, wr: _expert(xr, *experts[3 * e:3 * e + 3]).astype(
+                lambda xr, wr: body(xr, *experts[n * e:n * e + n]).astype(
                     F32) * wr[:, None], x[rows], wt)
             dxr, dwr = vjp(jnp.where(live[:, None], dy[rows], 0.0))
             old = jax.lax.dynamic_slice(dw_sorted, (pos,), (TILE_ROWS,))
@@ -351,9 +360,9 @@ def _fold_clients(fn, n_row_args: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _routed_fn(first: int):
-    fwd = _fold_clients(functools.partial(_routed_fwd, first), 3)
-    bwd = _fold_clients(functools.partial(_routed_bwd, first), 4)
+def _routed_fn(first: int, body, n: int):
+    fwd = _fold_clients(functools.partial(_routed_fwd, first, body, n), 3)
+    bwd = _fold_clients(functools.partial(_routed_bwd, first, body, n), 4)
 
     @jax.custom_vjp
     def routed(x, idx, w, *experts):
@@ -372,13 +381,35 @@ def _routed_fn(first: int):
     return routed
 
 
-def routed_experts(x, idx, w, experts, first_expert_held: int):
+def routed_experts(x, idx, w, experts, first_expert_held: int,
+                   body=swiglu_expert):
     """sum_k [idx_k held here] * w_k * E_{idx_k}(x): x [N, d] in the compute
     type, idx [N, K] over all the layer's experts, w [N, K] float32,
-    ``experts`` the held ones' ``(gate [d, f], up [d, f], down [f, d])`` in
-    order from ``first_expert_held``. Returns [N, d] float32."""
-    flat = [m for triple in experts for m in triple]
-    return _routed_fn(int(first_expert_held))(x, idx, w, *flat)
+    ``experts`` the held ones' matrices in order from ``first_expert_held``,
+    each a tuple in the order ``body(rows, *matrices)`` takes them (``(gate
+    [d, f], up [d, f], down [f, d])`` for ``swiglu_expert``). ``body`` is a
+    module-level function (it keys the cached ``custom_vjp``). Returns [N,
+    d] float32."""
+    flat = [m for mats in experts for m in mats]
+    return _routed_fn(int(first_expert_held), body, len(experts[0]))(
+        x, idx, w, *flat)
+
+
+def routed_layer(x, u, router, experts, first_expert_held: int, rule,
+                 body=swiglu_expert):
+    """The routed part of an expert layer, both families' one
+    implementation: ``rule(router, u) -> (idx [N, K] int32 over ALL the
+    layer's experts, w [N, K] float32)`` is the family's scoring rule over
+    the router's input ``u`` [N, d_router] (``route`` here: softmax, the
+    group limit, unnormalised; ``models/nemotron_h.py sigmoid_route``:
+    sigmoid, a selection bias, renormalised and scaled), ``body`` its expert
+    (``swiglu_expert`` / ``relu2_expert``) over the rows ``x`` [N, d] the
+    experts read (``u`` itself, or a latent of it), cast here to the
+    experts' type. The plan, the tiles, the client fold and the
+    frozen-expert VJP are ``routed_experts``'."""
+    idx, w = rule(router, u)
+    return routed_experts(x.astype(experts[0][0].dtype), idx, w, experts,
+                          first_expert_held, body)
 
 
 def moe(p, u, dims: DeepseekDims):
@@ -386,12 +417,12 @@ def moe(p, u, dims: DeepseekDims):
     dt = dims.dtype
     flat = u.reshape(-1, u.shape[-1])
     with part("moe"):
-        idx, w = route(p["gate"], flat, dims)
         experts = [tuple(p[f"experts_{j}"][name]["kernel"].astype(dt)
                          for name in ("gate_proj", "up_proj", "down_proj"))
                    for j in range(dims.experts_held)]
-        y = routed_experts(flat.astype(dt), idx, w, experts,
-                           dims.first_expert_held)
+        y = routed_layer(flat, flat, p["gate"], experts,
+                         dims.first_expert_held,
+                         lambda router, u: route(router, u, dims))
     with part("shared_experts"):
         shared = swiglu(p["shared_experts"], u, dims)
     return y.reshape(u.shape).astype(dt) + shared

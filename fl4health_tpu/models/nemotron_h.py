@@ -1,0 +1,427 @@
+"""Hybrid Mamba-2 / attention / latent mixture-of-experts decoder for
+federated adapter fine-tuning (the Nemotron-H family: NVIDIA 2025,
+arXiv:2504.03624; HF ``model_type: nemotron_h``).
+
+Parity surface: /root/reference/examples/fedllm_example — LoRA adapters
+trained federally over a frozen causal LM that every client loads once.
+
+A block is ONE mixer behind one RMSNorm and a residual add: ``h <- h +
+mixer_i(RMSNorm(h))``, the kind of block ``i`` read from character ``i`` of
+``pattern`` (HF ``hybrid_override_pattern``):
+
+- ``M``, Mamba-2 (``H`` heads of ``P``, ``G`` groups, state ``N``): ``[z |
+  xBC | dt] = in_proj(u)``; ``xBC <- silu(causal_depthwise_conv(xBC))``,
+  split ``x [H, P]``, ``B [G, N]``, ``C [G, N]``; ``dt = softplus(dt +
+  dt_bias)`` a head, ``a = -exp(A_log)`` a head; the scalar-decay recurrence
+  of ``kernels/ssd_scan.py`` in its chunked form (matmuls), plus ``D x``;
+  ``y <- RMSNorm_group(y * silu(z))`` (gate first, then a norm over each
+  group's lanes, times a scale of ``H * P``); ``out_proj``. Causal, and pad
+  positions sit at the tail, so no mask enters it.
+- ``*``, attention: q / k / v / o without bias, ``n_heads`` query heads over
+  ``n_kv_heads`` key/value heads of ``head_dim``, causal, scale
+  ``head_dim^-0.5``, NO positions (the state-space blocks carry them). The
+  attention function gets k / v with their own ``n_kv_heads``: the flash
+  calls address grouped heads where the model holds them (query head ``h``
+  reads key head ``h // (n_heads / n_kv_heads)``).
+- ``E``, mixture of experts in a latent: ``s = sigmoid(u W_r)`` over ALL
+  ``n_routed_experts`` in float32; the ``top_k`` largest of ``s + b`` (``b``
+  the selection bias: it picks, it does not weigh); ``w_k = routed_scale *
+  s_k / (sum_chosen s + 1e-20)``; ``l = u W_down_latent``; ``E_j(l) =
+  relu(l W_j_up)^2 W_j_down``; ``y = (sum_{k: idx_k held here} w_k
+  E_{idx_k}(l)) W_up_latent + relu(u W_s_up)^2 W_s_down`` (the shared expert
+  on the full width). The module is told which experts it HOLDS
+  (``experts_held`` from ``first_expert_held``: a chip's share under expert
+  parallelism), routes over all of them and adds its own only; the routed
+  part is ``models/deepseek.py routed_layer`` with this family's scoring
+  rule (``sigmoid_route``) and expert body (``relu2_expert``).
+
+Then the final RMSNorm and HF's last-non-pad-token ``score`` head (token id
+0 is padding, at the tail). Left out: the multi-token-prediction block, the
+balance loss (routers are frozen), the output head.
+
+Adapters (``lora_rank``) sit on the projections every token goes through:
+``in_proj`` / ``out_proj``, q / k / v / o and the shared expert's two
+matrices. The two latent projections carry none: the latent feeds and
+collects the routed experts only, so their adapters' gradient would exist
+only where a token picked an expert held here and would change by a whole
+contribution when a near-tied pick falls the other way. Under a loss that
+reads one token a sequence that is the gradient of a handful of picks: on the
+v5e the bfloat16 program and a float32 reference differed by 27 % on such a
+leaf's first update where every other leaf agreed to 6 % (``PERF.md``
+section 6, PR 41). Routed experts, routers, norms, the conv,
+``A_log`` / ``D`` / ``dt_bias`` and the embedding are frozen as well.
+
+Built the way ``models/jamba.py`` and ``models/deepseek.py`` are (a named
+parameter tree declared by a flax module, pure functions over one block's
+dict, ``per_client_param`` / ``bind_shared`` for the engine), on
+``models/decoder_common.py``. The published pattern alternates, so a run of
+LIKE blocks would be one block long; what repeats is a unit (``ME ME ME``):
+``runs()`` cuts the pattern into units of one or two blocks that repeat, and
+each run is one ``lax.scan`` over its stacked units, every block
+rematerialised on its own under ``remat`` less the flash calls' ``out`` /
+``lse`` (``decoder_common.NEMOTRON_REMAT_KEEPS``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from fl4health_tpu.core.pytree import merge_trees
+from fl4health_tpu.kernels.ssd_scan import n_chunks, ssd_scan
+from fl4health_tpu.models import decoder_common as common
+from fl4health_tpu.models.decoder_common import F32, lora_dense, rms_norm
+from fl4health_tpu.models.deepseek import relu2_expert, routed_layer
+from fl4health_tpu.models.jamba import causal_depthwise_conv
+from fl4health_tpu.observability.stages import layer as part
+
+MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+# the longest unit of unlike blocks that ``runs()`` looks for a repeat of
+MAX_UNIT = 2
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHDims:
+    """The sizes and static choices the block functions read."""
+
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_groups: int
+    ssm_state: int
+    chunk: int
+    experts_held: int
+    first_expert_held: int
+    top_k: int
+    routed_scale: float
+    rms_eps: float
+    lora_scale: float  # alpha / rank (0 without adapters)
+    dtype: Any
+    attention_fn: Any
+
+
+# ---------------------------------------------------------------------------
+# The mathematics: pure functions over one block's parameter dict
+# ---------------------------------------------------------------------------
+
+def ssd_mixer(p, u, dims: NemotronHDims):
+    with part("ssd_mixer"):
+        h, hp = dims.ssm_heads, dims.ssm_head_dim
+        d_inner, gn = h * hp, dims.ssm_groups * dims.ssm_state
+        z, xbc, dt = jnp.split(lora_dense(p["in_proj"], u, dims),
+                               [d_inner, 2 * d_inner + 2 * gn], axis=-1)
+        xbc = jax.nn.silu(causal_depthwise_conv(p["conv1d"], xbc)).astype(
+            dims.dtype)
+        x, b, c = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
+        x = x.reshape(*x.shape[:-1], h, hp)
+        b, c = (v.reshape(*v.shape[:-1], dims.ssm_groups, dims.ssm_state)
+                for v in (b, c))
+        # the time step and the decay, a head each, in float32
+        dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"])
+        y = ssd_scan(x, dt, -jnp.exp(p["A_log"].astype(F32)), b, c,
+                     dims.chunk)
+        y = y + p["D"].astype(F32)[:, None] * x.astype(F32)
+        # gate first, then the norm over each group's lanes
+        y = y.reshape(*y.shape[:-2], dims.ssm_groups, -1) * jax.nn.silu(
+            z.astype(F32)).reshape(*z.shape[:-1], dims.ssm_groups, -1)
+        y = rms_norm(y, p["norm"]["scale"].reshape(dims.ssm_groups, -1),
+                     dims.rms_eps)
+        return lora_dense(p["out_proj"], y.reshape(*y.shape[:-2], d_inner),
+                          dims)
+
+
+def gqa_attention(p, u, pad_mask, dims: NemotronHDims):
+    """``dims.attention_fn(q, k, v, pad_mask=mask) -> out`` must be causal
+    and take k / v with FEWER heads than q, each serving ``n_heads /
+    n_kv_heads`` consecutive query heads (``kernels.flash_attention``
+    does); ``None`` is the dense form over the repeated heads."""
+    with part("attention"):
+        def heads(name, n):
+            y = lora_dense(p[name], u, dims)
+            return y.reshape(*y.shape[:-1], n, dims.head_dim)
+
+        q = heads("q_proj", dims.n_heads)
+        k, v = heads("k_proj", dims.n_kv_heads), heads("v_proj", dims.n_kv_heads)
+        with part("gqa_flash"):
+            if dims.attention_fn is None:
+                rep = dims.n_heads // dims.n_kv_heads
+                out = common.dense_causal_attention(
+                    q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+                    pad_mask)
+            else:
+                out = dims.attention_fn(q, k, v, pad_mask=pad_mask)
+        return lora_dense(p["o_proj"], out.reshape(*out.shape[:-2], -1), dims)
+
+
+def sigmoid_route(p, u, top_k: int, routed_scale: float):
+    """This family's scoring rule over ALL the layer's experts, in float32
+    at full precision (a near tie decides which expert a token gets): u [N,
+    d] -> (idx [N, top_k] int32, w [N, top_k] float32). The selection bias
+    enters the choice and not the weight; the chosen scores are
+    renormalised, then scaled."""
+    with part("moe_router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            u.astype(F32), p["kernel"].astype(F32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(scores + p["e_score_correction_bias"], top_k)
+        chosen = jnp.take_along_axis(scores, idx, axis=1)
+        w = routed_scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w
+
+
+def relu2_mlp(p, u, dims: NemotronHDims):
+    return lora_dense(p["down_proj"], jnp.square(jax.nn.relu(
+        lora_dense(p["up_proj"], u, dims))), dims)
+
+
+def latent_moe(p, u, pad_mask, dims: NemotronHDims):
+    """The routed layer's part held here, through the latent, plus the
+    shared expert. A pad position picks no expert here: it lies behind the
+    last token anything reads (every mixer is causal), its stream settles
+    to one vector whose picks are all alike, and a held expert among them
+    would get every pad position of the batch as rows: the tiles follow the
+    tokens, not the draw's padding."""
+    dt = dims.dtype
+    flat = u.reshape(-1, u.shape[-1])
+    live = pad_mask.reshape(-1, 1) > 0
+
+    def rule(router, x):
+        idx, w = sigmoid_route(router, x, dims.top_k, dims.routed_scale)
+        # -1 is an expert held nowhere: the plan sorts such pairs behind
+        # every held expert's rows, into no tile
+        return jnp.where(live, idx, -1), w
+
+    with part("moe"):
+        with part("moe_latent"):
+            latent = lora_dense(p["fc1_latent_proj"], flat, dims)
+        experts = [tuple(p[f"experts_{j}"][name]["kernel"].astype(dt)
+                         for name in ("up_proj", "down_proj"))
+                   for j in range(dims.experts_held)]
+        y = routed_layer(
+            latent, flat, p["gate"], experts, dims.first_expert_held, rule,
+            relu2_expert)
+        with part("moe_latent"):
+            y = lora_dense(p["fc2_latent_proj"], y, dims)
+    with part("shared_experts"):
+        shared = relu2_mlp(p["shared_experts"], u, dims)
+    return y.reshape(shared.shape) + shared
+
+
+def block(p, h, pad_mask, kind: str, dims: NemotronHDims):
+    u = rms_norm(h, p["norm"]["scale"], dims.rms_eps)
+    if kind == MAMBA:
+        return h + ssd_mixer(p["mixer"], u, dims)
+    if kind == ATTENTION:
+        return h + gqa_attention(p["mixer"], u, pad_mask, dims)
+    return h + latent_moe(p["mixer"], u, pad_mask, dims)
+
+
+def pattern_runs(pattern: str) -> list[list[tuple[int, ...]]]:
+    """The pattern cut into runs that one ``lax.scan`` each covers: a run is
+    a unit of one or ``MAX_UNIT`` blocks and its immediate repeats, a trip a
+    unit (a tuple of block indices). Greedy from the left, the unit that
+    covers most. ``MEMEMEM*EME`` -> ``[(0, 1), (2, 3), (4, 5)]``, ``[(6,)]``,
+    ``[(7,)]``, ``[(8,)]``, ``[(9,)]``, ``[(10,)]``."""
+    runs, i = [], 0
+    while i < len(pattern):
+        best = (1, 1)
+        for size in range(1, MAX_UNIT + 1):
+            unit, reps = pattern[i:i + size], 1
+            while pattern[i + reps * size:i + (reps + 1) * size] == unit:
+                reps += 1
+            # a longer unit has to repeat to be worth a body of its own
+            if len(unit) == size and (size == 1 or reps > 1) and (
+                    size * reps > best[0] * best[1]):
+                best = (size, reps)
+        size, reps = best
+        runs.append([tuple(range(i + k * size, i + (k + 1) * size))
+                     for k in range(reps)])
+        i += size * reps
+    return runs
+
+
+# ---------------------------------------------------------------------------
+# The module
+# ---------------------------------------------------------------------------
+
+class NemotronHClassifier(nn.Module):
+    """Input: integer token ids [B, T], id 0 = padding at the tail."""
+
+    vocab_size: int
+    n_classes: int
+    pattern: str = "ME*E"
+    d_model: int = 64
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    ssm_heads: int = 4
+    ssm_head_dim: int = 8
+    ssm_groups: int = 2
+    ssm_state: int = 16
+    d_conv: int = 4
+    chunk: int = 128
+    n_routed_experts: int = 16  # the router's width
+    experts_held: int = 16
+    first_expert_held: int = 0
+    top_k: int = 4
+    routed_scaling_factor: float = 1.0
+    d_latent: int = 32
+    d_expert: int = 48  # one routed expert, on the latent
+    d_shared: int = 96  # the shared expert, on the full width
+    rms_eps: float = 1e-5
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    dtype: Any = jnp.float32
+    remat: bool = False  # rematerialise each block on the backward pass
+    attention_fn: Any = None  # causal, grouped key/value heads; None = dense
+
+    # -- structure ----------------------------------------------------------
+    @property
+    def dims(self) -> NemotronHDims:
+        if set(self.pattern) - {MAMBA, EXPERTS, ATTENTION}:
+            raise ValueError(f"pattern {self.pattern!r}: a block is one of "
+                             f"{MAMBA} {EXPERTS} {ATTENTION}")
+        if self.n_heads % self.n_kv_heads or self.ssm_heads % self.ssm_groups:
+            raise ValueError("query heads divide into key/value heads and "
+                             "state-space heads into groups")
+        if not (0 <= self.first_expert_held and self.first_expert_held
+                + self.experts_held <= self.n_routed_experts):
+            raise ValueError(
+                f"experts {self.first_expert_held}.."
+                f"{self.first_expert_held + self.experts_held - 1} are not "
+                f"among the router's {self.n_routed_experts}")
+        return NemotronHDims(
+            self.d_model, self.n_heads, self.n_kv_heads, self.head_dim,
+            self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+            self.ssm_state, self.chunk, self.experts_held,
+            self.first_expert_held, self.top_k, self.routed_scaling_factor,
+            self.rms_eps,
+            self.lora_alpha / self.lora_rank if self.lora_rank else 0.0,
+            self.dtype, self.attention_fn)
+
+    def runs(self) -> list[list[tuple[int, ...]]]:
+        return pattern_runs(self.pattern)
+
+    def _block_spec(self, kind: str) -> tuple:
+        d, r = self.d_model, self.lora_rank
+        proj, norm = common.proj_spec, common.norm_spec
+        if kind == MAMBA:
+            d_inner = self.ssm_heads * self.ssm_head_dim
+            conv = d_inner + 2 * self.ssm_groups * self.ssm_state
+            mixer = (
+                proj("in_proj", d, d_inner + conv + self.ssm_heads, r),
+                ("conv1d", (("kernel", ((self.d_conv, conv), "matrix")),
+                            ("bias", ((conv,), "zeros")))),
+                ("dt_bias", ((self.ssm_heads,), "zeros")),
+                ("A_log", ((self.ssm_heads,), "zeros")),
+                ("D", ((self.ssm_heads,), "ones")),
+                ("norm", norm(d_inner)),
+                proj("out_proj", d_inner, d, r))
+        elif kind == ATTENTION:
+            mixer = (proj("q_proj", d, self.n_heads * self.head_dim, r),
+                     proj("k_proj", d, self.n_kv_heads * self.head_dim, r),
+                     proj("v_proj", d, self.n_kv_heads * self.head_dim, r),
+                     proj("o_proj", self.n_heads * self.head_dim, d, r))
+        else:
+            # routed experts and the router are frozen and unadapted; every
+            # expert's leaves have names of their own
+            mixer = (
+                ("gate", (("kernel", ((d, self.n_routed_experts), "matrix")),
+                          ("e_score_correction_bias",
+                           ((self.n_routed_experts,), "zeros")))),
+                # the latent is read and written by the routed experts alone,
+                # so an adapter on either projection would take its gradient
+                # through a token's discrete picks (module docstring): none
+                proj("fc1_latent_proj", d, self.d_latent, 0),
+                proj("fc2_latent_proj", self.d_latent, d, 0),
+                *((f"experts_{j}", (
+                    proj("up_proj", self.d_latent, self.d_expert, 0),
+                    proj("down_proj", self.d_expert, self.d_latent, 0)))
+                  for j in range(self.experts_held)),
+                ("shared_experts", (proj("up_proj", d, self.d_shared, r),
+                                    proj("down_proj", self.d_shared, d, r))))
+        return ("norm", norm(d)), ("mixer", mixer)
+
+    # -- forward ------------------------------------------------------------
+    @nn.compact
+    def __call__(self, x, train: bool = True):
+        del train  # no dropout, no batch statistics
+        d = self.d_model
+        spec = [("embed_tokens", (("embedding", ((self.vocab_size, d),
+                                                 "embed")),)),
+                ("norm_f", common.norm_spec(d)),
+                ("score", (("kernel", ((d, self.n_classes), "matrix")),))]
+        spec += [(f"layers_{i}", self._block_spec(kind))
+                 for i, kind in enumerate(self.pattern)]
+        params = {name: common.Leaves(entry, name=name)()
+                  for name, entry in spec}
+        return self.forward(common.stack_runs(params, self.runs()), x)
+
+    def forward(self, stacked, x):
+        """``stacked``: the tree with its blocks stacked by
+        ``decoder_common.stack_runs`` over ``runs()``; each run is one
+        ``lax.scan``, a trip one unit of the pattern."""
+        dims = self.dims
+        pad_mask = (x > 0).astype(F32)
+        h = common.embed_tokens(stacked["embed_tokens"]["embedding"], x,
+                                self.dtype)
+        for k, run in enumerate(self.runs()):
+            kinds = [self.pattern[i] for i in run[0]]
+
+            def body(h_, unit, kinds=kinds):
+                for j, kind in enumerate(kinds):
+                    one = common.remat_layers(
+                        lambda h__, p, kind=kind: block(
+                            p, h__, pad_mask, kind, dims).astype(self.dtype),
+                        self.remat, common.NEMOTRON_REMAT_KEEPS)
+                    h_ = one(h_, unit[str(j)])
+                return h_, None
+
+            h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
+        return common.last_token_logits(
+            h, pad_mask, stacked["norm_f"]["scale"],
+            stacked["score"]["kernel"], self.rms_eps)
+
+    # -- the split of the parameters (clients/engine.py ModelDef) ----------
+    def per_client_param(self, path: str) -> bool:
+        return common.PER_CLIENT(path)
+
+    def prepare_shared(self, shared):
+        """The base in the form every client step of a round consumes: each
+        projection's and expert's ``kernel`` in the compute type (the
+        router's, the conv's taps, the norms, ``A_log`` / ``D`` /
+        ``dt_bias`` and the embedding stay float32), the blocks stacked over
+        their runs, each cast writing its slice of the stack."""
+        return common.prepare_shared(
+            shared, self.runs(), self.dtype,
+            lambda names: names[-1] == "kernel"
+            and names[-2] not in ("gate", "conv1d"))
+
+    def bind_shared(self, shared):
+        """``(per_client, x) -> (preds, features)`` over a base prepared
+        here, once a round."""
+        with part("shared_cast"):
+            prepared = self.prepare_shared(shared)
+        return lambda per_client, x: self.forward(
+            merge_trees(prepared, common.stack_runs(per_client, self.runs())),
+            x)
+
+    def build_gauges(self, batch_shape, n_clients: int) -> dict:
+        """Static facts of the state-space and routed blocks, which path the
+        forward's flash calls take and what the remat sites keep, for the
+        simulation's build-time gauges; ``batch_shape`` is one client's
+        [B, T]."""
+        return {"ssd_chunks": n_chunks(batch_shape[-1], self.chunk),
+                "ssd_heads": self.ssm_heads,
+                "moe_experts_held": self.experts_held,
+                "moe_router_width": self.n_routed_experts,
+                "moe_top_k": self.top_k,
+                **common.attention_gauges(self, batch_shape, n_clients,
+                                          common.NEMOTRON_REMAT_KEEPS)}

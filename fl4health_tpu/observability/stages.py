@@ -39,20 +39,29 @@ The canonical stages (:data:`SPINE_STAGES`; an op belongs to the LAST
 
 The parts of the client step (:data:`LAYER_SCOPES`; they nest, and an op
 counts for EVERY ``fl_layer::`` its name stack holds, :func:`layers_of`).
-The same names in all three model families, so one reader serves every
+The same names in all four model families, so one reader serves every
 cell:
 
 - ``embed`` / ``head`` — token (and position) embedding; pooling or the
   last-token gather and the classifier product
 - ``attention``     — projections, the flash call or the dense scores,
-  ``o_proj`` (models/transformer.py, models/jamba.py)
+  ``o_proj`` (models/transformer.py, models/jamba.py, models/nemotron_h.py)
+- ``gqa_flash``     — inside ``attention``: the three flash calls over
+  grouped key/value heads and what surrounds them (models/nemotron_h.py),
+  as ``mla_flash`` is inside ``mla_attention``
 - ``mla_attention`` ⊃ ``mla_flash`` — latent attention and its flash calls
   (models/deepseek.py)
 - ``mamba_mixer`` ⊃ ``ssm_scan`` — the state-space mixer and its scan
   (models/jamba.py, kernels/selective_scan.py)
+- ``ssd_mixer`` ⊃ ``ssd_scan`` — the scalar-decay (Mamba-2) mixer and its
+  chunked recurrence alone, from the split of ``xBC`` to ``y`` before the
+  gated norm (models/nemotron_h.py, kernels/ssd_scan.py)
 - ``mlp``           — the dense feed-forward (GELU or SwiGLU)
 - ``moe`` ⊃ ``moe_router``, ``moe_experts``; ``shared_experts`` beside it
-  (models/deepseek.py), disjoint from ``mlp``
+  (models/deepseek.py, models/nemotron_h.py), disjoint from ``mlp``
+- ``moe_latent``    — inside ``moe``: the two projections between the
+  model's width and the latent the routed experts work in
+  (models/nemotron_h.py)
 - ``norm``          — LayerNorm / RMSNorm, wherever one lies (so it nests
   in ``mamba_mixer`` / ``mla_attention``)
 - ``lora``          — an adapted projection's adapter branch only
@@ -106,10 +115,14 @@ LAYER_SCOPES = (
     "mla_flash",
     "mamba_mixer",
     "ssm_scan",
+    "ssd_mixer",
+    "ssd_scan",
+    "gqa_flash",
     "mlp",
     "moe",
     "moe_router",
     "moe_experts",
+    "moe_latent",
     "shared_experts",
     "norm",
     "lora",

@@ -218,4 +218,5 @@ def test_fewer_key_heads_than_query_heads_are_not_packed():
     q = jnp.ones((B, T, 4, 64))
     kv = jnp.ones((B, T, 2, 64))
     assert _lane_kinds((q,), (kv,), kv) is None
-    assert _lane_kinds((q,), (q,), q) == (("lane",), ("lane",), "lane", 2, 2)
+    assert _lane_kinds((q,), (q,), q) == (("lane",), ("lane",), "lane", 2, 2,
+                                          1)

@@ -7,7 +7,9 @@ causal over one shared key/value head at the adapter cell's (20 heads of 128,
 blocks 512/512: lane-indexed, the shared head's gradients one float32 array a
 client), the same three causal over latent attention's parts (128 heads; q
 and k as 128 lanes without positions and 64 rotary, the rotary key ONE head;
-v 128, T 1,024, a static scale: lane-indexed, nothing padded to 256), and the
+v 128, T 1,024, a static scale: lane-indexed, nothing padded to 256), the
+same three causal over grouped key/value heads at the Nemotron-H cell's (32
+query heads of 128 over 2), and the
 adapter cell's two selective-scan calls (4 clients x 2,048
 positions x 5,120 channels x 16 states). Nothing runs: the TPU's compiler
 works against a described chip. The only file that describes a topology;
@@ -146,6 +148,33 @@ def test_causal_shared_head_call_compiles_for_the_v5e(causal_calls, name):
     assert lines, f"no tpu_custom_call named {name}: {sorted(causal_calls)}"
     for line in lines:
         assert _result_shapes(line) == CAUSAL_KERNELS[name], (name, line)
+    assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
+# the Nemotron-H cell's attention block: 4 clients x batch 1 x 32 query heads
+# of 128 over TWO key/value heads, T 2,048, causal, blocks 512/512.
+# Lane-indexed with grouped key/value heads: q, the output and dQ are [.., T,
+# 32 * 128], k / v [.., T, 2 * 128] read at lane block h // 16; dK and dV
+# leave the call summed over each group's 16 heads in float32
+GQA_ROWS, GQA_KV = "bf16[4,1,2048,4096]", "f32[4,1,2048,256]"
+GQA_KERNELS = {
+    "flash_fwd": (GQA_ROWS, "f32[4,1,32,2048,1]"),
+    "flash_dq": (GQA_ROWS,),
+    "flash_dkv": (GQA_KV, GQA_KV),
+}
+
+
+@pytest.fixture(scope="module")
+def gqa_calls(one_chip):
+    return _compiled_calls(one_chip, 4, 1, 2048, 32, 2, 128, 512, causal=True)
+
+
+@pytest.mark.parametrize("name", sorted(GQA_KERNELS))
+def test_grouped_heads_call_compiles_for_the_v5e(gqa_calls, name):
+    lines = gqa_calls.get(name)
+    assert lines, f"no tpu_custom_call named {name}: {sorted(gqa_calls)}"
+    for line in lines:
+        assert _result_shapes(line) == GQA_KERNELS[name], (name, line[:400])
     assert len(lines) == (2 if name == "flash_fwd" else 1)
 
 

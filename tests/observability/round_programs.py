@@ -19,6 +19,7 @@ from fl4health_tpu.metrics.base import MetricManager
 from fl4health_tpu.models.cnn import Mlp
 from fl4health_tpu.models.deepseek import DeepseekV2Classifier
 from fl4health_tpu.models.jamba import JambaClassifier
+from fl4health_tpu.models.nemotron_h import NemotronHClassifier
 from fl4health_tpu.models.transformer import TransformerClassifier
 from fl4health_tpu.observability import (
     MetricsRegistry,
@@ -129,7 +130,7 @@ def lowered_programs(sim, mode=EXEC_PIPELINED, n_rounds=1):
 
 
 def family_module(family, **overrides):
-    """A toy module of one of the cells' three model families."""
+    """A toy module of one of the cells' four model families."""
     if family == "transformer":
         return TransformerClassifier(**{**dict(
             vocab_size=50, n_classes=N_CLASSES, d_model=16, n_heads=2,
@@ -146,6 +147,18 @@ def family_module(family, **overrides):
             n_routed_experts=8, experts_held=4, first_expert_held=2,
             n_group=4, topk_group=2, top_k=3, lora_rank=2, remat=True,
             dtype=jnp.bfloat16, attention_fn=flash), **overrides})
+    if family == "nemotron":
+        # a unit that repeats, then an attention and an expert block, over a
+        # shared base: 4 of 8 experts held, grouped key/value heads through
+        # the flash calls, the chunked scan over two chunks
+        return NemotronHClassifier(**{**dict(
+            vocab_size=50, n_classes=N_CLASSES, pattern="MEME*E", d_model=16,
+            n_heads=4, n_kv_heads=2, head_dim=8, ssm_heads=4, ssm_head_dim=4,
+            ssm_groups=2, ssm_state=4, chunk=4, n_routed_experts=8,
+            experts_held=4, first_expert_held=2, top_k=3,
+            routed_scaling_factor=2.5, d_latent=8, d_expert=8, d_shared=16,
+            lora_rank=2, remat=True, dtype=jnp.bfloat16, attention_fn=flash),
+            **overrides})
     # one Mamba layer and one attention layer over a shared base
     return JambaClassifier(**{**dict(
         vocab_size=50, n_classes=N_CLASSES, d_model=16, n_layers=2,

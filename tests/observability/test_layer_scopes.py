@@ -54,6 +54,7 @@ FAMILIES = {
     "jamba": dict(remat=True, dtype=jnp.bfloat16,
                   attention_fn=functools.partial(FLASH, causal=True)),
     "deepseek": {},
+    "nemotron": {},
 }
 DECLARED = {
     "transformer": ("embed", "attention", "mlp", "norm", "lora", "head",
@@ -63,11 +64,15 @@ DECLARED = {
     "deepseek": ("embed", "mla_attention", "mla_flash", "mlp", "moe",
                  "moe_router", "moe_experts", "shared_experts", "norm",
                  "lora", "head", "optimizer", "shared_cast"),
+    "nemotron": ("embed", "attention", "gqa_flash", "ssd_mixer", "ssd_scan",
+                 "moe", "moe_router", "moe_experts", "moe_latent",
+                 "shared_experts", "norm", "lora", "head", "optimizer",
+                 "shared_cast"),
 }
 # a frozen base's embedding has no gradient; XLA:CPU folds the cast's
 # transpose (a gradient back to float32) into the product that feeds it
 FORWARD_ONLY = {("jamba", "embed"), ("deepseek", "embed"),
-                ("transformer", "param_cast")}
+                ("nemotron", "embed"), ("transformer", "param_cast")}
 
 
 def _op_names(lowered):

@@ -172,7 +172,8 @@ def _model_fit_text(family):
 
 
 @pytest.mark.parametrize("stage", ["local_train", "server_update"])
-@pytest.mark.parametrize("family", ["transformer", "jamba", "deepseek"])
+@pytest.mark.parametrize("family", ["transformer", "jamba", "deepseek",
+                                    "nemotron"])
 def test_the_cells_model_families_keep_both_stage_scopes(family, stage):
     """``local_train_ms_per_round`` and ``server_update_ms_per_round`` read
     exactly these two strings out of a trace of ``fit_round_t``."""
@@ -201,3 +202,30 @@ def test_the_expert_familys_layer_scopes_reach_the_compiled_round(scope, op):
     if scope in ("moe_router", "moe_experts"):  # they nest in the layer's
         assert all("fl_layer::moe/" in line or "fl_layer::moe)" in line
                    for line in lines), scope
+
+
+@pytest.mark.parametrize("scope,op,inside", [
+    ("ssd_mixer", "dot", None), ("ssd_scan", "exponential", "ssd_mixer"),
+    ("attention", "dot", None), ("gqa_flash", "dynamic_slice", "attention"),
+    ("moe", "dot_general", None), ("moe_latent", "dot", "moe"),
+    ("moe_router", "dot", "moe"), ("moe_experts", "dot_general", "moe"),
+    ("shared_experts", "dot", None), ("shared_cast", "convert", None)])
+def test_the_hybrid_familys_layer_scopes_reach_the_compiled_round(scope, op,
+                                                                  inside):
+    """``ssd_mixer_ms_per_round``, ``ssd_scan_ms_per_round`` /
+    ``ssd_scan_roofline_pct``, ``gqa_flash_roofline_pct``,
+    ``moe_latent_ms_per_round`` and ``routed_experts_roofline_pct`` read
+    these strings out of a trace of ``fit_round_t``: each is in the name
+    stack of an op of the kind it should hold (the decays' exponentials, the
+    interpreted kernel's block slices, the router's logits, the products),
+    on the forward and, but for the once-a-round cast, on the backward pass,
+    and the inner scopes nest in their outer one."""
+    lines = [line for line in _model_fit_text("nemotron").splitlines()
+             if f"fl_layer::{scope}" in line]
+    assert any(re.match(rf"\s*(ROOT )?%\w*{op}", line) for line in lines), (
+        scope, len(lines))
+    if scope != "shared_cast":
+        assert any("transpose(" in line for line in lines), scope
+    if inside:
+        assert all(f"fl_layer::{inside}/" in line
+                   or f"fl_layer::{inside})" in line for line in lines), scope
