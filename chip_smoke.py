@@ -21,8 +21,9 @@ default ``Observability()`` — at the full width of a model the repo builds
 3. long_context  4L d512 seq-2048 through the Pallas flash kernel under
                  remat: the lowered round program holds the Mosaic custom
                  call (the kernel did not interpret)
-4. kernels       flash fwd/bwd and the fused DP clip against dense
-                 references on the device (tools/tpu_selftest.py, in-process)
+4. kernels       flash fwd/bwd, the fused DP clip and the chunked
+                 scalar-decay scan's two calls against their plain XLA
+                 forms on the device (tools/tpu_selftest.py, in-process)
 5. cnn           CIFAR CNN, 64 clients, bf16: vmapped conv + donated stack
 6. mesh_*        only with >= 4 devices, under ``MeshConfig``: the encoder
                  (one client per chip, against the one-chip trajectory), the

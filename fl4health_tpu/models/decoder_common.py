@@ -12,6 +12,7 @@ parameters) and ``dims.lora_scale`` (alpha / rank, 0 without adapters).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -124,18 +125,24 @@ def remat_layers(body, remat: bool, keeps):
             else body)
 
 
-def attention_gauges(module, batch_shape, n_clients: int, keeps) -> dict:
+def attention_gauges(module, batch_shape, n_clients: int, keeps,
+                     **counters) -> dict:
     """Facts of ``module``'s build that follow from shapes at trace time:
     how many ``kernels.flash_attention`` calls one trace of its forward
     holds on each of the kernel's two paths (a run of layers under
-    ``lax.scan`` traces its call once), and what its remat sites keep of a
-    layer (``core.remat.saved_gauges``; ``keeps`` is the family's list,
-    zeros without ``module.remat``). Traced abstractly: nothing is allocated
-    or run."""
+    ``lax.scan`` traces its call once; likewise ``<name>_<path>`` for each
+    further kernel's ``count_call_sites`` in ``counters``), and what its
+    remat sites keep of a layer (``core.remat.saved_gauges``; ``keeps`` is
+    the family's list, zeros without ``module.remat``). Traced abstractly:
+    nothing is allocated or run."""
     x = jax.ShapeDtypeStruct(tuple(batch_shape), jnp.int32)
-    with count_call_sites() as sites:
+    with contextlib.ExitStack() as stack:
+        sites = {name: stack.enter_context(count())
+                 for name, count in {"flash_calls": count_call_sites,
+                                     **counters}.items()}
         variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
-    return {**{f"flash_calls_{path}": n for path, n in sites.items()},
+    return {**{f"{name}_{path}": n for name, by_path in sites.items()
+               for path, n in by_path.items()},
             **remat_names.saved_gauges(
                 lambda v, x: module.apply(v, x)[0]["prediction"],
                 (variables, x), keeps if module.remat else (), n_clients)}

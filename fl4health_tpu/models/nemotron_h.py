@@ -13,7 +13,9 @@ mixer_i(RMSNorm(h))``, the kind of block ``i`` read from character ``i`` of
   xBC | dt] = in_proj(u)``; ``xBC <- silu(causal_depthwise_conv(xBC))``,
   split ``x [H, P]``, ``B [G, N]``, ``C [G, N]``; ``dt = softplus(dt +
   dt_bias)`` a head, ``a = -exp(A_log)`` a head; the scalar-decay recurrence
-  of ``kernels/ssd_scan.py`` in its chunked form (matmuls), plus ``D x``;
+  of ``kernels/ssd_scan.py`` in its chunked form (matmuls; at widths on the
+  128-lane tiles two Mosaic calls that keep the tiles on the chip), plus
+  ``D x``;
   ``y <- RMSNorm_group(y * silu(z))`` (gate first, then a norm over each
   group's lanes, times a scale of ``H * P``); ``out_proj``. Causal, and pad
   positions sit at the tail, so no mask enters it.
@@ -72,7 +74,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fl4health_tpu.core.pytree import merge_trees
-from fl4health_tpu.kernels.ssd_scan import n_chunks, ssd_scan
+from fl4health_tpu.kernels.ssd_scan import (count_call_sites, n_chunks,
+                                            ssd_scan)
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import F32, lora_dense, rms_norm
 from fl4health_tpu.models.deepseek import relu2_expert, routed_layer
@@ -415,13 +418,15 @@ class NemotronHClassifier(nn.Module):
 
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
         """Static facts of the state-space and routed blocks, which path the
-        forward's flash calls take and what the remat sites keep, for the
-        simulation's build-time gauges; ``batch_shape`` is one client's
-        [B, T]."""
+        forward's flash calls and chunked scans take (``ssd_calls_fused`` /
+        ``ssd_calls_xla``: traced call sites) and what the remat sites keep,
+        for the simulation's build-time gauges; ``batch_shape`` is one
+        client's [B, T]."""
         return {"ssd_chunks": n_chunks(batch_shape[-1], self.chunk),
                 "ssd_heads": self.ssm_heads,
                 "moe_experts_held": self.experts_held,
                 "moe_router_width": self.n_routed_experts,
                 "moe_top_k": self.top_k,
                 **common.attention_gauges(self, batch_shape, n_clients,
-                                          common.NEMOTRON_REMAT_KEEPS)}
+                                          common.NEMOTRON_REMAT_KEEPS,
+                                          ssd_calls=count_call_sites)}

@@ -55,7 +55,10 @@ cell:
   (models/jamba.py, kernels/selective_scan.py)
 - ``ssd_mixer`` ⊃ ``ssd_scan`` — the scalar-decay (Mamba-2) mixer and its
   chunked recurrence alone, from the split of ``xBC`` to ``y`` before the
-  gated norm (models/nemotron_h.py, kernels/ssd_scan.py)
+  gated norm: at widths on the 128-lane tiles the Mosaic calls
+  ``ssd_chunk_fwd`` / ``ssd_chunk_bwd`` with the running sums and their two
+  layouts around them, else the ``jnp`` form (models/nemotron_h.py,
+  kernels/ssd_scan.py)
 - ``mlp``           — the dense feed-forward (GELU or SwiGLU)
 - ``moe`` ⊃ ``moe_router``, ``moe_experts``; ``shared_experts`` beside it
   (models/deepseek.py, models/nemotron_h.py), disjoint from ``mlp``

@@ -9,9 +9,11 @@ client), the same three causal over latent attention's parts (128 heads; q
 and k as 128 lanes without positions and 64 rotary, the rotary key ONE head;
 v 128, T 1,024, a static scale: lane-indexed, nothing padded to 256), the
 same three causal over grouped key/value heads at the Nemotron-H cell's (32
-query heads of 128 over 2), and the
+query heads of 128 over 2), the
 adapter cell's two selective-scan calls (4 clients x 2,048
-positions x 5,120 channels x 16 states). Nothing runs: the TPU's compiler
+positions x 5,120 channels x 16 states), and the Nemotron-H cell's two
+chunked scalar-decay scan calls (4 clients x 2,048 positions x 128 heads of
+64 in 8 groups x state 128, chunks of 128). Nothing runs: the TPU's compiler
 works against a described chip. The only file that describes a topology;
 the description happens inside a module-scoped fixture, never at import."""
 
@@ -24,6 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks.harness.spec import load_module
+from fl4health_tpu.kernels import ssd_scan as ssd
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.kernels.selective_scan import (BLOCK_T, UNROLL,
                                                   _blocked_scan)
@@ -294,3 +297,106 @@ def test_selective_scan_call_compiles_for_the_v5e(scan_calls, name):
     lines = scan_calls.get(name)
     assert lines and len(lines) == 1, (name, sorted(scan_calls))
     assert _result_shapes(lines[0]) == SCAN_KERNELS[name], lines[0][:400]
+
+
+# the Nemotron-H cell's Mamba-2 blocks: 4 clients x batch 1 x 2,048 positions
+# x 128 heads of 64 in 8 groups (a group's 16 heads are 1,024 lanes of the
+# model's [.., T, 8,192]) x state 128, chunks of 128, bf16 operands
+SSD_X, SSD_BC = "bf16[4,1,2048,8192]", "bf16[4,1,2048,1024]"
+SSD_COLS = "f32[4,1,8,2048,16]"  # a group's heads side by side, a position a row
+SSD_KERNELS = {
+    # y, and under differentiation the states at the 16 chunks' starts
+    "ssd_chunk_fwd": ("f32[4,1,2048,8192]", "f32[4,1,16,8,128,1024]"),
+    # dx, dB, dC (a group's 16 heads summed inside the call), d(dt), d(cum)
+    # as columns, d(cum) as rows
+    "ssd_chunk_bwd": (SSD_X, SSD_BC, SSD_BC, SSD_COLS, SSD_COLS,
+                      "f32[4,1,16,8,16,128]"),
+}
+# a [128, 128] tile a (client, chunk, head): what the jnp form's decay,
+# scores and their cotangents are (537 MB of float32)
+SSD_TILES = 4 * 16 * 128 * 128 * 128
+
+
+@pytest.fixture(scope="module")
+def ssd_texts(one_chip):
+    """The compiled forward, forward + gradient and, for comparison, the
+    ``jnp`` form's forward."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def clients(scan):
+        return jax.vmap(lambda x, dt, a, b, c: scan(x, dt, a, b, c, 128),
+                        in_axes=(0, 0, None, 0, 0))
+
+    def loss(*ops):
+        return jnp.sum(clients(ssd.ssd_scan)(*ops))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ops = (arg((4, 1, 2048, 128, 64), jnp.bfloat16),
+           arg((4, 1, 2048, 128), jnp.float32), arg((128,), jnp.float32),
+           arg((4, 1, 2048, 8, 128), jnp.bfloat16),
+           arg((4, 1, 2048, 8, 128), jnp.bfloat16))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the public entry asks the platform (the CPU here) whether to interpret
+    steer = pytest.MonkeyPatch()
+    steer.setattr(ssd, "interpret_default", lambda: False)
+    try:
+        return {name: jax.jit(fn).lower(*ops).compile().as_text()
+                for name, fn in (
+                    ("forward", clients(ssd.ssd_scan)),
+                    ("gradient", jax.grad(loss, argnums=tuple(range(5)))),
+                    ("jnp forward", clients(ssd.ssd_scan_xla)))}
+    finally:
+        steer.undo()
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _ssd_calls(text, name):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and re.search(rf"%\w*{name}_*[.\d]* = ", line)]
+
+
+@pytest.mark.parametrize("name", sorted(SSD_KERNELS))
+def test_chunked_scan_call_compiles_for_the_v5e(ssd_texts, name):
+    (line,) = _ssd_calls(ssd_texts["gradient"], name)
+    assert _result_shapes(line) == SSD_KERNELS[name], line[:400]
+    # forward, recomputed forward and backward are told apart by JAX's own
+    # markers in the name stack (benchmarks/layer_metrics/pass_common.py)
+    op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+    assert "fl_layer::ssd_scan" in op_name, op_name
+    assert ("transpose(" in op_name) == (name == "ssd_chunk_bwd"), op_name
+    if name == "ssd_chunk_fwd":  # no gradient asked: no states written
+        (line,) = _ssd_calls(ssd_texts["forward"], name)
+        assert _result_shapes(line) == SSD_KERNELS[name][:1], line[:400]
+
+
+def _tile_arrays(text):
+    """The instructions of a compiled program, outside the Mosaic calls, whose
+    result holds a [128, 128] tile a (client, chunk, head) or more."""
+    found = []
+    for line in text.splitlines():
+        if " = " not in line or "tpu_custom_call" in line:
+            continue
+        result = line.split(" = ", 1)[1].split("(", 1)[0]
+        for dims in re.findall(r"(?:bf16|f32|pred|s32)\[([\d,]+)\]", result):
+            dims = [int(d) for d in dims.split(",")]
+            n = 1
+            for d in dims:
+                n *= d
+            if n >= SSD_TILES and dims[-2:] == [128, 128]:
+                found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("program", ["forward", "gradient"])
+def test_no_decay_tile_reaches_hbm(ssd_texts, program):
+    """Forward, and forward + backward: no array of rank >= 2 whose last two
+    axes are a chunk's positions twice (128, 128) times 4 clients x 16
+    chunks x 128 heads exists outside a custom call, where the ``jnp`` form
+    holds several (its forward's are found by the same reader)."""
+    assert _tile_arrays(ssd_texts[program]) == []
+    assert _tile_arrays(ssd_texts["jnp forward"])

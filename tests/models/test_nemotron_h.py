@@ -20,7 +20,7 @@ from benchmarks.harness.spec import load_module
 from fl4health_tpu.clients import engine
 from fl4health_tpu.core import pytree as ptu
 from fl4health_tpu.kernels.flash_attention import flash_attention
-from fl4health_tpu.kernels.ssd_scan import ssd_scan
+from fl4health_tpu.kernels.ssd_scan import ssd_scan, ssd_scan_xla
 from fl4health_tpu.models import nemotron_h as nh
 from tests.models.remat_probe import eqns
 
@@ -332,6 +332,60 @@ def test_build_gauges_state_the_static_facts():
     assert gauges["flash_calls_lane_indexed"] == 1
     assert gauges["remat_saved_names"] == 2
     assert _module().build_gauges((1, 20), 4)["remat_saved_names"] == 0
+
+
+# the state-space widths on the 128-lane tiles: the scan's fused path
+ON_TILES = dict(CFG, mamba_head_dim=64, ssm_state_size=128, chunk_size=128)
+
+
+def test_build_gauges_say_which_path_the_scans_take():
+    """Traced call sites, as the flash calls' are counted: the toy widths'
+    one run of ``ME`` units traces its scan once, on the ``jnp`` path; widths
+    on the tiles take the Mosaic calls at every site (a run of two like
+    blocks, and two blocks on their own)."""
+    toy = _module(remat=True).build_gauges((1, 20), 4)
+    assert (toy["ssd_calls_fused"], toy["ssd_calls_xla"]) == (0, 1)
+    wide = _module(dict(ON_TILES, hybrid_override_pattern="MMEM*M"),
+                   remat=True).build_gauges((1, 20), 4)
+    assert (wide["ssd_calls_fused"], wide["ssd_calls_xla"]) == (3, 0)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_model_on_the_tiles_is_the_model_off_them(monkeypatch, remat):
+    """The whole model at widths that take the scan's Mosaic calls (the
+    interpreter here) against itself with the ``jnp`` form in their place:
+    logits and every adapter's gradient under the engine's ``vmap`` over
+    clients, to float32 summation order."""
+    tree = build.nest(_weights(ON_TILES, 11))
+    module = _module(ON_TILES, remat=remat)
+    x = jnp.asarray(np.random.default_rng(1).integers(
+        1, CFG["vocab_size"], (2, 1, 20)), jnp.int32)
+    per_client, shared = ptu.split_by_path(tree, module.per_client_param)
+    clients = jax.tree_util.tree_map(lambda v: jnp.stack([v, 0.5 * v]),
+                                     per_client)
+
+    def run():
+        forward = module.bind_shared(shared)
+
+        def loss(p):
+            logits = jax.vmap(lambda p, x: forward(p, x)[0]["prediction"])(
+                p, x)
+            return jnp.sum(jax.nn.log_softmax(logits)[..., 0]), logits
+
+        with jax.default_matmul_precision("highest"):
+            (_, logits), grads = jax.value_and_grad(loss, has_aux=True)(
+                clients)
+        return logits, build.flatten(grads)
+
+    logits, grads = run()
+    monkeypatch.setattr(nh, "ssd_scan", ssd_scan_xla)
+    want_logits, want = run()
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               atol=3e-5, rtol=3e-5)
+    assert float(jnp.abs(want["layers_0/mixer/in_proj/lora_a"]).max()) > 0
+    for k in sorted(want):
+        np.testing.assert_allclose(np.asarray(grads[k]), np.asarray(want[k]),
+                                   atol=3e-5, rtol=2e-4, err_msg=k)
 
 
 def test_the_module_brings_its_own_split_and_cast(seeded):

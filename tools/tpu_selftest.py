@@ -1,6 +1,7 @@
-"""Kernel agreement checks for the Pallas kernels (flash attention + DP clip).
+"""Kernel agreement checks for the Pallas kernels (flash attention, DP clip,
+the chunked scalar-decay scan).
 
-Both kernels are interpret-mode validated by the CPU suite
+The kernels are interpret-mode validated by the CPU suite
 (tests/kernels/), but a Mosaic compile can fail or miscompute where
 interpret mode passes. These checks run the REAL compiled kernels on the
 attached TPU against dense XLA references on the same device.
@@ -228,8 +229,51 @@ def dp_clip_checks(toy: bool = False) -> list[dict]:
     return checks
 
 
+def ssd_scan_checks(toy: bool = False) -> list[dict]:
+    """The chunked scalar-decay scan's Mosaic calls against its ``jnp`` form,
+    forward and every gradient, four vmapped clients at a length that is no
+    multiple of the chunk. float32 operands agree to summation order (2e-4
+    holds the decays' gradient, a sum over every position that cancels; one
+    bfloat16 pass in a product is 1e-3 and more); bfloat16 gradients leave in
+    bfloat16 (a unit in the last place is 0.4-0.8 %)."""
+    import jax
+    import jax.numpy as jnp
+
+    from fl4health_tpu.kernels.ssd_scan import ssd_scan, ssd_scan_xla
+
+    heads, groups = (4, 2) if toy else (32, 2)
+
+    def case(dtype, tol, name):
+        def run():
+            keys = jax.random.split(jax.random.PRNGKey(0), 6)
+            lead, t = (4, 1), 328
+            n = jax.random.normal
+            ops = (n(keys[0], (*lead, t, heads, 64)).astype(dtype),
+                   jax.nn.softplus(n(keys[1], (*lead, t, heads))),
+                   -jnp.exp(n(keys[2], (heads,))),
+                   n(keys[3], (*lead, t, groups, 128)).astype(dtype),
+                   n(keys[4], (*lead, t, groups, 128)).astype(dtype))
+            cot = n(keys[5], ops[0].shape)
+
+            def both(scan):
+                clients = jax.vmap(lambda *o: scan(*o, 128),
+                                   in_axes=(0, 0, None, 0, 0))
+                with jax.default_matmul_precision("highest"):
+                    y, vjp = jax.vjp(clients, *ops)
+                    return (y, *vjp(cot))
+
+            errs = [_rel_err(g, w)
+                    for g, w in zip(both(ssd_scan), both(ssd_scan_xla))]
+            return max(errs) < tol, f"rel_errs={[f'{e:.2e}' for e in errs]}"
+
+        return _check(name, run)
+
+    return [case(jnp.float32, 2e-4, "ssd_scan_f32_t328_h%d" % heads),
+            case(jnp.bfloat16, 2e-2, "ssd_scan_bf16_t328_h%d" % heads)]
+
+
 def run_checks(toy: bool = False) -> list[dict]:
-    return flash_checks(toy) + dp_clip_checks(toy)
+    return flash_checks(toy) + dp_clip_checks(toy) + ssd_scan_checks(toy)
 
 
 def main() -> int:
