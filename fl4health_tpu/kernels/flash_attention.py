@@ -141,6 +141,21 @@ block_q, 64)`` block (the last dim equals the array's) is the form taken: the
 transpose is a third of q's bytes and XLA fuses it into the rotary fusion
 that computes the part anyway.
 
+A sliding window (``window``, static, beside ``causal``; PR 43): query i sees
+key j iff ``0 <= i - j < window`` (its own position and the ``window - 1``
+before it) and j is no pad. The mask joins the diagonal's in every tile
+(``_on_or_under_diagonal``), and a tile wholly behind the window is not
+executed, as a tile wholly above the diagonal is not: the step of a key block
+(in dK/dV, of a query block) sits behind ONE scalar condition on the two
+block starts (``_block_loop``'s ``live``; ``_reaches_window``), so a call
+does work in proportion to the band. ``live_tiles`` counts the tiles left by
+the same two conditions on Python integers: 70 of the causal 136 at T 8,192,
+a window of 2,048 and blocks of 512. K / V of a key head (Q / dO in dK/dV)
+still lie whole in VMEM, so the window spares no fetch and lifts no limit of
+``_check_compilable``; the index maps, the grids and both paths are what
+they were, and a call without a ``window`` traces to the program it traced
+to before the argument existed (``tests/kernels/test_flash_window.py``).
+
 Under a rematerialised layer: the forward rule of the ``custom_vjp`` gives
 ``out`` and ``lse`` names (``SAVED_NAMES``, through ``core/remat.py``), so a
 ``jax.checkpoint`` whose policy keeps those names saves the two arrays the
@@ -311,14 +326,41 @@ def _block_loop(n_blocks: int, block: int, resident: int, step, carry,
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _on_or_under_diagonal(q_start, nq: int, k_start, nk: int, transposed):
-    """bool [nq, nk] (or its transpose): key position <= query position."""
+def _on_or_under_diagonal(q_start, nq: int, k_start, nk: int, transposed,
+                          window=None):
+    """bool [nq, nk] (or its transpose): key position <= query position,
+    and under a ``window`` the query's own position and the ``window - 1``
+    before it only (``query - key < window``)."""
     shape = (nk, nq) if transposed else (nq, nk)
     qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape,
                                               1 if transposed else 0)
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape,
                                               0 if transposed else 1)
-    return kpos <= qpos
+    seen = kpos <= qpos
+    if window is not None:
+        seen = seen & (qpos - kpos < window)
+    return seen
+
+
+def _reaches_window(q_start, k_start, block_k: int, window: int):
+    """Is the LAST key of the key block at ``k_start`` inside the window of
+    the FIRST query of the query block at ``q_start``: if not, every score
+    of the tile lies behind the window and the tile is not executed (all
+    three kernels ask this of a tile, and ``live_tiles`` counts by it)."""
+    return k_start + (block_k - 1) > q_start - window
+
+
+def live_tiles(t: int, block_q: int, block_k: int,
+               window: int | None = None) -> int:
+    """Score tiles of ``block_q x block_k`` that a causal call over ``t``
+    (padded) positions executes a head, in each of its three kernels: those
+    with a score on or under the diagonal and, under a ``window``, inside
+    it (the kernels' own two conditions, on Python integers)."""
+    return sum(
+        1 for q_start in range(0, t, block_q) for k_start in range(0, t, block_k)
+        if k_start <= q_start + (block_q - 1) and (
+            window is None
+            or _reaches_window(q_start, k_start, block_k, window)))
 
 
 def _layout_of(kinds, qs, v, mask):
@@ -420,7 +462,8 @@ def _merge(per_head, head_lanes):
     return out
 
 
-def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1):
+def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1,
+                window=None):
     # Mosaic layout contract (learned on real silicon, KERNELS r5): every
     # block's trailing two dims must be (8k, 128k) or equal the array dims.
     # Row-per-(batch,head) vectors therefore travel as mask [B, 1, Tp] and
@@ -444,6 +487,11 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1):
     if causal:
         q_start = pl.program_id(q_axis) * bq
         live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
+        if window is not None:
+            under_diagonal = live
+            live = lambda k_start: (  # noqa: E731
+                under_diagonal(k_start)
+                & _reaches_window(q_start, k_start, block_k, window))
 
     def step(ks, carry):
         # a head: m [Bq, 1], l [Bq, lanes]; acc: [Bq, Dvp], all f32
@@ -453,7 +501,7 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1):
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
         if causal:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
-                                                block_k, False)
+                                                block_k, False, window)
         new_stats, ps, corrs = [], [], []
         for (m, l), qs_j in zip(stats, qs_of):
             s = _scores(qs_j, kbs, precision) * scale  # [Bq, Bk]
@@ -486,7 +534,7 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1):
 
 
 def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
-              kinds):
+              kinds, window=None):
     (q_kinds, k_kinds, v_kind, heads, group, rep, b, tp, widths, dvp, out,
      vec) = _layout_of(kinds, qs, v, mask)
     if heads is None:  # one grid row per (batch, head)
@@ -496,7 +544,7 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
     kernel = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
                                precision=_dot_precision(qs[0].dtype),
                                causal=causal, q_axis=len(grid) - 1,
-                               group=group)
+                               group=group, window=window)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -526,7 +574,7 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
-                   group=1):
+                   group=1, window=None):
     n = (len(refs) - 5) // 3
     q_refs, k_refs = refs[:n], refs[n:2 * n]
     v_ref, mask_ref, do_ref, lse_ref, delta_ref = refs[2 * n:2 * n + 5]
@@ -543,6 +591,11 @@ def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
     if causal:
         q_start = pl.program_id(q_axis) * bq
         live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
+        if window is not None:
+            under_diagonal = live
+            live = lambda k_start: (  # noqa: E731
+                under_diagonal(k_start)
+                & _reaches_window(q_start, k_start, block_k, window))
 
     def step(ks, dqs):
         kbs = [_mxu_operand(r[0, ks, :]) for r in k_refs]
@@ -550,7 +603,7 @@ def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
         if causal:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
-                                                block_k, False)
+                                                block_k, False, window)
         dss = []
         for qs_j, do_j, lse, delta in per_head:
             s = _scores(qs_j, kbs, precision) * scale
@@ -595,7 +648,7 @@ def _store(ref, value, head_axis, every=None):
 
 
 def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
-                    k_axis=1, group=1, rep=1):
+                    k_axis=1, group=1, rep=1, window=None):
     # The score tile is held transposed, [Bk, Bq]: dV += P^T dO and
     # dK += dS^T Q are then plain row-major dots, and lse / delta meet the
     # tile as rows [1, Bq] (a sublane broadcast) where the [Bq, Bk] form
@@ -617,6 +670,11 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
     if causal:
         k_start = pl.program_id(k_axis) * bk
         live = lambda q_start: q_start + (block_q - 1) >= k_start  # noqa: E731
+        if window is not None:
+            under_diagonal = live
+            live = lambda q_start: (  # noqa: E731
+                under_diagonal(q_start)
+                & _reaches_window(q_start, k_start, bk, window))
 
     def step(qs_, carry):
         dks, dv = carry
@@ -629,7 +687,7 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
             pt = jnp.exp(_scores(kbs_j, qs, precision) * scale - lse)
             if causal:
                 pt = jnp.where(_on_or_under_diagonal(
-                    qs_.start, block_q, k_start, bk, True), pt, 0.0)
+                    qs_.start, block_q, k_start, bk, True, window), pt, 0.0)
             pts.append(pt)
             dpts.append(_dot(vb_j, do, (1, 1), precision))
         # P_j^T dO and dS_j^T Q are right in head j's lanes
@@ -664,7 +722,7 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
 
 
 def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
-              dlse, causal, kinds):
+              dlse, causal, kinds, window=None):
     """``lse`` comes as the forward rule kept it, ``[.., Tp]``; ``dlse`` as
     the result's cotangent, ``[.., Tp, 1]``."""
     (q_kinds, k_kinds, v_kind, heads, group, rep, b, tp, widths, dvp, out,
@@ -702,7 +760,8 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
     prec = _dot_precision(qs[0].dtype)
     dq_kernel = functools.partial(_bwd_dq_kernel, block_k=block_k, scale=scale,
                                   precision=prec, causal=causal,
-                                  q_axis=len(grid) - 1, group=group)
+                                  q_axis=len(grid) - 1, group=group,
+                                  window=window)
     q_specs = [_spec(kind, block_q, w, pos) for kind, w in zip(q_kinds, widths)]
     dqs = pl.pallas_call(
         dq_kernel,
@@ -744,7 +803,7 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
                                    scale=scale, precision=prec, causal=causal,
                                    shared=shared, group=group, k_axis=k_axis,
-                                   rep=rep)
+                                   rep=rep, window=window)
     k_specs = [_spec(kind, block_k, w, pos, rep=rep)
                for kind, w in zip(k_kinds, widths)]
     v_spec = _spec(v_kind, block_k, dvp, pos, rep=rep)
@@ -777,9 +836,9 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
 # custom_vjp over the operands as the kernels address them
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_lse(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
-               kinds):
+               kinds, window):
     """(out, lse) pair with lse a first-class differentiable output so
     partial-attention results can be merged exactly (ring-flash). The
     plain-``out`` path (flash_attention) wraps this and drops lse — its
@@ -789,11 +848,11 @@ def _flash_lse(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
     Dp]``, out the same and lse ``[BH, Tp, 1]``; else out ``[B, Tp, H*Dv]``
     and lse ``[B, H, Tp, 1]``)."""
     return _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret,
-                     causal, kinds)
+                     causal, kinds, window)
 
 
 def _flash_lse_fwd(qs, ks, v, mask, block_q, block_k, scale, interpret,
-                   causal, kinds):
+                   causal, kinds, window):
     """The forward rule names what the backward reads of it, so a remat
     policy can keep exactly that (``SAVED_NAMES``) and the layer's recompute
     holds no second ``flash_fwd``; no policy, no effect. ``lse`` is kept as
@@ -803,19 +862,19 @@ def _flash_lse_fwd(qs, ks, v, mask, block_q, block_k, scale, interpret,
     one, so a recompute that reads it (ring-flash's merge) needs no kernel
     either."""
     out, lse = _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret,
-                         causal, kinds)
+                         causal, kinds, window)
     out = named(out, FLASH_OUT)
     lse = named(lse.reshape(lse.shape[:-1]), FLASH_LSE)
     return (out, lse.reshape(*lse.shape, 1)), (qs, ks, v, mask, out, lse)
 
 
-def _flash_lse_bwd(block_q, block_k, scale, interpret, causal, kinds, res,
-                   cts):
+def _flash_lse_bwd(block_q, block_k, scale, interpret, causal, kinds, window,
+                   res, cts):
     do, dlse = cts
     qs, ks, v, mask, out, lse = res
     dqs, dks, dv = _bwd_call(qs, ks, v, mask, out, lse, do, block_q, block_k,
                              scale, interpret, dlse=dlse, causal=causal,
-                             kinds=kinds)
+                             kinds=kinds, window=window)
     # a shared part's gradient was summed over the heads in float32
     return (tuple(dqs), tuple(dk.astype(k.dtype) for dk, k in zip(dks, ks)),
             dv.astype(v.dtype), None)
@@ -833,7 +892,11 @@ _site_counters: list = []
 def count_call_sites():
     """Counts, while open, the ``flash_attention`` / ``flash_attention_lse``
     calls TRACED, by path: ``{"lane_indexed": n, "transposed": m}``. A run
-    of layers under ``lax.scan`` traces its call once."""
+    of layers under ``lax.scan`` traces its call once. Calls under a
+    ``window`` add three keys of their own, and only they do: ``window``
+    (how many of the calls counted above), ``window_tiles_live`` and
+    ``window_tiles_causal`` (``live_tiles`` a head of the newest such call,
+    with its window and without)."""
     sites = collections.Counter(lane_indexed=0, transposed=0)
     _site_counters.append(sites)
     try:
@@ -901,6 +964,7 @@ def flash_attention(
     interpret: bool | None = None,
     causal: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Exact softmax attention, flash-style. q, k: [B, T, H, D], v:
     [B, T, H, Dv]; pad_mask: [B, T] with 1 = real token (key positions);
@@ -918,8 +982,13 @@ def flash_attention(
 
     ``causal`` (static): a query sees the keys at its own position and
     before; key blocks wholly above the diagonal are skipped in all three
-    kernels. ``k`` / ``v``, or a part of ``k``, with a single head (``[B, T,
-    1, D]``) is shared by every query head (multi-query attention), and its
+    kernels. ``window`` (static, needs ``causal``): a query sees its own
+    position and the ``window - 1`` before it (``0 <= i - j < window``:
+    sliding-window attention), and key blocks (in dK/dV query blocks) wholly
+    behind the window are skipped like those above the diagonal:
+    ``live_tiles`` counts what is left; ``None`` traces what a call without
+    the argument traced. ``k`` / ``v``, or a part of ``k``, with a single
+    head (``[B, T, 1, D]``) is shared by every query head (multi-query attention), and its
     gradient is the sum over the query heads.
 
     Which of the two paths a call takes follows from its shapes (module
@@ -930,7 +999,7 @@ def flash_attention(
     silent zero grads here — use the dense path for that; stop_gradient in
     the shared prep makes the contract explicit)."""
     out, _ = flash_attention_lse(q, k, v, pad_mask, block_q, block_k,
-                                 interpret, causal, scale)
+                                 interpret, causal, scale, window)
     return out
 
 
@@ -944,6 +1013,7 @@ def flash_attention_lse(
     interpret: bool | None = None,
     causal: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """flash_attention returning (out [B,T,H,Dv], lse [B,H,T]) with lse a
     DIFFERENTIABLE output — the partial-softmax statistic that lets two
@@ -958,6 +1028,12 @@ def flash_attention_lse(
     flash_attention."""
     if interpret is None:
         interpret = interpret_default()
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(
+            f"flash_attention: window={window!r} with causal={causal}: a "
+            "window is the query's own position and the window - 1 before "
+            "it, so it needs causal=True and at least 1")
+    window = None if window is None else int(window)
     qs = tuple(q) if isinstance(q, (tuple, list)) else (q,)
     ks = tuple(k) if isinstance(k, (tuple, list)) else (k,)
     if len(qs) != len(ks) or any(
@@ -971,9 +1047,18 @@ def flash_attention_lse(
     kinds = _lane_kinds(qs, ks, v)
     for sites in _site_counters:
         sites["transposed" if kinds is None else "lane_indexed"] += 1
+        if window is not None:
+            # a call under a window, and the tiles a head of it executes
+            # beside what ``causal`` alone would (the newest call's)
+            multiple = math.lcm(block_q, block_k)
+            tp = pl.cdiv(qs[0].shape[1], multiple) * multiple
+            sites["window"] += 1
+            sites["window_tiles_live"] = live_tiles(tp, block_q, block_k,
+                                                    window)
+            sites["window_tiles_causal"] = live_tiles(tp, block_q, block_k)
     if kinds is not None:
         return _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret,
-                             causal, scale, kinds)
+                             causal, scale, kinds, window)
     # the transposed path copies anyway: one part each, a part of k with one
     # head under parts with heads of their own broadcast to theirs, grouped
     # key/value heads repeated to the query heads that read them
@@ -990,7 +1075,7 @@ def flash_attention_lse(
         [jnp.broadcast_to(a, (*a.shape[:2], k_heads, a.shape[3])) for a in ks],
         axis=-1)
     return _transposed(q, k, v, pad_mask, block_q, block_k, interpret, causal,
-                       scale)
+                       scale, window)
 
 
 def _sharded(call, n_operands: int):
@@ -1010,7 +1095,7 @@ def _sharded(call, n_operands: int):
 
 
 def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
-                  scale, kinds):
+                  scale, kinds, window=None):
     """The operands where the model holds them: nothing is transposed,
     padded or broadcast but a narrow part of q (``_lane_kinds``) and, where
     the blocks do not divide it, the sequence."""
@@ -1042,14 +1127,14 @@ def _lane_indexed(qs, ks, v, pad_mask, block_q, block_k, interpret, causal,
 
     def call(qs, ks, v, maskp):
         return _flash_lse(qs, ks, v, maskp, block_q, block_k, scale,
-                          interpret, causal, kinds)
+                          interpret, causal, kinds, window)
 
     out, lse = _sharded(call, 4)(qs, ks, v, maskp)
     return out[:, :t].reshape(b, t, h, dv), lse[:, :, :t, 0]
 
 
 def _transposed(q, k, v, pad_mask, block_q, block_k, interpret, causal,
-                scale):
+                scale, window=None):
     """Every operand copied to ``[B*H, T, Dpadded]``."""
     b, t, h, d = q.shape
     dv = v.shape[-1]
@@ -1091,7 +1176,7 @@ def _transposed(q, k, v, pad_mask, block_q, block_k, interpret, causal,
 
     def padded(qp, kp, vp, maskp):
         return _flash_lse((qp,), (kp,), vp, maskp, block_q, block_k, scale,
-                          interpret, causal, None)
+                          interpret, causal, None, window)
 
     out, lse = _sharded(padded, 4)(qp, kp, vp, maskp)
     out = out[:, :t, :dv].reshape(b, h, t, dv)

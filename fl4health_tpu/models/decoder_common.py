@@ -1,5 +1,6 @@
 """What the decoder families over a frozen base share (``models/jamba.py``,
-``models/deepseek.py``, ``models/nemotron_h.py``): RMSNorm, the adapted projection, SwiGLU, the
+``models/deepseek.py``, ``models/nemotron_h.py``, ``models/afmoe.py``):
+RMSNorm, the adapted projection, SwiGLU, the
 declaration of a named parameter tree, the split into per-client adapters and
 a base held once, and the form the base takes for a round (its matrices cast
 to the compute type and written into one stack per run of layers).
@@ -46,6 +47,8 @@ DEEPSEEK_REMAT_KEEPS = (*FLASH_SAVED, MLA_STREAM)
 # calls' pair; a Mamba-2 or an expert block keeps nothing (the chunked scan's
 # chunk states are 4 MB a sequence and chunk, its decay tiles far more)
 NEMOTRON_REMAT_KEEPS = FLASH_SAVED
+# afmoe's layers (window and full attention alike) keep the flash calls' pair
+AFMOE_REMAT_KEEPS = FLASH_SAVED
 
 
 # ---------------------------------------------------------------------------
@@ -79,12 +82,13 @@ def swiglu(p, u, dims):
     return lora_dense(p["down_proj"], gated, dims)
 
 
-def dense_causal_attention(q, k, v, pad_mask, scale=None):
+def dense_causal_attention(q, k, v, pad_mask, scale=None, window=None):
     """The plain form (float32 softmax) for when a family is given no
     ``attention_fn``: q / k [B, T, H, D], v [B, T, H, Dv], or k / v with one
     shared head; q and k may be tuples of parts whose scores add (D is their
     widths together, a part of k may have the one head alone); ``scale``
-    None is ``1 / sqrt(D)``."""
+    None is ``1 / sqrt(D)``; under a ``window`` a query sees its own
+    position and the ``window - 1`` before it."""
     qs = q if isinstance(q, (tuple, list)) else (q,)
     ks = k if isinstance(k, (tuple, list)) else (k,)
     t, d = qs[0].shape[1], sum(a.shape[-1] for a in qs)
@@ -96,6 +100,9 @@ def dense_causal_attention(q, k, v, pad_mask, scale=None):
               else scores * scale)
     keep = (pad_mask[:, None, None, :] > 0) & (
         jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])[None, None]
+    if window is not None:
+        keep = keep & (jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+                       < window)[None, None]
     attn = jax.nn.softmax(jnp.where(keep, scores, jnp.finfo(F32).min),
                           axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", attn, v)
@@ -112,10 +119,14 @@ def last_token_logits(h, pad_mask, final_scale, score_kernel, eps):
         return {"prediction": logits.astype(F32)}, {"features": pooled}
 
 
-def embed_tokens(embedding, x, dtype):
-    """The rows of ``embedding`` at the token ids ``x``, in ``dtype``."""
+def embed_tokens(embedding, x, dtype, scale=None):
+    """The rows of ``embedding`` at the token ids ``x``, in ``dtype``;
+    times ``scale`` (in the embedding's float32) where a family has one."""
     with part("embed"):
-        return embedding[x].astype(dtype)
+        rows = embedding[x]
+        if scale is not None:
+            rows = rows * scale
+        return rows.astype(dtype)
 
 
 def remat_layers(body, remat: bool, keeps):

@@ -242,6 +242,23 @@ def route(p, u, dims: DeepseekDims):
         return idx.astype(jnp.int32), w * dims.routed_scale
 
 
+def sigmoid_route(p, u, top_k: int, routed_scale: float):
+    """The sigmoid scoring rule (``models/nemotron_h.py``,
+    ``models/afmoe.py``) over ALL the layer's experts, in float32 at full
+    precision (a near tie decides which expert a token gets): u [N, d] ->
+    (idx [N, top_k] int32, w [N, top_k] float32). The selection bias
+    (``p["e_score_correction_bias"]``) enters the choice and not the weight;
+    the chosen scores are renormalised, then scaled."""
+    with part("moe_router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            u.astype(F32), p["kernel"].astype(F32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(scores + p["e_score_correction_bias"], top_k)
+        chosen = jnp.take_along_axis(scores, idx, axis=1)
+        w = routed_scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w
+
+
 def swiglu_expert(x, gate, up, down):
     """An expert body: SwiGLU over the expert's rows, in the rows' type
     (three matrices an expert: this family's)."""
@@ -401,7 +418,7 @@ def routed_layer(x, u, router, experts, first_expert_held: int, rule,
     implementation: ``rule(router, u) -> (idx [N, K] int32 over ALL the
     layer's experts, w [N, K] float32)`` is the family's scoring rule over
     the router's input ``u`` [N, d_router] (``route`` here: softmax, the
-    group limit, unnormalised; ``models/nemotron_h.py sigmoid_route``:
+    group limit, unnormalised; ``sigmoid_route``:
     sigmoid, a selection bias, renormalised and scaled), ``body`` its expert
     (``swiglu_expert`` / ``relu2_expert``) over the rows ``x`` [N, d] the
     experts read (``u`` itself, or a latent of it), cast here to the

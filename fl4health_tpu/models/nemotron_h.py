@@ -35,7 +35,7 @@ mixer_i(RMSNorm(h))``, the kind of block ``i`` read from character ``i`` of
   (``experts_held`` from ``first_expert_held``: a chip's share under expert
   parallelism), routes over all of them and adds its own only; the routed
   part is ``models/deepseek.py routed_layer`` with this family's scoring
-  rule (``sigmoid_route``) and expert body (``relu2_expert``).
+  rule (``deepseek.sigmoid_route``) and expert body (``relu2_expert``).
 
 Then the final RMSNorm and HF's last-non-pad-token ``score`` head (token id
 0 is padding, at the tail). Left out: the multi-token-prediction block, the
@@ -78,7 +78,8 @@ from fl4health_tpu.kernels.ssd_scan import (count_call_sites, n_chunks,
                                             ssd_scan)
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import F32, lora_dense, rms_norm
-from fl4health_tpu.models.deepseek import relu2_expert, routed_layer
+from fl4health_tpu.models.deepseek import (relu2_expert, routed_layer,
+                                           sigmoid_route)
 from fl4health_tpu.models.jamba import causal_depthwise_conv
 from fl4health_tpu.observability.stages import layer as part
 
@@ -161,22 +162,6 @@ def gqa_attention(p, u, pad_mask, dims: NemotronHDims):
             else:
                 out = dims.attention_fn(q, k, v, pad_mask=pad_mask)
         return lora_dense(p["o_proj"], out.reshape(*out.shape[:-2], -1), dims)
-
-
-def sigmoid_route(p, u, top_k: int, routed_scale: float):
-    """This family's scoring rule over ALL the layer's experts, in float32
-    at full precision (a near tie decides which expert a token gets): u [N,
-    d] -> (idx [N, top_k] int32, w [N, top_k] float32). The selection bias
-    enters the choice and not the weight; the chosen scores are
-    renormalised, then scaled."""
-    with part("moe_router"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            u.astype(F32), p["kernel"].astype(F32),
-            precision=jax.lax.Precision.HIGHEST))
-        _, idx = jax.lax.top_k(scores + p["e_score_correction_bias"], top_k)
-        chosen = jnp.take_along_axis(scores, idx, axis=1)
-        w = routed_scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
-        return idx.astype(jnp.int32), w
 
 
 def relu2_mlp(p, u, dims: NemotronHDims):

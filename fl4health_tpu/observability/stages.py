@@ -39,7 +39,7 @@ The canonical stages (:data:`SPINE_STAGES`; an op belongs to the LAST
 
 The parts of the client step (:data:`LAYER_SCOPES`; they nest, and an op
 counts for EVERY ``fl_layer::`` its name stack holds, :func:`layers_of`).
-The same names in all four model families, so one reader serves every
+The same names in all five model families, so one reader serves every
 cell:
 
 - ``embed`` / ``head`` — token (and position) embedding; pooling or the
@@ -47,8 +47,12 @@ cell:
 - ``attention``     — projections, the flash call or the dense scores,
   ``o_proj`` (models/transformer.py, models/jamba.py, models/nemotron_h.py)
 - ``gqa_flash``     — inside ``attention``: the three flash calls over
-  grouped key/value heads and what surrounds them (models/nemotron_h.py),
-  as ``mla_flash`` is inside ``mla_attention``
+  grouped key/value heads and what surrounds them (models/nemotron_h.py,
+  and the full-attention layers of models/afmoe.py), as ``mla_flash`` is
+  inside ``mla_attention``
+- ``window_flash``  — inside ``attention``: the same three calls under a
+  sliding window (``flash_attention(window=...)``) and what surrounds them,
+  a sliding layer's in place of ``gqa_flash`` (models/afmoe.py)
 - ``mla_attention`` ⊃ ``mla_flash`` — latent attention and its flash calls
   (models/deepseek.py)
 - ``mamba_mixer`` ⊃ ``ssm_scan`` — the state-space mixer and its scan
@@ -121,6 +125,7 @@ LAYER_SCOPES = (
     "ssd_mixer",
     "ssd_scan",
     "gqa_flash",
+    "window_flash",
     "mlp",
     "moe",
     "moe_router",

@@ -9,7 +9,9 @@ client), the same three causal over latent attention's parts (128 heads; q
 and k as 128 lanes without positions and 64 rotary, the rotary key ONE head;
 v 128, T 1,024, a static scale: lane-indexed, nothing padded to 256), the
 same three causal over grouped key/value heads at the Nemotron-H cell's (32
-query heads of 128 over 2), the
+query heads of 128 over 2), the same three under a sliding window at the
+trinity_mini cell's (32 query heads of 128 over 4, T 8,192, a window of
+2,048), the
 adapter cell's two selective-scan calls (4 clients x 2,048
 positions x 5,120 channels x 16 states), and the Nemotron-H cell's two
 chunked scalar-decay scan calls (4 clients x 2,048 positions x 128 heads of
@@ -68,7 +70,8 @@ def _text_as_a_trace_names_ops(compiled):
 
 
 def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
-                    block, causal, v_dim=None, scale=None, shared_part=None):
+                    block, causal, v_dim=None, scale=None, shared_part=None,
+                    window=None):
     """name of the kernel -> its custom-call instructions in the HLO of the
     compiled forward and backward programs. ``shared_part``: q and k are two
     parts, ``head_dim`` wide with ``kv_heads`` heads and ``shared_part`` wide
@@ -77,7 +80,7 @@ def _compiled_calls(one_chip, clients, batch, seq, heads, kv_heads, head_dim,
 
     def attend(q, k, v, mask):
         return flash_attention(q, k, v, mask, block, block, interpret=False,
-                               causal=causal, scale=scale)
+                               causal=causal, scale=scale, window=window)
 
     def loss(q, k, v, mask):
         return jnp.sum(jax.vmap(attend)(q, k, v, mask).astype(jnp.float32))
@@ -178,6 +181,34 @@ def test_grouped_heads_call_compiles_for_the_v5e(gqa_calls, name):
     assert lines, f"no tpu_custom_call named {name}: {sorted(gqa_calls)}"
     for line in lines:
         assert _result_shapes(line) == GQA_KERNELS[name], (name, line[:400])
+    assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
+# the trinity_mini cell's sliding layers: 4 clients x batch 1 x 32 query heads
+# of 128 over FOUR key/value heads, T 8,192, causal under a window of 2,048,
+# blocks 512/512 (70 of the causal 136 tiles a head are executed: the others'
+# steps sit behind a scalar condition). K / V of one key head whole in VMEM
+# are 4 MiB, inside ``_check_compilable``'s 8
+WINDOW_ROWS, WINDOW_KV = "bf16[4,1,8192,4096]", "f32[4,1,8192,512]"
+WINDOW_KERNELS = {
+    "flash_fwd": (WINDOW_ROWS, "f32[4,1,32,8192,1]"),
+    "flash_dq": (WINDOW_ROWS,),
+    "flash_dkv": (WINDOW_KV, WINDOW_KV),
+}
+
+
+@pytest.fixture(scope="module")
+def window_calls(one_chip):
+    return _compiled_calls(one_chip, 4, 1, 8192, 32, 4, 128, 512, causal=True,
+                           window=2048)
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_KERNELS))
+def test_window_call_compiles_for_the_v5e(window_calls, name):
+    lines = window_calls.get(name)
+    assert lines, f"no tpu_custom_call named {name}: {sorted(window_calls)}"
+    for line in lines:
+        assert _result_shapes(line) == WINDOW_KERNELS[name], (name, line[:400])
     assert len(lines) == (2 if name == "flash_fwd" else 1)
 
 

@@ -114,3 +114,56 @@ def test_the_experts_get_no_gradient_whatever_their_body():
     grads = jax.grad(lambda e: jnp.sum(ds.routed_experts(
         x, idx, w, e, 0, ds.relu2_expert)))(experts)
     assert all(float(jnp.abs(m).max()) == 0.0 for pair in grads for m in pair)
+
+
+def test_the_sigmoid_rule_at_eight_of_128_with_a_share_and_pads():
+    """The rule at the afmoe family's numbers (``models/afmoe.py``: a
+    128-wide router, the 8 largest of ``s + b``, renormalised, times 2.826)
+    through the one implementation, 16 experts held from the 32nd, the pad
+    positions picking none (index -1: no tile holds them): forward and both
+    gradients against every held expert over every token by a plain loop."""
+    width, top_k, held, first = 128, 8, 16, 32
+    keys = iter(jax.random.split(jax.random.PRNGKey(8), 64))
+    router = {"kernel": jax.random.normal(next(keys), (D_ROUTER, width)) / 3,
+              "e_score_correction_bias": 0.05 * jax.random.normal(
+                  next(keys), (width,))}
+    experts = [tuple(jax.random.normal(next(keys), s) / 3
+                     for s in [(D_ROWS, F)] * 2 + [(F, D_ROWS)])
+               for _ in range(held)]
+    u = jax.random.normal(next(keys), (N, D_ROUTER))
+    live = (jnp.arange(N) < N - 7)[:, None]
+
+    def rule(router, u):
+        idx, w = ds.sigmoid_route(router, u, top_k, 2.826)
+        return jnp.where(live, idx, -1), w
+
+    def plain(x):
+        idx, w = rule(router, x)
+        y = jnp.zeros((N, D_ROWS))
+        for j, mats in enumerate(experts):
+            combine = jnp.sum(jnp.where(idx == first + j, w, 0.0), axis=1)
+            y = y + combine[:, None] * _plain_body("swiglu", x[:, :D_ROWS],
+                                                   mats)
+        return y
+
+    def program(x):
+        return ds.routed_layer(x[:, :D_ROWS], x, router, experts, first, rule,
+                               ds.swiglu_expert)
+
+    with jax.default_matmul_precision("highest"):
+        idx, w = rule(router, u)
+        got, vjp = jax.vjp(program, u)
+        want, want_vjp = jax.vjp(plain, u)
+        cot = jax.random.normal(next(keys), got.shape)
+        (grad,), (want_grad,) = vjp(cot), want_vjp(cot)
+    assert idx.shape == (N, top_k)
+    np.testing.assert_allclose(np.asarray(w).sum(axis=1), 2.826, rtol=1e-5)
+    # the share sees some of the picks and not all (8 * 16 / 128 = 1 a token
+    # expected), and a pad position none
+    mine = (idx >= first) & (idx < first + held)
+    assert 0 < int(mine.sum()) < mine.size // 2
+    assert float(jnp.abs(got[N - 7:]).max()) == 0.0
+    assert float(jnp.abs(want[:N - 7]).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(want_grad),
+                               atol=5e-5, rtol=2e-4)

@@ -16,6 +16,7 @@ from fl4health_tpu.datasets.synthetic import synthetic_classification
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.metrics import efficient
 from fl4health_tpu.metrics.base import MetricManager
+from fl4health_tpu.models.afmoe import AfmoeClassifier
 from fl4health_tpu.models.cnn import Mlp
 from fl4health_tpu.models.deepseek import DeepseekV2Classifier
 from fl4health_tpu.models.jamba import JambaClassifier
@@ -130,7 +131,7 @@ def lowered_programs(sim, mode=EXEC_PIPELINED, n_rounds=1):
 
 
 def family_module(family, **overrides):
-    """A toy module of one of the cells' four model families."""
+    """A toy module of one of the cells' five model families."""
     if family == "transformer":
         return TransformerClassifier(**{**dict(
             vocab_size=50, n_classes=N_CLASSES, d_model=16, n_heads=2,
@@ -159,6 +160,19 @@ def family_module(family, **overrides):
             routed_scaling_factor=2.5, d_latent=8, d_expert=8, d_shared=16,
             lora_rank=2, remat=True, dtype=jnp.bfloat16, attention_fn=flash),
             **overrides})
+    if family == "afmoe":
+        # a dense and an expert layer under the window, then a full layer,
+        # over a shared base: 4 of 8 experts held, grouped key/value heads
+        # through the flash calls, a window of 5 under 8 positions
+        return AfmoeClassifier(**{**dict(
+            vocab_size=50, n_classes=N_CLASSES,
+            layer_types=("sliding_attention", "sliding_attention",
+                         "full_attention"),
+            num_dense_layers=1, d_model=16, n_heads=4, n_kv_heads=2,
+            head_dim=8, d_ff=32, d_expert=8, n_routed_experts=8,
+            experts_held=4, first_expert_held=2, top_k=3, route_scale=2.5,
+            sliding_window=5, lora_rank=2, remat=True, dtype=jnp.bfloat16,
+            attention_fn=flash), **overrides})
     # one Mamba layer and one attention layer over a shared base
     return JambaClassifier(**{**dict(
         vocab_size=50, n_classes=N_CLASSES, d_model=16, n_layers=2,

@@ -6,7 +6,7 @@ The pinned contracts:
   reaches the op metadata of the COMPILED ``fit_round_t`` (where a profiler
   trace's names come from), and ``fl_stage::evaluate`` that of the evaluation
   program on both drivers, with and without telemetry;
-- the scopes are METADATA-ONLY: the lowered StableHLO of the three families'
+- the scopes are METADATA-ONLY: the lowered StableHLO of the families'
   round programs is text-equal with the scopes on and off;
 - the pass is read from JAX's own markers (``jvp(``, ``transpose(``,
   ``rematted_computation``): they are where ``pass_of`` expects them in the
@@ -55,6 +55,7 @@ FAMILIES = {
                   attention_fn=functools.partial(FLASH, causal=True)),
     "deepseek": {},
     "nemotron": {},
+    "afmoe": {},
 }
 DECLARED = {
     "transformer": ("embed", "attention", "mlp", "norm", "lora", "head",
@@ -68,11 +69,15 @@ DECLARED = {
                  "moe", "moe_router", "moe_experts", "moe_latent",
                  "shared_experts", "norm", "lora", "head", "optimizer",
                  "shared_cast"),
+    "afmoe": ("embed", "attention", "gqa_flash", "window_flash",
+              "mlp", "moe", "moe_router", "moe_experts", "shared_experts",
+              "norm", "lora", "head", "optimizer", "shared_cast"),
 }
 # a frozen base's embedding has no gradient; XLA:CPU folds the cast's
 # transpose (a gradient back to float32) into the product that feeds it
 FORWARD_ONLY = {("jamba", "embed"), ("deepseek", "embed"),
-                ("nemotron", "embed"), ("transformer", "param_cast")}
+                ("nemotron", "embed"), ("afmoe", "embed"),
+                ("transformer", "param_cast")}
 
 
 def _op_names(lowered):

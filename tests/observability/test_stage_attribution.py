@@ -173,7 +173,7 @@ def _model_fit_text(family):
 
 @pytest.mark.parametrize("stage", ["local_train", "server_update"])
 @pytest.mark.parametrize("family", ["transformer", "jamba", "deepseek",
-                                    "nemotron"])
+                                    "nemotron", "afmoe"])
 def test_the_cells_model_families_keep_both_stage_scopes(family, stage):
     """``local_train_ms_per_round`` and ``server_update_ms_per_round`` read
     exactly these two strings out of a trace of ``fit_round_t``."""
@@ -229,3 +229,36 @@ def test_the_hybrid_familys_layer_scopes_reach_the_compiled_round(scope, op,
     if inside:
         assert all(f"fl_layer::{inside}/" in line
                    or f"fl_layer::{inside})" in line for line in lines), scope
+
+
+@pytest.mark.parametrize("scope,op,inside", [
+    ("attention", "dot", None), ("window_flash", "dynamic_slice", "attention"),
+    ("gqa_flash", "dynamic_slice", "attention"),
+    ("norm", "rsqrt", None),
+    ("mlp", "dot", None), ("moe", "dot_general", None),
+    ("moe_router", "dot", "moe"), ("moe_experts", "dot_general", "moe"),
+    ("shared_experts", "dot", None), ("shared_cast", "convert", None)])
+def test_the_window_familys_layer_scopes_reach_the_compiled_round(scope, op,
+                                                                  inside):
+    """``window_flash_ms_per_round`` / ``window_flash_roofline_pct``,
+    ``gqa_flash_roofline_pct`` (the full layers' calls keep that name) read
+    these strings out of a trace of
+    ``fit_round_t``: each is in the name stack of an op of the kind it should
+    hold (the interpreted kernel's block slices, the products), on the forward and, but for the once-a-round cast, on the
+    backward pass, and the inner scopes nest in their outer one. The two
+    head norms nest in ``attention``."""
+    lines = [line for line in _model_fit_text("afmoe").splitlines()
+             if f"fl_layer::{scope}" in line]
+    assert any(re.match(rf"\s*(ROOT )?%\w*{op}", line) for line in lines), (
+        scope, len(lines))
+    if scope != "shared_cast":
+        assert any("transpose(" in line for line in lines), scope
+    if inside:
+        assert all(f"fl_layer::{inside}/" in line
+                   or f"fl_layer::{inside})" in line for line in lines), scope
+    if scope == "norm":
+        assert any("fl_layer::attention/" in line for line in lines)
+        assert any("fl_layer::attention" not in line for line in lines)
+    if scope in ("window_flash", "gqa_flash"):  # a layer has one or the other
+        other = {"window_flash": "gqa_flash", "gqa_flash": "window_flash"}
+        assert not any(f"fl_layer::{other[scope]}" in line for line in lines)

@@ -458,8 +458,14 @@ def test_the_new_metrics_read_an_older_programs_trace_or_none(spans_small,
     markers and the accepted stages, which an older program has too."""
     new = [m["name"] for m in load_json(os.path.join(
         REPO, "BENCHMARK.json"))["per_layer"]]
-    # PR 39's, then PR 41's six over the scopes of a family no older trace has
-    hybrid = new[new.index("ssd_mixer_ms_per_round"):]
+    # PR 39's, then PR 41's six and PR 43's four over the scopes of
+    # families no older trace has
+    windowed = new[new.index("window_flash_ms_per_round"):]
+    assert windowed == ["window_flash_ms_per_round",
+                        "window_flash_roofline_pct",
+                        "moe_experts_ms_per_round",
+                        "swiglu_experts_roofline_pct"]
+    hybrid = new[new.index("ssd_mixer_ms_per_round"):new.index(windowed[0])]
     assert hybrid == ["ssd_mixer_ms_per_round", "ssd_scan_ms_per_round",
                       "ssd_scan_roofline_pct", "gqa_flash_roofline_pct",
                       "moe_latent_ms_per_round", "routed_experts_roofline_pct"]
@@ -469,7 +475,7 @@ def test_the_new_metrics_read_an_older_programs_trace_or_none(spans_small,
         "backward_ms_per_round", "recompute_ms_per_round",
         "unscoped_local_train_pct"}
     enc = ctx_of(spans_small["trace"], spans_small["root"], 3)
-    assert [reader(m).read(enc) for m in hybrid] == [None] * 6
+    assert [reader(m).read(enc) for m in hybrid + windowed] == [None] * 10
     got = {m: reader(m).read(enc) for m in new}
     reads = {m for m, v in got.items() if v is not None}
     assert reads == {"forward_ms_per_round", "backward_ms_per_round",
@@ -484,7 +490,7 @@ def test_the_new_metrics_read_an_older_programs_trace_or_none(spans_small,
     assert sum(by.values()) * 1e3 / 3 == pytest.approx(360.65, rel=1e-4)
 
     ada = ctx_of(jamba_small["trace"], jamba_small["root"], 2)
-    assert [reader(m).read(ada) for m in hybrid] == [None] * 6
+    assert [reader(m).read(ada) for m in hybrid + windowed] == [None] * 10
     got = {m: reader(m).read(ada) for m in new}
     assert {m for m, v in got.items() if v is not None} == {
         "forward_ms_per_round", "backward_ms_per_round",

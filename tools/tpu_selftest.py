@@ -76,6 +76,43 @@ def _rel_err(a, b) -> float:
     return float(jnp.max(jnp.abs(a - b)) / jnp.maximum(jnp.max(jnp.abs(b)), 1e-6))
 
 
+def _dense_window_ref(q, k, v, mask, window, block):
+    """Causal attention under a sliding window over grouped key/value heads
+    (query head h reads key head ``h // rep``) with the scores
+    materialised, ``block`` queries at a time against the keys they can see
+    at all, each block rematerialised on the way back and the sequences one
+    after another, so that [B, H, T, T] never exists (4 x 32 x 8,192^2
+    float32 would be 34 GB); the mask inside a block is the plain one."""
+    import jax
+    import jax.numpy as jnp
+
+    prec = jax.lax.Precision.HIGHEST
+    t, d = q.shape[1], q.shape[-1]
+    rep = q.shape[2] // k.shape[2]
+
+    @jax.checkpoint
+    def rows(qb, kb, vb, maskb, q0, k0):
+        i = q0 + jnp.arange(qb.shape[0])[:, None]
+        j = k0 + jnp.arange(kb.shape[0])[None, :]
+        keep = (j <= i) & (i - j < window) & (maskb[None, :] > 0)
+        s = jnp.einsum("qhd,khd->hqk", qb, kb, precision=prec) / (d ** 0.5)
+        p = jax.nn.softmax(jnp.where(keep[None], s, -1e30), axis=-1)
+        return jnp.einsum("hqk,khd->qhd", p, vb, precision=prec)
+
+    def one(args):
+        q, k, v, mask = args
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+        out = []
+        for q0 in range(0, t, block):
+            q1, k0 = min(q0 + block, t), max(0, q0 - window + 1)
+            out.append(rows(q[q0:q1], k[k0:q1], v[k0:q1], mask[k0:q1], q0,
+                            k0))
+        return jnp.concatenate(out, axis=0)
+
+    return jax.lax.map(one, (q, k, v, mask))
+
+
 def flash_checks(toy: bool = False) -> list[dict]:
     """Flash attention vs dense attention. ``toy`` keeps one forward case,
     the engine-shaped backward case and the two rejections, at shapes (and
@@ -159,6 +196,54 @@ def flash_checks(toy: bool = False) -> list[dict]:
                   "flash_bwd_bf16_t16384_vmem_limit", b=1, h=2)
         grad_case(8192, 128, jnp.float32, 128, 128, 1e-3,
                   "flash_bwd_f32_t8192_d128_vmem_limit", b=1, h=2)
+
+    def window_case(b, t, h, kv, d, dtype, block, window, tol, name):
+        """The three calls under a sliding window over grouped key/value
+        heads (``models/afmoe.py``'s sliding layers) against dense masked
+        attention: the forward's real rows, and dQ / dK / dV relative to the
+        reference's largest gradient."""
+        def run():
+            ks = jax.random.split(jax.random.PRNGKey(2), 3)
+            q, k, v = (jax.random.normal(kk, (b, t, n, d), dtype)
+                       for kk, n in zip(ks, (h, kv, kv)))
+            n_real = t - t // 8
+            mask = jnp.broadcast_to(
+                (jnp.arange(t)[None, :] < n_real).astype(jnp.float32), (b, t))
+            w = mask[:, :, None, None]
+
+            def flash(q, k, v):
+                return flash_attention(q, k, v, mask, block_q=block,
+                                       block_k=block, causal=True,
+                                       window=window)
+
+            def dense(q, k, v):
+                return _dense_window_ref(q, k, v, mask, window, block)
+
+            def loss(attn):
+                def f(q, k, v):
+                    o = attn(q, k, v).astype(jnp.float32)
+                    return jnp.sum(o * o * w), o
+                return f
+
+            (g_f, o_f), (g_r, o_r) = (
+                jax.jit(jax.grad(loss(attn), argnums=(0, 1, 2),
+                                 has_aux=True))(q, k, v)
+                for attn in (flash, dense))
+            fwd = float(jnp.max(jnp.abs((o_f - o_r) * w)))
+            errs = [_rel_err(a, b_) for a, b_ in zip(g_f, g_r)]
+            return (max([fwd] + errs) < tol,
+                    f"fwd max_abs_err={fwd:.1e} rel grad errs dq/dk/dv="
+                    f"{[f'{e:.1e}' for e in errs]} tol={tol}")
+        checks.append(_check(name, run))
+
+    if toy:
+        window_case(2, 64, 4, 2, 128, jnp.float32, blk, 24, 1e-3,
+                    "flash_window_f32_grouped")
+    else:
+        # the sliding layers of the trinity_mini cell: 4 sequences of 8,192,
+        # 32 query heads over 4 key/value heads of 128, a window of 2,048
+        window_case(4, 8192, 32, 4, 128, jnp.bfloat16, 512, 2048, 3e-2,
+                    "flash_window_bf16_t8192_w2048_grouped")
 
     def rejected(name, t, d, dtype, block):
         """A request Mosaic cannot compile must fail in Python, naming the
