@@ -23,7 +23,10 @@ default ``Observability()`` — at the full width of a model the repo builds
                  call (the kernel did not interpret)
 4. kernels       flash fwd/bwd, the fused DP clip and the chunked
                  scalar-decay scan's two calls against their plain XLA
-                 forms on the device (tools/tpu_selftest.py, in-process)
+                 forms on the device (tools/tpu_selftest.py, in-process);
+                 and the flash calls' microseconds an executed tile at the
+                 trinity_mini cell's shape, not causal / causal / under the
+                 window (``flash_tile_probe_*``: a reading, not a limit)
 5. cnn           CIFAR CNN, 64 clients, bf16: vmapped conv + donated stack
 6. mesh_*        only with >= 4 devices, under ``MeshConfig``: the encoder
                  (one client per chip, against the one-chip trajectory), the

@@ -143,18 +143,41 @@ that computes the part anyway.
 
 A sliding window (``window``, static, beside ``causal``; PR 43): query i sees
 key j iff ``0 <= i - j < window`` (its own position and the ``window - 1``
-before it) and j is no pad. The mask joins the diagonal's in every tile
-(``_on_or_under_diagonal``), and a tile wholly behind the window is not
-executed, as a tile wholly above the diagonal is not: the step of a key block
-(in dK/dV, of a query block) sits behind ONE scalar condition on the two
-block starts (``_block_loop``'s ``live``; ``_reaches_window``), so a call
-does work in proportion to the band. ``live_tiles`` counts the tiles left by
-the same two conditions on Python integers: 70 of the causal 136 at T 8,192,
-a window of 2,048 and blocks of 512. K / V of a key head (Q / dO in dK/dV)
-still lie whole in VMEM, so the window spares no fetch and lifts no limit of
-``_check_compilable``; the index maps, the grids and both paths are what
-they were, and a call without a ``window`` traces to the program it traced
-to before the argument existed (``tests/kernels/test_flash_window.py``).
+before it) and j is no pad. A tile wholly behind the window is not executed,
+as a tile wholly above the diagonal is not, so a call does work in
+proportion to the band. ``live_tiles`` counts the tiles left, on Python
+integers: 70 of the causal 136 at T 8,192, a window of 2,048 and blocks of
+512. K / V of a key head (Q / dO in dK/dV) still lie whole in VMEM, so the
+window spares no fetch and lifts no limit of ``_check_compilable``; the index
+maps, the grids and both paths are what they were.
+
+How a causal call walks the tiles it executes (PR 44). The key blocks a query
+block executes (in dK/dV, the query blocks of a key block) are ONE range of
+the streamed axis, whose ends are integers of the grid index, the blocks and
+the window (``_live_range``), and inside it only the tiles that the diagonal
+or the window's far edge crosses can be changed by a positional mask: the
+first and the last run of the range; the run between them is wholly visible
+and takes the key-padding mask alone. The kernels walk that range
+(``_walk_live``), ascending as before: where most resident blocks agree on
+the three runs' lengths (a sliding layer past its first window: one far-edge
+tile, three interior, the diagonal's, at blocks of 512 under 2,048), one
+scalar condition picks ONE straight-line body of that many steps from a
+dynamic start; elsewhere a run whose length the grid index sets goes as
+whole straight-line trips under a trip count read from it, with what is
+short of a trip before them as its binary digits, each a straight-line body
+behind one condition (``_steps``). Until PR 44 every block step of the whole
+axis sat behind a scalar condition of its own and every executed tile built
+the positional mask: at T 8,192 and 512 / 512 the forward ran 2.0 (causal)
+and 3.1 (window) microseconds an executed tile where the straight-line body
+of a call that is not causal runs 0.81, and 1.02 / 1.09 after (v5e, bf16, 32
+heads of 128 over 4; dQ 1.38 / 1.59 -> 1.17 / 1.24, dK/dV 2.17 / 2.97 ->
+1.61 / 1.78). The same tiles execute, in the same order, with the same masks
+wherever a mask can change a score. ``traversal`` counts edge and interior
+tiles and the block steps still behind a condition from the same function,
+for ``count_call_sites`` and the models' build gauges. A causal call asks
+Mosaic for a scoped-VMEM limit of 32 MiB (``_CAUSAL_PARAMS``); a call that is
+not causal traces to the program it always traced to
+(``tests/kernels/test_flash_window.py``, ``test_flash_causal.py``).
 
 Under a rematerialised layer: the forward rule of the ``custom_vjp`` gives
 ``out`` and ``lse`` names (``SAVED_NAMES``, through ``core/remat.py``), so a
@@ -180,6 +203,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from fl4health_tpu.core.remat import named
@@ -196,6 +220,12 @@ _PACKED_WIDTHS = (64, 32)
 FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
 SAVED_NAMES = (FLASH_OUT, FLASH_LSE)
 NEG_INF = -1e30
+# A causal call's scoped-VMEM limit. The walk of the live range holds two
+# forms of the loop (``_walk_live``), and their temporaries beside the
+# double-buffered whole-sequence pair passed Mosaic's default 16 MiB by
+# 160 kB in dQ at the trinity_mini cell's shape (compiled for a described
+# v5e, PR 44); a call that is not causal passes no parameters, as before.
+_CAUSAL_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20)
 # Whole-sequence operand pair (K+V, or Q+dO) a compiled kernel may keep in
 # VMEM: the largest that compiled under the 16 MiB scoped limit (see the
 # module docstring).
@@ -285,41 +315,207 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
 _TILES_PER_TRIP = 64
 
 
-def _block_loop(n_blocks: int, block: int, resident: int, step, carry,
-                live=None):
-    """``carry = step(pl.ds(j * block, block), carry)`` for j in
-    range(n_blocks), each step a [resident, block] score tile (or its
-    transpose). Consecutive blocks worth up to _TILES_PER_TRIP tiles of
-    128 x 128 are one straight-line body, so the scheduler can start a
-    block's first dot while the one before is still in its softmax; a longer
-    axis loops over such trips and finishes with the remainder, so the code
-    stays bounded at any length and any block size. ``live(start)`` (the
-    causal kernels: is any score of the block at ``start`` on or under the
-    diagonal) makes a block's step conditional; ``None`` traces the
-    unconditional loop."""
-    per_trip = max(1, _TILES_PER_TRIP
-                   // (pl.cdiv(resident, _LANE) * pl.cdiv(block, _LANE)))
+def _per_trip(block: int, resident: int) -> int:
+    """Blocks of the streamed axis to a straight-line trip."""
+    return max(1, _TILES_PER_TRIP
+               // (pl.cdiv(resident, _LANE) * pl.cdiv(block, _LANE)))
 
+
+def _steps(first, count, block: int, per_trip: int, step, carry,
+           at_most: int | None = None):
+    """``carry = step(pl.ds(j * block, block), carry)`` for j in
+    range(first, first + count), ascending. ``count`` a Python integer:
+    consecutive blocks worth up to a trip are one straight-line body, a
+    longer run loops over such trips and finishes with the remainder, so the
+    code stays bounded at any length. ``count`` a scalar of the grid index,
+    no larger than ``at_most``: the ``count % per_trip`` blocks short of a
+    trip go first, as the binary digits of that number, each digit a
+    straight-line body behind one scalar condition (``_chunks``), then whole
+    straight-line trips under a trip count read from the scalar: the run
+    ends where ``first + count`` says, no trip without a live block is
+    entered and no block step has a condition of its own."""
     def run(first, count, carry):
         for u in range(count):
             start = (first + u) * block
             if not isinstance(start, int):
                 start = pl.multiple_of(start, block)
-            ks = pl.ds(start, block)
-            if live is None:
-                carry = step(ks, carry)
-            else:
-                carry = jax.lax.cond(
-                    live(start), lambda c, ks=ks: step(ks, c), lambda c: c,
-                    carry)
+            carry = step(pl.ds(start, block), carry)
         return carry
 
-    if n_blocks <= per_trip:
-        return run(0, n_blocks, carry)
-    trips, rest = divmod(n_blocks, per_trip)
-    carry = jax.lax.fori_loop(
-        0, trips, lambda t, c: run(t * per_trip, per_trip, c), carry)
-    return run(trips * per_trip, rest, carry)
+    if isinstance(count, int):
+        if count <= per_trip:
+            return run(first, count, carry)
+        trips, rest = divmod(count, per_trip)
+        carry = jax.lax.fori_loop(
+            0, trips, lambda t, c: run(first + t * per_trip, per_trip, c),
+            carry)
+        return run(first + trips * per_trip, rest, carry)
+    whole_trips = at_most >= per_trip
+    rest = jax.lax.rem(count, per_trip) if whole_trips else count
+    for size in _chunks(min(per_trip - 1, at_most)):
+        taken = rest & size  # 0 or ``size``
+        carry = jax.lax.cond(
+            taken != 0, lambda c, first=first, size=size: run(first, size, c),
+            lambda c: c, carry)
+        first = first + taken
+    if whole_trips:
+        carry = jax.lax.fori_loop(
+            0, jax.lax.div(count, per_trip),
+            lambda t, c: run(first + t * per_trip, per_trip, c), carry)
+    return carry
+
+
+def _chunks(most: int) -> list:
+    """The powers of two, largest first, whose sums give every count up to
+    ``most``: a run of unknown length under a trip is walked as its binary
+    digits, each a straight-line body behind one scalar condition."""
+    return [1 << k for k in reversed(range(most.bit_length()))]
+
+
+def _live_range(start, resident: int, block: int, n_blocks: int,
+                window: int | None, keys_streamed: bool):
+    """The streamed blocks that the resident block at position ``start``
+    executes under ``causal`` (and a ``window``) are one range, ``[a, e)``,
+    in three runs: ``[a, b)`` and ``[c, e)`` hold tiles that a positional
+    mask can change (the diagonal crosses them, or the window's far edge),
+    ``[b, c)`` tiles wholly under the diagonal and wholly inside the window,
+    which take no positional mask. ``keys_streamed``: the forward and dQ (a
+    query block resident, key blocks streamed: the window's edge first, the
+    diagonal last); else dK/dV (a key block resident, query blocks streamed:
+    the diagonal first). Integer arithmetic that holds for a Python integer
+    and for a scalar of the grid index alike (every dividend is >= 0), so
+    the kernels and the counts (``traversal``) ask one function."""
+    def div(x, n):
+        return x // n if isinstance(x, int) else jax.lax.div(x, n)
+
+    def clamp(x, lo, hi):
+        if all(isinstance(y, int) for y in (x, lo, hi)):
+            return max(lo, min(x, hi))
+        return jnp.clip(x, lo, hi)
+
+    if keys_streamed:  # keys [j * block, (j + 1) * block) against queries
+        q_start, bq, bk = start, resident, block
+        e = div(q_start + (bq - 1), bk) + 1  # one past the diagonal's block
+        c = div(q_start + 1, bk)  # first not wholly on or under the diagonal
+        if window is None:
+            a = b = 0
+        else:
+            # the block of the FIRST query's oldest key, and the first block
+            # wholly inside the LAST query's window
+            a = div(clamp(q_start - (window - 1), 0, q_start), bk)
+            b = div(clamp(q_start + bq - window, 0, q_start + bq) + (bk - 1),
+                    bk)
+    else:  # queries [i * block, (i + 1) * block) against the key block
+        k_start, bk, bq = start, resident, block
+        a = div(k_start, bq)  # the first block with a query at or past it
+        # one past the last block with a query BEFORE the block's last key
+        b = div(k_start + bk + bq - 2, bq)
+        if window is None:
+            c = e = n_blocks
+        else:
+            c = div(k_start + window, bq)  # first not wholly in the window
+            e = div(k_start + bk + window - 2, bq) + 1  # the edge's last
+            e = clamp(e, 0, n_blocks)
+    b = clamp(b, a, e)
+    return a, b, clamp(c, b, e), e
+
+
+def _walk_plan(n_resident: int, resident: int, block: int, n_blocks: int,
+               window: int | None, keys_streamed: bool):
+    """How the causal kernels walk their ranges, from Python integers: the
+    three runs' lengths (``_live_range``) of every resident block; the
+    lengths that most resident blocks share, if more than one does
+    (``steady``: a sliding layer's ``(1, 3, 1)`` past the first window), else
+    None; and per run the one length all share (a Python integer: the run is
+    straight-line in every resident block) or None with the largest."""
+    counts = []
+    for p in range(n_resident):
+        a, b, c, e = _live_range(p * resident, resident, block, n_blocks,
+                                 window, keys_streamed)
+        counts.append((b - a, c - b, e - c))
+    steady, shared = collections.Counter(counts).most_common(1)[0]
+    runs = [(lens[0] if len(set(lens)) == 1 else None, max(lens))
+            for lens in zip(*counts)]
+    if shared == 1 or shared == n_resident:  # none to share, or all static
+        steady = None
+    return counts, steady, runs
+
+
+def _walk_live(pid, n_resident: int, resident: int, block: int,
+               n_blocks: int, window: int | None, keys_streamed: bool, step,
+               carry):
+    """The causal kernels' loop: ``carry = step(pl.ds(j * block, block),
+    carry, edge)`` over the live range of resident block ``pid`` (a scalar
+    of the grid index), ascending; ``edge`` (static) says whether the tile
+    needs the positional mask. Where the runs' lengths are those most
+    resident blocks share, the whole range is ONE straight-line body from a
+    dynamic start (one scalar condition a resident block picks it); else
+    each run goes by its own length: straight-line where every resident
+    block agrees on it, else ``_steps``' whole trips with the binary digits
+    of what is short of a trip before them. No block that is not live is
+    stepped over, and none that is live is left out."""
+    per_trip = _per_trip(block, resident)
+    _, steady, runs = _walk_plan(n_resident, resident, block, n_blocks,
+                                 window, keys_streamed)
+    a, b, c, e = _live_range(pid * resident, resident, block, n_blocks,
+                             window, keys_streamed)
+    firsts, lengths = (a, b, c), (b - a, c - b, e - c)
+
+    def walk(lengths, at_most):
+        def go(carry):
+            for first, n, most, edge in zip(firsts, lengths, at_most,
+                                            (True, False, True)):
+                carry = _steps(
+                    first, n, block, per_trip,
+                    lambda ks, c_, edge=edge: step(ks, c_, edge), carry, most)
+            return carry
+        return go
+
+    general = walk([n if n is not None else dyn
+                    for (n, _), dyn in zip(runs, lengths)],
+                   [most for _, most in runs])
+    if steady is None:
+        return general(carry)
+    is_steady = functools.reduce(
+        jnp.logical_and, [dyn == n for dyn, n in zip(lengths, steady)])
+    return jax.lax.cond(is_steady, walk(steady, steady), general, carry)
+
+
+def _block_loop(causal: bool, axis: int, resident: int, block: int, t: int,
+                window: int | None, keys_streamed: bool, step, carry):
+    """A kernel's loop over the streamed axis of ``t`` positions, each step
+    a [resident, block] score tile (or its transpose): every block where the
+    call is not causal, consecutive blocks worth up to _TILES_PER_TRIP tiles
+    of 128 x 128 as one straight-line body, so the scheduler can start a
+    block's first dot while the one before is still in its softmax
+    (``_steps``); else the live range of the resident block that grid axis
+    ``axis`` names (``_walk_live``)."""
+    if not causal:
+        return _steps(0, t // block, block, _per_trip(block, resident),
+                      lambda ks, c: step(ks, c, False), carry)
+    return _walk_live(pl.program_id(axis), t // resident, resident, block,
+                      t // block, window, keys_streamed, step, carry)
+
+
+def traversal(t: int, block_q: int, block_k: int,
+              window: int | None = None) -> dict:
+    """What a head of a causal call over ``t`` (padded) positions executes
+    in its forward (dQ walks the same ranges), from the kernels' own
+    ``_live_range`` on Python integers: ``tiles_edge``, which build the
+    positional mask, ``tiles_interior``, which take the key-padding mask
+    alone (together ``live_tiles``), and ``cond_steps``: block steps that
+    sit behind a scalar condition of their own, summed over the query blocks
+    whose path holds them (none in a query block that walks the steady
+    straight-line body)."""
+    n_q, n_k = t // block_q, t // block_k
+    counts, steady, runs = _walk_plan(n_q, block_q, block_k, n_k, window,
+                                      True)
+    per_trip = _per_trip(block_k, block_q)
+    conds = sum(sum(_chunks(min(per_trip - 1, most)))
+                for n, most in runs if n is None)
+    return {"tiles_edge": sum(n[0] + n[2] for n in counts),
+            "tiles_interior": sum(n[1] for n in counts),
+            "cond_steps": conds * sum(1 for n in counts if n != steady)}
 
 
 # ---------------------------------------------------------------------------
@@ -483,23 +679,17 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1,
     heads = _head_lanes(group, bq)
     qs_of = [[_only(q, lanes_j) for q in qs] for lanes_j in heads]
     lanes = _LANE if block_k % _LANE == 0 else block_k
-    live = None
     if causal:
         q_start = pl.program_id(q_axis) * bq
-        live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
-        if window is not None:
-            under_diagonal = live
-            live = lambda k_start: (  # noqa: E731
-                under_diagonal(k_start)
-                & _reaches_window(q_start, k_start, block_k, window))
 
-    def step(ks, carry):
-        # a head: m [Bq, 1], l [Bq, lanes]; acc: [Bq, Dvp], all f32
+    def step(ks, carry, edge):
+        # a head: m [Bq, 1], l [Bq, lanes]; acc: [Bq, Dvp], all f32.
+        # ``edge``: the diagonal or the window's far edge crosses the tile
         stats, acc = carry
         kbs = [_mxu_operand(r[0, ks, :]) for r in k_refs]
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
-        if causal:
+        if edge:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
                                                 block_k, False, window)
         new_stats, ps, corrs = [], [], []
@@ -522,10 +712,10 @@ def _fwd_kernel(*refs, block_k, scale, precision, causal, q_axis=1, group=1,
         return tuple(new_stats), acc
 
     stats, acc = _block_loop(
-        v_ref.shape[1] // block_k, block_k, bq, step,
+        causal, q_axis, bq, block_k, v_ref.shape[1], window, True, step,
         (tuple((jnp.full((bq, 1), NEG_INF, jnp.float32),
                 jnp.zeros((bq, lanes), jnp.float32)) for _ in heads),
-         jnp.zeros((bq, dvp), jnp.float32)), live)
+         jnp.zeros((bq, dvp), jnp.float32)))
     denoms = [jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
               for _, l in stats]
     o_ref[0] = (acc / _merge(denoms, heads)).astype(o_ref.dtype)
@@ -565,6 +755,7 @@ def _fwd_call(qs, ks, v, mask, block_q, block_k, scale, interpret, causal,
                                         1), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_CAUSAL_PARAMS if causal else None,
         name="flash_fwd",
     )(*qs, *ks, v, mask)
 
@@ -587,21 +778,14 @@ def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
     per_head = [([_only(q, lanes_j) for q in qs], _only(do, lanes_j),
                  lse_ref[j], delta_ref[j])
                 for j, lanes_j in enumerate(heads)]
-    live = None
     if causal:
         q_start = pl.program_id(q_axis) * bq
-        live = lambda k_start: k_start <= q_start + (bq - 1)  # noqa: E731
-        if window is not None:
-            under_diagonal = live
-            live = lambda k_start: (  # noqa: E731
-                under_diagonal(k_start)
-                & _reaches_window(q_start, k_start, block_k, window))
 
-    def step(ks, dqs):
+    def step(ks, dqs, edge):
         kbs = [_mxu_operand(r[0, ks, :]) for r in k_refs]
         vb = _mxu_operand(v_ref[0, ks, :])
         keep = mask_ref[0, :, ks] > 0  # [1, Bk]
-        if causal:
+        if edge:
             keep = keep & _on_or_under_diagonal(q_start, bq, ks.start,
                                                 block_k, False, window)
         dss = []
@@ -617,9 +801,9 @@ def _bwd_dq_kernel(*refs, block_k, scale, precision, causal, q_axis=1,
                         heads)
             for dq, kb in zip(dqs, kbs))
 
-    dqs = _block_loop(v_ref.shape[1] // block_k, block_k, bq, step,
-                      tuple(jnp.zeros(q.shape, jnp.float32) for q in qs),
-                      live)
+    dqs = _block_loop(causal, q_axis, bq, block_k, v_ref.shape[1], window,
+                      True, step,
+                      tuple(jnp.zeros(q.shape, jnp.float32) for q in qs))
     for dq_ref, dq in zip(dq_refs, dqs):
         dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
@@ -666,17 +850,10 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
     heads = _head_lanes(group, bk)
     per_head = [([_only(kb, lanes_j) for kb in kbs], _only(vb, lanes_j))
                 for lanes_j in heads]
-    live = None
     if causal:
         k_start = pl.program_id(k_axis) * bk
-        live = lambda q_start: q_start + (block_q - 1) >= k_start  # noqa: E731
-        if window is not None:
-            under_diagonal = live
-            live = lambda q_start: (  # noqa: E731
-                under_diagonal(q_start)
-                & _reaches_window(q_start, k_start, bk, window))
 
-    def step(qs_, carry):
+    def step(qs_, carry, edge):
         dks, dv = carry
         qs = [_mxu_operand(r[0, qs_, :]) for r in q_refs]
         do = _mxu_operand(do_ref[0, qs_, :])
@@ -685,7 +862,7 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
             lse = lse_ref[j, :, qs_]  # [1, Bq]
             deltas.append(delta_ref[j, :, qs_])
             pt = jnp.exp(_scores(kbs_j, qs, precision) * scale - lse)
-            if causal:
+            if edge:
                 pt = jnp.where(_on_or_under_diagonal(
                     qs_.start, block_q, k_start, bk, True, window), pt, 0.0)
             pts.append(pt)
@@ -705,8 +882,8 @@ def _bwd_dkv_kernel(*refs, block_q, scale, precision, causal, shared,
     # equal-width calls traced to before the second width
     dv0 = (dks0[0] if vb.shape == kbs[0].shape
            else jnp.zeros(vb.shape, jnp.float32))
-    dks, dv = _block_loop(do_ref.shape[1] // block_q, block_q, bk,
-                          step, (dks0, dv0), live)
+    dks, dv = _block_loop(causal, k_axis, bk, block_q, do_ref.shape[1],
+                          window, False, step, (dks0, dv0))
     # A row of dK / dV depends on its own key alone, so the key-padding mask
     # is one select on the sums: a padded key's row is zero, as when every
     # p of that key was zeroed in the loop.
@@ -779,6 +956,7 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
         out_specs=q_specs,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs],
         interpret=interpret,
+        compiler_params=_CAUSAL_PARAMS if causal else None,
         name="flash_dq",
     )(*qs, *ks, v, mask, do, lse.reshape(*lse.shape, 1), delta)
 
@@ -825,6 +1003,7 @@ def _bwd_call(qs, ks, v, mask, o, lse, do, block_q, block_k, scale, interpret,
                                         jnp.float32 if acc else x.dtype)
                    for x, acc in zip((*ks, v), shared)],
         interpret=interpret,
+        compiler_params=_CAUSAL_PARAMS if causal else None,
         name="flash_dkv",
     )(*qs, *ks, v, mask.reshape(b, tp, 1), do,
       lse.reshape(*lse.shape[:-1], 1, tp),
@@ -892,7 +1071,11 @@ _site_counters: list = []
 def count_call_sites():
     """Counts, while open, the ``flash_attention`` / ``flash_attention_lse``
     calls TRACED, by path: ``{"lane_indexed": n, "transposed": m}``. A run
-    of layers under ``lax.scan`` traces its call once. Calls under a
+    of layers under ``lax.scan`` traces its call once. Causal calls add
+    ``tiles_edge``, ``tiles_interior`` and ``cond_steps`` (``traversal`` a
+    head of the newest such call: the tiles that build the positional mask,
+    those that do not, and the block steps behind a condition of their own),
+    and only they do. Calls under a
     ``window`` add three keys of their own, and only they do: ``window``
     (how many of the calls counted above), ``window_tiles_live`` and
     ``window_tiles_causal`` (``live_tiles`` a head of the newest such call,
@@ -1047,11 +1230,17 @@ def flash_attention_lse(
     kinds = _lane_kinds(qs, ks, v)
     for sites in _site_counters:
         sites["transposed" if kinds is None else "lane_indexed"] += 1
+        if not causal:
+            continue
+        multiple = math.lcm(block_q, block_k)
+        tp = pl.cdiv(qs[0].shape[1], multiple) * multiple
+        # how a head of the newest causal call walks its live range (set,
+        # not added: ``Counter.update`` would sum them over the calls)
+        for fact, n in traversal(tp, block_q, block_k, window).items():
+            sites[fact] = n
         if window is not None:
             # a call under a window, and the tiles a head of it executes
             # beside what ``causal`` alone would (the newest call's)
-            multiple = math.lcm(block_q, block_k)
-            tp = pl.cdiv(qs[0].shape[1], multiple) * multiple
             sites["window"] += 1
             sites["window_tiles_live"] = live_tiles(tp, block_q, block_k,
                                                     window)

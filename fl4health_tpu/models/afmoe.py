@@ -368,7 +368,9 @@ class AfmoeClassifier(nn.Module):
         """Static facts of the attention mix and the routed layer, which
         path the forward's flash calls take, how many of the traced calls
         run under the window and how many tiles a head of one executes
-        beside what ``causal`` alone would, and what the remat sites keep,
+        beside what ``causal`` alone would, how a head of the newest causal
+        call walks its live range (``flash_tiles_edge`` / ``_interior``,
+        ``flash_cond_steps``), and what the remat sites keep,
         for the simulation's build-time gauges; ``batch_shape`` is one
         client's [B, T]."""
         gauges = common.attention_gauges(self, batch_shape, n_clients,

@@ -136,13 +136,21 @@ def remat_layers(body, remat: bool, keeps):
             else body)
 
 
+# how a head of the newest causal flash call walks its live range
+# (``kernels/flash_attention.py traversal``), under the gauges' own names
+_WALK_GAUGES = {("flash_calls", key): f"flash_{key}"
+                for key in ("tiles_edge", "tiles_interior", "cond_steps")}
+
+
 def attention_gauges(module, batch_shape, n_clients: int, keeps,
                      **counters) -> dict:
     """Facts of ``module``'s build that follow from shapes at trace time:
     how many ``kernels.flash_attention`` calls one trace of its forward
     holds on each of the kernel's two paths (a run of layers under
     ``lax.scan`` traces its call once; likewise ``<name>_<path>`` for each
-    further kernel's ``count_call_sites`` in ``counters``), and what its
+    further kernel's ``count_call_sites`` in ``counters``; a causal flash
+    call's ``flash_tiles_edge`` / ``flash_tiles_interior`` /
+    ``flash_cond_steps``, a head of the newest one), and what its
     remat sites keep of a layer (``core.remat.saved_gauges``; ``keeps`` is
     the family's list, zeros without ``module.remat``). Traced abstractly:
     nothing is allocated or run."""
@@ -152,7 +160,8 @@ def attention_gauges(module, batch_shape, n_clients: int, keeps,
                  for name, count in {"flash_calls": count_call_sites,
                                      **counters}.items()}
         variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
-    return {**{f"{name}_{path}": n for name, by_path in sites.items()
+    return {**{_WALK_GAUGES.get((name, path), f"{name}_{path}"): n
+               for name, by_path in sites.items()
                for path, n in by_path.items()},
             **remat_names.saved_gauges(
                 lambda v, x: module.apply(v, x)[0]["prediction"],

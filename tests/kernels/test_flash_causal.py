@@ -1,8 +1,11 @@
 """Causal flash attention (a static ``causal`` argument of the three
 kernels) against dense attention: one key/value head shared by every query
 head, head size 128, padded tails, block pairs that do and do not divide,
-forward and gradients, under ``vmap``. And ``causal=False`` traces the
-kernels it always traced."""
+forward and gradients, under ``vmap``; the walk of the live range in whole
+trips (PR 44) at sixteen blocks of four to a trip. And ``causal=False``
+traces the kernels it always traced."""
+
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +14,10 @@ import pytest
 
 from fl4health_tpu.kernels.flash_attention import (flash_attention,
                                                    flash_attention_lse)
+
+# the package exports a function of the module's name over it
+flash_module = importlib.import_module(
+    "fl4health_tpu.kernels.flash_attention")
 
 B, T, H, D = 2, 80, 3, 128
 
@@ -58,6 +65,43 @@ def test_causal_forward_and_gradients_match_dense(bq, bk, kv_heads):
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * weight), (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         assert g.shape == w.shape  # the shared head's gradient is summed
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
+
+
+@pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16), (16, 32)])
+@pytest.mark.parametrize("kv_heads", [1, H])
+def test_the_causal_walk_in_whole_trips_matches_dense(monkeypatch, bq, bk,
+                                                      kv_heads):
+    """T 256 at four blocks of 16 to a trip, as T 8,192 at blocks of 512 on
+    the chip: a query block's interior key blocks go as the binary digits of
+    what is short of a trip, then whole straight-line trips under a trip
+    count of the grid index, then the diagonal's tile; in dK/dV the
+    diagonal's tile first. Against dense attention, forward and gradients,
+    a padded tail on the second sequence."""
+    monkeypatch.setattr(flash_module, "_TILES_PER_TRIP", 4)
+    t = 256
+    key = jax.random.PRNGKey(7)
+    q = jax.random.normal(key, (B, t, H, D))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, t, kv_heads, D))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, t, kv_heads, D))
+    mask = (jnp.arange(t) < jnp.asarray([t, 170])[:, None]).astype(
+        jnp.float32)
+    weight = jax.random.normal(jax.random.PRNGKey(9), q.shape) * mask[
+        ..., None, None]
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask, bq, bk, causal=True)
+
+    def dense(q, k, v):
+        return _dense(q, k, v, mask, True)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v) * mask[..., None, None]),
+        np.asarray(dense(q, k, v) * mask[..., None, None]), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * weight), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * weight), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
 
 

@@ -109,10 +109,13 @@ def test_lse_is_the_scaled_scores_logsumexp():
 # nothing). PR 40 packed heads of 64 two to a lane block, so the second
 # program moved from two heads of 64 to three, which stay transposed: its
 # value was taken on PR 39's tree (c9d3218) at the new shape and holds on
-# this one, as the first, untouched, does.
+# this one, as the first, untouched, did until PR 44 took it anew (was
+# 9aaf82a8...): a causal call walks the live range of a query block (a key
+# block in dK/dV), the positional mask on edge tiles alone; the second, not
+# causal, is PR 39's.
 BEFORE_THE_SECOND_WIDTH = {
     "causal, one shared key/value head, bfloat16":
-        "9aaf82a8db1a8a11c93575092b43a44da50d0bc81ff30afb6b372db965e922eb",
+        "c4981b7452e9fa1480fa662077204291b59fcf2d63c65ae44d628e700c6c31f0",
     "not causal, three heads of 64, float32, blocks 32/16":
         "0e12726b1a3de754fc0fcc04c60645d77b57d500585fa7e2c3f19df355b0e655",
 }

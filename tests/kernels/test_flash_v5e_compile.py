@@ -186,9 +186,12 @@ def test_grouped_heads_call_compiles_for_the_v5e(gqa_calls, name):
 
 # the trinity_mini cell's sliding layers: 4 clients x batch 1 x 32 query heads
 # of 128 over FOUR key/value heads, T 8,192, causal under a window of 2,048,
-# blocks 512/512 (70 of the causal 136 tiles a head are executed: the others'
-# steps sit behind a scalar condition). K / V of one key head whole in VMEM
-# are 4 MiB, inside ``_check_compilable``'s 8
+# blocks 512/512 (70 of the causal 136 tiles a head are executed: a query
+# block walks its live range alone, five key blocks as one straight-line body
+# past the first window). K / V of one key head whole in VMEM are 4 MiB,
+# inside ``_check_compilable``'s 8; the causal calls ask for a scoped limit of
+# 32 MiB (``_CAUSAL_PARAMS``: dQ's two forms of the loop passed the default
+# 16 by 160 kB at this shape)
 WINDOW_ROWS, WINDOW_KV = "bf16[4,1,8192,4096]", "f32[4,1,8192,512]"
 WINDOW_KERNELS = {
     "flash_fwd": (WINDOW_ROWS, "f32[4,1,32,8192,1]"),
@@ -207,6 +210,23 @@ def window_calls(one_chip):
 def test_window_call_compiles_for_the_v5e(window_calls, name):
     lines = window_calls.get(name)
     assert lines, f"no tpu_custom_call named {name}: {sorted(window_calls)}"
+    for line in lines:
+        assert _result_shapes(line) == WINDOW_KERNELS[name], (name, line[:400])
+    assert len(lines) == (2 if name == "flash_fwd" else 1)
+
+
+# the trinity_mini cell's full layers: the same operands, causal with no
+# window: 136 tiles a head, a query block's interior key blocks in whole
+# trips of four under a trip count of the grid index
+@pytest.fixture(scope="module")
+def full_calls(one_chip):
+    return _compiled_calls(one_chip, 4, 1, 8192, 32, 4, 128, 512, causal=True)
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_KERNELS))
+def test_full_layer_call_compiles_for_the_v5e(full_calls, name):
+    lines = full_calls.get(name)
+    assert lines, f"no tpu_custom_call named {name}: {sorted(full_calls)}"
     for line in lines:
         assert _result_shapes(line) == WINDOW_KERNELS[name], (name, line[:400])
     assert len(lines) == (2 if name == "flash_fwd" else 1)

@@ -335,6 +335,11 @@ def test_the_layers_read_grouped_heads_lane_indexed_under_the_window():
     assert gauges["flash_window"] == 2048
     assert (gauges["flash_window_tiles_live"],
             gauges["flash_window_tiles_causal"]) == (70, 136)
+    # how a head of the newest causal call, the last full layer's, walks
+    # its live range: the diagonal's 16 tiles build the positional mask, 120
+    # do not, and three block steps a query block sit behind a condition
+    assert (gauges["flash_tiles_edge"], gauges["flash_tiles_interior"],
+            gauges["flash_cond_steps"]) == (16, 120, 48)
     assert gauges["remat_saved_names"] == 2
     # the stream is not kept: out [4, 8192, 4096] bf16... here float32
     assert gauges["remat_saved_bytes_per_layer"] == 4 * (

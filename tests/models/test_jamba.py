@@ -202,8 +202,13 @@ def test_build_gauges_count_the_flash_call_sites_by_path(d_model, attention,
     fn = (functools.partial(flash_attention, causal=True, block_q=8,
                             block_k=8) if attention == "flash" else None)
     gauges = _module(fn).clone(d_model=d_model).build_gauges((2, 20), 4)
+    # a causal call says how a head walks its live range (PR 44): T 20 is
+    # three blocks of 8, the diagonal's three tiles and the three under it,
+    # the two digits of a query block's interior run on every one of three
+    walk = ({"flash_tiles_edge": 3, "flash_tiles_interior": 3,
+             "flash_cond_steps": 9} if attention == "flash" else {})
     assert gauges == {**{f"flash_calls_{k}": v for k, v in want.items()},
-                      "remat_saved_names": 0,
+                      **walk, "remat_saved_names": 0,
                       "remat_saved_bytes_per_layer": 0}
 
 

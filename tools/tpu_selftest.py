@@ -245,6 +245,61 @@ def flash_checks(toy: bool = False) -> list[dict]:
         window_case(4, 8192, 32, 4, 128, jnp.bfloat16, 512, 2048, 3e-2,
                     "flash_window_bf16_t8192_w2048_grouped")
 
+    def tile_probe(b, t, h, kv, d, block, window, causal, name):
+        """Microseconds an executed score tile of the forward and of the
+        three calls together, by the host's clock around whole calls (PR 44:
+        what a causal call's traversal costs beside the straight-line body of
+        a call that is not causal, at the trinity_mini cell's shape). On the
+        CPU nothing is timed: the detail holds the tile count alone."""
+        def run():
+            import time
+
+            from fl4health_tpu.kernels.flash_attention import live_tiles
+
+            ks = jax.random.split(jax.random.PRNGKey(3), 3)
+            q, k, v = (jax.random.normal(kk, (b, t, n, d), jnp.bfloat16)
+                       for kk, n in zip(ks, (h, kv, kv)))
+
+            def flash(q, k, v):
+                return flash_attention(q, k, v, None, block_q=block,
+                                       block_k=block, causal=causal,
+                                       window=window)
+
+            def loss(q, k, v):
+                o = flash(q, k, v).astype(jnp.float32)
+                return jnp.sum(o * o)
+
+            fwd = jax.jit(flash)
+            all3 = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            tiles = b * h * (live_tiles(t, block, block, window) if causal
+                             else (t // block) ** 2)
+            out = jax.block_until_ready((fwd(q, k, v), all3(q, k, v)))
+            finite = all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
+                         for x in jax.tree_util.tree_leaves(out))
+            if jax.default_backend() != "tpu":
+                return finite, f"tiles={tiles} (not timed off the chip)"
+
+            def ms(fn, n=10):
+                jax.block_until_ready(fn(q, k, v))
+                t0 = time.perf_counter()
+                jax.block_until_ready([fn(q, k, v) for _ in range(n)])
+                return (time.perf_counter() - t0) * 1e3 / n
+
+            f_ms, a_ms = ms(fwd), ms(all3)
+            return finite, (
+                f"tiles={tiles} fwd {f_ms:.2f} ms = {f_ms * 1e3 / tiles:.3f} "
+                f"us/tile; fwd+dq+dkv {a_ms:.2f} ms = "
+                f"{a_ms * 1e3 / tiles:.3f} us/tile")
+        checks.append(_check(name, run))
+
+    # the trinity_mini cell's calls three ways: every tile in one
+    # straight-line body, the causal triangle, the band of a 2,048 window
+    probe = ((2, 64, 4, 2, 128, blk, 24) if toy
+             else (4, 8192, 32, 4, 128, 512, 2048))
+    tile_probe(*probe[:6], None, False, "flash_tile_probe_not_causal")
+    tile_probe(*probe[:6], None, True, "flash_tile_probe_causal")
+    tile_probe(*probe, True, "flash_tile_probe_window")
+
     def rejected(name, t, d, dtype, block):
         """A request Mosaic cannot compile must fail in Python, naming the
         reason — checked with interpret=False so it runs anywhere."""
