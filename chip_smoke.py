@@ -26,7 +26,13 @@ default ``Observability()`` — at the full width of a model the repo builds
                  forms on the device (tools/tpu_selftest.py, in-process);
                  and the flash calls' microseconds an executed tile at the
                  trinity_mini cell's shape, not causal / causal / under the
-                 window (``flash_tile_probe_*``: a reading, not a limit)
+                 window (``flash_tile_probe_*``: a reading, not a limit);
+                 and how the routed layer's held rows travel at that cell's
+                 shape: device microseconds a held row of its forward and
+                 backward, whole and without the products, of one long
+                 gather and one long scatter-add (``routed_rows_probe_*``),
+                 and its combine (``kernels/row_combine.py``) against
+                 XLA's scatter-add to the bit
 5. cnn           CIFAR CNN, 64 clients, bf16: vmapped conv + donated stack
 6. mesh_*        only with >= 4 devices, under ``MeshConfig``: the encoder
                  (one client per chip, against the one-chip trajectory), the

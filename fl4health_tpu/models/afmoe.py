@@ -77,8 +77,9 @@ from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
                                                  swiglu)
 from fl4health_tpu.models.deepseek import (RopeScaling, apply_rope,
-                                           rope_tables, routed_layer,
-                                           sigmoid_route, swiglu_expert)
+                                           rope_tables, routed_gauges,
+                                           routed_layer, sigmoid_route,
+                                           swiglu_expert)
 from fl4health_tpu.observability.stages import layer as part
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -388,4 +389,7 @@ class AfmoeClassifier(nn.Module):
                 "moe_experts_held": self.experts_held,
                 "moe_router_width": self.n_routed_experts,
                 "moe_top_k": self.top_k,
+                **routed_gauges(n_clients * math.prod(batch_shape),
+                                self.top_k, self.experts_held,
+                                self.n_routed_experts),
                 **gauges}

@@ -30,11 +30,24 @@ which is what goes on to the next layer.
 
 The routed part is dropless and does work in proportion to the assignments
 (``routed_experts``): the (token, choice) pairs that chose a held expert are
-sorted by expert and each expert runs over its own rows in tiles of
-``TILE_ROWS``, as many as its count needs (a loop with a data-dependent trip
-count), gathering its rows and scatter-adding its weighted results. Nothing
-has a capacity: every pair could be local and the loops would run that many
-tiles. The held experts are a frozen base here: the function's VJP gives the
+sorted by expert (``_plan``: ONE permutation into expert order); each expert
+owns tiles of ``TILE_ROWS`` of its sorted rows, as many as its count needs,
+and walks them in chunks of ``_chunk_rows`` rows, as many chunks as its tiles
+need (a loop with a data-dependent trip count). A chunk moves its rows once
+in and once out: ONE gather ``x[tokens]`` into a contiguous buffer with a
+slot of ``TILE_ROWS`` rows a tile, then the expert's tiles in the chunk (a
+second data-dependent loop; the expert's matrices stay where the outer loop
+put them) on slices of that buffer, their weighted results written to the
+same slots of a second buffer, then ONE combine of the buffer into the
+float32 sum (``kernels/row_combine.py add_rows``: a Mosaic call of row copies
+where the rows are whole 128-lane tiles, the sum kept ``[N, d / 128, 128]``
+between the chunks; XLA's scatter-add for narrower rows), a row that is no
+tile's own skipped. No tile gathers from or adds into an ``[N, d]`` array;
+the backward does the same with ``x`` and ``dy`` gathered and ``dx``
+combined once a chunk. Nothing has a capacity: every pair could be local and
+the loops would run that many chunks and tiles. A token's choices are
+distinct experts (a top-k), so inside a chunk no token repeats. The
+held experts are a frozen base here: the function's VJP gives the
 gradients of the tokens and of the combine weights (through which the router's
 input trains upstream adapters) and NONE for the expert matrices. Because the
 experts carry no client axis, a ``vmap`` over clients is met by folding the
@@ -63,6 +76,7 @@ from flax import linen as nn
 
 from fl4health_tpu.core.pytree import merge_trees
 from fl4health_tpu.core.remat import named
+from fl4health_tpu.kernels import row_combine
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
                                                  swiglu)
@@ -72,6 +86,13 @@ from fl4health_tpu.observability.stages import layer as part
 # once per tile, so a tile should hold an expert's usual load whole (about 154
 # rows at 4,096 tokens, 8 of 160 experts held) and no more
 TILE_ROWS = 256
+# the most rows the routed layer gathers, runs one expert's tiles over and
+# combines at once (``_chunk_rows``): 16 tiles; at the widest rows in use
+# ([., 2,048] bfloat16 in, float32 out) such a chunk's rows and results are
+# 17 + 34 MB of scratch, the backward's rows, cotangents and gradients 17 + 34
+# + 34. A larger chunk saves nothing a row: the gather and the combine cost by
+# the row (0.03 and 0.05 microseconds on a v5e), not by the call
+CHUNK_ROWS = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,17 +313,40 @@ def _plan(idx, w, first: int, held: int):
     return order, tok, w_sorted, jnp.cumsum(counts) - counts, counts
 
 
-def _tile(t, start, count, tok, w_sorted):
-    """Tile ``t`` of the expert whose sorted rows are [start, start + count):
-    (position, tokens [TILE_ROWS], combine weights, which rows are its own)."""
-    pos = start + t * TILE_ROWS
-    rows = jax.lax.dynamic_slice(tok, (pos,), (TILE_ROWS,))
-    wt = jax.lax.dynamic_slice(w_sorted, (pos,), (TILE_ROWS,))
-    return pos, rows, wt, t * TILE_ROWS + jnp.arange(TILE_ROWS) < count
+def _chunk_rows(n: int) -> int:
+    """Rows a chunk of a call over ``n`` tokens holds: a sixteenth of the
+    tokens in whole tiles, at least one tile and at most ``CHUNK_ROWS``. A
+    chunk holds tiles of ONE expert, so it should hold an expert's usual
+    load whole and little more (the three cells' experts see 2,048 / 352 /
+    154 rows of 32,768 / 8,192 / 4,096 tokens at most: 8 / 2 / 1 tiles)."""
+    return min(CHUNK_ROWS, max(TILE_ROWS, n // 16 // TILE_ROWS * TILE_ROWS))
 
 
 def _tiles(count):
     return (count + TILE_ROWS - 1) // TILE_ROWS
+
+
+def _chunk(c, per, n, tok, w_sorted, start, count):
+    """Chunk ``c`` of the expert whose sorted rows are [start, start +
+    count): its tiles ``[c * per, (c + 1) * per)``, one slot of TILE_ROWS
+    rows each, a tile's rows what they always were (``start + t *
+    TILE_ROWS`` onwards): (how many of the slots hold a tile; each slot's
+    first sorted row; its rows' tokens [per, TILE_ROWS]; the same with every
+    row that is not the slot's own sent to ``n``, past every token, so that
+    the combine skips it; the rows' combine weights; which rows are a
+    slot's own)."""
+    t = c * per + jnp.arange(per)
+    own = jnp.clip(count - t * TILE_ROWS, 0, TILE_ROWS)
+    pos = jnp.where(own > 0, start + t * TILE_ROWS, 0)
+
+    def rows_of(a):
+        return jax.vmap(lambda p: jax.lax.dynamic_slice(a, (p,),
+                                                        (TILE_ROWS,)))(pos)
+
+    toks = rows_of(tok)
+    live = jnp.arange(TILE_ROWS)[None, :] < own[:, None]
+    return (jnp.clip(_tiles(count) - c * per, 0, per), pos, toks,
+            jnp.where(live, toks, n), rows_of(w_sorted), live)
 
 
 def _routed_fwd(first, body, n, x, idx, w, *experts):
@@ -310,44 +354,85 @@ def _routed_fwd(first, body, n, x, idx, w, *experts):
     ``body``'s order -> sum over the held experts chosen of w * body(x,
     *matrices), [N, d] float32."""
     held = len(experts) // n
+    tokens, d = x.shape
+    per = _chunk_rows(tokens) // TILE_ROWS
+    slab = row_combine.slab(d)
     _, tok, w_sorted, starts, counts = _plan(idx, w, first, held)
-    y = jnp.zeros(x.shape, F32)
+    carry = (jnp.zeros((tokens, *slab), F32),
+             jnp.zeros((per * TILE_ROWS, *slab), F32))
     for e in range(held):
-        def tile(t, y, e=e):
-            _, rows, wt, live = _tile(t, starts[e], counts[e], tok, w_sorted)
-            out = body(x[rows], *experts[n * e:n * e + n]).astype(F32)
-            return y.at[rows].add(
-                jnp.where(live[:, None], out * wt[:, None], 0.0))
+        def chunk(c, carry, e=e):
+            y, ys = carry
+            tiles, _, toks, live_toks, wc, live = _chunk(
+                c, per, tokens, tok, w_sorted, starts[e], counts[e])
+            xs = x[toks.reshape(-1)]
 
-        y = jax.lax.fori_loop(0, _tiles(counts[e]), tile, y)
-    return y
+            def tile(s, ys):
+                xt = jax.lax.dynamic_slice(xs, (s * TILE_ROWS, 0),
+                                           (TILE_ROWS, d))
+                out = body(xt, *experts[n * e:n * e + n]).astype(F32)
+                out = jnp.where(live[s][:, None], out * wc[s][:, None], 0.0)
+                return jax.lax.dynamic_update_slice(
+                    ys, out.reshape(TILE_ROWS, *slab),
+                    (s * TILE_ROWS,) + (0,) * len(slab))
+
+            ys = jax.lax.fori_loop(0, tiles, tile, ys)
+            return row_combine.add_rows(y, live_toks.reshape(-1), ys,
+                                        TILE_ROWS), ys
+
+        carry = jax.lax.fori_loop(0, (_tiles(counts[e]) + per - 1) // per,
+                                  chunk, carry)
+    return carry[0].reshape(tokens, d)
 
 
 def _routed_bwd(first, body, n, x, idx, w, dy, *experts):
     """(dx [N, d] float32, dw [N, K] float32) of ``_routed_fwd``: the same
-    tiles, each recomputing its expert's forward."""
+    chunks and tiles, each tile recomputing its expert's forward."""
     held = len(experts) // n
+    tokens, d = x.shape
+    per = _chunk_rows(tokens) // TILE_ROWS
+    slab = row_combine.slab(d)
     order, tok, w_sorted, starts, counts = _plan(idx, w, first, held)
-    dx, dw_sorted = jnp.zeros(x.shape, F32), jnp.zeros(w_sorted.shape, F32)
+    carry = (jnp.zeros((tokens, *slab), F32),
+             jnp.zeros((per * TILE_ROWS, *slab), F32),
+             jnp.zeros(w_sorted.shape, F32))
     for e in range(held):
-        def tile(t, carry, e=e):
-            dx, dw_sorted = carry
-            pos, rows, wt, live = _tile(t, starts[e], counts[e], tok,
-                                        w_sorted)
-            _, vjp = jax.vjp(
-                lambda xr, wr: body(xr, *experts[n * e:n * e + n]).astype(
-                    F32) * wr[:, None], x[rows], wt)
-            dxr, dwr = vjp(jnp.where(live[:, None], dy[rows], 0.0))
-            old = jax.lax.dynamic_slice(dw_sorted, (pos,), (TILE_ROWS,))
-            dw_sorted = jax.lax.dynamic_update_slice(
-                dw_sorted, jnp.where(live, dwr, old), (pos,))
-            return dx.at[rows].add(dxr.astype(F32)), dw_sorted
+        def chunk(c, carry, e=e):
+            dx, dxs, dw_sorted = carry
+            tiles, pos, toks, live_toks, wc, live = _chunk(
+                c, per, tokens, tok, w_sorted, starts[e], counts[e])
+            xs, dys = x[toks.reshape(-1)], dy[toks.reshape(-1)]
 
-        dx, dw_sorted = jax.lax.fori_loop(0, _tiles(counts[e]), tile,
-                                          (dx, dw_sorted))
+            def tile(s, carry):
+                dxs, dw_sorted = carry
+                at = (s * TILE_ROWS, 0)
+                _, vjp = jax.vjp(
+                    lambda xr, wr: body(xr, *experts[n * e:n * e + n]).astype(
+                        F32) * wr[:, None],
+                    jax.lax.dynamic_slice(xs, at, (TILE_ROWS, d)), wc[s])
+                dxr, dwr = vjp(jnp.where(
+                    live[s][:, None],
+                    jax.lax.dynamic_slice(dys, at, (TILE_ROWS, d)), 0.0))
+                old = jax.lax.dynamic_slice(dw_sorted, (pos[s],),
+                                            (TILE_ROWS,))
+                return (jax.lax.dynamic_update_slice(
+                    dxs, dxr.astype(F32).reshape(TILE_ROWS, *slab),
+                    (s * TILE_ROWS,) + (0,) * len(slab)),
+                        jax.lax.dynamic_update_slice(
+                            dw_sorted, jnp.where(live[s], dwr, old),
+                            (pos[s],)))
+
+            dxs, dw_sorted = jax.lax.fori_loop(0, tiles, tile,
+                                               (dxs, dw_sorted))
+            return (row_combine.add_rows(dx, live_toks.reshape(-1), dxs,
+                                         TILE_ROWS), dxs, dw_sorted)
+
+        carry = jax.lax.fori_loop(0, (_tiles(counts[e]) + per - 1) // per,
+                                  chunk, carry)
+    dx, _, dw_sorted = carry
     dw = jnp.zeros(order.shape, F32).at[order].set(
         dw_sorted[:order.shape[0]])
-    return dx, dw.reshape(w.shape)
+    return dx.reshape(tokens, d), dw.reshape(w.shape)
 
 
 def _fold_clients(fn, n_row_args: int):
@@ -401,7 +486,9 @@ def _routed_fn(first: int, body, n: int):
 def routed_experts(x, idx, w, experts, first_expert_held: int,
                    body=swiglu_expert):
     """sum_k [idx_k held here] * w_k * E_{idx_k}(x): x [N, d] in the compute
-    type, idx [N, K] over all the layer's experts, w [N, K] float32,
+    type, idx [N, K] over all the layer's experts (a token's K choices
+    distinct, as a top-k's are; -1 or any index held elsewhere picks
+    nothing here), w [N, K] float32,
     ``experts`` the held ones' matrices in order from ``first_expert_held``,
     each a tuple in the order ``body(rows, *matrices)`` takes them (``(gate
     [d, f], up [d, f], down [f, d])`` for ``swiglu_expert``). ``body`` is a
@@ -410,6 +497,18 @@ def routed_experts(x, idx, w, experts, first_expert_held: int,
     flat = [m for mats in experts for m in mats]
     return _routed_fn(int(first_expert_held), body, len(experts[0]))(
         x, idx, w, *flat)
+
+
+def routed_gauges(tokens: int, top_k: int, held: int, total: int) -> dict:
+    """How the held rows of a folded call over ``tokens`` tokens travel, for
+    a family's ``build_gauges``: the tile's and the chunk's rows, and the
+    long moves (one gather and one combine a chunk) a forward layer-pass
+    emits at the expected load, ``tokens * top_k / total`` rows an expert."""
+    size = _chunk_rows(tokens)
+    tiles = max(1, -(-(tokens * top_k // total) // TILE_ROWS))
+    return {"moe_tile_rows": TILE_ROWS, "moe_chunk_rows": size,
+            "moe_row_moves_per_pass":
+                2 * held * -(-tiles // (size // TILE_ROWS))}
 
 
 def routed_layer(x, u, router, experts, first_expert_held: int, rule,
@@ -623,5 +722,7 @@ class DeepseekV2Classifier(nn.Module):
                 # of every token could be a held expert
                 "moe_assignment_rows_bound":
                     tokens * min(self.top_k, self.experts_held),
+                **routed_gauges(tokens, self.top_k, self.experts_held,
+                                self.n_routed_experts),
                 **common.attention_gauges(self, batch_shape, n_clients,
                                           common.DEEPSEEK_REMAT_KEEPS)}

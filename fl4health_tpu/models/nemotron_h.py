@@ -67,6 +67,7 @@ rematerialised on its own under ``remat`` less the flash calls' ``out`` /
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -78,8 +79,8 @@ from fl4health_tpu.kernels.ssd_scan import (count_call_sites, n_chunks,
                                             ssd_scan)
 from fl4health_tpu.models import decoder_common as common
 from fl4health_tpu.models.decoder_common import F32, lora_dense, rms_norm
-from fl4health_tpu.models.deepseek import (relu2_expert, routed_layer,
-                                           sigmoid_route)
+from fl4health_tpu.models.deepseek import (relu2_expert, routed_gauges,
+                                           routed_layer, sigmoid_route)
 from fl4health_tpu.models.jamba import causal_depthwise_conv
 from fl4health_tpu.observability.stages import layer as part
 
@@ -412,6 +413,9 @@ class NemotronHClassifier(nn.Module):
                 "moe_experts_held": self.experts_held,
                 "moe_router_width": self.n_routed_experts,
                 "moe_top_k": self.top_k,
+                **routed_gauges(n_clients * math.prod(batch_shape),
+                                self.top_k, self.experts_held,
+                                self.n_routed_experts),
                 **common.attention_gauges(self, batch_shape, n_clients,
                                           common.NEMOTRON_REMAT_KEEPS,
                                           ssd_calls=count_call_sites)}

@@ -15,7 +15,9 @@ trinity_mini cell's (32 query heads of 128 over 4, T 8,192, a window of
 adapter cell's two selective-scan calls (4 clients x 2,048
 positions x 5,120 channels x 16 states), and the Nemotron-H cell's two
 chunked scalar-decay scan calls (4 clients x 2,048 positions x 128 heads of
-64 in 8 groups x state 128, chunks of 128). Nothing runs: the TPU's compiler
+64 in 8 groups x state 128, chunks of 128), and the routed layer's combine
+at the trinity_mini cell's (a chunk's 4,096 rows of 16 x 128 float32 lanes
+added into 32,768, ``kernels/row_combine.py``). Nothing runs: the TPU's compiler
 works against a described chip. The only file that describes a topology;
 the description happens inside a module-scoped fixture, never at import."""
 
@@ -28,6 +30,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks.harness.spec import load_module
+from fl4health_tpu.kernels import row_combine
 from fl4health_tpu.kernels import ssd_scan as ssd
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.kernels.selective_scan import (BLOCK_T, UNROLL,
@@ -451,3 +454,35 @@ def test_no_decay_tile_reaches_hbm(ssd_texts, program):
     holds several (its forward's are found by the same reader)."""
     assert _tile_arrays(ssd_texts[program]) == []
     assert _tile_arrays(ssd_texts["jnp forward"])
+
+
+def test_the_routed_layers_combine_compiles_for_the_v5e(one_chip):
+    """``add_rows`` at the window cell's shape: one row copy in and one out
+    an index, ``y`` updated in place (aliased: no second [32,768, 16, 128]
+    among the temporaries)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slab = row_combine.slab(2048)
+    assert slab == (16, 128)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda y, rows, updates: row_combine._add_rows_call(
+                y, rows, updates, block=256, interpret=False),
+            donate_argnums=0).lower(
+                arg((32768, *slab), jnp.float32), arg((4096,), jnp.int32),
+                arg((4096, *slab), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and re.search(r"%\w*add_rows[.\d]* = ", calls[0])
+    assert "f32[32768,16,128]" in calls[0].split(" = ")[1].split("(")[0]
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == 32768 * 2048 * 4
+    assert stats.temp_size_in_bytes < 1 << 20

@@ -335,6 +335,8 @@ def test_the_layers_read_grouped_heads_lane_indexed_under_the_window():
     assert gauges["flash_window"] == 2048
     assert (gauges["flash_window_tiles_live"],
             gauges["flash_window_tiles_causal"]) == (70, 136)
+    # 32,768 tokens in flight: the routed layer's chunks are 2,048 rows
+    assert gauges["moe_chunk_rows"] == 2048
     # how a head of the newest causal call, the last full layer's, walks
     # its live range: the diagonal's 16 tiles build the positional mask, 120
     # do not, and three block steps a query block sit behind a condition
@@ -352,6 +354,12 @@ def test_build_gauges_state_the_static_facts():
         "moe_experts_held", "moe_router_width", "moe_top_k", "flash_window")
     } == {"moe_experts_held": 8, "moe_router_width": 40, "moe_top_k": 6,
           "flash_window": 6}
+    # how the held rows travel (``deepseek.routed_gauges``): at 80 tokens a
+    # chunk is one tile and each of the 8 held experts has a chunk of its own
+    assert {k: gauges[k] for k in (
+        "moe_tile_rows", "moe_chunk_rows", "moe_row_moves_per_pass")} == {
+            "moe_tile_rows": 256, "moe_chunk_rows": 256,
+            "moe_row_moves_per_pass": 16}
     # runs [SS] [S] [F] [SS]: three sliding runs and a full one
     assert (gauges["flash_calls_window"], gauges["flash_calls_full"]) == (3, 1)
     # T 20 padded to 24 under blocks of 8, a window of 6: the diagonal tile

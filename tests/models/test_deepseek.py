@@ -344,6 +344,10 @@ def test_the_module_brings_its_own_split_and_cast(seeded):
     assert module.build_gauges((1, 20), 4) == {
         "moe_experts_held": 8, "moe_experts_total": 40,
         "moe_assignment_rows_bound": 4 * 20 * 6,
+        # 80 tokens: a chunk is one tile, and each of the 8 held experts
+        # has a chunk of its own: a gather and a combine a chunk
+        "moe_tile_rows": 256, "moe_chunk_rows": 256,
+        "moe_row_moves_per_pass": 16,
         "flash_calls_lane_indexed": 0, "flash_calls_transposed": 0,
         "remat_saved_names": 0, "remat_saved_bytes_per_layer": 0}
 

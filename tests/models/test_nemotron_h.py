@@ -328,6 +328,18 @@ def test_build_gauges_state_the_static_facts():
         "moe_top_k")} == {"ssd_chunks": 3, "ssd_heads": 4,
                           "moe_experts_held": 8, "moe_router_width": 40,
                           "moe_top_k": 6}
+    # how the held rows travel (``deepseek.routed_gauges``): at 80 tokens a
+    # chunk is one tile and each of the 8 held experts has a chunk of its
+    # own; at the hybrid cell's shape (4 x 2,048 tokens, the 22 best of 512
+    # with 16 held) 352 rows an expert are two tiles, one chunk of 512
+    assert {k: gauges[k] for k in (
+        "moe_tile_rows", "moe_chunk_rows", "moe_row_moves_per_pass")} == {
+            "moe_tile_rows": 256, "moe_chunk_rows": 256,
+            "moe_row_moves_per_pass": 16}
+    at_cell = _module(dict(CFG, router_width=512, n_routed_experts=16,
+                           num_experts_per_tok=22)).build_gauges((1, 2048), 4)
+    assert (at_cell["moe_chunk_rows"],
+            at_cell["moe_row_moves_per_pass"]) == (512, 32)
     # the attention block's run traces its call once; out and lse are kept
     assert gauges["flash_calls_lane_indexed"] == 1
     assert gauges["remat_saved_names"] == 2

@@ -1,5 +1,5 @@
 """Kernel agreement checks for the Pallas kernels (flash attention, DP clip,
-the chunked scalar-decay scan).
+the chunked scalar-decay scan, the routed layer's row combine).
 
 The kernels are interpret-mode validated by the CPU suite
 (tests/kernels/), but a Mosaic compile can fail or miscompute where
@@ -412,8 +412,161 @@ def ssd_scan_checks(toy: bool = False) -> list[dict]:
             case(jnp.bfloat16, 2e-2, "ssd_scan_bf16_t328_h%d" % heads)]
 
 
+def _device_us(fn, *args, n: int = 5, carry: bool = False) -> float:
+    """Microseconds one call of the jitted ``fn`` keeps the device busy: the
+    union of the ``XLA Ops`` events of ``n`` calls under ``jax.profiler``
+    (the device's clock, so a short op is not read as its dispatch).
+    ``carry``: the result is the next call's first argument (a donated
+    buffer updated in place)."""
+    import shutil
+    import tempfile
+
+    import jax
+
+    from benchmarks import trace_reduce
+
+    def call(args):
+        out = jax.block_until_ready(fn(*args))
+        return ((out, *args[1:]) if carry else args)
+
+    args = call(args)
+    where = tempfile.mkdtemp(prefix="routed_rows_probe_")
+    try:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 0
+        jax.profiler.start_trace(where, profiler_options=opts)
+        try:
+            for _ in range(n):
+                args = call(args)
+        finally:
+            jax.profiler.stop_trace()
+        trace = trace_reduce.load(trace_reduce.find_xplane(where))
+        return trace.busy_s() * 1e6 / n
+    finally:
+        shutil.rmtree(where, ignore_errors=True)
+
+
+def _rows_only(x, *matrices):
+    """An expert body without its products: what is left is the rows'
+    travel."""
+    return x
+
+
+def routed_rows_checks(toy: bool = False) -> list[dict]:
+    """How the routed layer's held rows travel, at the trinity_mini cell's
+    shape (``x [32,768, 2,048]`` bfloat16, the 8 best of 128 by a seeded
+    score, 16 held, a pad tail picking none): device microseconds a held row
+    of ``models/deepseek.py``'s forward and backward, whole and with the
+    body's products taken out, of ONE long gather and ONE long scatter-add
+    of as many rows (PR 45's step 0), and of the layer's own combine, the
+    Mosaic call ``kernels/row_combine.py add_rows``. Readings, not limits:
+    the checks fail only on a result that is not finite, a forward that
+    leaves the plain loop or a combine that is not XLA's scatter-add to the
+    bit. On the CPU nothing is timed."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from fl4health_tpu.kernels import row_combine
+    from fl4health_tpu.models import deepseek as ds
+
+    n, d, f, width, top_k, held = ((96, 16, 8, 16, 4, 4) if toy else
+                                   (32768, 2048, 1024, 128, 8, 16))
+    keys = iter(jax.random.split(jax.random.PRNGKey(45), 10 + 3 * held))
+    real = n - n // 4  # the tail of a seed's documents: pads pick no expert
+    idx = jax.lax.top_k(jax.random.uniform(next(keys), (n, width)), top_k)[1]
+    idx = jnp.where((jnp.arange(n) < real)[:, None], idx, -1).astype(
+        jnp.int32)
+    w = jax.random.uniform(next(keys), (n, top_k))
+    x = jax.random.normal(next(keys), (n, d), jnp.bfloat16)
+    dy = jax.random.normal(next(keys), (n, d))
+    experts = [tuple(jax.random.normal(next(keys), s, jnp.bfloat16) / 32
+                     for s in ((d, f), (d, f), (f, d))) for _ in range(held)]
+    flat = [m for mats in experts for m in mats]
+    rows = int(jnp.sum((idx >= 0) & (idx < held)))
+    timed = jax.default_backend() == "tpu"
+    checks = []
+
+    def reading(name, fn, *args, per=rows, carry=False):
+        def run():
+            out = jax.block_until_ready(fn(*args))
+            finite = all(bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+                         for a in jax.tree_util.tree_leaves(out))
+            if not timed:
+                return finite, f"rows={per} (not timed off the chip)"
+            us = _device_us(fn, *((out, *args[1:]) if carry else args),
+                            carry=carry)
+            return finite, (f"rows={per} {us / 1e3:.3f} ms = "
+                            f"{us / per:.4f} us/row")
+        checks.append(_check(name, run))
+
+    forward = {label: jax.jit(functools.partial(ds._routed_fwd, 0, body, 3))
+               for label, body in (("whole", ds.swiglu_expert),
+                                   ("rows_only", _rows_only))}
+    for label, body in (("whole", ds.swiglu_expert), ("rows_only",
+                                                      _rows_only)):
+        reading(f"routed_rows_probe_forward_{label}", forward[label], x, idx,
+                w, *flat)
+        reading(f"routed_rows_probe_backward_{label}", jax.jit(
+            functools.partial(ds._routed_bwd, 0, body, 3)), x, idx, w, dy,
+            *flat)
+
+    def agrees():
+        got = forward["whole"](x, idx, w, *flat)
+        want = jnp.zeros((n, d), jnp.float32)
+        for j, (gate, up, down) in enumerate(experts):
+            combine = jnp.sum(jnp.where(idx == j, w, 0.0), axis=1)
+            want = want + combine[:, None] * (
+                (jax.nn.silu(x @ gate) * (x @ up)) @ down).astype(jnp.float32)
+        return _rel_err(got, want) < 2e-2, f"rel_err={_rel_err(got, want):.2e}"
+    checks.append(_check("routed_rows_forward_agrees_with_plain_loop",
+                         agrees))
+
+    # ONE gather and ONE combine of a chunk's and of a pass's rows, the
+    # tokens as the plan leaves them: ascending inside an expert
+    _, tok, _, _, _ = ds._plan(idx, w, 0, held)
+    for count in sorted({min(rows, 4096), rows}):
+        t = tok[:count]
+        reading(f"routed_rows_probe_gather_bf16_{count}",
+                jax.jit(lambda x, t: x[t]), x, t, per=count)
+        reading(f"routed_rows_probe_gather_f32_{count}",
+                jax.jit(lambda a, t: a[t]), dy, t, per=count)
+        reading(f"routed_rows_probe_scatter_add_{count}",
+                jax.jit(lambda y, t, u: y.at[t].add(u), donate_argnums=0),
+                jnp.zeros((n, d), jnp.float32), t, dy[:count], per=count,
+                carry=True)
+
+    # the layer's own combine (``kernels/row_combine.py``): a chunk's rows,
+    # unique inside a tile, some dead, the same token free to come again in
+    # the next tile; against XLA's scatter-add, to the bit
+    tile = 8 if toy else ds.TILE_ROWS
+    count = 4 * tile if toy else 4096
+    slab = row_combine.slab(128 if toy else d)
+    t = jnp.concatenate([
+        jax.random.permutation(k, n)[:tile]
+        for k in jax.random.split(next(keys), count // tile)]).astype(
+            jnp.int32)
+    t = jnp.where(jnp.arange(count) % 5 == 0, n, t)
+    u = jax.random.normal(next(keys), (count, *slab))
+    combine = jax.jit(lambda y, t, u: row_combine.add_rows(y, t, u, tile),
+                      donate_argnums=0)
+
+    def same():
+        got = combine(jnp.ones((n, *slab), jnp.float32), t, u)
+        want = jnp.ones((n, *slab), jnp.float32).at[t].add(u, mode="drop")
+        return bool(jnp.array_equal(got, want)), (
+            f"largest gap {float(jnp.abs(got - want).max()):.1e}")
+    checks.append(_check("routed_rows_add_rows_is_a_scatter_add", same))
+    reading(f"routed_rows_probe_add_rows_{count}", combine,
+            jnp.zeros((n, *slab), jnp.float32), t, u, per=count, carry=True)
+    return checks
+
+
 def run_checks(toy: bool = False) -> list[dict]:
-    return flash_checks(toy) + dp_clip_checks(toy) + ssd_scan_checks(toy)
+    return (flash_checks(toy) + dp_clip_checks(toy) + ssd_scan_checks(toy)
+            + routed_rows_checks(toy))
 
 
 def main() -> int:
