@@ -19,7 +19,7 @@ W_k``, ``v = u W_v`` as ``n_kv_heads`` heads, ``g = u W_g`` [modeling:
 ``gate_proj``], none with a bias; ``q <- RMSNorm(q)``, ``k <- RMSNorm(k)``
 over each head's lanes with learned scales ``q_norm`` / ``k_norm``
 [modeling]; on a SLIDING layer only, rotary positions over the whole head
-(``rope_theta``, no scaling, the halves layout of ``deepseek.apply_rope``); a
+(``rope_theta``, no scaling, the halves layout of ``apply_rope``); a
 FULL layer applies no positions [modeling]. Scores ``q k^T / sqrt(head_dim)``,
 query head h over key head ``h // (n_heads / n_kv_heads)``; query i sees key
 j iff ``j <= i``, j is no pad, and on a sliding layer ``i - j <
@@ -35,11 +35,11 @@ width ``d_expert``; plus ONE shared SwiGLU of ``n_shared_experts *
 d_expert`` on every token. The module is told which experts it HOLDS
 (``experts_held`` from ``first_expert_held``: a chip's share under expert
 parallelism), routes over all of them and adds its own only: the routed part
-is ``models/deepseek.py routed_layer`` with ``deepseek.sigmoid_route`` (the
-rule ``models/nemotron_h.py`` has, at other numbers) and ``swiglu_expert``.
-A pad position picks no expert, as in ``nemotron_h.latent_moe`` and for its
-reason. ``load_balance_coeff`` is the training recipe's bias update: unused
-(router and bias are frozen).
+is ``models/routed.py routed_layer`` with ``sigmoid_route`` (the rule
+``models/nemotron_h.py`` has, at other numbers) and ``swiglu_expert``.
+A pad position picks no expert (``routed.no_pick_at_pads``).
+``load_balance_coeff`` is the training recipe's bias update: unused (router
+and bias are frozen).
 
 Then the final RMSNorm and HF's last-non-pad-token ``score`` head (token id
 0 is padding, at the tail). Left out: the output head.
@@ -51,38 +51,39 @@ expert's three matrices; ``score`` trains. Routed experts, the router,
 are frozen and unadapted: an adapter inside the routed path takes its
 gradient through a token's discrete picks (``models/nemotron_h.py``).
 
-Built the way the other adapter families are (a named parameter tree
-declared by a flax module, pure functions over one layer's dict,
-``per_client_param`` / ``bind_shared`` for the engine), on
-``models/decoder_common.py``. Layers alike in kind of attention AND in
-feed-forward that follow one another are one ``lax.scan`` (``runs()``: two
-periods of the published pattern with two leading dense layers are ``[SS]
-[S] [F] [SSS] [F]``), every layer rematerialised on its own under ``remat``
-less the flash calls' ``out`` / ``lse`` (``decoder_common.AFMOE_REMAT_KEEPS``).
+A family of ``decoder_common.DecoderStack``: it declares the kinds of its
+layers (kind of attention AND of feed-forward), their spec and ``layer``; the
+leaves, the forward, the split of the parameters and ``bind_shared`` are the
+stack's. Layers alike in kind that follow one another are one ``lax.scan``
+(``runs()``: two periods of the published pattern with two leading dense
+layers are ``[SS] [S] [F] [SSS] [F]``), every layer rematerialised on its own
+under ``remat`` less ``REMAT_KEEPS``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import itertools
+import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-from flax import linen as nn
 
-from fl4health_tpu.core.pytree import merge_trees
 from fl4health_tpu.models import decoder_common as common
-from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
+from fl4health_tpu.models.decoder_common import (F32, apply_rope, lora_dense,
+                                                 rms_norm, rope_tables,
                                                  swiglu)
-from fl4health_tpu.models.deepseek import (RopeScaling, apply_rope,
-                                           rope_tables, routed_gauges,
-                                           routed_layer, sigmoid_route,
-                                           swiglu_expert)
+from fl4health_tpu.models.routed import (check_share, held_kernels,
+                                         no_pick_at_pads, routed_gauges,
+                                         routed_layer, sigmoid_route,
+                                         swiglu_expert)
 from fl4health_tpu.observability.stages import layer as part
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# what a rematerialised layer keeps (core/remat.py): the flash calls' ``out``
+# / ``lse``, window and full attention alike
+REMAT_KEEPS = common.FLASH_SAVED
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,23 +154,15 @@ def gated_attention(p, u, pad_mask, window, rope, dims: AfmoeDims):
 
 def moe(p, u, pad_mask, dims: AfmoeDims):
     """The routed layer's part held here plus the shared expert. A pad
-    position picks no expert (``nemotron_h.latent_moe``: it lies behind the
-    last token anything reads, and a held expert among its picks would get
-    every pad position of the batch as rows)."""
+    position picks no expert (``routed.no_pick_at_pads``)."""
     dt = dims.dtype
     flat = u.reshape(-1, u.shape[-1])
-    live = pad_mask.reshape(-1, 1) > 0
-
-    def rule(router, x):
-        idx, w = sigmoid_route(router, x, dims.top_k, dims.route_scale)
-        # -1 is an expert held nowhere: the plan sorts such pairs behind
-        # every held expert's rows, into no tile
-        return jnp.where(live, idx, -1), w
-
+    rule = no_pick_at_pads(
+        lambda router, x: sigmoid_route(router, x, dims.top_k,
+                                        dims.route_scale), pad_mask)
     with part("moe"):
-        experts = [tuple(p[f"experts_{j}"][name]["kernel"].astype(dt)
-                         for name in ("gate_proj", "up_proj", "down_proj"))
-                   for j in range(dims.experts_held)]
+        experts = held_kernels(p, ("gate_proj", "up_proj", "down_proj"),
+                               dims.experts_held, dt)
         y = routed_layer(
             flat, flat,
             {"kernel": p["router"]["kernel"],
@@ -183,10 +176,7 @@ def moe(p, u, pad_mask, dims: AfmoeDims):
 def layer(p, h, pad_mask, kind: str, routed: bool, dims: AfmoeDims):
     eps, dt = dims.rms_eps, dims.dtype
     sliding = kind == SLIDING
-    # plain rotary embedding: ``rope_tables`` at factor 1 returns theta's
-    # own frequencies and multiplies the tables by exactly 1.0
-    rope = (rope_tables(h.shape[1], dims.head_dim,
-                        RopeScaling(theta=dims.rope_theta))
+    rope = (rope_tables(h.shape[1], dims.head_dim, dims.rope_theta)
             if sliding else None)
     u = rms_norm(h, p["input_layernorm"]["scale"], eps)
     a = gated_attention(p["self_attn"], u, pad_mask,
@@ -205,7 +195,7 @@ def layer(p, h, pad_mask, kind: str, routed: bool, dims: AfmoeDims):
 # The module
 # ---------------------------------------------------------------------------
 
-class AfmoeClassifier(nn.Module):
+class AfmoeClassifier(common.DecoderStack):
     """Input: integer token ids [B, T], id 0 = padding at the tail."""
 
     vocab_size: int
@@ -233,7 +223,18 @@ class AfmoeClassifier(nn.Module):
     remat: bool = False  # rematerialise each layer on the backward pass
     attention_fn: Any = None  # causal, grouped heads, ``window``; None = dense
 
-    # -- structure ----------------------------------------------------------
+    # -- what the stack reads (decoder_common.DecoderStack) ------------------
+    remat_keeps = REMAT_KEEPS
+    float32_kernels = ("router",)
+
+    @staticmethod
+    def block(p, h, pad_mask, kind: tuple, dims: AfmoeDims):
+        return layer(p, h, pad_mask, *kind, dims)
+
+    @property
+    def embed_scale(self) -> float:
+        return math.sqrt(self.d_model)
+
     @property
     def dims(self) -> AfmoeDims:
         if set(self.layer_types) - {SLIDING, FULL}:
@@ -242,12 +243,8 @@ class AfmoeClassifier(nn.Module):
         if self.n_heads % self.n_kv_heads or self.head_dim % 2:
             raise ValueError("query heads divide into key/value heads and "
                              "a head into two halves")
-        if not (0 <= self.first_expert_held and self.first_expert_held
-                + self.experts_held <= self.n_routed_experts):
-            raise ValueError(
-                f"experts {self.first_expert_held}.."
-                f"{self.first_expert_held + self.experts_held - 1} are not "
-                f"among the router's {self.n_routed_experts}")
+        check_share(self.first_expert_held, self.experts_held,
+                    self.n_routed_experts)
         return AfmoeDims(
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim,
             self.sliding_window, self.rope_theta, self.experts_held,
@@ -259,23 +256,16 @@ class AfmoeClassifier(nn.Module):
     def routed(self, i: int) -> bool:
         return i >= self.num_dense_layers
 
-    def runs(self) -> list[list[int]]:
-        """Layers that follow one another alike in kind of attention and in
-        feed-forward: one ``lax.scan`` each."""
-        alike = itertools.groupby(
-            range(len(self.layer_types)),
-            key=lambda i: (self.layer_types[i], self.routed(i)))
-        return [list(run) for _, run in alike]
+    def kinds(self) -> list[tuple[str, bool]]:
+        """(kind of attention, is the feed-forward routed) of each layer."""
+        return [(kind, self.routed(i))
+                for i, kind in enumerate(self.layer_types)]
 
-    def _layer_spec(self, routed: bool) -> tuple:
+    def spec(self, kind: tuple) -> tuple:
+        routed = kind[1]
         d, r, hd = self.d_model, self.lora_rank, self.head_dim
         proj, norm = common.proj_spec, common.norm_spec
-
-        def mlp(width, rank):
-            return (proj("gate_proj", d, width, rank),
-                    proj("up_proj", d, width, rank),
-                    proj("down_proj", width, d, rank))
-
+        mlp = functools.partial(common.swiglu_spec, d)
         attn = ("self_attn", (
             proj("q_proj", d, self.n_heads * hd, r),
             proj("k_proj", d, self.n_kv_heads * hd, r),
@@ -300,71 +290,6 @@ class AfmoeClassifier(nn.Module):
                 ("pre_mlp_layernorm", norm(d)), ("mlp", ffn),
                 ("post_mlp_layernorm", norm(d)))
 
-    # -- forward ------------------------------------------------------------
-    @nn.compact
-    def __call__(self, x, train: bool = True):
-        del train  # no dropout, no batch statistics
-        d = self.d_model
-        spec = [("embed_tokens", (("embedding", ((self.vocab_size, d),
-                                                 "embed")),)),
-                ("norm", common.norm_spec(d)),
-                ("score", (("kernel", ((d, self.n_classes), "matrix")),))]
-        spec += [(f"layers_{i}", self._layer_spec(self.routed(i)))
-                 for i in range(len(self.layer_types))]
-        params = {name: common.Leaves(entry, name=name)()
-                  for name, entry in spec}
-        return self.forward(common.stack_runs(params, self.runs()), x)
-
-    def forward(self, stacked, x):
-        """``stacked``: the tree with its layers stacked by
-        ``decoder_common.stack_runs`` over ``runs()``; each run is one
-        ``lax.scan``."""
-        dims = self.dims
-        pad_mask = (x > 0).astype(F32)
-        h = common.embed_tokens(
-            stacked["embed_tokens"]["embedding"], x, self.dtype,
-            scale=math.sqrt(self.d_model))
-        for k, run in enumerate(self.runs()):
-            kind, routed = self.layer_types[run[0]], self.routed(run[0])
-
-            def body(h_, p, kind=kind, routed=routed):
-                return layer(p, h_, pad_mask, kind, routed, dims).astype(
-                    self.dtype), None
-
-            # one remat site a layer: a site for each half of it (attention,
-            # feed-forward) made XLA's plan for the cell's round program
-            # LARGER (14.31 GiB of temporaries for 12.75: one more copy of
-            # the stream kept a layer, and no array recomputed later)
-            body = common.remat_layers(body, self.remat,
-                                       common.AFMOE_REMAT_KEEPS)
-            h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
-        return common.last_token_logits(
-            h, pad_mask, stacked["norm"]["scale"], stacked["score"]["kernel"],
-            self.rms_eps)
-
-    # -- the split of the parameters (clients/engine.py ModelDef) ----------
-    def per_client_param(self, path: str) -> bool:
-        return common.PER_CLIENT(path)
-
-    def prepare_shared(self, shared):
-        """The base in the form every client step of a round consumes: each
-        projection's and expert's ``kernel`` in the compute type (the
-        router's, ``expert_bias``, the norms and the embedding stay
-        float32), the layers stacked over their runs, each cast writing its
-        slice of the stack."""
-        return common.prepare_shared(
-            shared, self.runs(), self.dtype,
-            lambda names: names[-1] == "kernel" and names[-2] != "router")
-
-    def bind_shared(self, shared):
-        """``(per_client, x) -> (preds, features)`` over a base prepared
-        here, once a round."""
-        with part("shared_cast"):
-            prepared = self.prepare_shared(shared)
-        return lambda per_client, x: self.forward(
-            merge_trees(prepared, common.stack_runs(per_client, self.runs())),
-            x)
-
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
         """Static facts of the attention mix and the routed layer, which
         path the forward's flash calls take, how many of the traced calls
@@ -375,7 +300,7 @@ class AfmoeClassifier(nn.Module):
         for the simulation's build-time gauges; ``batch_shape`` is one
         client's [B, T]."""
         gauges = common.attention_gauges(self, batch_shape, n_clients,
-                                         common.AFMOE_REMAT_KEEPS)
+                                         self.remat_keeps)
         under_window = gauges.pop("flash_calls_window", 0)
         traced = gauges["flash_calls_lane_indexed"] + gauges[
             "flash_calls_transposed"]

@@ -19,20 +19,13 @@ TPU-native design. The parameters are an ordinary tree with stable names
 (``layers_<i>/mamba/in_proj/{kernel,lora_a,lora_b}`` ...; every adapted
 projection is ``W x + (alpha / r) * B^T (A^T x)`` as ``transformer.LoraDense``
 has it), declared by a flax module, and the mathematics is a set of pure
-functions over one layer's dict. Consecutive layers of one kind run as ONE
-``lax.scan`` over their stacked dicts (13 Mamba layers compile as two bodies,
-not thirteen), each layer rematerialised on the backward pass under
-``remat``, less the flash calls' ``out`` / ``lse``, which are kept
-(``decoder_common.JAMBA_REMAT_KEEPS``): the attention layer's recompute runs
-no flash forward. ``dtype`` is the compute type at float32 parameters.
-
-The module brings the split of its parameters with it
-(``per_client_param``: adapters and head per client, the base shared) and the
-forward over the two halves (``bind_shared``: the base's matrices cast to
-``dtype`` and stacked over each run of layers once a round, under the
-``fl_layer::shared_cast`` scope, then the forward over a client's own
-leaves), which ``clients/engine.from_flax`` hands to the engine: the base
-then exists once on the device however many clients train adapters over it.
+functions over one layer's dict. A family of ``decoder_common.DecoderStack``:
+it declares the kinds of its layers, their spec and ``layer``; the stack runs
+consecutive layers of one kind as ONE ``lax.scan`` over their stacked dicts
+(13 Mamba layers compile as two bodies, not thirteen), each layer
+rematerialised on the backward pass under ``remat`` less ``REMAT_KEEPS``, and
+brings the split of the parameters and ``bind_shared`` for the engine.
+``dtype`` is the compute type at float32 parameters.
 """
 
 from __future__ import annotations
@@ -42,18 +35,22 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from flax import linen as nn
 
-from fl4health_tpu.core.pytree import merge_trees
 from fl4health_tpu.kernels.selective_scan import selective_scan
 from fl4health_tpu.models import decoder_common as common
-from fl4health_tpu.models.decoder_common import (F32, lora_dense, rms_norm,
-                                                 swiglu)
+from fl4health_tpu.models.decoder_common import (F32, causal_depthwise_conv,
+                                                 lora_dense, rms_norm, swiglu)
 from fl4health_tpu.observability.stages import layer as part
 
 # projections that carry an adapter (the PEFT recipe of AI21's model card)
 ADAPTED = frozenset({"in_proj", "x_proj", "out_proj", "gate_proj", "up_proj",
                      "down_proj", "q_proj", "k_proj", "v_proj"})
+# What a rematerialised layer keeps (core/remat.py): the flash calls' ``out``
+# / ``lse``, per byte kept the dearest thing a layer would recompute (the
+# attention layer's recompute runs no flash forward). The mixers' stream and
+# the scan's residuals are not kept: thirteen layers of them want memory that
+# has to be freed first (ROADMAP S9).
+REMAT_KEEPS = common.FLASH_SAVED
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,14 +74,6 @@ class JambaDims:
 # ---------------------------------------------------------------------------
 # The mathematics: pure functions over one layer's parameter dict
 # ---------------------------------------------------------------------------
-
-def causal_depthwise_conv(p, x):
-    """y_t = sum_j kernel[j] * x_{t - (K - 1) + j} + bias per channel (HF
-    ``conv1d.weight[c, 0, j]`` is ``kernel[j, c]``), float32."""
-    width, t = p["kernel"].shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(F32), ((0, 0), (width - 1, 0), (0, 0)))
-    return sum(xp[:, j:j + t] * p["kernel"][j] for j in range(width)) + p["bias"]
-
 
 def mamba_mixer(p, u, dims: JambaDims):
     with part("mamba_mixer"):
@@ -153,7 +142,7 @@ def _proj_spec(name: str, n_in: int, n_out: int, rank: int):
     return common.proj_spec(name, n_in, n_out, rank if name in ADAPTED else 0)
 
 
-class JambaClassifier(nn.Module):
+class JambaClassifier(common.DecoderStack):
     """Input: integer token ids [B, T], id 0 = padding at the tail."""
 
     vocab_size: int
@@ -176,7 +165,12 @@ class JambaClassifier(nn.Module):
     remat: bool = False  # rematerialise each layer on the backward pass
     attention_fn: Any = None  # causal; None = the dense form
 
-    # -- structure ----------------------------------------------------------
+    # -- what the stack reads (decoder_common.DecoderStack) ------------------
+    final_norm = "final_layernorm"
+    remat_keeps = REMAT_KEEPS
+    float32_kernels = ("conv1d",)  # the conv's taps: an elementwise operand
+    block = staticmethod(layer)
+
     @property
     def dims(self) -> JambaDims:
         return JambaDims(
@@ -189,17 +183,10 @@ class JambaClassifier(nn.Module):
     def is_attention(self, i: int) -> bool:
         return i % self.attn_layer_period == self.attn_layer_offset
 
-    def runs(self) -> list[list[int]]:
-        """Consecutive layers of one kind: [[0..6], [7], [8..13]]."""
-        out: list[list[int]] = []
-        for i in range(self.n_layers):
-            if out and self.is_attention(out[-1][0]) == self.is_attention(i):
-                out[-1].append(i)
-            else:
-                out.append([i])
-        return out
+    def kinds(self) -> list[bool]:
+        return [self.is_attention(i) for i in range(self.n_layers)]
 
-    def _layer_spec(self, attention: bool) -> tuple:
+    def spec(self, attention: bool) -> tuple:
         d, r = self.d_model, self.lora_rank
         norm = common.norm_spec
         if attention:
@@ -228,74 +215,9 @@ class JambaClassifier(nn.Module):
                                   _proj_spec("up_proj", d, self.d_ff, r),
                                   _proj_spec("down_proj", self.d_ff, d, r))))
 
-    # -- forward ------------------------------------------------------------
-    @nn.compact
-    def __call__(self, x, train: bool = True):
-        del train  # no dropout, no batch statistics
-        d = self.d_model
-        spec = [("embed_tokens", (("embedding", ((self.vocab_size, d),
-                                                 "embed")),)),
-                ("final_layernorm", common.norm_spec(d)),
-                ("score", (("kernel", ((d, self.n_classes), "matrix")),))]
-        spec += [(f"layers_{i}", self._layer_spec(self.is_attention(i)))
-                 for i in range(self.n_layers)]
-        params = {name: common.Leaves(entry, name=name)()
-                  for name, entry in spec}
-        return self.forward(self.stack_runs(params), x)
-
-    def stack_runs(self, tree):
-        return common.stack_runs(tree, self.runs())
-
-    def forward(self, stacked, x):
-        """``stacked``: the tree with its layers stacked by ``stack_runs``.
-        Each run of layers is one ``lax.scan`` over its stack. (One scan over
-        all the Mamba layers with the attention layer under a ``lax.cond``
-        would compile one body fewer, but XLA then plans 13.1 GB of
-        temporaries for the round where this form takes 9.9: PR 27.)"""
-        dims = self.dims
-        pad_mask = (x > 0).astype(F32)
-        h = common.embed_tokens(stacked["embed_tokens"]["embedding"], x,
-                                self.dtype)
-        for k, run in enumerate(self.runs()):
-            attention = self.is_attention(run[0])
-
-            def body(h_, p, attention=attention):
-                return layer(p, h_, pad_mask, attention, dims).astype(
-                    self.dtype), None
-
-            body = common.remat_layers(body, self.remat,
-                                       common.JAMBA_REMAT_KEEPS)
-            h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
-        return common.last_token_logits(
-            h, pad_mask, stacked["final_layernorm"]["scale"],
-            stacked["score"]["kernel"], self.rms_eps)
-
-    # -- the split of the parameters (clients/engine.py ModelDef) ----------
-    def per_client_param(self, path: str) -> bool:
-        return common.PER_CLIENT(path)
-
-    def prepare_shared(self, shared):
-        """The base in the form every client step of a round consumes: each
-        projection's ``kernel`` in the compute type (the conv's taps, the
-        norms, ``A_log``, ``D`` and the embedding stay float32: elementwise
-        operands and a gather), the layers stacked over their runs, each
-        cast writing its slice of the stack."""
-        return common.prepare_shared(
-            shared, self.runs(), self.dtype,
-            lambda names: names[-1] == "kernel" and "conv1d" not in names)
-
-    def bind_shared(self, shared):
-        """``(per_client, x) -> (preds, features)`` over a base prepared
-        here, once: the client's own leaves are stacked at each call (they
-        are small)."""
-        with part("shared_cast"):
-            prepared = self.prepare_shared(shared)
-        return lambda per_client, x: self.forward(
-            merge_trees(prepared, self.stack_runs(per_client)), x)
-
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
         """Which path the forward's flash calls take and what the remat
         sites keep, for the simulation's build-time gauges; ``batch_shape``
         is one client's [B, T]."""
         return common.attention_gauges(self, batch_shape, n_clients,
-                                       common.JAMBA_REMAT_KEEPS)
+                                       self.remat_keeps)

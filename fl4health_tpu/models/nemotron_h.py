@@ -34,8 +34,8 @@ mixer_i(RMSNorm(h))``, the kind of block ``i`` read from character ``i`` of
   on the full width). The module is told which experts it HOLDS
   (``experts_held`` from ``first_expert_held``: a chip's share under expert
   parallelism), routes over all of them and adds its own only; the routed
-  part is ``models/deepseek.py routed_layer`` with this family's scoring
-  rule (``deepseek.sigmoid_route``) and expert body (``relu2_expert``).
+  part is ``models/routed.py routed_layer`` with the sigmoid scoring rule
+  (``sigmoid_route``) and this family's expert body (``relu2_expert``).
 
 Then the final RMSNorm and HF's last-non-pad-token ``score`` head (token id
 0 is padding, at the tail). Left out: the multi-token-prediction block, the
@@ -53,15 +53,14 @@ leaf's first update where every other leaf agreed to 6 % (``PERF.md``
 section 6, PR 41). Routed experts, routers, norms, the conv,
 ``A_log`` / ``D`` / ``dt_bias`` and the embedding are frozen as well.
 
-Built the way ``models/jamba.py`` and ``models/deepseek.py`` are (a named
-parameter tree declared by a flax module, pure functions over one block's
-dict, ``per_client_param`` / ``bind_shared`` for the engine), on
-``models/decoder_common.py``. The published pattern alternates, so a run of
-LIKE blocks would be one block long; what repeats is a unit (``ME ME ME``):
+A family of ``decoder_common.DecoderStack``: it declares the kinds of its
+blocks (the pattern's characters), their spec and ``block``; the leaves, the
+forward, the split of the parameters and ``bind_shared`` are the stack's. The
+published pattern alternates, so a run of LIKE blocks would be one block
+long; what repeats is a unit (``ME ME ME``): at ``MAX_UNIT`` 2 the stack's
 ``runs()`` cuts the pattern into units of one or two blocks that repeat, and
 each run is one ``lax.scan`` over its stacked units, every block
-rematerialised on its own under ``remat`` less the flash calls' ``out`` /
-``lse`` (``decoder_common.NEMOTRON_REMAT_KEEPS``).
+rematerialised on its own under ``remat`` less ``REMAT_KEEPS``.
 """
 
 from __future__ import annotations
@@ -72,21 +71,26 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from flax import linen as nn
 
-from fl4health_tpu.core.pytree import merge_trees
 from fl4health_tpu.kernels.ssd_scan import (count_call_sites, n_chunks,
                                             ssd_scan)
 from fl4health_tpu.models import decoder_common as common
-from fl4health_tpu.models.decoder_common import F32, lora_dense, rms_norm
-from fl4health_tpu.models.deepseek import (relu2_expert, routed_gauges,
-                                           routed_layer, sigmoid_route)
-from fl4health_tpu.models.jamba import causal_depthwise_conv
+from fl4health_tpu.models.decoder_common import (F32, causal_depthwise_conv,
+                                                 lora_dense, rms_norm)
+from fl4health_tpu.models.routed import (check_share, held_kernels,
+                                         no_pick_at_pads, relu2_expert,
+                                         routed_gauges, routed_layer,
+                                         sigmoid_route)
 from fl4health_tpu.observability.stages import layer as part
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 # the longest unit of unlike blocks that ``runs()`` looks for a repeat of
 MAX_UNIT = 2
+# What a rematerialised block keeps (core/remat.py). A block is one mixer:
+# the attention block keeps the flash calls' pair; a Mamba-2 or an expert
+# block keeps nothing (the chunked scan's chunk states are 4 MB a sequence
+# and chunk, its decay tiles far more)
+REMAT_KEEPS = common.FLASH_SAVED
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,27 +176,17 @@ def relu2_mlp(p, u, dims: NemotronHDims):
 
 def latent_moe(p, u, pad_mask, dims: NemotronHDims):
     """The routed layer's part held here, through the latent, plus the
-    shared expert. A pad position picks no expert here: it lies behind the
-    last token anything reads (every mixer is causal), its stream settles
-    to one vector whose picks are all alike, and a held expert among them
-    would get every pad position of the batch as rows: the tiles follow the
-    tokens, not the draw's padding."""
-    dt = dims.dtype
+    shared expert. A pad position picks no expert
+    (``routed.no_pick_at_pads``)."""
     flat = u.reshape(-1, u.shape[-1])
-    live = pad_mask.reshape(-1, 1) > 0
-
-    def rule(router, x):
-        idx, w = sigmoid_route(router, x, dims.top_k, dims.routed_scale)
-        # -1 is an expert held nowhere: the plan sorts such pairs behind
-        # every held expert's rows, into no tile
-        return jnp.where(live, idx, -1), w
-
+    rule = no_pick_at_pads(
+        lambda router, x: sigmoid_route(router, x, dims.top_k,
+                                        dims.routed_scale), pad_mask)
     with part("moe"):
         with part("moe_latent"):
             latent = lora_dense(p["fc1_latent_proj"], flat, dims)
-        experts = [tuple(p[f"experts_{j}"][name]["kernel"].astype(dt)
-                         for name in ("up_proj", "down_proj"))
-                   for j in range(dims.experts_held)]
+        experts = held_kernels(p, ("up_proj", "down_proj"),
+                               dims.experts_held, dims.dtype)
         y = routed_layer(
             latent, flat, p["gate"], experts, dims.first_expert_held, rule,
             relu2_expert)
@@ -212,35 +206,11 @@ def block(p, h, pad_mask, kind: str, dims: NemotronHDims):
     return h + latent_moe(p["mixer"], u, pad_mask, dims)
 
 
-def pattern_runs(pattern: str) -> list[list[tuple[int, ...]]]:
-    """The pattern cut into runs that one ``lax.scan`` each covers: a run is
-    a unit of one or ``MAX_UNIT`` blocks and its immediate repeats, a trip a
-    unit (a tuple of block indices). Greedy from the left, the unit that
-    covers most. ``MEMEMEM*EME`` -> ``[(0, 1), (2, 3), (4, 5)]``, ``[(6,)]``,
-    ``[(7,)]``, ``[(8,)]``, ``[(9,)]``, ``[(10,)]``."""
-    runs, i = [], 0
-    while i < len(pattern):
-        best = (1, 1)
-        for size in range(1, MAX_UNIT + 1):
-            unit, reps = pattern[i:i + size], 1
-            while pattern[i + reps * size:i + (reps + 1) * size] == unit:
-                reps += 1
-            # a longer unit has to repeat to be worth a body of its own
-            if len(unit) == size and (size == 1 or reps > 1) and (
-                    size * reps > best[0] * best[1]):
-                best = (size, reps)
-        size, reps = best
-        runs.append([tuple(range(i + k * size, i + (k + 1) * size))
-                     for k in range(reps)])
-        i += size * reps
-    return runs
-
-
 # ---------------------------------------------------------------------------
 # The module
 # ---------------------------------------------------------------------------
 
-class NemotronHClassifier(nn.Module):
+class NemotronHClassifier(common.DecoderStack):
     """Input: integer token ids [B, T], id 0 = padding at the tail."""
 
     vocab_size: int
@@ -271,7 +241,13 @@ class NemotronHClassifier(nn.Module):
     remat: bool = False  # rematerialise each block on the backward pass
     attention_fn: Any = None  # causal, grouped key/value heads; None = dense
 
-    # -- structure ----------------------------------------------------------
+    # -- what the stack reads (decoder_common.DecoderStack) ------------------
+    max_unit = MAX_UNIT
+    final_norm = "norm_f"
+    remat_keeps = REMAT_KEEPS
+    float32_kernels = ("gate", "conv1d")  # the router's, the conv's taps
+    block = staticmethod(block)
+
     @property
     def dims(self) -> NemotronHDims:
         if set(self.pattern) - {MAMBA, EXPERTS, ATTENTION}:
@@ -280,12 +256,8 @@ class NemotronHClassifier(nn.Module):
         if self.n_heads % self.n_kv_heads or self.ssm_heads % self.ssm_groups:
             raise ValueError("query heads divide into key/value heads and "
                              "state-space heads into groups")
-        if not (0 <= self.first_expert_held and self.first_expert_held
-                + self.experts_held <= self.n_routed_experts):
-            raise ValueError(
-                f"experts {self.first_expert_held}.."
-                f"{self.first_expert_held + self.experts_held - 1} are not "
-                f"among the router's {self.n_routed_experts}")
+        check_share(self.first_expert_held, self.experts_held,
+                    self.n_routed_experts)
         return NemotronHDims(
             self.d_model, self.n_heads, self.n_kv_heads, self.head_dim,
             self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
@@ -295,10 +267,10 @@ class NemotronHClassifier(nn.Module):
             self.lora_alpha / self.lora_rank if self.lora_rank else 0.0,
             self.dtype, self.attention_fn)
 
-    def runs(self) -> list[list[tuple[int, ...]]]:
-        return pattern_runs(self.pattern)
+    def kinds(self) -> str:
+        return self.pattern
 
-    def _block_spec(self, kind: str) -> tuple:
+    def spec(self, kind: str) -> tuple:
         d, r = self.d_model, self.lora_rank
         proj, norm = common.proj_spec, common.norm_spec
         if kind == MAMBA:
@@ -338,70 +310,6 @@ class NemotronHClassifier(nn.Module):
                                     proj("down_proj", self.d_shared, d, r))))
         return ("norm", norm(d)), ("mixer", mixer)
 
-    # -- forward ------------------------------------------------------------
-    @nn.compact
-    def __call__(self, x, train: bool = True):
-        del train  # no dropout, no batch statistics
-        d = self.d_model
-        spec = [("embed_tokens", (("embedding", ((self.vocab_size, d),
-                                                 "embed")),)),
-                ("norm_f", common.norm_spec(d)),
-                ("score", (("kernel", ((d, self.n_classes), "matrix")),))]
-        spec += [(f"layers_{i}", self._block_spec(kind))
-                 for i, kind in enumerate(self.pattern)]
-        params = {name: common.Leaves(entry, name=name)()
-                  for name, entry in spec}
-        return self.forward(common.stack_runs(params, self.runs()), x)
-
-    def forward(self, stacked, x):
-        """``stacked``: the tree with its blocks stacked by
-        ``decoder_common.stack_runs`` over ``runs()``; each run is one
-        ``lax.scan``, a trip one unit of the pattern."""
-        dims = self.dims
-        pad_mask = (x > 0).astype(F32)
-        h = common.embed_tokens(stacked["embed_tokens"]["embedding"], x,
-                                self.dtype)
-        for k, run in enumerate(self.runs()):
-            kinds = [self.pattern[i] for i in run[0]]
-
-            def body(h_, unit, kinds=kinds):
-                for j, kind in enumerate(kinds):
-                    one = common.remat_layers(
-                        lambda h__, p, kind=kind: block(
-                            p, h__, pad_mask, kind, dims).astype(self.dtype),
-                        self.remat, common.NEMOTRON_REMAT_KEEPS)
-                    h_ = one(h_, unit[str(j)])
-                return h_, None
-
-            h, _ = jax.lax.scan(body, h, stacked["runs"][str(k)])
-        return common.last_token_logits(
-            h, pad_mask, stacked["norm_f"]["scale"],
-            stacked["score"]["kernel"], self.rms_eps)
-
-    # -- the split of the parameters (clients/engine.py ModelDef) ----------
-    def per_client_param(self, path: str) -> bool:
-        return common.PER_CLIENT(path)
-
-    def prepare_shared(self, shared):
-        """The base in the form every client step of a round consumes: each
-        projection's and expert's ``kernel`` in the compute type (the
-        router's, the conv's taps, the norms, ``A_log`` / ``D`` /
-        ``dt_bias`` and the embedding stay float32), the blocks stacked over
-        their runs, each cast writing its slice of the stack."""
-        return common.prepare_shared(
-            shared, self.runs(), self.dtype,
-            lambda names: names[-1] == "kernel"
-            and names[-2] not in ("gate", "conv1d"))
-
-    def bind_shared(self, shared):
-        """``(per_client, x) -> (preds, features)`` over a base prepared
-        here, once a round."""
-        with part("shared_cast"):
-            prepared = self.prepare_shared(shared)
-        return lambda per_client, x: self.forward(
-            merge_trees(prepared, common.stack_runs(per_client, self.runs())),
-            x)
-
     def build_gauges(self, batch_shape, n_clients: int) -> dict:
         """Static facts of the state-space and routed blocks, which path the
         forward's flash calls and chunked scans take (``ssd_calls_fused`` /
@@ -417,5 +325,5 @@ class NemotronHClassifier(nn.Module):
                                 self.top_k, self.experts_held,
                                 self.n_routed_experts),
                 **common.attention_gauges(self, batch_shape, n_clients,
-                                          common.NEMOTRON_REMAT_KEEPS,
+                                          self.remat_keeps,
                                           ssd_calls=count_call_sites)}

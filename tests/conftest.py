@@ -164,6 +164,22 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip_slow)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Drop JAX's compiled programs after each test module. A loaded XLA:CPU
+    executable holds memory mappings of its own (one eager gradient through
+    the routed layer leaves some 900), JAX keeps every program a process has
+    compiled, and Linux gives a process 65,530 mappings
+    (``vm.max_map_count``). A tier-1 worker that had run a few of the model
+    files stood at that limit, and the next mapping XLA asked for was a
+    segmentation fault: reading a cache entry (PR 45's run), writing one,
+    compiling (PR 46's runs), always in the same late test of a long file.
+    ``tests/models/test_afmoe.py`` alone maps some 45,000: a module is the
+    unit that has to stay under the limit."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture
 def eight_devices():
     devs = jax.devices()

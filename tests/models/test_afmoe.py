@@ -22,7 +22,8 @@ from fl4health_tpu.clients import engine
 from fl4health_tpu.core import pytree as ptu
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.models import afmoe
-from fl4health_tpu.models import deepseek
+from fl4health_tpu.models import decoder_common as common
+from fl4health_tpu.models import deepseek, routed
 from tests.models.remat_probe import eqns
 
 REF = load_module("reference", "afmoe_classifier")
@@ -157,8 +158,7 @@ def test_gated_attention_matches_the_reference(seeded, layer, attention):
     p, tree_p, u, mask = _attention_inputs(seeded, layer)
     module = _module(attention_fn=FLASH if attention == "flash" else None)
     dims, kind = module.dims, CFG["layer_types"][layer]
-    rope = (deepseek.rope_tables(20, 128, deepseek.RopeScaling(theta=1e4))
-            if kind == S else None)
+    rope = common.rope_tables(20, 128, 1e4) if kind == S else None
     with jax.default_matmul_precision("highest"):
         got = afmoe.gated_attention(tree_p, u, mask,
                                     6 if kind == S else None, rope, dims)
@@ -176,7 +176,7 @@ def test_what_the_attention_tolerance_refuses(seeded, fault):
     layer = 3 if fault == "positions on a full layer" else 0
     p, tree_p, u, mask = _attention_inputs(seeded, layer)
     dims, kind = _module().dims, CFG["layer_types"][layer]
-    rope = deepseek.rope_tables(20, 128, deepseek.RopeScaling(theta=1e4))
+    rope = common.rope_tables(20, 128, 1e4)
     window = None if kind == F else 6
     if fault == "a window one position short":
         window = 5
@@ -219,16 +219,20 @@ def test_rotary_positions_on_the_sliding_layers_only(seeded):
 
 
 def test_plain_rotary_tables_are_theta_alone():
-    """``deepseek.rope_tables`` at factor 1 is the plain rotary embedding
-    exactly: theta's own frequencies, times 1.0."""
-    cos, sin = deepseek.rope_tables(20, 128, deepseek.RopeScaling(theta=1e4))
+    """``decoder_common.rope_tables`` is the plain rotary embedding, and
+    ``deepseek.rope_tables`` at factor 1 is it exactly: theta's own
+    frequencies, times 1.0."""
+    cos, sin = common.rope_tables(20, 128, 1e4)
+    for a, b in zip((cos, sin), deepseek.rope_tables(
+            20, 128, deepseek.RopeScaling(theta=1e4))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     inv = np.asarray([1e4 ** (-2.0 * i / 128) for i in range(64)], np.float32)
     ang = np.arange(20, dtype=np.float32)[:, None] * inv[None, :]
     np.testing.assert_array_equal(np.asarray(cos), np.asarray(jnp.cos(ang)))
     np.testing.assert_array_equal(np.asarray(sin), np.asarray(jnp.sin(ang)))
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 20, 2, 128))
     np.testing.assert_allclose(
-        np.asarray(deepseek.apply_rope(x, cos, sin)),
+        np.asarray(common.apply_rope(x, cos, sin)),
         np.asarray(REF._rotary(x, 1e4)), atol=1e-6)
 
 
@@ -241,7 +245,7 @@ def test_router_picks_what_the_reference_picks(seeded):
     mlp = tree["layers_2"]["mlp"]
     assert float(jnp.abs(mlp["expert_bias"]).max()) > 0.01
     u = jax.random.normal(jax.random.PRNGKey(3), (64, 32))
-    idx, w = deepseek.sigmoid_route(
+    idx, w = routed.sigmoid_route(
         {"kernel": mlp["router"]["kernel"],
          "e_score_correction_bias": mlp["expert_bias"]}, u, 6, 2.826)
     want = np.asarray(REF.route(_layer_leaves(flat, 2), u,
@@ -354,7 +358,7 @@ def test_build_gauges_state_the_static_facts():
         "moe_experts_held", "moe_router_width", "moe_top_k", "flash_window")
     } == {"moe_experts_held": 8, "moe_router_width": 40, "moe_top_k": 6,
           "flash_window": 6}
-    # how the held rows travel (``deepseek.routed_gauges``): at 80 tokens a
+    # how the held rows travel (``routed.routed_gauges``): at 80 tokens a
     # chunk is one tile and each of the 8 held experts has a chunk of its own
     assert {k: gauges[k] for k in (
         "moe_tile_rows", "moe_chunk_rows", "moe_row_moves_per_pass")} == {

@@ -21,6 +21,7 @@ from fl4health_tpu.core import pytree as ptu
 from fl4health_tpu.core import remat as remat_names
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.models import deepseek as ds
+from fl4health_tpu.models import routed
 from fl4health_tpu.models.decoder_common import rms_norm, swiglu
 from tests.models.remat_probe import eqns, pallas_calls, products_with
 
@@ -319,18 +320,8 @@ def test_the_module_brings_its_own_split_and_cast(seeded):
         "lora_a", "lora_b", "kernel"}
     assert [k for k in build.flatten(per_client) if k.endswith("kernel")] == [
         "score/kernel"]
-    captured = {}
-
-    def spy(shared_, runs, dtype, is_matrix):
-        captured["tree"] = real(shared_, runs, dtype, is_matrix)
-        return captured["tree"]
-
-    real = ds.common.prepare_shared
-    ds.common.prepare_shared = spy
-    try:
-        got = module.bind_shared(shared)(per_client, x)[0]["prediction"]
-    finally:
-        ds.common.prepare_shared = real
+    captured = {"tree": module.prepare_shared(shared)}
+    got = module.bind_shared(shared)(per_client, x)[0]["prediction"]
     for k, v in build.flatten(captured["tree"]).items():
         matrix = k.endswith("/kernel") and "/gate/" not in k
         assert v.dtype == (jnp.bfloat16 if matrix else jnp.float32), k
@@ -390,7 +381,7 @@ def test_twenty_shares_of_eight_experts_add_up_to_the_uncut_layer():
         experts = [tuple(tree["mlp"][f"experts_{8 * share + j}"][name]["kernel"]
                          for name in ("gate_proj", "up_proj", "down_proj"))
                    for j in range(8)]
-        total = total + ds.routed_experts(
+        total = total + routed.routed_experts(
             flat_u, idx, w, experts, dims.first_expert_held).reshape(h.shape)
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -442,18 +433,19 @@ def test_dropless_when_every_choice_lands_on_a_held_expert():
     idx = jnp.asarray(idx, jnp.int32)
     w = jax.random.uniform(jax.random.PRNGKey(2), (n, k), minval=0.2)
     x = jax.random.normal(jax.random.PRNGKey(3), (n, 16))
-    order, tok, _, starts, counts = ds._plan(idx, w, first, held)
-    assert int(counts.sum()) == n * k and int(counts[0]) == n > ds.TILE_ROWS
-    assert int(counts[-1]) == 0 and tok.shape == (n * k + ds.TILE_ROWS,)
+    order, tok, _, starts, counts = routed._plan(idx, w, first, held)
+    assert int(counts.sum()) == n * k
+    assert int(counts[0]) == n > routed.TILE_ROWS
+    assert int(counts[-1]) == 0 and tok.shape == (n * k + routed.TILE_ROWS,)
     np.testing.assert_array_equal(np.asarray(starts),
                                   np.cumsum(counts) - np.asarray(counts))
-    got = ds.routed_experts(x, idx, w, experts, first)
+    got = routed.routed_experts(x, idx, w, experts, first)
     want = _dense_routed(x, idx, w, experts, first)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
     # every token got all six of its experts: none of them reads zero
     assert float(jnp.abs(got).sum(axis=1).min()) > 0
-    gx, gw = jax.grad(lambda x, w: jnp.sum(jnp.sin(ds.routed_experts(
+    gx, gw = jax.grad(lambda x, w: jnp.sum(jnp.sin(routed.routed_experts(
         x, idx, w, experts, first))), argnums=(0, 1))(x, w)
     wx, ww = jax.grad(lambda x, w: jnp.sum(jnp.sin(_dense_routed(
         x, idx, w, experts, first))), argnums=(0, 1))(x, w)
@@ -472,13 +464,13 @@ def test_choices_held_elsewhere_add_nothing_and_get_no_gradient():
     x = jax.random.normal(jax.random.PRNGKey(6), (n, 16))
     local = (idx >= first) & (idx < first + held)
     assert 0 < int(local.sum()) < n * k
-    got = ds.routed_experts(x, idx, w, experts, first)
+    got = routed.routed_experts(x, idx, w, experts, first)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(_dense_routed(x, idx, w, experts, first)),
         atol=2e-5, rtol=2e-5)
     none_local = ~local.any(axis=1)
     assert none_local.any() and float(jnp.abs(got[none_local]).max()) == 0.0
-    gw = jax.grad(lambda w: jnp.sum(ds.routed_experts(x, idx, w, experts,
+    gw = jax.grad(lambda w: jnp.sum(routed.routed_experts(x, idx, w, experts,
                                                       first)))(w)
     assert float(jnp.abs(jnp.where(local, 0.0, gw)).max()) == 0.0
     assert float(jnp.abs(jnp.where(local, gw, 1.0)).min()) > 0.0
@@ -496,7 +488,7 @@ def test_the_folded_client_axis_is_vmap_of_the_per_client_form():
     x = jax.random.normal(jax.random.PRNGKey(9), (c, n, 16))
 
     def one(x, idx, w):
-        return ds.routed_experts(x, idx, w, experts, first)
+        return routed.routed_experts(x, idx, w, experts, first)
 
     def value_and_grads(x, idx, w):
         return one(x, idx, w), jax.grad(
@@ -515,9 +507,9 @@ def test_the_folded_client_axis_is_vmap_of_the_per_client_form():
     # experts that do carry the axis get the plain vmap
     per_client_experts = [tuple(jnp.stack([m, 2 * m, 3 * m]) for m in e)
                           for e in experts]
-    got = jax.vmap(lambda x, idx, w, e: ds.routed_experts(x, idx, w, e, first)
-                   )(x, idx, w, per_client_experts)
-    want = jnp.stack([ds.routed_experts(
+    got = jax.vmap(lambda x, idx, w, e: routed.routed_experts(
+        x, idx, w, e, first))(x, idx, w, per_client_experts)
+    want = jnp.stack([routed.routed_experts(
         x[i], idx[i], w[i], [tuple((i + 1) * m for m in e) for e in experts],
         first) for i in range(c)])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,

@@ -22,6 +22,7 @@ from fl4health_tpu.core import pytree as ptu
 from fl4health_tpu.kernels.flash_attention import flash_attention
 from fl4health_tpu.kernels.ssd_scan import ssd_scan, ssd_scan_xla
 from fl4health_tpu.models import nemotron_h as nh
+from fl4health_tpu.models.decoder_common import pattern_runs
 from tests.models.remat_probe import eqns
 
 REF = load_module("reference", "nemotron_h_classifier")
@@ -117,7 +118,7 @@ def test_the_pattern_is_cut_into_units_that_repeat(pattern, want):
     """A run is one ``lax.scan``: like blocks, or a unit of two unlike ones
     and its repeats (the published pattern alternates); every block is in
     exactly one run, in order."""
-    runs = nh.pattern_runs(pattern)
+    runs = pattern_runs(pattern, nh.MAX_UNIT)
     assert runs == want
     assert [i for run in runs for unit in run for i in unit] == list(
         range(len(pattern)))
@@ -328,7 +329,7 @@ def test_build_gauges_state_the_static_facts():
         "moe_top_k")} == {"ssd_chunks": 3, "ssd_heads": 4,
                           "moe_experts_held": 8, "moe_router_width": 40,
                           "moe_top_k": 6}
-    # how the held rows travel (``deepseek.routed_gauges``): at 80 tokens a
+    # how the held rows travel (``routed.routed_gauges``): at 80 tokens a
     # chunk is one tile and each of the 8 held experts has a chunk of its
     # own; at the hybrid cell's shape (4 x 2,048 tokens, the 22 best of 512
     # with 16 held) 352 rows an expert are two tiles, one chunk of 512
@@ -500,7 +501,7 @@ def test_a_pad_position_picks_no_expert_and_the_tokens_are_untouched():
 
 
 def test_relu2_experts_square_after_the_relu():
-    from fl4health_tpu.models.deepseek import relu2_expert
+    from fl4health_tpu.models.routed import relu2_expert
 
     x = jnp.asarray([[1.0, -2.0]])
     up = jnp.asarray([[1.0, 1.0], [0.5, -1.0]])

@@ -1,4 +1,4 @@
-"""``models/deepseek.py routed_layer``: ONE implementation of the plan, the
+"""``models/routed.py routed_layer``: ONE implementation of the plan, the
 tiles, the client fold and the frozen-expert VJP under both families'
 scoring rules (softmax, group-limited, unnormalised / sigmoid, a selection
 bias, renormalised and scaled) and both expert bodies (a SwiGLU triple / two
@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from fl4health_tpu.models import deepseek as ds
+from fl4health_tpu.models import routed as rt
 from fl4health_tpu.models import nemotron_h as nh
 
 N, D_ROUTER, D_ROWS, F, WIDTH, HELD, FIRST, TOP_K = 48, 12, 8, 10, 16, 6, 4, 5
@@ -32,8 +33,8 @@ def _sigmoid_rule():
 
 RULES = {"softmax, group-limited, unnormalised": _softmax_rule,
          "sigmoid, selection bias, renormalised": _sigmoid_rule}
-BODIES = {"swiglu (three matrices)": (ds.swiglu_expert, 3),
-          "relu2 (two matrices)": (ds.relu2_expert, 2)}
+BODIES = {"swiglu (three matrices)": (rt.swiglu_expert, 3),
+          "relu2 (two matrices)": (rt.relu2_expert, 2)}
 
 
 def _plain_body(name, x, mats):
@@ -61,7 +62,7 @@ def test_both_rules_and_both_bodies_through_one_implementation(rule, body):
     x = jax.random.normal(next(keys), (N, D_ROWS))
 
     def program(x, u):
-        return ds.routed_layer(x, u, router, experts, FIRST, score, fn)
+        return rt.routed_layer(x, u, router, experts, FIRST, score, fn)
 
     def plain(x, u):
         idx, w = score(router, u)
@@ -94,7 +95,7 @@ def test_the_client_fold_is_vmaps_mathematics_for_either_body(body):
     x = jax.random.normal(next(keys), (3, N, D_ROWS))
     idx = jax.random.randint(next(keys), (3, N, TOP_K), 0, WIDTH)
     w = jax.random.uniform(next(keys), (3, N, TOP_K))
-    one = functools.partial(ds.routed_experts, experts=experts,
+    one = functools.partial(rt.routed_experts, experts=experts,
                             first_expert_held=FIRST, body=fn)
     got = jax.vmap(lambda x, i, w: one(x, i, w))(x, idx, w)
     want = jnp.stack([one(x[c], idx[c], w[c]) for c in range(3)])
@@ -111,8 +112,8 @@ def test_the_experts_get_no_gradient_whatever_their_body():
     x = jax.random.normal(next(keys), (N, D_ROWS))
     idx = jax.random.randint(next(keys), (N, 2), 0, 2)
     w = jnp.ones((N, 2))
-    grads = jax.grad(lambda e: jnp.sum(ds.routed_experts(
-        x, idx, w, e, 0, ds.relu2_expert)))(experts)
+    grads = jax.grad(lambda e: jnp.sum(rt.routed_experts(
+        x, idx, w, e, 0, rt.relu2_expert)))(experts)
     assert all(float(jnp.abs(m).max()) == 0.0 for pair in grads for m in pair)
 
 
@@ -134,7 +135,7 @@ def test_the_sigmoid_rule_at_eight_of_128_with_a_share_and_pads():
     live = (jnp.arange(N) < N - 7)[:, None]
 
     def rule(router, u):
-        idx, w = ds.sigmoid_route(router, u, top_k, 2.826)
+        idx, w = rt.sigmoid_route(router, u, top_k, 2.826)
         return jnp.where(live, idx, -1), w
 
     def plain(x):
@@ -147,8 +148,8 @@ def test_the_sigmoid_rule_at_eight_of_128_with_a_share_and_pads():
         return y
 
     def program(x):
-        return ds.routed_layer(x[:, :D_ROWS], x, router, experts, first, rule,
-                               ds.swiglu_expert)
+        return rt.routed_layer(x[:, :D_ROWS], x, router, experts, first, rule,
+                               rt.swiglu_expert)
 
     with jax.default_matmul_precision("highest"):
         idx, w = rule(router, u)
@@ -182,11 +183,11 @@ def small_chunks(monkeypatch):
     """Tiles of 4 rows and chunks of 16 (four tiles), so that an expert's
     share of 256 tokens is several chunks; the cached ``custom_vjp`` closes over nothing
     of the constants, but clear it on both sides all the same."""
-    monkeypatch.setattr(ds, "TILE_ROWS", TILE)
-    monkeypatch.setattr(ds, "CHUNK_ROWS", CHUNK)
-    ds._routed_fn.cache_clear()
+    monkeypatch.setattr(rt, "TILE_ROWS", TILE)
+    monkeypatch.setattr(rt, "CHUNK_ROWS", CHUNK)
+    rt._routed_fn.cache_clear()
     yield
-    ds._routed_fn.cache_clear()
+    rt._routed_fn.cache_clear()
 
 
 def _held_everywhere(idx):
@@ -234,7 +235,7 @@ def test_chunks_of_the_tiles_against_the_plain_loop(
         return picks(idx), w
 
     def program(x, u):
-        return ds.routed_layer(x, u, router, experts, first, routed, fn)
+        return rt.routed_layer(x, u, router, experts, first, routed, fn)
 
     def plain(x, u):
         idx, w = routed(router, u)
@@ -245,9 +246,9 @@ def test_chunks_of_the_tiles_against_the_plain_loop(
         return y
 
     idx, w = routed(router, u)
-    _, _, _, starts, counts = ds._plan(idx, w, first, held)
+    _, _, _, starts, counts = rt._plan(idx, w, first, held)
     pairs = int(counts.sum())
-    assert ds._chunk_rows(MANY) == CHUNK
+    assert rt._chunk_rows(MANY) == CHUNK
     if load == "no pair held":
         assert pairs == 0
     elif load == "every pair held":
@@ -290,7 +291,7 @@ def test_the_client_fold_over_many_chunks(small_chunks, body):
                       axis=-1)[..., :TOP_K].astype(jnp.int32)
     w = jax.random.uniform(next(keys), (3, MANY, TOP_K))
     cot = jax.random.normal(next(keys), (3, MANY, D_ROWS))
-    one = functools.partial(ds.routed_experts, experts=experts,
+    one = functools.partial(rt.routed_experts, experts=experts,
                             first_expert_held=FIRST, body=fn)
 
     def folded(x, w):
@@ -299,7 +300,7 @@ def test_the_client_fold_over_many_chunks(small_chunks, body):
     def looped(x, w):
         return jnp.stack([one(x[c], idx[c], w[c]) for c in range(3)])
 
-    assert ds._chunk_rows(3 * MANY) == CHUNK
+    assert rt._chunk_rows(3 * MANY) == CHUNK
     with jax.default_matmul_precision("highest"):
         got, vjp = jax.vjp(folded, x, w)
         want, want_vjp = jax.vjp(looped, x, w)
@@ -354,9 +355,9 @@ def test_no_tile_moves_a_row_of_the_callers_arrays(body, width):
         return e.primitive.name.startswith("scatter") and e.invars[
             0].aval.shape[0] == n and e.invars[0].aval.ndim > 1
 
-    fwd = jax.make_jaxpr(functools.partial(ds._routed_fwd, 0, fn, n_mats))(
+    fwd = jax.make_jaxpr(functools.partial(rt._routed_fwd, 0, fn, n_mats))(
         x, idx, w, *flat).jaxpr
-    bwd = jax.make_jaxpr(functools.partial(ds._routed_bwd, 0, fn, n_mats))(
+    bwd = jax.make_jaxpr(functools.partial(rt._routed_bwd, 0, fn, n_mats))(
         x, idx, w, jnp.ones((n, d)), *flat).jaxpr
     # three held experts: a chunk loop each
     assert _loops_around(fwd, gathers) == [1] * 3
@@ -375,15 +376,15 @@ def test_the_gauges_of_how_rows_travel():
     """A chunk is a sixteenth of the tokens in whole tiles, between one tile
     and ``CHUNK_ROWS``; the moves are a gather and a combine a chunk, and an
     expert's expected load is one chunk in each of the three cells."""
-    assert (ds.TILE_ROWS, ds.CHUNK_ROWS) == (256, 4096)
-    assert [ds._chunk_rows(n) for n in (48, 4096, 8192, 32768, 10 ** 6)
+    assert (rt.TILE_ROWS, rt.CHUNK_ROWS) == (256, 4096)
+    assert [rt._chunk_rows(n) for n in (48, 4096, 8192, 32768, 10 ** 6)
             ] == [256, 256, 512, 2048, 4096]
     # window cell: 2,048 rows an expert are eight tiles, one chunk
-    assert ds.routed_gauges(32768, 8, 16, 128) == {
+    assert rt.routed_gauges(32768, 8, 16, 128) == {
         "moe_tile_rows": 256, "moe_chunk_rows": 2048,
         "moe_row_moves_per_pass": 32}
     # hybrid: 352 rows are two tiles, one chunk; expert: 153 rows one tile
-    assert ds.routed_gauges(8192, 22, 16, 512)["moe_row_moves_per_pass"] == 32
-    assert ds.routed_gauges(4096, 6, 8, 160)["moe_row_moves_per_pass"] == 16
+    assert rt.routed_gauges(8192, 22, 16, 512)["moe_row_moves_per_pass"] == 32
+    assert rt.routed_gauges(4096, 6, 8, 160)["moe_row_moves_per_pass"] == 16
     # an expert that sees twice its chunk takes two
-    assert ds.routed_gauges(32768, 8, 16, 64)["moe_row_moves_per_pass"] == 64
+    assert rt.routed_gauges(32768, 8, 16, 64)["moe_row_moves_per_pass"] == 64

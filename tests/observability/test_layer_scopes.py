@@ -85,7 +85,7 @@ def _op_names(lowered):
     start at the program. A stack that starts further in is left out here.
     Some are the ops inside a reduction's own computation, which are no ops
     of a trace. Others ARE: the routed layer's ``custom_vmap`` rule
-    (``models/deepseek.py _fold_clients``) is traced in a name stack of its
+    (``models/routed.py _fold_clients``) is traced in a name stack of its
     own, so its sort, scatter-adds and tile loops keep ``fl_layer::moe`` and
     lose ``fl_stage::local_train`` (PERF.md section 7: the expert cell's
     ``unstaged_device_pct``)."""

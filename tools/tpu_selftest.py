@@ -457,7 +457,7 @@ def routed_rows_checks(toy: bool = False) -> list[dict]:
     """How the routed layer's held rows travel, at the trinity_mini cell's
     shape (``x [32,768, 2,048]`` bfloat16, the 8 best of 128 by a seeded
     score, 16 held, a pad tail picking none): device microseconds a held row
-    of ``models/deepseek.py``'s forward and backward, whole and with the
+    of ``models/routed.py``'s forward and backward, whole and with the
     body's products taken out, of ONE long gather and ONE long scatter-add
     of as many rows (PR 45's step 0), and of the layer's own combine, the
     Mosaic call ``kernels/row_combine.py add_rows``. Readings, not limits:
@@ -470,7 +470,7 @@ def routed_rows_checks(toy: bool = False) -> list[dict]:
     import jax.numpy as jnp
 
     from fl4health_tpu.kernels import row_combine
-    from fl4health_tpu.models import deepseek as ds
+    from fl4health_tpu.models import routed
 
     n, d, f, width, top_k, held = ((96, 16, 8, 16, 4, 4) if toy else
                                    (32768, 2048, 1024, 128, 8, 16))
@@ -502,15 +502,15 @@ def routed_rows_checks(toy: bool = False) -> list[dict]:
                             f"{us / per:.4f} us/row")
         checks.append(_check(name, run))
 
-    forward = {label: jax.jit(functools.partial(ds._routed_fwd, 0, body, 3))
-               for label, body in (("whole", ds.swiglu_expert),
+    forward = {label: jax.jit(functools.partial(routed._routed_fwd, 0, body, 3))
+               for label, body in (("whole", routed.swiglu_expert),
                                    ("rows_only", _rows_only))}
-    for label, body in (("whole", ds.swiglu_expert), ("rows_only",
+    for label, body in (("whole", routed.swiglu_expert), ("rows_only",
                                                       _rows_only)):
         reading(f"routed_rows_probe_forward_{label}", forward[label], x, idx,
                 w, *flat)
         reading(f"routed_rows_probe_backward_{label}", jax.jit(
-            functools.partial(ds._routed_bwd, 0, body, 3)), x, idx, w, dy,
+            functools.partial(routed._routed_bwd, 0, body, 3)), x, idx, w, dy,
             *flat)
 
     def agrees():
@@ -526,7 +526,7 @@ def routed_rows_checks(toy: bool = False) -> list[dict]:
 
     # ONE gather and ONE combine of a chunk's and of a pass's rows, the
     # tokens as the plan leaves them: ascending inside an expert
-    _, tok, _, _, _ = ds._plan(idx, w, 0, held)
+    _, tok, _, _, _ = routed._plan(idx, w, 0, held)
     for count in sorted({min(rows, 4096), rows}):
         t = tok[:count]
         reading(f"routed_rows_probe_gather_bf16_{count}",
@@ -541,7 +541,7 @@ def routed_rows_checks(toy: bool = False) -> list[dict]:
     # the layer's own combine (``kernels/row_combine.py``): a chunk's rows,
     # unique inside a tile, some dead, the same token free to come again in
     # the next tile; against XLA's scatter-add, to the bit
-    tile = 8 if toy else ds.TILE_ROWS
+    tile = 8 if toy else routed.TILE_ROWS
     count = 4 * tile if toy else 4096
     slab = row_combine.slab(128 if toy else d)
     t = jnp.concatenate([
